@@ -56,6 +56,16 @@ def _compile_pool():
             max_workers=1, thread_name_prefix="coreth-compile")
     return _COMPILE_POOL
 
+
+
+def wait_warm_compiles() -> None:
+    """Block until every pre-warm compile queued so far has finished
+    (the pool is one FIFO worker) — for callers that count compiles
+    per phase."""
+    if _COMPILE_POOL is not None:
+        _COMPILE_POOL.submit(lambda: None).result()
+
+
 WORD_ZERO = b"\x00" * 32
 
 # Per-runner cap on specialized programs compiled into one OCC kernel:
@@ -826,6 +836,7 @@ class MachineWindowRunner:
         self.premap_array = 0       # keys derived via array recipes
         self.discovery_dispatches = 0  # re-dispatches for missed keys
         self.kernel_retraces = 0    # mid-run compiles at dispatch time
+        self.warm_failures = 0      # background pre-warms that raised
         self.lanes_specialized = 0  # lanes run on a traced sub-program
         self.specialize_escapes = 0  # lanes kept on the generic kernel
         self.programs_traced = 0    # contracts compiled to sub-programs
@@ -1416,7 +1427,7 @@ class MachineWindowRunner:
         fn = self._get_kernel(p, occ)
         _count_dispatch()
         with obs.jax_span("coreth/occ_window"):
-            out = fn(table, key_tab, inputs)
+            out = self._dispatch(fn, table, key_tab, inputs)
         # the input table was donated into the dispatch; the output
         # handle (post-window committed state) replaces it
         self.table = out["table"]
@@ -1425,6 +1436,19 @@ class MachineWindowRunner:
         return dict(out=out, items=items, discovered=discovered, p=p,
                     occ=occ, premaps=premaps, predicted=predicted,
                     attempt=attempt)
+
+    def _dispatch(self, fn, *args):
+        """Run the window kernel.  The value table is DONATED into the
+        call, so when the call raises, the handle this runner still
+        holds may already be consumed: mark the device table stale, and
+        a supervised retry (BackendSupervisor.run re-invokes issue())
+        rebuilds it from the host mirror instead of handing the kernel
+        a deleted buffer."""
+        try:
+            return fn(*args)
+        except Exception:  # noqa: BLE001 — bookkeeping only; re-raised
+            self._stale = True
+            raise
 
     # ------------------------------------------------------------ kernels
     def seed_window_hint(self, blocks: int) -> None:
@@ -1481,7 +1505,7 @@ class MachineWindowRunner:
             try:
                 fut.result()
             except Exception:  # noqa: BLE001 — warm compile is advisory; the dispatch below compiles synchronously if it failed
-                pass
+                self.warm_failures += 1
         return self._kernel(p, occ)
 
     def _lane_count(self, p: M.MachineParams) -> int:

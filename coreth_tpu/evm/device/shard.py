@@ -77,6 +77,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from coreth_tpu import faults, obs
@@ -90,7 +91,7 @@ from coreth_tpu.evm.device.adapter import (
 from coreth_tpu.evm.device.specialize import KDIG_CAP
 from coreth_tpu.ops import u256
 from coreth_tpu.parallel import (
-    _shard_map, account_bucket, collective_reduce, contract_bucket,
+    account_bucket, collective_reduce, contract_bucket,
     exchange_mode, slot_bucket,
 )
 
@@ -180,7 +181,7 @@ def build_sharded_occ_machine(params: M.MachineParams, occ: M.OccParams,
         def run(table, key_tab, blocks_in):
             return inner(table, key_tab, blocks_in)
 
-        return _shard_map(
+        return shard_map(
             run, mesh=mesh,
             in_specs=(PS("dp"), PS("dp"), specs),
             out_specs={"table": PS("dp"), "packed": PS(None, "dp")},
@@ -235,7 +236,7 @@ def build_sharded_occ_machine(params: M.MachineParams, occ: M.OccParams,
         tab, packed = jax.lax.scan(body, table, xs)
         return {"table": tab, "packed": packed}
 
-    return _shard_map(
+    return shard_map(
         run_kr, mesh=mesh,
         in_specs=(PS("dp"), PS("dp"), specs, PS()),
         out_specs={"table": PS("dp"), "packed": PS(None, "dp")},
@@ -255,10 +256,9 @@ def get_sharded_occ_machine(params: M.MachineParams, occ: M.OccParams,
     key = (params, occ, _mesh_key(mesh), spec, xchg, mode)
     fn = _OCC_SHARDED.get(key)
     if fn is None:
-        donate = () if jax.default_backend() == "cpu" else (0,)
         fn = jax.jit(build_sharded_occ_machine(params, occ, mesh, spec,
                                                xchg, mode),
-                     donate_argnums=donate)
+                     donate_argnums=(0,))
         _OCC_SHARDED[key] = fn
         M.count_occ_build()
     return fn
@@ -284,7 +284,7 @@ def get_shard_exchange(mesh, mode: str = "psum"):
                                esc_l.astype(jnp.int32)], axis=1)
             return collective_reduce(flags, "dp", n, mode, op="add")
 
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(shard_map(
             ex, mesh=mesh,
             in_specs=(PS(None, "dp"), PS(None, "dp")),
             out_specs=PS(), check_vma=False))
@@ -1009,9 +1009,9 @@ class ShardedWindowRunner(MachineWindowRunner):
             faults.fire(PT_KEY_EXCHANGE)
         with obs.jax_span("coreth/shard_occ_window"):
             if rows_j is None:
-                out = fn(table, key_tab, inputs)
+                out = self._dispatch(fn, table, key_tab, inputs)
             else:
-                out = fn(table, key_tab, inputs, rows_j)
+                out = self._dispatch(fn, table, key_tab, inputs, rows_j)
         self.table = out["table"]
         self._dispatched += 1
         # the exchange rides the same device queue, right behind the

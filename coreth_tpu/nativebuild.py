@@ -88,6 +88,26 @@ def build(sanitize: bool = False, tsan: bool = False,
     return os.path.exists(lib_path(sanitize, tsan))
 
 
+def rebuild(timeout: int = 600) -> str:
+    """Rebuild the production library from ``native/*.cc``
+    unconditionally (``make -B``) and return its path; raises with the
+    compiler's output when the build fails.  For runs that must not
+    load whatever ``.so`` happens to sit on disk — a library copied in
+    from another machine, or a stale one kept by :func:`ensure_built`
+    after a failed rebuild — nor carry on without one on the
+    pure-Python paths.  Call before the first ``crypto.native.load()``:
+    a loaded handle is cached for the process."""
+    proc = subprocess.run(["make", "-B", "-C", NATIVE_DIR],
+                          capture_output=True, text=True,
+                          timeout=timeout)
+    path = lib_path()
+    if proc.returncode != 0 or not os.path.exists(path):
+        raise RuntimeError(
+            f"native build failed (rc={proc.returncode}):\n"
+            + (proc.stderr or proc.stdout)[-2000:])
+    return path
+
+
 def stale(path: str, sanitize: bool = False, tsan: bool = False) -> bool:
     """True when any C++ source or the Makefile is newer than the
     built library at ``path``."""

@@ -136,8 +136,8 @@ def keccak_f1600(state):
     state: uint32 array (..., 25, 2); returns the same shape.  The 24
     rounds run under lax.fori_loop with the round constants indexed from
     a baked array — the graph is one round body, so CPU compile stays in
-    seconds (round 1 unrolled 24 rounds x 25 scalar lanes and took ~10
-    minutes to compile; VERDICT.md weak#4)."""
+    seconds (unrolling 24 rounds x 25 scalar lanes took ~10 minutes
+    to compile)."""
     lo = state[..., 0]
     hi = state[..., 1]
     rc_lo = jnp.asarray(_RC_LO)

@@ -8,7 +8,6 @@ per-account reductions cross shards with psum_scatter.
 """
 
 from coreth_tpu.parallel.mesh import (  # noqa: F401
-    _shard_map,
     collective_reduce,
     make_mesh,
     sharded_recover,

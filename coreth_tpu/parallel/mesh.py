@@ -18,36 +18,14 @@ array.
 
 from __future__ import annotations
 
-import inspect
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from coreth_tpu.ops import u256
-
-# `check_vma` landed well after the shard_map API stabilized; the
-# installed JAX may predate it (ROADMAP open item: 3 tier-1 failures on
-# older runtimes).  Passing it unconditionally would TypeError at
-# module import, so feature-detect once and drop the kwarg when absent.
-_SHARD_MAP_KWARGS = frozenset(
-    inspect.signature(shard_map).parameters)
-
-
-def _shard_map(fn, **kwargs):
-    if "check_vma" not in _SHARD_MAP_KWARGS:
-        v = kwargs.pop("check_vma", None)
-        if v is not None and "check_rep" in _SHARD_MAP_KWARGS:
-            # older jax spells the same knob check_rep; without the
-            # translation a body containing lax.while_loop trips "No
-            # replication rule for while"
-            kwargs["check_rep"] = v
-    return shard_map(fn, **kwargs)
 
 
 def make_mesh(devices=None, axis: str = "dp") -> Mesh:
@@ -152,7 +130,7 @@ def sharded_transfer_step(mesh: Mesh, num_accounts: int):
     spec_acc1 = PS("dp")
     spec_tx2 = PS("dp", None)
     spec_tx1 = PS("dp")
-    sharded = _shard_map(
+    sharded = shard_map(
         step, mesh=mesh,
         in_specs=(spec_acc2, spec_acc1, spec_tx1, spec_tx1, spec_tx2,
                   spec_tx2, spec_tx2, spec_tx1, spec_tx1, spec_tx1, PS()),
@@ -190,7 +168,7 @@ def sharded_slot_step(mesh: Mesh, num_slots: int):
         new_vals = u256.sub(u256.add(slot_vals, credit_tot), debit_tot)
         return new_vals, ok
 
-    sharded = _shard_map(
+    sharded = shard_map(
         step, mesh=mesh,
         in_specs=(PS("dp", None), PS("dp"), PS("dp"), PS("dp", None),
                   PS("dp")),
@@ -213,7 +191,7 @@ def sharded_recover(mesh: Mesh):
             x_bytes.astype(jnp.uint8), parity.astype(jnp.int32),
             u1w.astype(jnp.int32), u2w.astype(jnp.int32))
 
-    sharded = _shard_map(
+    sharded = shard_map(
         step, mesh=mesh,
         in_specs=(PS("dp", None), PS("dp"), PS("dp", None),
                   PS("dp", None)),

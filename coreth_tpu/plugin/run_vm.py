@@ -17,9 +17,11 @@ import threading
 
 
 def main(path: str, start_time: int = 1_000) -> None:
+    from coreth_tpu import compile_cache
     from coreth_tpu.plugin import VM
     from coreth_tpu.plugin.service import serve
 
+    compile_cache.configure()
     clock = itertools.count(start_time, 10).__next__
     vm = VM(clock=clock)
     server = serve(vm, path)

@@ -42,10 +42,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from coreth_tpu.ops import u256
-from coreth_tpu.parallel import _shard_map
 
 
 # jitted window kernels memoized per (mesh, exchange mode): rebuilding
@@ -179,7 +179,7 @@ def _build_window(mesh, mode: str = "psum"):
         return nb, nn, nsv, fetches
 
     tab2, tab1 = PS("dp", None), PS("dp")
-    sharded = _shard_map(
+    sharded = shard_map(
         window, mesh=mesh,
         in_specs=(tab2, tab1, tab2, PS(), PS(),
                   PS(None, "dp", None), PS(), PS()),

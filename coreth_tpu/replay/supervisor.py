@@ -204,7 +204,11 @@ class BackendSupervisor:
         every wrapped site either fails before mutating shared state
         or contains its own mid-run faults (machine_block.execute_run
         returns its consumed count instead of raising once progress
-        has been staged).
+        has been staged).  That includes device buffers: the OCC slot
+        table is DONATED into each window dispatch, so a dispatch that
+        fails may have consumed it — the window runner marks its table
+        stale on any failed dispatch (adapter._dispatch) and the retry
+        rebuilds it from the host mirror.
 
         Consensus failures (:class:`~coreth_tpu.replay.engine
         .ReplayError`) are NEVER a backend fault: they propagate

@@ -274,6 +274,8 @@ def main(argv=None) -> None:
                     help="coordinator HOST:PORT")
     ap.add_argument("--worker", required=True, help="worker id")
     args = ap.parse_args(argv)
+    from coreth_tpu import compile_cache
+    compile_cache.configure()
     host, port = args.connect.rsplit(":", 1)
     run_worker(host, int(port), args.worker)
 

@@ -100,6 +100,7 @@ class Prefetcher:
                 self.shard_sigs += len(todo)
             return True
         except Exception:  # noqa: BLE001 — advisory: host path recovers
+            e.stats.recover_degraded += 1
             return False
         finally:
             # keep the engine's phase attribution honest: this IS

@@ -134,3 +134,48 @@ def test_device_recover_malformed_lane_no_poison(monkeypatch):
     assert bad.cached_sender() is None
     with pytest.raises(ValueError, match="invalid signature"):
         eng.signer.sender(bad)
+
+
+@pytest.mark.parametrize("seam", ["issue_recover", "complete_recover"])
+def test_failed_device_recovery_is_not_counted_as_device_work(
+        monkeypatch, seam):
+    """A device recovery that RAISES (at dispatch or at the result
+    read) must not read as device work: sigs_device counts only what
+    complete_recover returned, the failed batch shows in
+    recover_degraded, and the txs still recover per-tx on the host —
+    slower, never wrong, and never silently."""
+    from coreth_tpu.crypto import secp_device
+    monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
+    monkeypatch.setenv("CORETH_RECOVER_SPLIT", "1.0")
+    monkeypatch.setattr(ReplayEngine, "DEVICE_RECOVER_MIN", 1)
+
+    def boom(*_a, **_k):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(secp_device, seam, boom)
+    blocks = _fresh(_build_chain(3))
+    eng = _engine()
+    assert eng.replay(blocks) == blocks[-1].root
+    assert eng.stats.sigs_device == 0
+    assert eng.stats.recover_degraded >= 1
+    assert eng.stats.blocks_fallback == 0
+
+    # the synchronous form (serve prefetch, replay_block) counts the same
+    eng2 = _engine()
+    eng2.warm_senders(_fresh(blocks))
+    assert eng2.stats.sigs_device == 0
+    assert eng2.stats.recover_degraded == 1
+
+
+def test_accelerator_probe_does_not_swallow_a_broken_backend(monkeypatch):
+    """A backend probe that raises must propagate: returning False
+    would route every signature to the host and look healthy."""
+    from coreth_tpu.replay import engine as E
+    monkeypatch.delenv("CORETH_RECOVER_FORCE_DEVICE", raising=False)
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(E.jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        E._has_accelerator()

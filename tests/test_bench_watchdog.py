@@ -1,8 +1,8 @@
-"""Bench emission is unconditional (ROADMAP item 5 / BENCH_r05).
+"""Bench emission is unconditional.
 
-BENCH_r05 exited rc 124 with NO JSON despite the in-process watchdog
-thread: a wedged section holding the GIL starves every Python thread,
-the timer included.  bench.py now (a) flushes incremental per-section
+A bench run once exited rc 124 with NO JSON despite the in-process
+watchdog thread: a wedged section holding the GIL starves every Python
+thread, the timer included.  bench.py now (a) flushes incremental per-section
 state and (b) runs a child-process watchdog that SIGKILLs a wedged
 parent at the deadline and prints the recorded state as the stdout
 JSON line itself.  These tests wedge bench.py deliberately — including
@@ -38,7 +38,7 @@ def _last_json_line(stdout):
 
 
 def test_gil_wedged_section_still_yields_json_line():
-    """The worst case that took down BENCH_r05's line: the main thread
+    """The worst case, which once cost a run its line: the main thread
     stuck inside a C call that never releases the GIL.  The in-process
     timer thread cannot run; the CHILD watchdog must SIGKILL the
     parent and print the recorded state as a parseable stdout line."""

@@ -42,17 +42,14 @@ from tests.ckpt_child import build_chain
 # point is protocol + recovery, not throughput
 EKW = dict(capacity=256, batch_pad=64, window=4)
 
-# env every worker needs: host-platform jax with the suite's shared
-# compile cache (first cell pays the trace, the rest reuse it)
-_CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      ".jax_cache")
+# env every worker needs: host-platform jax.  The workers place the
+# suite's shared compile cache themselves (coreth_tpu.compile_cache:
+# first cell pays the trace, the rest reuse it)
 
 
 def _base_env():
     return {
         "JAX_PLATFORMS": "cpu",
-        "JAX_COMPILATION_CACHE_DIR": _CACHE,
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1.0",
         "CORETH_CHECKPOINT_SYNC": "1",
         "CORETH_TELEMETRY_PORT": "",  # no per-worker server in tests
     }

@@ -312,6 +312,40 @@ def test_machine_occ_device_fault_demotes(monkeypatch):
     assert eng.stats.blocks_fallback == len(blocks)
 
 
+def test_retry_after_failed_donated_dispatch(monkeypatch):
+    """The OCC slot table is DONATED into every window dispatch.  A
+    dispatch that fails after the device consumed it leaves the runner
+    holding a deleted buffer; the supervisor's retry must rebuild the
+    table from the host mirror — not hand the kernel the dead handle
+    and turn one transient fault into a demotion."""
+    from coreth_tpu.evm.device.adapter import MachineWindowRunner
+    _fast_supervisor_env(monkeypatch, strikes="5")
+    monkeypatch.setenv("CORETH_NO_TOKEN_FASTPATH", "1")
+    real = MachineWindowRunner._get_kernel
+    failed = []
+
+    def flaky(self, p, occ):
+        fn = real(self, p, occ)
+        if failed:
+            return fn
+
+        def lost_after_launch(table, *rest):
+            fn(table, *rest)
+            assert table.is_deleted()  # donation is live on this backend
+            failed.append(1)
+            raise RuntimeError("device lost after launch")
+        return lost_after_launch
+
+    monkeypatch.setattr(MachineWindowRunner, "_get_kernel", flaky)
+    genesis, blocks = build_token_chain()
+    eng, _ = _fresh_engine(genesis)
+    assert eng.replay(list(blocks)) == blocks[-1].header.root
+    assert failed == [1]
+    sup = eng.supervisor.snapshot()
+    assert (sup["retries"], sup["strikes"], sup["demotions"]) == (1, 0, 0)
+    assert eng.stats.blocks_fallback == 0
+
+
 def test_shard_exchange_fault_demotes(monkeypatch):
     """The cross-shard collective exchange seam on a 2-device mesh."""
     import jax

@@ -6,7 +6,7 @@ EIP-2200/3529 SSTORE, EIP-2930 access lists, EIP-1153/5656 Cancun
 ops, SELFDESTRUCT charges, quadratic memory) — the arithmetic is in
 the comments, so regenerating expectations from this implementation
 is impossible.  Complements tests/test_independent_vectors.py where
-the self-pinned statetests corpus is weakest (VERDICT round 4 #5).
+the self-pinned statetests corpus is weakest.
 
 Gas parameter provenance (external):
   EIP-2929: cold account 2600, cold sload 2100, warm 100

@@ -155,9 +155,9 @@ def test_replay_matches_blockchain_insert():
 
 
 def test_replay_windows_multiple_blocks_per_device_call(monkeypatch):
-    """Regression for VERDICT.md weak#2: replay() must batch consecutive
-    device-replayable blocks into ONE device call (the lax.scan window),
-    not issue per-block round trips."""
+    """replay() must batch consecutive device-replayable blocks into
+    ONE device call (the lax.scan window), not issue per-block round
+    trips."""
     from coreth_tpu.replay import engine as engine_mod
     genesis, gblock, blocks = build_transfer_chain(6, 8)
     db = Database()
@@ -181,7 +181,7 @@ def test_replay_windows_multiple_blocks_per_device_call(monkeypatch):
 
 def test_prepare_window_pads_to_pow2_not_full_window():
     """A 1-block window must not pad out to `window` scan slots
-    (VERDICT.md weak#2: 16-slot scans for single blocks)."""
+    (a 16-slot scan for a single block)."""
     genesis, gblock, blocks = build_transfer_chain(3, 8)
     db = Database()
     gb = genesis.to_block(db)

@@ -10,6 +10,12 @@ hardware the dp-sharded segment sums scale with chip count while this
 harness can only show that the sharded program stays correct and how
 much partitioning costs when the hardware underneath is serial.
 
+This is the CPU REHEARSAL of the mesh path: JAX_PLATFORMS is pinned to
+cpu and the mesh is built from ``jax.devices("cpu")`` on purpose (it
+also keeps this script safe to start as a child of a process that
+holds the chip).  The run on real chips is ``python chip_smoke.py
+--chips 4``.
+
 Writes MULTICHIP_SCALING.json at the repo root and prints it.
 """
 
@@ -31,9 +37,9 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_DIR, "tests", ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from coreth_tpu import compile_cache  # noqa: E402
+
+compile_cache.configure()
 
 from coreth_tpu.chain import Genesis, GenesisAccount, generate_chain  # noqa: E402
 from coreth_tpu.crypto.secp256k1 import priv_to_address  # noqa: E402
