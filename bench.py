@@ -825,12 +825,11 @@ def run_specialize():
             assert engine.root == fresh[-1].header.root
             txs = sum(len(b.transactions) for b in fresh[1:])
             mc = engine._machine.machine_counters()
-            sums = {}
-            for ev in tracer.export()["traceEvents"]:
-                if ev.get("ph") == "X":
-                    sums[ev["name"]] = sums.get(ev["name"], 0.0) \
-                        + float(ev.get("dur", 0.0))
-            total = max(dt * 1e6, 1e-9)
+            # self times: a span's children (commit/flush under
+            # machine/window_complete) are taken out of it, so the
+            # shares never count one second twice
+            sums = obs.self_times(tracer.export()["traceEvents"])
+            total = max(dt, 1e-9)
             out[label] = {
                 "txs_s": round(txs / dt, 1),
                 "lanes_specialized": mc["lanes_specialized"],

@@ -99,6 +99,15 @@ def issue_recover(hashes: bytes, rs: bytes, ss: bytes,
     return ctxs
 
 
+def fetch_recover(ctxs: list) -> None:
+    """Block until every issued chunk's result is on the host: the
+    device wait alone.  ``complete_recover`` after it only finishes on
+    the host, so a caller can tell waiting from work."""
+    for ctx in ctxs:
+        if ctx is not None and "out" not in ctx:
+            ctx["out"] = np.asarray(ctx["dev_out"])
+
+
 def complete_recover(ctxs: list) -> Tuple[bytes, bytes]:
     """Block on issued chunks; returns (addresses, ok) packed bytes."""
     addrs = bytearray()
@@ -201,7 +210,8 @@ def _complete_chunk(ctx) -> Tuple[bytes, bytes]:
     ok = ctx["ok"]
     hashes, rs, ss = ctx["hashes"], ctx["rs"], ctx["ss"]
     recids = ctx["recids"]
-    out = np.asarray(ctx["dev_out"])[:n]
+    fetch_recover([ctx])
+    out = ctx["out"][:n]
 
     from coreth_tpu.crypto import native
     if native.load() is not None:

@@ -84,11 +84,15 @@ DISPATCH_COUNT = 0
 _DISPATCH_MU = _threading.Lock()
 
 
-def _count_dispatch() -> None:
+def _count_dispatch() -> int:
+    """Counts one dispatch and marks it in flight (obs.device_issue:
+    the calling thread's account is ticked, so a machine block never
+    reads as an empty chip).  Returns the ticket the result fetch
+    retires with obs.device_done."""
     global DISPATCH_COUNT
     with _DISPATCH_MU:
         DISPATCH_COUNT += 1
-    obs.instant("device/dispatch")
+    return obs.device_issue()
 
 
 @jax.jit
@@ -366,9 +370,10 @@ class MachineRunner:
         for _ in range(self.max_rounds):
             p = self._params(txs)
             fn = M.get_machine(p)
-            _count_dispatch()
+            ticket = _count_dispatch()
             out = PackedOut(np.asarray(fn(self._pack(txs, p))["packed"]),
                             p)
+            obs.device_done(ticket)
             missing = self._collect_misses(out, txs)
             if not missing:
                 return self._unpack(out, txs)
@@ -1425,9 +1430,8 @@ class MachineWindowRunner:
             chainid_w=jnp.asarray(word16(chain_id)),
         )
         fn = self._get_kernel(p, occ)
-        _count_dispatch()
-        with obs.jax_span("coreth/occ_window"):
-            out = self._dispatch(fn, table, key_tab, inputs)
+        ticket = _count_dispatch()
+        out = self._dispatch(fn, table, key_tab, inputs)
         # the input table was donated into the dispatch; the output
         # handle (post-window committed state) replaces it
         self.table = out["table"]
@@ -1435,7 +1439,7 @@ class MachineWindowRunner:
         self._prewarm(p, occ, n_blocks=len(items))
         return dict(out=out, items=items, discovered=discovered, p=p,
                     occ=occ, premaps=premaps, predicted=predicted,
-                    attempt=attempt)
+                    attempt=attempt, ticket=ticket)
 
     def _dispatch(self, fn, *args):
         """Run the window kernel.  The value table is DONATED into the
@@ -1630,8 +1634,10 @@ class MachineWindowRunner:
         return li
 
     def _on_result_fetch(self, handle: dict) -> None:
-        """Hook for the sharded runner's dispatch-ordering trace."""
-        obs.instant("device/result_fetch")
+        """The window's packed result is on the host: its dispatch is
+        no longer in flight.  The sharded runner adds its
+        dispatch-ordering trace entry."""
+        obs.device_done(handle["ticket"])
 
     def _discover_key(self, handle: dict, bi: int, li: int,
                       contract: bytes, key: bytes) -> None:

@@ -1191,8 +1191,10 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                     conflict, slow_sweep, fast_sweep, operand=None)
                 return (rnd + 1, pend2, seeds2, res, ok, esc, t2)
 
-            rnd, pending, _seeds, res, committed, escape, tbl_f = \
-                jax.lax.while_loop(occ_cond, occ_body, carry0)
+            # one OCC round's stable name in a device trace
+            with jax.named_scope("coreth/occ_round"):
+                rnd, pending, _seeds, res, committed, escape, tbl_f = \
+                    jax.lax.while_loop(occ_cond, occ_body, carry0)
             # committed/escape/pending/rounds ride as 4 extra packed
             # columns so the host fetches ONE tensor per window
             extra = jnp.stack(

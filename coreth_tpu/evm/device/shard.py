@@ -519,6 +519,7 @@ class ShardedWindowRunner(MachineWindowRunner):
         return handle["lane_map"][bi][li]
 
     def _on_result_fetch(self, handle: dict) -> None:
+        super()._on_result_fetch(handle)
         EVENT_LOG.append(f"result_fetch:{handle['seq']}")
 
     def _discover_key(self, handle: dict, bi: int, li: int,
@@ -998,7 +999,7 @@ class ShardedWindowRunner(MachineWindowRunner):
             chainid_w=jnp.asarray(word16(chain_id)),
         )
         fn = self._get_kernel(p, occ)
-        _count_dispatch()
+        ticket = _count_dispatch()
         seq = _next_seq()
         EVENT_LOG.append(f"dispatch:{seq}")
         if rows_j is not None:
@@ -1007,11 +1008,10 @@ class ShardedWindowRunner(MachineWindowRunner):
             # PT_EXCHANGE below — execute_run keeps the committed
             # prefix and the supervisor strikes the device scope.
             faults.fire(PT_KEY_EXCHANGE)
-        with obs.jax_span("coreth/shard_occ_window"):
-            if rows_j is None:
-                out = self._dispatch(fn, table, key_tab, inputs)
-            else:
-                out = self._dispatch(fn, table, key_tab, inputs, rows_j)
+        if rows_j is None:
+            out = self._dispatch(fn, table, key_tab, inputs)
+        else:
+            out = self._dispatch(fn, table, key_tab, inputs, rows_j)
         self.table = out["table"]
         self._dispatched += 1
         # the exchange rides the same device queue, right behind the
@@ -1033,7 +1033,7 @@ class ShardedWindowRunner(MachineWindowRunner):
         return dict(out=out, ex=ex, items=items, discovered=discovered,
                     p=p, occ=occ, premaps=premaps, predicted=predicted,
                     attempt=attempt, lane_map=lane_map, seq=seq,
-                    sync=len(sync))
+                    sync=len(sync), ticket=ticket)
 
     # complete() / _update_common are fully inherited: the base walks
     # packed rows through _block_stride/_lane_idx (the lane_map

@@ -10,6 +10,12 @@ timing evidence through it, so it imports nothing of the tree above
   per-block :class:`BlockTrace` contexts whose stage intervals become
   ``StreamReport.stage_breakdown``, a bounded ring, and Chrome
   trace-event / Perfetto JSON export (CORETH_TRACE_OUT).
+- ``obs.account`` — the ALWAYS-ON self-time account of the replay
+  thread (:class:`Account`: a phase stack whose seconds sum to the
+  engine's age) and the in-flight count of device work
+  (``device_issue`` / ``device_done``) that says which phase the host
+  was in while the chip had nothing to do; the same sites feed the
+  tracer's ring and the profiler's host plane when the tracer is armed.
 - ``obs.server`` — the zero-dependency live telemetry endpoint
   (CORETH_TELEMETRY_PORT): /metrics, /trace, /report.
 - ``obs.recorder`` — the divergence flight recorder
@@ -19,16 +25,21 @@ timing evidence through it, so it imports nothing of the tree above
   (tools/replay_bundle.py is the matching bisection CLI).
 """
 
+from coreth_tpu.obs.account import (
+    NULL as NULL_ACCOUNT, Account, InFlight, accounts_between,
+    device_done, device_issue,
+)
 from coreth_tpu.obs.trace import (
     PT_EXPORT_FAIL, BlockTrace, EventRing, SpanTracer,
     StageAccumulator, arm_from_env, block_begin, enabled, install,
-    instant, jax_span, span, tracer, uninstall, write_out,
+    instant, self_times, span, tracer, uninstall, write_out,
 )
 from coreth_tpu.obs import recorder  # noqa: F401 — re-export the forensics module (and its obs/bundle_fail declaration) under the obs namespace
 
 __all__ = [
-    "PT_EXPORT_FAIL", "BlockTrace", "EventRing", "SpanTracer",
-    "StageAccumulator", "arm_from_env", "block_begin", "enabled",
-    "install", "instant", "jax_span", "span", "recorder", "tracer",
-    "uninstall", "write_out",
+    "NULL_ACCOUNT", "PT_EXPORT_FAIL", "Account", "BlockTrace",
+    "EventRing", "InFlight", "SpanTracer", "StageAccumulator",
+    "accounts_between", "arm_from_env", "block_begin", "device_done",
+    "device_issue", "enabled", "install", "instant", "recorder",
+    "self_times", "span", "tracer", "uninstall", "write_out",
 ]

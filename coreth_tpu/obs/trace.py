@@ -35,9 +35,12 @@ mold):
    ``StreamReport.stage_breakdown`` and the bench ``tracing`` section
    publish.
 
-``CORETH_TRACE_JAX=1`` additionally brackets device dispatches with
-``jax.profiler.TraceAnnotation`` (:func:`jax_span`) so XLA activity
-lines up under the same timeline when a jax profile is captured.
+Every span and every phase of ``obs.account`` records an ``id`` and
+the ``parent`` that enclosed it on its thread (:data:`PARENT`), so
+:func:`self_times` can take a span's children out of its duration.
+While the tracer is armed the account's phases also open
+``jax.profiler.TraceAnnotation`` (:func:`annotation`), so XLA activity
+lines up under the program's own phases in a captured jax profile.
 """
 
 from __future__ import annotations
@@ -68,6 +71,13 @@ TRACER: Optional["SpanTracer"] = None
 # threading the id through every call signature
 _FLOW: "contextvars.ContextVar[Optional[int]]" = contextvars.ContextVar(
     "coreth_trace_flow", default=None)
+
+# id of the innermost open span or account phase on this thread: what a
+# span opened now records as its parent.  Written only while the tracer
+# is armed (the disabled path never reaches a _Span or a phase frame).
+PARENT: "contextvars.ContextVar[Optional[int]]" = contextvars.ContextVar(
+    "coreth_trace_parent", default=None)
+SPAN_IDS = itertools.count(1)
 
 # Stable per-thread trace ids.  threading.get_ident() is the raw
 # pthread handle, which the OS RECYCLES the moment a thread exits — a
@@ -106,7 +116,8 @@ class _Span:
     """One recorded span: a complete ``X`` event emitted at exit, with
     flow inheritance through the contextvar while it is open."""
 
-    __slots__ = ("_t", "name", "_flow", "_args", "_t0", "_tok")
+    __slots__ = ("_t", "name", "_flow", "_args", "_t0", "_tok", "_id",
+                 "_parent", "_ptok")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  flow: Optional[int], args: dict):
@@ -119,6 +130,9 @@ class _Span:
     def __enter__(self):
         t = self._t
         self._t0 = t._now_us()
+        self._id = next(SPAN_IDS)
+        self._parent = PARENT.get()
+        self._ptok = PARENT.set(self._id)
         if self._flow is None:
             self._flow = _FLOW.get()
         else:
@@ -131,15 +145,13 @@ class _Span:
         t = self._t
         tid = _tid()
         t._note_thread(tid)
-        ev = {"ph": "X", "name": self.name, "ts": self._t0,
-              "dur": t._now_us() - self._t0, "tid": tid}
+        args = dict(self._args, id=self._id, parent=self._parent)
         if self._flow is not None:
-            args = dict(self._args) if self._args else {}
             args["flow"] = self._flow
-            ev["args"] = args
-        elif self._args:
-            ev["args"] = self._args
-        t._emit(ev)
+        t._emit({"ph": "X", "name": self.name, "ts": self._t0,
+                 "dur": t._now_us() - self._t0, "tid": tid,
+                 "args": args})
+        PARENT.reset(self._ptok)
         if self._tok is not None:
             _FLOW.reset(self._tok)
             self._tok = None
@@ -152,8 +164,7 @@ class StageAccumulator:
     Each consumer (a StreamingPipeline run) owns ONE of these, so two
     pipelines sharing the process-global tracer — a builder+replica
     pair, or back-to-back bench reps armed via CORETH_TRACE=1 — never
-    blend each other's blocks into one breakdown.  The tracer embeds a
-    default instance for consumers that don't pass their own."""
+    blend each other's blocks into one breakdown."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -190,8 +201,7 @@ class StageAccumulator:
 class SpanTracer:
     """Thread-safe span/instant recorder over a bounded ring."""
 
-    def __init__(self, ring: int = 65536, clock=time.monotonic,
-                 jax_annotations: Optional[bool] = None):
+    def __init__(self, ring: int = 65536, clock=time.monotonic):
         self._clock = clock
         self._t0 = clock()
         self._lock = threading.Lock()
@@ -200,13 +210,6 @@ class SpanTracer:
         self.dropped = 0           # events evicted from the full ring
         self.export_failures = 0   # write_out failures (counted, eaten)
         self._thread_names: Dict[int, str] = {}
-        if jax_annotations is None:
-            jax_annotations = bool(int(
-                os.environ.get("CORETH_TRACE_JAX", "0") or "0"))
-        self.jax = jax_annotations
-        # default attribution sink (BlockTrace folds here unless its
-        # owner passed a per-consumer StageAccumulator)
-        self.attribution = StageAccumulator()
 
     # ------------------------------------------------------------ recording
     def _now_us(self) -> int:
@@ -259,17 +262,6 @@ class SpanTracer:
         if args:
             ev["args"] = args
         self._emit(ev)
-
-    # ------------------------------------------------- stage attribution
-    def add_block(self, stages: Dict[str, float],
-                  total_s: float) -> None:
-        """Fold into the tracer's default attribution sink."""
-        self.attribution.add_block(stages, total_s)
-
-    def stage_breakdown(self) -> dict:
-        """The default sink's breakdown (per-consumer sinks — the
-        pipeline's — report through their own StageAccumulator)."""
-        return self.attribution.breakdown()
 
     # --------------------------------------------------------------- export
     def export(self) -> dict:
@@ -362,13 +354,13 @@ class BlockTrace:
                  "prefetch_s", "t_exec")
 
     def __init__(self, tracer: SpanTracer, number: int,
-                 t_enqueue: Optional[float] = None,
-                 sink: Optional[StageAccumulator] = None):
+                 t_enqueue: Optional[float],
+                 sink: StageAccumulator):
         self._t = tracer
         # attribution sink: the owner's per-consumer accumulator, so
         # concurrent/sequential pipelines sharing the global tracer
-        # never blend breakdowns (default: the tracer's own)
-        self._sink = sink if sink is not None else tracer.attribution
+        # never blend breakdowns
+        self._sink = sink
         self.number = number
         self.t_enqueue = tracer._clock() if t_enqueue is None \
             else t_enqueue
@@ -467,9 +459,8 @@ def instant(name: str, **kw) -> None:
     t.instant(name, **kw)
 
 
-def block_begin(number: int, t_enqueue: Optional[float] = None,
-                sink: Optional[StageAccumulator] = None
-                ) -> Optional[BlockTrace]:
+def block_begin(number: int, t_enqueue: Optional[float],
+                sink: StageAccumulator) -> Optional[BlockTrace]:
     """A BlockTrace riding block ``number`` (None when tracing is off
     — callers carry the None and skip their marks).  ``sink`` is the
     owner's per-consumer StageAccumulator."""
@@ -479,19 +470,36 @@ def block_begin(number: int, t_enqueue: Optional[float] = None,
     return BlockTrace(t, number, t_enqueue, sink)
 
 
-def jax_span(name: str):
-    """``jax.profiler.TraceAnnotation`` bracketing a device dispatch
-    when CORETH_TRACE_JAX=1 and tracing is on (so XLA activity lines up
-    under the same timeline in a captured jax profile); the shared
-    no-op otherwise."""
-    t = TRACER
-    if t is None or not t.jax:
-        return _NULL_SPAN
+def annotation(name: str):
+    """An ENTERED ``jax.profiler.TraceAnnotation`` (the caller exits
+    it), or None where this jax has no profiler API.  Only the armed
+    path of ``obs.account`` calls this."""
     try:
         from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — annotation is advisory; a jax without the profiler API must not break tracing
-        return _NULL_SPAN
+    except ImportError:
+        return None
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def self_times(events) -> Dict[str, float]:
+    """Seconds of SELF time by name over exported ``X`` events: each
+    event's duration minus the part its children cover (the events
+    that name it as ``args.parent``; children of one parent run on its
+    thread, one after another).  An event with no ``args.id`` has no
+    children to take out."""
+    covered: Dict[int, float] = {}
+    spans = [e for e in events if e.get("ph") == "X"]
+    for e in spans:
+        parent = e.get("args", {}).get("parent")
+        if parent is not None:
+            covered[parent] = covered.get(parent, 0.0) + e["dur"]
+    out: Dict[str, float] = {}
+    for e in spans:
+        own = e["dur"] - covered.get(e.get("args", {}).get("id"), 0.0)
+        out[e["name"]] = out.get(e["name"], 0.0) + max(own, 0.0) / 1e6
+    return out
 
 
 def install(tracer: Optional[SpanTracer] = None,

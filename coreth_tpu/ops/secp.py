@@ -393,6 +393,12 @@ def recover_kernel(x_bytes, parity, u1w, u2w):
     u1w/u2w: (B, 8) int32 LE scalar words.
     Returns (B, 102) uint8: X(33) ++ Y(33) ++ Z(33) canonical Jacobian
     bytes ++ [inf, collision, is_residue] flag bytes."""
+    # the kernel's stable name in a device trace
+    with jax.named_scope("coreth/recover_ladder"):
+        return _recover_body(x_bytes, parity, u1w, u2w)
+
+
+def _recover_body(x_bytes, parity, u1w, u2w):
     x = unpack_fe_bytes(x_bytes)
     Bsz = x.shape[0]
     seven = jnp.broadcast_to(jnp.asarray(_const_limbs(7)), x.shape)

@@ -36,7 +36,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Tuple
 
-from coreth_tpu import faults, obs, rlp
+from coreth_tpu import faults, rlp
 from coreth_tpu.obs import recorder as forensics
 from coreth_tpu.crypto import keccak256
 from coreth_tpu.mpt.rehash import device_rehash
@@ -180,7 +180,7 @@ class CommitPipeline:
         root against the last staged header, advance engine.root."""
         if not self.staged_blocks:
             return self.e.root
-        with obs.span("commit/flush", blocks=self.staged_blocks):
+        with self.e.account.enter("commit/flush"):
             return self._flush()
 
     def _flush(self) -> bytes:

@@ -28,6 +28,7 @@ both engines can interleave over one chain.
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from dataclasses import dataclass, field
@@ -160,6 +161,36 @@ class ReplayStats:
         return dict(self.__dict__)
 
 
+def _in_phase(name: str):
+    """Run an engine method inside account phase ``name`` (the method
+    may ``switch`` it: the decorator closes whatever is on top)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(self, *args, **kwargs):
+            acct = self.account
+            acct.enter(name)
+            try:
+                return fn(self, *args, **kwargs)
+            finally:
+                acct.exit()
+        return wrapper
+    return deco
+
+
+def _public_call(fn):
+    """A public entry of the engine: claims the account for the calling
+    thread and runs under its ``loop`` phase (nested public calls ride
+    the outer one's)."""
+    @functools.wraps(fn)
+    def wrapper(self, *args, **kwargs):
+        tok = self.account.begin()
+        try:
+            return fn(self, *args, **kwargs)
+        finally:
+            self.account.end(tok)
+    return wrapper
+
+
 # Packed tx-batch column layout — ONE host->device transfer per block
 # (each separate transfer pays a dispatch and a sync of its own):
 #   0 sender_idx | 1 recip_idx | 2 tx_nonce | 3 nonce_offset | 4 mask
@@ -273,8 +304,11 @@ def _transfer_window(balances, nonces, slot_vals, acct_gids, slot_gids,
     def body(carry, inp):
         bal, non, sv = carry
         txd, t_idx, s_idx = inp
-        nb, nn, nsv, ok = _step_core(bal, non, sv, txd, L, SL)
-        return (nb, nn, nsv), _gather_fetch(nb, nn, nsv, ok, t_idx, s_idx)
+        # the scanned step's stable name in a device trace
+        with jax.named_scope("coreth/transfer_step"):
+            nb, nn, nsv, ok = _step_core(bal, non, sv, txd, L, SL)
+            fetch = _gather_fetch(nb, nn, nsv, ok, t_idx, s_idx)
+        return (nb, nn, nsv), fetch
 
     (lb, ln, ls), fetches = jax.lax.scan(
         body, (lb, ln, ls), (txds, t_idxs, s_idxs))
@@ -630,9 +664,10 @@ class _SenderPipeline:
 
     def _issue(self, s: int) -> None:
         eng = self.engine
-        obs.instant("replay/sender_issue", seg=s)
+        acct = eng.account
         t0 = time.monotonic()
         h = {"todo": [], "kind": "empty"}
+        acct.enter("sender/pack")
         try:
             faults.fire(PT_RECOVER)  # degrade: lazy per-tx recovery
             todo, hashes, rs, ss, recids = eng._pack_sigs(
@@ -659,14 +694,18 @@ class _SenderPipeline:
                         issue_recover)
                     self.dev_sigs += n
                     h["kind"] = "device"
+                    acct.switch("sender/issue_device")
                     h["ctxs"] = issue_recover(
                         hashes, rs, ss, recids,
                         kernel=eng._recover_kernel())
+                    h["ticket"] = obs.device_issue(acct)
                 # else: no native lib, no accelerator — signer.sender's
                 # per-tx python path recovers lazily
         except Exception:  # noqa: BLE001 — degrade to lazy per-tx
             h["kind"] = "empty"
             eng.stats.recover_degraded += 1
+        finally:
+            acct.exit()
         self.issued.append(h)
         self._account(h["kind"], time.monotonic() - t0)
 
@@ -681,21 +720,26 @@ class _SenderPipeline:
     def _complete(self, s: int) -> None:
         eng = self.engine
         h = self.issued[s]
+        acct = eng.account
         t0 = time.monotonic()
+        acct.enter("sender/apply")
         try:
             out = ok = None
             if h["kind"] == "host":
+                acct.switch("sender/wait_host")
                 out, ok = h["fut"].result()
                 eng.stats.sigs_host += len(h["todo"])
             elif h["kind"] == "device":
-                from coreth_tpu.crypto.secp_device import complete_recover
-                out, ok = complete_recover(h["ctxs"])
+                out, ok = eng._complete_device_recover(
+                    h["ctxs"], h["ticket"], acct)
                 eng.stats.sigs_device += len(h["todo"])
             if out is not None:
+                acct.switch("sender/apply")
                 eng._apply_recovered(h["todo"], out, ok)
         except Exception:  # noqa: BLE001 — per-tx python path later
             eng.stats.recover_degraded += 1
         finally:
+            acct.exit()
             self._account(h["kind"], time.monotonic() - t0)
 
     def ensure(self, block_idx: int) -> None:
@@ -724,6 +768,12 @@ class ReplayEngine:
         totals reduce with psum_scatter over ICI, and sender recovery
         fans out across chips.  Bit-identical to the single-device path
         (pinned by tests/test_parallel.py)."""
+        # CORETH_TRACE=1 installs the span tracer; then the self-time
+        # account of this engine's life (obs/account.py), opened first
+        # so that construction is its first phase
+        obs.arm_from_env()
+        self.account = obs.Account()
+        build = self.account.begin("engine/build")
         self.config = config
         self.db = db
         self.mesh = None
@@ -815,9 +865,7 @@ class ReplayEngine:
         # fault supervision: retry/demote/probe over the execution
         # ladder (replay/supervisor.py); CORETH_FAULT_PLAN arms the
         # injection registry for this process if nothing armed it yet
-        # (CORETH_TRACE=1 likewise installs the span tracer)
         faults.arm_from_env()
-        obs.arm_from_env()
         # divergence flight recorder (obs/recorder.py): armed by
         # CORETH_FORENSICS=1; the engine hands it the chain config
         # scalars + backend fingerprint every bundle embeds
@@ -839,6 +887,7 @@ class ReplayEngine:
         # independent native demotion ladders instead of the last
         # constructor winning a module global
         self.db.fault_observer = self.supervisor
+        self.account.end(build)
 
     # ---------------------------------------------------------------- index
     def _flat_view(self):
@@ -998,23 +1047,46 @@ class ReplayEngine:
         overlap segmented recovery with window execution."""
         if isinstance(blocks, Block):
             blocks = [blocks]
-        with obs.span("replay/sender_recover", blocks=len(blocks)):
-            self._warm_senders_run(blocks)
-
-    def _warm_senders_run(self, blocks) -> None:
-        t0 = time.monotonic()
-        todo, hashes, rs, ss, recids = self._pack_sigs(blocks)
-        if not todo:
-            self.stats.t_sender += time.monotonic() - t0
-            return
+        # the serve prefetcher calls this from ITS thread while the
+        # replay thread holds the account: that time is not the replay
+        # thread's, so its phases go nowhere
+        tok = self.account.begin()
         try:
-            out, ok = self._recover_packed(hashes, rs, ss, recids)
-            if out is not None:
-                self._apply_recovered(todo, out, ok)
-        except Exception:  # noqa: BLE001 — fall back to per-tx path
-            self.stats.recover_degraded += 1
+            self._warm_senders_run(
+                blocks, self.account if tok >= 0 else obs.NULL_ACCOUNT)
         finally:
+            self.account.end(tok)
+
+    def _warm_senders_run(self, blocks, acct) -> None:
+        t0 = time.monotonic()
+        acct.enter("sender/pack")
+        try:
+            todo, hashes, rs, ss, recids = self._pack_sigs(blocks)
+            if not todo:
+                return
+            try:
+                out, ok = self._recover_packed(hashes, rs, ss, recids,
+                                               acct)
+                if out is not None:
+                    acct.switch("sender/apply")
+                    self._apply_recovered(todo, out, ok)
+            except Exception:  # noqa: BLE001 — fall back to per-tx path
+                self.stats.recover_degraded += 1
+        finally:
+            acct.exit()
             self.stats.t_sender += time.monotonic() - t0
+
+    def _complete_device_recover(self, ctxs, ticket, acct):
+        """Read an issued device recovery back (``sender/wait_device``:
+        the blocking read alone), retire its ticket, and finish on the
+        host (``sender/apply``).  Returns (addresses, ok)."""
+        from coreth_tpu.crypto.secp_device import (
+            complete_recover, fetch_recover)
+        acct.switch("sender/wait_device")
+        fetch_recover(ctxs)
+        obs.device_done(ticket, acct)
+        acct.switch("sender/apply")
+        return complete_recover(ctxs)
 
     # Device share of the hybrid recovery split.  The device ladder and
     # the host C++ batch run CONCURRENTLY (the ctypes call releases the
@@ -1042,8 +1114,9 @@ class ReplayEngine:
         return dev_rate / (dev_rate + host_rate)
 
     def _recover_packed(self, hashes: bytes, rs: bytes, ss: bytes,
-                        recids: bytes):
-        """Hybrid batched recovery over packed buffers -> (addrs, ok)."""
+                        recids: bytes, acct):
+        """Hybrid batched recovery over packed buffers -> (addrs, ok).
+        Runs inside the caller's ``sender/*`` phase and switches it."""
         faults.fire(PT_RECOVER)  # callers degrade to per-tx recovery
         from coreth_tpu.crypto import native
         n = len(recids)
@@ -1055,6 +1128,7 @@ class ReplayEngine:
             if not have_native:
                 return None, None  # per-tx python path in signer.sender
             t0 = time.monotonic()
+            # the native batch runs ON this thread: work, not a wait
             out = native.recover_addresses_batch(hashes, rs, ss, recids)
             self.stats.sigs_host += n
             self.stats.t_sender_host += time.monotonic() - t0
@@ -1069,18 +1143,20 @@ class ReplayEngine:
             host_fut = self._recover_pool_get().submit(
                 native.recover_addresses_batch, hashes[32 * n_dev:],
                 rs[32 * n_dev:], ss[32 * n_dev:], recids[n_dev:])
-        from coreth_tpu.crypto.secp_device import (
-            complete_recover, issue_recover)
+        from coreth_tpu.crypto.secp_device import issue_recover
         t0 = time.monotonic()
+        acct.switch("sender/issue_device")
         ctxs = issue_recover(hashes[:32 * n_dev], rs[:32 * n_dev],
                              ss[:32 * n_dev], recids[:n_dev],
                              kernel=self._recover_kernel())
-        out_dev, ok_dev = complete_recover(ctxs)
+        out_dev, ok_dev = self._complete_device_recover(
+            ctxs, obs.device_issue(acct), acct)
         t1 = time.monotonic()
         self.stats.sigs_device += n_dev
         self.stats.t_sender_device += t1 - t0
         if host_fut is None:
             return out_dev, ok_dev
+        acct.switch("sender/wait_host")
         out_host, ok_host = host_fut.result()
         self.stats.sigs_host += n - n_dev
         self.stats.t_sender_host += time.monotonic() - t1
@@ -1409,6 +1485,7 @@ class ReplayEngine:
         return (txds, t_idxs, s_idxs, acct_gids, slot_gids,
                 touched_lists, slot_lists, flushed)
 
+    @_in_phase("window/prepare")
     def _issue_window_mesh(self, items: List[Tuple[Block, dict]],
                            fetch: bool = True) -> dict:
         """Mesh-sharded execution of a whole window in ONE dispatch
@@ -1425,6 +1502,7 @@ class ReplayEngine:
         from coreth_tpu.replay.shard import (
             interleave_txs, sharded_transfer_window)
         t0 = time.monotonic()
+        acct = self.account
         (txds, t_idxs, s_idxs, acct_rows, slot_rows, touched_lists,
          slot_lists, flushed) = self._prepare_window(items)
         prev = (self.state.balances, self.state.nonces,
@@ -1439,11 +1517,13 @@ class ReplayEngine:
             self.state.capacity + self.state.slot_capacity,
             self._n_shards)
         win = sharded_transfer_window(self.mesh, mode)
-        with obs.jax_span("coreth/transfer_window"):
-            new_bal, new_non, new_sv, fetches = win(
-                prev[0], prev[1], prev[2], jnp.asarray(acct_rows),
-                jnp.asarray(slot_rows), jnp.asarray(txds[:, perm]),
-                jnp.asarray(t_idxs), jnp.asarray(s_idxs))
+        acct.switch("window/upload")
+        ups = (jnp.asarray(acct_rows), jnp.asarray(slot_rows),
+               jnp.asarray(txds[:, perm]), jnp.asarray(t_idxs),
+               jnp.asarray(s_idxs))
+        acct.switch("window/dispatch")
+        new_bal, new_non, new_sv, fetches = win(
+            prev[0], prev[1], prev[2], *ups)
         self.state.balances = new_bal
         self.state.nonces = new_non
         self.state.slot_vals = new_sv
@@ -1454,10 +1534,12 @@ class ReplayEngine:
                 self.stats.reads_prefetched += 1
             except AttributeError:
                 pass
+        ticket = obs.device_issue(acct)
         self.stats.t_device += time.monotonic() - t0
         return dict(items=items, prev=prev, fetches=fetches,
                     touched_lists=touched_lists, slot_lists=slot_lists,
-                    t_pad=t_idxs.shape[1], flushed=flushed)
+                    t_pad=t_idxs.shape[1], flushed=flushed,
+                    ticket=ticket)
 
     def _issue_window(self, items: List[Tuple[Block, dict]]) -> dict:
         """Supervised window dispatch: transient faults retry with
@@ -1466,9 +1548,9 @@ class ReplayEngine:
         the exact host path).  The injected seam is PT_DISPATCH."""
         if forensics.enabled():
             self._record_window_dispatch(items)
-        with obs.span("replay/issue_window", blocks=len(items)):
-            return self.supervisor.run("device", PT_DISPATCH,
-                                       self._issue_window_run, items)
+        run = self._issue_window_run if self.mesh is None \
+            else self._issue_window_mesh
+        return self.supervisor.run("device", PT_DISPATCH, run, items)
 
     def _record_window_dispatch(self, items) -> None:
         """Flight-recorder ring entries for a transfer/token window:
@@ -1491,17 +1573,20 @@ class ReplayEngine:
                                       touched)
             parent = block.header
 
+    @_in_phase("window/prepare")
     def _issue_window_run(self, items: List[Tuple[Block, dict]]) -> dict:
         """One device call for a whole run of transfer blocks: upload the
         stacked batches, lax.scan the steps, download one stacked fetch
-        tensor.  Round-trip latency amortizes over the window."""
-        if self.mesh is not None:
-            return self._issue_window_mesh(items)
+        tensor.  Round-trip latency amortizes over the window.  Three
+        phases — pack, upload, dispatch — so host packing and a
+        supervised retry's backoff never read as device time."""
         t0 = time.monotonic()
+        acct = self.account
         (txds, t_idxs, s_idxs, acct_gids, slot_gids, touched_lists,
          slot_lists, flushed) = self._prepare_window(items)
         prev = (self.state.balances, self.state.nonces,
                 self.state.slot_vals)
+        acct.switch("window/upload")
         ups = (jnp.asarray(acct_gids), jnp.asarray(slot_gids),
                jnp.asarray(txds), jnp.asarray(t_idxs),
                jnp.asarray(s_idxs))
@@ -1511,12 +1596,9 @@ class ReplayEngine:
             # host's fold work; shipping the inputs here lets the scan
             # start while the host validates the previous window
             jax.block_until_ready(ups)
-        # annotation on the dispatch itself (not the supervised wrapper
-        # above): retries/backoff and host packing must not read as
-        # device time in a captured jax profile
-        with obs.jax_span("coreth/transfer_window"):
-            new_bal, new_non, new_sv, fetches = _transfer_window(
-                prev[0], prev[1], prev[2], *ups)
+        acct.switch("window/dispatch")
+        new_bal, new_non, new_sv, fetches = _transfer_window(
+            prev[0], prev[1], prev[2], *ups)
         self.state.balances = new_bal
         self.state.nonces = new_non
         self.state.slot_vals = new_sv
@@ -1531,10 +1613,12 @@ class ReplayEngine:
             self.stats.reads_prefetched += 1
         except AttributeError:
             pass  # non-jax array (mesh path fetches are already np)
+        ticket = obs.device_issue(acct)
         self.stats.t_device += time.monotonic() - t0
         return dict(items=items, prev=prev, fetches=fetches,
                     touched_lists=touched_lists, slot_lists=slot_lists,
-                    t_pad=t_idxs.shape[1], flushed=flushed)
+                    t_pad=t_idxs.shape[1], flushed=flushed,
+                    ticket=ticket)
 
     def _discard_window(self, win: dict) -> None:
         """Drop a speculatively issued window whose base state was
@@ -1561,39 +1645,22 @@ class ReplayEngine:
         """Validate a window from its fetched tensors.  Returns None on
         full success, else the index (into ``blocks``) to resume from
         after the rewind+fallback recovery."""
-        with obs.span("replay/complete_window",
-                      blocks=len(win["items"])):
-            return self._complete_window_run(win, blocks, start_idx)
-
-    def _complete_window_run(self, win: dict, blocks: List[Block],
-                             start_idx: int) -> Optional[int]:
         t0 = time.monotonic()
-        arr = np.asarray(win["fetches"])  # ONE device read per window
+        acct = self.account
+        acct.enter("window/fetch_wait")
+        try:
+            arr = np.asarray(win["fetches"])  # ONE device read per window
+            obs.device_done(win["ticket"], acct)
+        finally:
+            acct.exit()
         self.stats.t_device += time.monotonic() - t0
-        items = win["items"]
-        for k, (block, batch) in enumerate(items):
-            if arr[k, -1, 0] != 1:
-                # fold the staged valid prefix [0, k) before the
-                # rewind: _fallback opens a StateDB at self.root
-                self.commit_pipe.flush()
-                return self._recover_window(win, arr, k, blocks, start_idx)
-            try:
-                self._validate_and_advance(block, batch, arr[k],
-                                           win["touched_lists"][k],
-                                           win["slot_lists"][k],
-                                           win["t_pad"])
-            except ReplayError:
-                # device-path VALIDATION failed (a malformed block, or
-                # a gas/receipt-model gap): before giving up, rewind
-                # and retry the block on the exact host path — the
-                # same recovery an execution failure gets.  A block
-                # that fails there too re-raises with .block attached
-                # (the streaming pipeline's quarantine seam).
-                # _validate_and_advance raises before staging, so the
-                # staged set is exactly the valid prefix [0, k).
-                self.commit_pipe.flush()
-                return self._recover_window(win, arr, k, blocks,
-                                            start_idx)
+        failed = self._validate_window(win, arr)
+        if failed is not None:
+            # fold the staged valid prefix [0, failed) before the
+            # rewind: _fallback opens a StateDB at self.root
+            self.commit_pipe.flush()
+            return self._recover_window(win, arr, failed, blocks,
+                                        start_idx)
         # ONE deduped fold + root check for the whole window
         self.commit_pipe.flush()
         # NOTE: the classifier's slot overlay is NOT cleared here — with
@@ -1605,6 +1672,42 @@ class ReplayEngine:
         # paths clear the overlay because there slot_host is repaired
         # from the trie.
         return None
+
+    @_in_phase("validate")
+    def _validate_window(self, win: dict, arr) -> Optional[int]:
+        """Hold each block of a fetched window to its header and stage
+        it; returns the index of the first block that failed, else
+        None.  ONE ``validate`` phase for the window: a one-tx block is
+        too short to carry boundaries of its own (10,000 a pass cost
+        2% of ``valuetx``, my chip runs, PR 29), so the seconds the
+        blocks spent staging — read by ``t_trie``'s clock pair in
+        _validate_and_advance — are moved to ``commit/stage`` here."""
+        staged = self.stats.t_trie
+        blocks = self.stats.blocks_device
+        try:
+            for k, (block, batch) in enumerate(win["items"]):
+                if arr[k, -1, 0] != 1:
+                    return k
+                try:
+                    self._validate_and_advance(
+                        block, batch, arr[k], win["touched_lists"][k],
+                        win["slot_lists"][k], win["t_pad"])
+                except ReplayError:
+                    # device-path VALIDATION failed (a malformed
+                    # block, or a gas/receipt-model gap): before
+                    # giving up, rewind and retry the block on the
+                    # exact host path — the same recovery an execution
+                    # failure gets.  A block that fails there too
+                    # re-raises with .block attached (the streaming
+                    # pipeline's quarantine seam).
+                    # _validate_and_advance raises before staging, so
+                    # the staged set is exactly the valid prefix [0, k).
+                    return k
+            return None
+        finally:
+            self.account.move("validate", "commit/stage",
+                              self.stats.t_trie - staged,
+                              self.stats.blocks_device - blocks)
 
     def _rebuild_device_rows(self) -> None:
         """Rebuild every device-table row from the authoritative host
@@ -1634,6 +1737,7 @@ class ReplayEngine:
                 sv[st.slot_row_of[s_idx]] = u256.pack_np([v])[0]
         st.slot_vals = jnp.asarray(sv)
 
+    @_in_phase("recover_window")
     def _recover_window(self, win, arr, k: int, blocks, start_idx: int) -> int:
         """Block k of the window failed the device validation: the valid
         prefix [0, k) has already been folded into the trie by the loop
@@ -1675,7 +1779,10 @@ class ReplayEngine:
                               touched_slots: List[int],
                               t_pad: int) -> None:
         """Host-side consensus checks + staged commit for one device
-        block (the trie fold itself is window-batched)."""
+        block (the trie fold itself is window-batched).  Runs inside
+        _validate_window's ``validate`` phase and costs the account no
+        boundary of its own: the staging part is what ``t_trie``'s
+        clock pair below reads, and the window moves it over."""
         B = len(block.transactions)
         gas_list = batch["gas_used"]
         logs = batch["logs"]
@@ -1777,14 +1884,17 @@ class ReplayEngine:
             return False
         mx = self._machine_executor()
         t0 = time.monotonic()
-        plans = mx.classify(block)
+        with self.account.enter("classify"):
+            plans = mx.classify(block)
         self.stats.t_classify += time.monotonic() - t0
         if plans is None:
             return False
         from coreth_tpu.replay.supervisor import BackendFault
         try:
-            return self.supervisor.run(
-                "device", None, mx.execute_run, [(block, plans)]) == 1
+            with self.account.enter("machine"):
+                return self.supervisor.run(
+                    "device", None, mx.execute_run,
+                    [(block, plans)]) == 1
         except BackendFault:
             return False  # caller takes the exact host path
 
@@ -1828,10 +1938,12 @@ class ReplayEngine:
             # the batch built here would be stale by then (classify
             # simulates token slot values against current state, and
             # the machine blocks before j move that state)
-            if j > i and self._classify(blocks[j]) is not None:
+            with self.account.enter("classify"):
+                fast = self._classify(blocks[j]) if j > i else None
+                plans = mx.classify(blocks[j]) if fast is None else None
+            if fast is not None:
                 self.stats.t_classify += time.monotonic() - t0
                 break
-            plans = mx.classify(blocks[j])
             self.stats.t_classify += time.monotonic() - t0
             if plans is None or (fork is not None
                                  and mx._fork != fork):
@@ -1845,8 +1957,9 @@ class ReplayEngine:
         mx._fork = fork
         from coreth_tpu.replay.supervisor import BackendFault
         try:
-            consumed = self.supervisor.run("device", None,
-                                           mx.execute_run, items)
+            with self.account.enter("machine"):
+                consumed = self.supervisor.run("device", None,
+                                               mx.execute_run, items)
         except BackendFault:
             # persistent device fault with no progress: the run's
             # first block takes the exact host path; the rest
@@ -1858,11 +1971,13 @@ class ReplayEngine:
             consumed = 1
         return consumed
 
+    @_public_call
     def replay_block(self, block: Block) -> bytes:
         """Process one block synchronously (tests; replay() windows)."""
         self.warm_senders(block)
         t0 = time.monotonic()
-        batch = self._classify(block)
+        with self.account.enter("classify"):
+            batch = self._classify(block)
         self.stats.t_classify += time.monotonic() - t0
         if batch is None:
             if self._try_machine(block):
@@ -1876,6 +1991,7 @@ class ReplayEngine:
         resume = self._complete_window(win, [block], 0)
         return self.root if resume is None else self.root
 
+    @_public_call
     def replay(self, blocks: List[Block],
                window: Optional[int] = None) -> bytes:
         """Windowed, PIPELINED replay.
@@ -1903,6 +2019,7 @@ class ReplayEngine:
         from coreth_tpu.replay.supervisor import BackendFault
         window = window or self.window
         n = len(blocks)
+        acct = self.account
         pipe = _SenderPipeline(self, blocks)
         i = 0
         pending: Optional[Tuple[dict, int]] = None
@@ -1911,16 +2028,21 @@ class ReplayEngine:
             run: List[Tuple[Block, dict]] = []
             run_start = i
             hit_fallback = False
-            while i < n and len(run) < window:
-                pipe.ensure(i)
-                t0 = time.monotonic()
-                batch = self._classify(blocks[i])
-                self.stats.t_classify += time.monotonic() - t0
-                if batch is None:
-                    hit_fallback = True
-                    break
-                run.append((blocks[i], batch))
-                i += 1
+            # ONE classify phase a window, not one a block: the sender
+            # phases that ensure() enters nest inside it and take their
+            # own time out (self times), and a one-tx block is too
+            # short to carry two more boundaries
+            with acct.enter("classify"):
+                while i < n and len(run) < window:
+                    pipe.ensure(i)
+                    t0 = time.monotonic()
+                    batch = self._classify(blocks[i])
+                    self.stats.t_classify += time.monotonic() - t0
+                    if batch is None:
+                        hit_fallback = True
+                        break
+                    run.append((blocks[i], batch))
+                    i += 1
             win = None
             failed_run = None
             if run:
@@ -1955,6 +2077,7 @@ class ReplayEngine:
                 i += self._machine_run(blocks, i, ensure=pipe.ensure)
         return self.root
 
+    @_public_call
     def quarantine_block(self, block: Block) -> List[str]:
         """Tolerant host application of a poison block — one that
         failed validation on EVERY backend (device, native, and the
@@ -1976,6 +2099,7 @@ class ReplayEngine:
         self.stats.blocks_quarantined += 1
         return reasons
 
+    @_public_call
     def rollback_block(self, block: Block) -> bytes:
         """Reorg primitive: pop a quarantined block's generation and
         re-converge the engine to the pre-block (strict-mode) state.
@@ -2092,6 +2216,7 @@ class ReplayEngine:
                 "complete": complete,
                 "failed_tx_index": failed_tx_index}
 
+    @_in_phase("fallback")
     def _fallback(self, block: Block, strict: bool = True,
                   reasons: Optional[List[str]] = None) -> bytes:
         """Bit-exact host path for non-transfer blocks; device state for
@@ -2099,12 +2224,6 @@ class ReplayEngine:
         the quarantine mode: consensus mismatches are appended to
         ``reasons`` instead of raised and the computed state still
         commits (see quarantine_block)."""
-        with obs.span("replay/host_fallback", number=block.number,
-                      strict=strict):
-            return self._fallback_run(block, strict, reasons)
-
-    def _fallback_run(self, block: Block, strict: bool,
-                      reasons: Optional[List[str]]) -> bytes:
         self.commit_pipe.flush()  # staged windows precede this block
         prev_root = self.root
         prev_header = self.parent_header

@@ -821,10 +821,6 @@ class MachineBlockExecutor:
         # the FIRST dispatch propagates failures: nothing is staged
         # yet, so the supervisor wrapping this call (engine
         # _machine_run) can safely retry or strike toward demotion.
-        # (No jax_span here: the tighter annotation around the kernel
-        # call itself lives in adapter/shard issue(), with the right
-        # per-runner label — an outer one would double-label it and
-        # sweep host-side packing under "device" time.)
         with obs.span("machine/window_issue", blocks=len(chunks[0])):
             inflight = runner.issue(self._window_items(chunks[0]))
         e.stats.t_device += time.monotonic() - t0

@@ -119,6 +119,11 @@ class StreamReport:
     # bundle paths; quarantined entries above also gain a "bundle"
     # path.  {} when the recorder is off.
     forensics: dict = field(default_factory=dict)
+    # the engine's self-time account (obs/account.py Account.row()):
+    # seconds and entries per phase of the execute stage's thread, and
+    # the seconds of each phase in which the device had nothing in
+    # flight.  Always on; the stream runs under its ``loop`` phase.
+    account: dict = field(default_factory=dict)
 
     def row(self) -> dict:
         return dict(self.__dict__)
@@ -489,12 +494,13 @@ class StreamingPipeline:
             run = []
             k = 0
             t0 = time.monotonic()
-            while k < len(buf) and len(run) < e.window:
-                batch = e._classify(buf[k].block)
-                if batch is None:
-                    break
-                run.append((buf[k].block, batch))
-                k += 1
+            with e.account.enter("classify"):
+                while k < len(buf) and len(run) < e.window:
+                    batch = e._classify(buf[k].block)
+                    if batch is None:
+                        break
+                    run.append((buf[k].block, batch))
+                    k += 1
             e.stats.t_classify += time.monotonic() - t0
             win = None
             if run:
@@ -591,6 +597,11 @@ class StreamingPipeline:
                                       name="serve-feed", daemon=True)
             pre_t = threading.Thread(target=self._prefetch_loop,
                                      name="serve-prefetch", daemon=True)
+            # the execute stage is the engine's replay thread: claim
+            # its account BEFORE the prefetch thread can call
+            # warm_senders, whose time is not this thread's
+            acct = self.engine.account
+            claim = acct.begin()
             feed_t.start()
             pre_t.start()
             try:
@@ -603,6 +614,7 @@ class StreamingPipeline:
                     # anything still staged belongs to completed blocks
                     self.engine.commit_pipe.flush()
                     restore()
+                    acct.end(claim)
                 if self._errors:
                     raise self._errors[0]
                 if self._ckpt is not None and self.stats.blocks:
@@ -648,6 +660,8 @@ class StreamingPipeline:
         }
         if self._stages is not None:
             row["stage_breakdown"] = self._stages.breakdown()
+        # as of the execute stage's last phase boundary
+        row["account"] = self.engine.account.row()
         rec = forensics.recorder()
         if rec is not None:
             # quarantine forensics, live: counters + bundle paths for
@@ -737,6 +751,7 @@ class StreamingPipeline:
         flat = getattr(self.engine, "flat", None)
         if flat is not None:
             s.flat = flat.snapshot()
+        s.account = self.engine.account.row()
         if self._stages is not None:
             # per-stage share of enqueue->committed time (sums to ~1.0
             # across queue_feed/prefetch/queue_exec/execute/commit) —

@@ -94,9 +94,8 @@ def test_disabled_mode_is_noop():
     the SAME shared null span object, no ring, no BlockTrace."""
     assert obs.tracer() is None
     assert obs.span("anything", blocks=3) is _NULL_SPAN
-    assert obs.jax_span("anything") is _NULL_SPAN
     assert obs.instant("anything") is None
-    assert obs.block_begin(7) is None
+    assert obs.block_begin(7, None, obs.StageAccumulator()) is None
     assert obs.write_out() is None
     assert obs.arm_from_env() is None  # env unset -> stays off
     with obs.span("still-a-noop"):
@@ -228,8 +227,8 @@ def test_traced_stream_breakdown_and_perfetto_schema():
     names = {e["name"] for e in evs}
     for want in ("block/enqueue", "block/prefetched",
                  "block/exec_start", "block/committed",
-                 "serve/prefetch_warm", "replay/issue_window",
-                 "replay/complete_window", "commit/flush"):
+                 "serve/prefetch_warm", "window/dispatch",
+                 "window/fetch_wait", "commit/flush"):
         assert want in names, want
 
 
