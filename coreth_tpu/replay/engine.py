@@ -132,6 +132,10 @@ class ReplayStats:
     # windows whose fetch-tensor download was started asynchronously at
     # issue time (the windowed device-read prefetch; serve/prefetch.py)
     reads_prefetched: int = 0
+    # lane fill of the transfer windows issued: transactions packed
+    # (real) against K x pad lanes uploaded and scanned (padded)
+    lanes_real: int = 0
+    lanes_padded: int = 0
     # blocks applied tolerantly after failing validation on every
     # backend (supervisor quarantine — streaming callers only)
     blocks_quarantined: int = 0
@@ -199,6 +203,21 @@ def _public_call(fn):
 # Native transfers carry amount16 = 0 / slots = 0 (the reserved dummy);
 # token transfers carry value16 = 0.  Both kinds batch into one step.
 TXD_COLS = 72
+# Lane (tx) axis of a transfer window: the smallest LANE_FLOOR *
+# LANE_STEP**n that holds the window's largest block — 16, 64, 256,
+# 1024, ... — so a window of one-tx blocks packs, uploads and scans 16
+# lanes a block, a full C-Chain block (714 transfers) 1,024, and the
+# padding never exceeds LANE_STEP x the real lanes.  x4 steps keep the
+# compiled _transfer_window variants at four for mainnet traffic.
+LANE_FLOOR = 16
+LANE_STEP = 4
+
+
+def lane_bucket(n_txs: int) -> int:
+    pad = LANE_FLOOR
+    while pad < n_txs:
+        pad *= LANE_STEP
+    return pad
 
 
 def pack_txd(batch: dict, B: int, pad: int) -> np.ndarray:
@@ -762,7 +781,12 @@ class ReplayEngine:
                  capacity: int = 1 << 14, window: int = 16,
                  slot_capacity: Optional[int] = None, mesh=None,
                  engine=None):
-        """mesh: a jax.sharding.Mesh with >1 device switches execution
+        """batch_pad: INERT since PR 30 — accepted so existing call
+        sites keep working, read by nothing: a window's lane pad is
+        lane_bucket() of its largest block (ROADMAP D4 removes the
+        argument and its call sites).
+
+        mesh: a jax.sharding.Mesh with >1 device switches execution
         to the mesh-sharded kernels (parallel/mesh.py): tx batches and
         state rows shard over the ``dp`` axis, per-account/per-slot
         totals reduce with psum_scatter over ICI, and sender recovery
@@ -784,13 +808,13 @@ class ReplayEngine:
             scap = slot_capacity or capacity
             n_dev = mesh.devices.size
             for name, dim in (("capacity", cap), ("slot_capacity", scap),
-                              ("batch_pad", batch_pad)):
+                              ("LANE_FLOOR", LANE_FLOOR)):
                 if dim % n_dev:
                     raise ValueError(
                         f"{name}={dim} must divide by the mesh size "
                         f"{n_dev} (rows/txs shard over the dp axis); "
-                        "doubling growth preserves divisibility, so fix "
-                        "the initial value")
+                        "table doubling and the lane buckets preserve "
+                        "divisibility, so fix the initial value")
             self.mesh = mesh
             self._n_shards = n_dev
             # the transfer-window kernel itself is fetched per window
@@ -822,7 +846,6 @@ class ReplayEngine:
         self.engine.set_config(config)
         self.processor = Processor(config, engine=self.engine)
         self.stats = ReplayStats()
-        self.batch_pad = batch_pad
         self.window = window
         self.root = state_root
         # parent header of the next block to replay; needed by the
@@ -1404,12 +1427,15 @@ class ReplayEngine:
         while never scanning more than 2x the real work.  With a
         non-power-of-two window the top bucket exceeds it (window=12
         compiles K=16); keep ``window`` a power of two to avoid the
-        extra padded slots."""
+        extra padded slots.  The lane axis is lane_bucket() of the
+        window's largest block: the masked-out lanes it leaves off
+        contributed zeros to every segment sum."""
         flushed = self.state.flush_staged()
         K = 1
         while K < len(items):
             K *= 2
-        pad = self.batch_pad
+        pad = lane_bucket(max(len(block.transactions)
+                              for block, _ in items))
         t_pad = 256
         s_pad = 8
         touched_lists = []
@@ -1436,9 +1462,6 @@ class ReplayEngine:
 
         local_batches = []
         for block, batch in items:
-            B = len(block.transactions)
-            while pad < B:
-                pad *= 2
             lb = dict(batch)
             lb["senders"] = [a_loc(g) for g in batch["senders"]]
             lb["recips"] = [a_loc(g) for g in batch["recips"]]
@@ -1484,6 +1507,11 @@ class ReplayEngine:
                 [slot_local[g] for g in slot_lists[k]]
         return (txds, t_idxs, s_idxs, acct_gids, slot_gids,
                 touched_lists, slot_lists, flushed)
+
+    def _count_lanes(self, items, txds) -> None:
+        self.stats.lanes_real += sum(
+            len(block.transactions) for block, _ in items)
+        self.stats.lanes_padded += txds.shape[0] * txds.shape[1]
 
     @_in_phase("window/prepare")
     def _issue_window_mesh(self, items: List[Tuple[Block, dict]],
@@ -1534,6 +1562,9 @@ class ReplayEngine:
                 self.stats.reads_prefetched += 1
             except AttributeError:
                 pass
+            # (the fetch=False re-apply of _recover_window is no new
+            # window: the single-device path does not count it either)
+            self._count_lanes(items, txds)
         ticket = obs.device_issue(acct)
         self.stats.t_device += time.monotonic() - t0
         return dict(items=items, prev=prev, fetches=fetches,
@@ -1613,6 +1644,7 @@ class ReplayEngine:
             self.stats.reads_prefetched += 1
         except AttributeError:
             pass  # non-jax array (mesh path fetches are already np)
+        self._count_lanes(items, txds)
         ticket = obs.device_issue(acct)
         self.stats.t_device += time.monotonic() - t0
         return dict(items=items, prev=prev, fetches=fetches,

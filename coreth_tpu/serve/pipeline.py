@@ -124,6 +124,10 @@ class StreamReport:
     # the seconds of each phase in which the device had nothing in
     # flight.  Always on; the stream runs under its ``loop`` phase.
     account: dict = field(default_factory=dict)
+    # lane fill of the engine's transfer windows (ReplayStats
+    # lanes_real / lanes_padded): transactions packed against lanes
+    # uploaded and scanned
+    lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
         return dict(self.__dict__)
@@ -662,6 +666,7 @@ class StreamingPipeline:
             row["stage_breakdown"] = self._stages.breakdown()
         # as of the execute stage's last phase boundary
         row["account"] = self.engine.account.row()
+        row["lanes"] = self._lanes()
         rec = forensics.recorder()
         if rec is not None:
             # quarantine forensics, live: counters + bundle paths for
@@ -705,6 +710,10 @@ class StreamingPipeline:
         self._stop.set()
 
     # ------------------------------------------------------------ report
+    def _lanes(self) -> dict:
+        st = self.engine.stats
+        return {"real": st.lanes_real, "padded": st.lanes_padded}
+
     def _publish(self, wall: float) -> None:
         s = self.stats
         s.wall_s = round(wall, 3)
@@ -752,6 +761,7 @@ class StreamingPipeline:
         if flat is not None:
             s.flat = flat.snapshot()
         s.account = self.engine.account.row()
+        s.lanes = self._lanes()
         if self._stages is not None:
             # per-stage share of enqueue->committed time (sums to ~1.0
             # across queue_feed/prefetch/queue_exec/execute/commit) —

@@ -328,6 +328,7 @@ def test_engine_publishes_metrics():
     engine.publish_metrics(reg)
     snap = reg.snapshot()
     assert "replay/t_device" in snap and "replay/blocks_device" in snap
+    assert "replay/lanes_real" in snap and "replay/lanes_padded" in snap
 
 
 def test_avax_service_queries(tmp_path):

@@ -59,17 +59,21 @@ def test_u256_segment_headroom():
 
 # ------------------------------------------------------------ replay parity
 
-def build_transfer_chain(n_blocks, txs_per_block, cross=False):
+def build_sized_chain(sizes, cross=False):
+    """Transfers from the eight keys in turn, ``sizes[i]`` of them in
+    block i: to fresh addresses, or (``cross``) to the next key."""
     genesis = Genesis(config=CFG, gas_limit=8_000_000,
                       alloc={a: GenesisAccount(balance=10**24)
                              for a in ADDRS})
     db = Database()
     gblock = genesis.to_block(db)
     nonces = [0] * len(KEYS)
+    sent = [0]
 
     def gen(i, bg):
-        for j in range(txs_per_block):
-            k = (i * txs_per_block + j) % len(KEYS)
+        for j in range(sizes[i]):
+            k = sent[0] % len(KEYS)
+            sent[0] += 1
             to = ADDRS[(k + 1) % len(KEYS)] if cross \
                 else bytes([0x40 + k]) * 20
             bg.add_tx(sign_tx(DynamicFeeTx(
@@ -79,8 +83,12 @@ def build_transfer_chain(n_blocks, txs_per_block, cross=False):
             ), KEYS[k], CFG.chain_id))
             nonces[k] += 1
 
-    blocks, _ = generate_chain(CFG, gblock, db, n_blocks, gen, gap=2)
+    blocks, _ = generate_chain(CFG, gblock, db, len(sizes), gen, gap=2)
     return genesis, gblock, blocks
+
+
+def build_transfer_chain(n_blocks, txs_per_block, cross=False):
+    return build_sized_chain([txs_per_block] * n_blocks, cross)
 
 
 def test_replay_disjoint_transfers():
@@ -196,6 +204,63 @@ def test_prepare_window_pads_to_pow2_not_full_window():
          (blocks[1], engine._classify(blocks[1])),
          (blocks[2], engine._classify(blocks[2]))])
     assert txds2.shape[0] == 4  # 3 blocks -> pow2 bucket of 4
+
+
+# consecutive blocks either side of every lane-bucket edge a C-Chain
+# block can reach (714 plain transfers fill 15M gas)
+LANE_SIZES = [1, 16, 17, 64, 65, 714]
+
+
+@pytest.fixture(scope="module")
+def sized_chain():
+    genesis, _, blocks = build_sized_chain(LANE_SIZES)
+    return genesis, blocks
+
+
+def _prepared_shapes(genesis, blocks, **engine_kw):
+    """(txds, t_idxs) shapes of ONE window holding ``blocks``."""
+    db = Database()
+    gb = genesis.to_block(db)
+    engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
+                          capacity=256, window=16, **engine_kw)
+    items = []
+    for block in blocks:
+        engine.warm_senders(block)
+        items.append((block, engine._classify(block)))
+    txds, t_idxs, *_ = engine._prepare_window(items)
+    return txds.shape, t_idxs.shape
+
+
+@pytest.mark.parametrize("n_txs,lanes", [
+    (1, 16), (16, 16), (17, 64), (64, 64), (65, 256), (714, 1024)])
+def test_prepare_window_lane_bucket(sized_chain, n_txs, lanes):
+    """The lane axis of a window is the x4 bucket (floor 16) of its
+    largest block — not a fixed 1,024."""
+    genesis, blocks = sized_chain
+    block = blocks[LANE_SIZES.index(n_txs)]
+    assert len(block.transactions) == n_txs
+    txds_shape, _ = _prepared_shapes(genesis, [block])
+    assert txds_shape == (1, lanes, 72)
+
+
+def test_prepare_window_largest_block_decides(sized_chain):
+    """A window that mixes 1-tx and 65-tx blocks pads every block to
+    the 65-tx block's bucket, whatever the order."""
+    genesis, blocks = sized_chain
+    one, big = blocks[0], blocks[4]
+    assert (len(one.transactions), len(big.transactions)) == (1, 65)
+    for window in ([one, big], [big, one]):
+        txds_shape, _ = _prepared_shapes(genesis, window)
+        assert txds_shape == (2, 256, 72)
+
+
+@pytest.mark.parametrize("batch_pad", [8, 1024])
+def test_batch_pad_is_inert(sized_chain, batch_pad):
+    """The constructor still takes ``batch_pad``; nothing reads it."""
+    genesis, blocks = sized_chain
+    for window in ([blocks[0]], [blocks[2]], blocks[:5]):
+        assert _prepared_shapes(genesis, window, batch_pad=batch_pad) \
+            == _prepared_shapes(genesis, window)
 
 
 def test_device_rehash_parity():
@@ -457,11 +522,10 @@ def test_replay_speculative_window_discard():
     assert engine.stats.blocks_device == 2
 
 
-def test_replay_mid_window_failure_recovery():
-    """A block that is sequentially valid but fails the conservative
-    device check (sender spends credits received earlier in the same
-    block) triggers the rewind/re-apply/fallback/resume path at k>0
-    (_recover_window), producing the exact sequential result."""
+def _insolvent_mid_block_chain():
+    """Block 1 is sequentially valid but fails the conservative device
+    check (its second sender spends credits received earlier in the
+    same block)."""
     genesis = Genesis(config=CFG, gas_limit=8_000_000,
                       alloc={ADDRS[0]: GenesisAccount(balance=10**24),
                              ADDRS[1]: GenesisAccount(balance=10**17),
@@ -491,11 +555,57 @@ def test_replay_mid_window_failure_recovery():
                 KEYS[0], CFG.chain_id))
 
     blocks, _ = generate_chain(CFG, gblock, db0, 3, gen, gap=2)
+    return genesis, blocks
+
+
+@pytest.mark.parametrize("shape", ["insolvent_two_tx", "one_tx_blocks"])
+def test_replay_mid_window_failure_recovery(monkeypatch, shape):
+    """A block that fails the device path at k>0 of its window triggers
+    the rewind/re-apply/fallback/resume path (_recover_window),
+    producing the exact sequential result.  ``insolvent_two_tx``: the
+    device's own conservative check refuses block 1.  ``one_tx_blocks``:
+    a one-tx-a-block chain, every window in the 16-lane floor bucket,
+    block 3 made to fail its device validation once — the valid prefix
+    [0, 3) is re-applied through _prepare_window in that bucket."""
+    from coreth_tpu.replay.engine import ReplayError
+    if shape == "insolvent_two_tx":
+        genesis, blocks = _insolvent_mid_block_chain()
+        failed = 1
+    else:
+        genesis, _, blocks = build_sized_chain([1] * 6)
+        failed = 3
     db = Database()
     gb = genesis.to_block(db)
     engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
-                          capacity=256, batch_pad=64, window=16)
+                          capacity=256, window=16)
+    lanes, recovered = [], []
+    prepare, recover = engine._prepare_window, engine._recover_window
+    validate = engine._validate_and_advance
+
+    def spy_prepare(items):
+        out = prepare(items)
+        lanes.append(out[0].shape[:2])
+        return out
+
+    def spy_recover(win, arr, k, *rest):
+        recovered.append(k)
+        return recover(win, arr, k, *rest)
+
+    def fail_once(block, *rest):
+        if block is blocks[failed] and not recovered:
+            raise ReplayError("forced device validation failure")
+        return validate(block, *rest)
+
+    monkeypatch.setattr(engine, "_prepare_window", spy_prepare)
+    monkeypatch.setattr(engine, "_recover_window", spy_recover)
+    if shape == "one_tx_blocks":
+        monkeypatch.setattr(engine, "_validate_and_advance", fail_once)
     root = engine.replay(blocks)
     assert root == blocks[-1].root
-    assert engine.stats.blocks_fallback == 1   # the insolvent-check block
-    assert engine.stats.blocks_device == 2     # prefix + resumed tail
+    assert recovered == [failed]
+    assert engine.stats.blocks_fallback == 1   # the failed block
+    assert engine.stats.blocks_device == len(blocks) - 1  # prefix + tail
+    # the window, the prefix re-apply and the resumed tail: K is the
+    # pow2 of each run, every one of them 16 lanes wide
+    assert [l[1] for l in lanes] == [16, 16, 16], lanes
+    assert lanes[1][0] >= failed

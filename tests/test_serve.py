@@ -199,6 +199,11 @@ def test_stream_prefetch_overlap_counters():
     assert report.prefetch["sigs"] > 0
     assert report.prefetch["hits"] > 0
     assert report.prefetch["reads_prefetched"] > 0
+    # the windows' lane fill rides the report (and /report) too
+    assert report.lanes["real"] == sum(len(b.transactions) for b in blocks)
+    assert report.lanes["padded"] == stream_eng.stats.lanes_padded \
+        >= report.lanes["real"]
+    assert pipe._live_report()["lanes"] == report.lanes
     assert report.latency_ms["p99"] >= report.latency_ms["p50"] > 0
     assert report.sustained_txs_s > 0
 

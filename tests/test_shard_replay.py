@@ -58,6 +58,28 @@ if _native.load() is not None:
     _trie_backends.append("native")
 
 
+def _mappings() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def map_headroom():
+    """Every XLA:CPU executable maps its code sections, and a process
+    may hold 65,530 mappings here (vm.max_map_count).  This file's
+    cases compile ~60,000 mappings' worth between them, on top of what
+    the worker's earlier files left: past the limit LLVM cannot
+    allocate and the worker dies mid-compile.  So a case that starts
+    past 40,000 drops the compiled programs first (each case warms its
+    own)."""
+    if _mappings() > 40_000:
+        jax.clear_caches()
+    yield
+
+
 def _alloc(extra=None):
     alloc = {a: GenesisAccount(balance=10**24) for a in ADDRS}
     alloc[POOL] = pool_genesis_account(10**15, 10**15)
@@ -625,3 +647,70 @@ def test_two_device_hot_contract_smoke(monkeypatch):
     assert tps2 >= 0.8 * tps1, (
         f"hot-contract 2-device curve collapsed: {tps2:.0f} vs "
         f"{tps1:.0f} txs/s")
+
+
+# ------------------------------------------- lane buckets (PR 30)
+# blocks either side of the 16- and 64-lane bucket edges, in a row
+EDGE_SIZES = [1, 16, 17, 64, 65, 1]
+
+
+@pytest.fixture(scope="module")
+def edge_chain():
+    def gen(i, nonces):
+        return [_tx(j % 8, nonces,
+                    bytes([0x61 + i]) + bytes([j % 8]) * 19,
+                    gas=21_000, value=1000 + j)
+                for j in range(EDGE_SIZES[i])]
+    return _build_chain(len(EDGE_SIZES), gen)
+
+
+@pytest.fixture
+def fresh_jit_caches():
+    """No compiled program before the test, so the count of variants
+    it compiles is exact, and none after it (map_headroom above)."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("window,shapes", [
+    # (K, lanes) of the windows issued: K the pow2 of the run, lanes
+    # the bucket of its largest block
+    (4, [(4, 64), (2, 256)]),
+    # a window a block: EVERY header root is checked
+    (1, [(1, 16), (1, 16), (1, 64), (1, 64), (1, 256), (1, 16)]),
+], ids=["window4", "window1"])
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_lane_buckets_exact_across_edges(monkeypatch, fresh_jit_caches,
+                                         edge_chain, n_dev, window,
+                                         shapes):
+    """One chain whose consecutive blocks cross the lane-bucket edges
+    replays to the header roots on one device and on a 2-device mesh,
+    wholly on the device, and the lane counters say what was packed
+    and what was scanned.  On one device the same run counts the
+    _transfer_window variants it compiled (PERF.md §7, Speed 8)."""
+    from coreth_tpu.replay import engine as engine_mod
+    blocks = edge_chain
+    mesh = make_mesh(jax.devices("cpu")[:n_dev]) if n_dev > 1 else None
+    seen = []
+    prepare = ReplayEngine._prepare_window
+
+    def spy(self, items):
+        out = prepare(self, items)
+        seen.append(tuple(a.shape for a in out[:5]))
+        return out
+
+    monkeypatch.setattr(ReplayEngine, "_prepare_window", spy)
+    before = engine_mod._transfer_window._cache_size()
+    root, eng = _replay(blocks, mesh, window=window)
+    compiled = engine_mod._transfer_window._cache_size() - before
+    assert root == blocks[-1].root
+    assert eng.stats.blocks_device == len(blocks)
+    assert eng.stats.blocks_fallback == 0
+    assert [s[0][:2] for s in seen] == shapes
+    assert eng.stats.lanes_real == sum(EDGE_SIZES) == eng.stats.txs
+    assert eng.stats.lanes_padded == sum(k * pad for k, pad in shapes)
+    # one executable per distinct input shape: 2 for this chain at
+    # window 4, 3 a block at a time (the mesh path has a jit of its own)
+    assert len(set(seen)) == len(set(shapes))
+    assert compiled == (len(set(shapes)) if n_dev == 1 else 0)

@@ -122,6 +122,23 @@ def test_transfer_window_full_width(one_chip):
     assert mem.temp_size_in_bytes < 1 << 30
 
 
+def test_transfer_window_one_tx_blocks(one_chip):
+    """engine._transfer_window at the steady window of a one-tx-a-block
+    chain at the engine's defaults (the benchmark's ``valuetx`` cell):
+    16 blocks in the 16-lane floor bucket (engine.LANE_FLOOR), 256
+    account locals, the 2^14-row tables."""
+    s = one_chip
+    cap = 1 << 14
+    compiled = E._transfer_window.lower(
+        s((cap, 16)), s((cap,)), s((cap, 16)),
+        s((256,)), s((8,)), s((16, E.LANE_FLOOR, E.TXD_COLS)),
+        s((16, 256)), s((16, 8))).compile()
+    mem = compiled.memory_analysis()
+    # the three tables and a 73 KB window (16 x 16 x 72 int32), not
+    # the 4.7 MB of a 1,024-lane one
+    assert mem.argument_size_in_bytes < cap * 33 * 4 + (1 << 20)
+
+
 def test_erc20_window_and_block_steps(one_chip):
     """The ERC-20 fast path's window (256-tx blocks, 4096 slot locals)
     and the per-block _transfer_step / _slot_step it is built from
