@@ -845,6 +845,14 @@ class MachineWindowRunner:
         self.lanes_specialized = 0  # lanes run on a traced sub-program
         self.specialize_escapes = 0  # lanes kept on the generic kernel
         self.programs_traced = 0    # contracts compiled to sub-programs
+        # the self-time account the runner's phases are charged to
+        # (machine/upload, dispatch, fetch_wait): the owning engine's,
+        # which its executor sets; nobody's for a bare runner
+        self.account = obs.NULL_ACCOUNT
+        # lane fill of the windows dispatched (every attempt): call
+        # lanes packed against blocks x lanes uploaded and scanned
+        self.lanes_real = 0
+        self.lanes_padded = 0
         # key-range sharding surface (evm/device/shard.py overrides
         # populate these; the single-chip runner has no shards, so they
         # stay zero — machine_counters() reads them uniformly)
@@ -1397,41 +1405,51 @@ class MachineWindowRunner:
                 for j, key in enumerate(block_pre[li]):
                     sgid[bi, li, j] = self._gid(t.address, key)
         fill_kdig(kdig, kjobs)
-        table, key_tab = self._device_tables(G)
-        if code_cached is None:
-            code_cached = (jnp.asarray(code), jnp.asarray(jdest),
-                           jnp.asarray(code_len))
-            if len(self._win_code_cache) >= 2:
-                # steady state needs two signatures at most (the short
-                # lead window + the full window); a shifting workload
-                # just rebuilds
-                self._win_code_cache.clear()
-            self._win_code_cache[code_sig] = code_cached
-        code_j, jdest_j, code_len_j = code_cached
-        inputs = dict(
-            code=code_j, jdest=jdest_j,
-            code_len=code_len_j,
-            calldata=jnp.asarray(calldata),
-            data_len=jnp.asarray(data_len),
-            start_gas=jnp.asarray(start_gas),
-            active=jnp.asarray(active), sgid=jnp.asarray(sgid),
-            prog_id=jnp.asarray(prog_id),
-            kdig=jnp.asarray(kdig),
-            callvalue=jnp.asarray(words["callvalue"]),
-            caller_w=jnp.asarray(words["caller_w"]),
-            address_w=jnp.asarray(words["address_w"]),
-            origin_w=jnp.asarray(words["origin_w"]),
-            gasprice_w=jnp.asarray(words["gasprice_w"]),
-            timestamp=jnp.asarray(timestamp),
-            number=jnp.asarray(number),
-            gaslimit=jnp.asarray(gaslimit),
-            coinbase_w=jnp.asarray(coinbase_w),
-            basefee_w=jnp.asarray(basefee_w),
-            chainid_w=jnp.asarray(word16(chain_id)),
-        )
-        fn = self._get_kernel(p, occ)
-        ticket = _count_dispatch()
-        out = self._dispatch(fn, table, key_tab, inputs)
+        self.lanes_real += int(active.sum())
+        self.lanes_padded += active.size
+        # the packing above is the caller's phase (machine/prepare);
+        # the host->device copies and the jitted call are two more
+        acct = self.account
+        acct.enter("machine/upload")
+        try:
+            table, key_tab = self._device_tables(G)
+            if code_cached is None:
+                code_cached = (jnp.asarray(code), jnp.asarray(jdest),
+                               jnp.asarray(code_len))
+                if len(self._win_code_cache) >= 2:
+                    # steady state needs two signatures at most (the
+                    # short lead window + the full window); a shifting
+                    # workload just rebuilds
+                    self._win_code_cache.clear()
+                self._win_code_cache[code_sig] = code_cached
+            code_j, jdest_j, code_len_j = code_cached
+            inputs = dict(
+                code=code_j, jdest=jdest_j,
+                code_len=code_len_j,
+                calldata=jnp.asarray(calldata),
+                data_len=jnp.asarray(data_len),
+                start_gas=jnp.asarray(start_gas),
+                active=jnp.asarray(active), sgid=jnp.asarray(sgid),
+                prog_id=jnp.asarray(prog_id),
+                kdig=jnp.asarray(kdig),
+                callvalue=jnp.asarray(words["callvalue"]),
+                caller_w=jnp.asarray(words["caller_w"]),
+                address_w=jnp.asarray(words["address_w"]),
+                origin_w=jnp.asarray(words["origin_w"]),
+                gasprice_w=jnp.asarray(words["gasprice_w"]),
+                timestamp=jnp.asarray(timestamp),
+                number=jnp.asarray(number),
+                gaslimit=jnp.asarray(gaslimit),
+                coinbase_w=jnp.asarray(coinbase_w),
+                basefee_w=jnp.asarray(basefee_w),
+                chainid_w=jnp.asarray(word16(chain_id)),
+            )
+            acct.switch("machine/dispatch")
+            fn = self._get_kernel(p, occ)
+            ticket = _count_dispatch()
+            out = self._dispatch(fn, table, key_tab, inputs)
+        finally:
+            acct.exit()
         # the input table was donated into the dispatch; the output
         # handle (post-window committed state) replaces it
         self.table = out["table"]
@@ -1656,8 +1674,11 @@ class MachineWindowRunner:
         while True:
             p = handle["p"]
             Lp = self._block_stride(handle)
-            packed = np.asarray(handle["out"]["packed"])
-            self._on_result_fetch(handle)
+            # the blocking read alone; the caller's phase
+            # (machine/fold) takes the unpacking below
+            with self.account.enter("machine/fetch_wait"):
+                packed = np.asarray(handle["out"]["packed"])
+                self._on_result_fetch(handle)
             pw = packed.shape[2] - 4
             pout = PackedOut(
                 packed[:, :, :pw].reshape(-1, pw), p)
@@ -1684,9 +1705,10 @@ class MachineWindowRunner:
                 # failed attempt's device table holds partial commits)
                 self.discovery_dispatches += 1
                 self._stale = True
-                handle = self.issue(handle["items"],
-                                    handle["discovered"],
-                                    attempt=handle["attempt"] + 1)
+                with self.account.enter("machine/prepare"):
+                    handle = self.issue(handle["items"],
+                                        handle["discovered"],
+                                        attempt=handle["attempt"] + 1)
                 continue
             break
         self._cold = False

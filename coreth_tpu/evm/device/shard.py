@@ -784,7 +784,9 @@ class ShardedWindowRunner(MachineWindowRunner):
         dispatching the next window before the packed-result fetch."""
         clean = handle.get("clean")
         if clean is None:
-            ex = np.asarray(handle["ex"])
+            # a blocking read like the packed results': the same phase
+            with self.account.enter("machine/fetch_wait"):
+                ex = np.asarray(handle["ex"])
             EVENT_LOG.append(f"exchange_fetch:{handle['seq']}")
             clean = bool((ex[:, 0] == self.n_shards).all()
                          and (ex[:, 1] == 0).all())
@@ -975,43 +977,51 @@ class ShardedWindowRunner(MachineWindowRunner):
                     rows[j, s] = g
                 rows[j, n] = cps[0][0]
             rows_j = jnp.asarray(rows)
-        table, key_tab = self._device_tables(G)
-        active_j = jnp.asarray(active)
-        inputs = dict(
-            code=jnp.asarray(code), jdest=jnp.asarray(jdest),
-            code_len=jnp.asarray(code_len),
-            calldata=jnp.asarray(calldata),
-            data_len=jnp.asarray(data_len),
-            start_gas=jnp.asarray(start_gas),
-            active=active_j, sgid=jnp.asarray(sgid),
-            prog_id=jnp.asarray(prog_id),
-            kdig=jnp.asarray(kdig),
-            callvalue=jnp.asarray(words["callvalue"]),
-            caller_w=jnp.asarray(words["caller_w"]),
-            address_w=jnp.asarray(words["address_w"]),
-            origin_w=jnp.asarray(words["origin_w"]),
-            gasprice_w=jnp.asarray(words["gasprice_w"]),
-            timestamp=jnp.asarray(timestamp),
-            number=jnp.asarray(number),
-            gaslimit=jnp.asarray(gaslimit),
-            coinbase_w=jnp.asarray(coinbase_w),
-            basefee_w=jnp.asarray(basefee_w),
-            chainid_w=jnp.asarray(word16(chain_id)),
-        )
-        fn = self._get_kernel(p, occ)
-        ticket = _count_dispatch()
-        seq = _next_seq()
-        EVENT_LOG.append(f"dispatch:{seq}")
-        if rows_j is not None:
-            # PT_KEY_EXCHANGE: the intra-contract replica-sync
-            # collective compiled into THIS dispatch.  Contained like
-            # PT_EXCHANGE below — execute_run keeps the committed
-            # prefix and the supervisor strikes the device scope.
-            faults.fire(PT_KEY_EXCHANGE)
-        if rows_j is None:
-            out = self._dispatch(fn, table, key_tab, inputs)
-        else:
-            out = self._dispatch(fn, table, key_tab, inputs, rows_j)
+        self.lanes_real += int(active.sum())
+        self.lanes_padded += active.size
+        acct = self.account
+        acct.enter("machine/upload")
+        try:
+            table, key_tab = self._device_tables(G)
+            active_j = jnp.asarray(active)
+            inputs = dict(
+                code=jnp.asarray(code), jdest=jnp.asarray(jdest),
+                code_len=jnp.asarray(code_len),
+                calldata=jnp.asarray(calldata),
+                data_len=jnp.asarray(data_len),
+                start_gas=jnp.asarray(start_gas),
+                active=active_j, sgid=jnp.asarray(sgid),
+                prog_id=jnp.asarray(prog_id),
+                kdig=jnp.asarray(kdig),
+                callvalue=jnp.asarray(words["callvalue"]),
+                caller_w=jnp.asarray(words["caller_w"]),
+                address_w=jnp.asarray(words["address_w"]),
+                origin_w=jnp.asarray(words["origin_w"]),
+                gasprice_w=jnp.asarray(words["gasprice_w"]),
+                timestamp=jnp.asarray(timestamp),
+                number=jnp.asarray(number),
+                gaslimit=jnp.asarray(gaslimit),
+                coinbase_w=jnp.asarray(coinbase_w),
+                basefee_w=jnp.asarray(basefee_w),
+                chainid_w=jnp.asarray(word16(chain_id)),
+            )
+            acct.switch("machine/dispatch")
+            fn = self._get_kernel(p, occ)
+            ticket = _count_dispatch()
+            seq = _next_seq()
+            EVENT_LOG.append(f"dispatch:{seq}")
+            if rows_j is not None:
+                # PT_KEY_EXCHANGE: the intra-contract replica-sync
+                # collective compiled into THIS dispatch.  Contained like
+                # PT_EXCHANGE below — execute_run keeps the committed
+                # prefix and the supervisor strikes the device scope.
+                faults.fire(PT_KEY_EXCHANGE)
+            if rows_j is None:
+                out = self._dispatch(fn, table, key_tab, inputs)
+            else:
+                out = self._dispatch(fn, table, key_tab, inputs, rows_j)
+        finally:
+            acct.exit()
         self.table = out["table"]
         self._dispatched += 1
         # the exchange rides the same device queue, right behind the

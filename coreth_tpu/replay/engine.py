@@ -136,6 +136,11 @@ class ReplayStats:
     # (real) against K x pad lanes uploaded and scanned (padded)
     lanes_real: int = 0
     lanes_padded: int = 0
+    # the same for the fused machine windows dispatched (every
+    # attempt): call lanes packed against blocks x lanes uploaded and
+    # scanned (the window runner counts; machine_block._chunk_loop)
+    machine_lanes_real: int = 0
+    machine_lanes_padded: int = 0
     # blocks applied tolerantly after failing validation on every
     # backend (supervisor quarantine — streaming callers only)
     blocks_quarantined: int = 0
@@ -910,6 +915,15 @@ class ReplayEngine:
         # independent native demotion ladders instead of the last
         # constructor winning a module global
         self.db.fault_observer = self.supervisor
+        # general-bytecode block executor (machine_block.py): counters
+        # and its window (``CORETH_MACHINE_WINDOW``, default 8 blocks a
+        # fused dispatch) and lookahead (``CORETH_MACHINE_LOOKAHEAD``,
+        # default 32 blocks classified ahead for one run), read here;
+        # the window runner and its kernels stay lazy.  Always there,
+        # so whatever reads the machine's counters after a replay finds
+        # zeros where no machine block ran, never a missing attribute
+        from coreth_tpu.replay.machine_block import MachineBlockExecutor
+        self._machine = MachineBlockExecutor(self)
         self.account.end(build)
 
     # ---------------------------------------------------------------- index
@@ -1898,23 +1912,18 @@ class ReplayEngine:
         self.stats.blocks_device += 1
         self.stats.txs += B
 
-    def _machine_executor(self):
-        """Lazy general-bytecode block executor (machine_block.py)."""
-        if not hasattr(self, "_machine"):
-            from coreth_tpu.replay.machine_block import (
-                MachineBlockExecutor)
-            self._machine = MachineBlockExecutor(self)
-        return self._machine
-
     def _try_machine(self, block: Block) -> bool:
         """Execute an unclassifiable block on the general device step
         machine when every tx is device-eligible; False -> host path.
-        CORETH_MACHINE=0 forces the host path (A/B benching)."""
+        One block is one run of one window (bucketed to the executor's
+        default 8 all the same, so the first full window compiles
+        nothing new).  CORETH_MACHINE=0 forces the host path (A/B
+        benching)."""
         if not bool(int(os.environ.get("CORETH_MACHINE", "1"))):
             return False
         if not self.supervisor.allows("device"):
             return False
-        mx = self._machine_executor()
+        mx = self._machine
         t0 = time.monotonic()
         with self.account.enter("classify"):
             plans = mx.classify(block)
@@ -1937,7 +1946,10 @@ class ReplayEngine:
         and execute them as fused device OCC windows
         (machine_block.execute_run — one dispatch covers a whole window
         of blocks), else the exact host path.  Returns how many blocks
-        were processed (>= 1).
+        were processed (>= 1).  At the defaults a run is up to 32
+        blocks (the executor's LOOKAHEAD) in windows of 8 (its WINDOW):
+        account phase ``machine`` once a run, ``machine/*`` once a
+        window or dispatch.
 
         Classifying ahead is safe: machine blocks cannot deploy code or
         set multicoin flags, which is all classify() reads — but a host
@@ -1948,7 +1960,7 @@ class ReplayEngine:
                 or not self.supervisor.allows("device"):
             self._fallback(blocks[i])
             return 1
-        mx = self._machine_executor()
+        mx = self._machine
         # legacy mode consumes exactly one block per execute_run call:
         # collecting a LOOKAHEAD run would re-classify the same blocks
         # on every call (O(N*LOOKAHEAD)) and skew the A/B's t_classify

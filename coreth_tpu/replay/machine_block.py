@@ -82,13 +82,17 @@ class MachineBlockExecutor:
     """
 
     def __init__(self, engine):
+        """Defaults (what a deployment and the benchmark's contract
+        cell run under): ``WINDOW`` 8 machine blocks fused into one
+        device dispatch, ``LOOKAHEAD`` 32 blocks classified ahead by
+        ``engine._machine_run`` for one run — so a backlog of machine
+        blocks goes out as runs of four windows of eight.  Both are
+        read from the environment here, per executor (not at import),
+        so tests and callers can retune between engine constructions
+        like the other CORETH_* toggles this module consults at call
+        time."""
         self.e = engine
-        # read per-executor (not import time) so tests and callers can
-        # retune via env between engine constructions, like the other
-        # CORETH_* toggles this module consults at call time
-        # machine blocks fused into one device dispatch
         self.WINDOW = int(os.environ.get("CORETH_MACHINE_WINDOW", "8"))
-        # how many blocks ahead _machine_run classifies for one run
         self.LOOKAHEAD = int(
             os.environ.get("CORETH_MACHINE_LOOKAHEAD", "32"))
         self.rounds = 0            # OCC re-execution rounds (stats)
@@ -113,13 +117,16 @@ class MachineBlockExecutor:
             premap_array=0, discovery_dispatches=0, kernel_retraces=0,
             warm_failures=0,
             lanes_specialized=0, specialize_escapes=0,
-            programs_traced=0, kr_lanes=0, load_imb_sum=0,
-            load_imb_windows=0, exchange_psum=0, exchange_ppermute=0)
+            programs_traced=0, lanes_real=0, lanes_padded=0,
+            kr_lanes=0, load_imb_sum=0, load_imb_windows=0,
+            exchange_psum=0, exchange_ppermute=0)
 
     def machine_counters(self) -> dict:
-        """Predicted-premap + kernel-retrace counters over every
-        window runner this executor has owned (bench machine section;
-        the CI gates pin kernel_retraces and the discovery rate)."""
+        """Predicted-premap, kernel-retrace and lane-fill counters over
+        every window runner this executor has owned (bench machine
+        section; the CI gates pin kernel_retraces and the discovery
+        rate; ``lanes_real`` / ``lanes_padded`` feed
+        ``ReplayStats.machine_lanes_*``)."""
         out = dict(self._runner_totals)
         r = self._runner
         if r is not None:
@@ -311,7 +318,14 @@ class MachineBlockExecutor:
         """Run the block; returns the post-state root, or None when a
         lane escapes to the host (caller falls back).  Raises
         ReplayError on consensus validation failure, like the transfer
-        path."""
+        path.  Account phase ``machine/host_occ``: the whole of it, a
+        block at a time — an exit the fused path takes for a dirty
+        block, never its steady state."""
+        with self.e.account.enter("machine/host_occ"):
+            return self._execute_host_occ(block, plans)
+
+    def _execute_host_occ(self, block: Block,
+                          plans: List[TxPlan]) -> Optional[bytes]:
         e = self.e
         # a fused window may have staged earlier blocks of this run;
         # _host_resolve commits the engine tries for its scratch
@@ -730,6 +744,9 @@ class MachineBlockExecutor:
             else:
                 self._runner = MachineWindowRunner(
                     self._fork, self._base_value)
+            # the runner charges its own phases (machine/upload,
+            # dispatch, fetch_wait) to this engine's account
+            self._runner.account = e.account
             self._runner.seed_window_hint(self.WINDOW)
             self._runner_fork = self._fork
         self._runner_epoch = e.storage_epoch
@@ -803,7 +820,8 @@ class MachineBlockExecutor:
             k = 1
             while k < len(items) and self._serial_eligible(items[k][1]):
                 k += 1
-            with obs.span("machine/serial_run", blocks=k):
+            with obs.span("machine/serial_run", blocks=k), \
+                    e.account.enter("machine/serial"):
                 return self._execute_serial_run(items[:k])
         # ... and a serial block mid-run ends this window batch so the
         # NEXT execute_run call gives it the short-circuit
@@ -822,7 +840,7 @@ class MachineBlockExecutor:
         # yet, so the supervisor wrapping this call (engine
         # _machine_run) can safely retry or strike toward demotion.
         with obs.span("machine/window_issue", blocks=len(chunks[0])):
-            inflight = runner.issue(self._window_items(chunks[0]))
+            inflight = self._issue(runner, chunk=chunks[0])
         e.stats.t_device += time.monotonic() - t0
         from coreth_tpu.consensus.engine import ConsensusError
         from coreth_tpu.replay.engine import ReplayError
@@ -842,120 +860,151 @@ class MachineBlockExecutor:
                 raise
             return consumed
 
+    def _issue(self, runner, chunk=None, items=None) -> dict:
+        """One window from plans to a dispatch in flight, in account
+        phase ``machine/prepare`` (lanes from plans, premap, packing);
+        the runner nests ``machine/upload`` and ``machine/dispatch``
+        inside it.  ``items``: the lanes, where ``_chunk_loop`` built
+        them ahead (in an entry of the same phase)."""
+        with self.e.account.enter("machine/prepare"):
+            if items is None:
+                items = self._window_items(chunk)
+            return runner.issue(items)
+
     def _chunk_loop(self, runner, chunks, inflight) -> int:
         """The fused-window chunk loop of execute_run (split out so the
         fault containment above can recover progress: every fully
-        finished-and-staged block bumps ``_inflight_consumed``)."""
+        finished-and-staged block bumps ``_inflight_consumed``).
+
+        Account phases, entered by the WINDOW, never by the block (a
+        boundary a block cost 1.5% in PR 29): ``machine/fold`` from
+        before the read to the last block staged; nested inside it and
+        taking their own time out, the next window's ``machine/prepare``
+        (twice: its lanes are built BEFORE the read, while this window
+        is on the chip, and issued after it) and the read itself
+        (``machine/fetch_wait``, in the runner); ``commit/flush``
+        follows outside."""
         e = self.e
         consumed = 0
         ci = 0
         while ci < len(chunks):
-            chunk = chunks[ci]
-            # sharded runner: the collective exchange tensor (tiny) is
-            # fetched FIRST; if every shard committed clean and the
-            # next window provably needs no table rebuild, its
-            # per-shard dispatch goes out BEFORE this window's packed
-            # results are fetched — the cross-shard exchange overlaps
-            # the next window's dispatch (pinned by the EVENT_LOG
-            # ordering test).  The mirror still learns this window's
-            # writes before any future rebuild: can_pipeline proved
-            # the early dispatch itself cannot rebuild.
-            early = None
-            next_items = self._window_items(chunks[ci + 1]) \
-                if ci + 1 < len(chunks) else None
-            if next_items is not None and hasattr(runner, "poll_clean"):
-                t0 = time.monotonic()
-                if (runner.poll_clean(inflight)
-                        and runner.can_pipeline(next_items)):
-                    early = runner.issue(next_items)
-                e.stats.t_device += time.monotonic() - t0
-            t0 = time.monotonic()
-            with obs.span("machine/window_complete",
-                          blocks=len(chunk)):
-                wres = runner.complete(inflight)
-            e.stats.t_device += time.monotonic() - t0
-            inflight = None
-            self.windows += 1
-            self.window_attempts += wres.attempts
-            imb_w = (self._runner_totals["load_imb_windows"]
-                     + runner.load_imb_windows)
-            if imb_w:
-                # max/mean per-shard lane occupancy (permille counts),
-                # averaged over EVERY sharded window this executor has
-                # run — including runners a fault rebuild discarded
-                # (ReplayStats -> metrics registry -> bench
-                # multichip/hot_contract sections)
-                e.stats.load_imbalance = round(
-                    (self._runner_totals["load_imb_sum"]
-                     + runner.load_imb_sum) / imb_w / 1000, 3)
-            if early is not None and not all(wres.clean):
-                # cannot happen (a clean exchange implies clean packed
-                # results); distrust the device table if it ever does
-                runner.invalidate()
+            with e.account.enter("machine/fold"):
+                chunk = chunks[ci]
+                # sharded runner: the collective exchange tensor (tiny) is
+                # fetched FIRST; if every shard committed clean and the
+                # next window provably needs no table rebuild, its
+                # per-shard dispatch goes out BEFORE this window's packed
+                # results are fetched — the cross-shard exchange overlaps
+                # the next window's dispatch (pinned by the EVENT_LOG
+                # ordering test).  The mirror still learns this window's
+                # writes before any future rebuild: can_pipeline proved
+                # the early dispatch itself cannot rebuild.
                 early = None
-            # pipeline: issue the NEXT chunk before folding this one —
-            # its base state is the device-resident table, so the
-            # dispatch needs nothing from the folds below.  The
-            # runner's HOST MIRROR must still learn this chunk's
-            # committed writes FIRST: if the next chunk's premap grows
-            # the table past its pow2 cap, issue() rebuilds the device
-            # table from the mirror, and a mirror lagging one chunk
-            # would resurrect pre-chunk values (root mismatch).  The
-            # trie folds below stay deferred — only the cheap dict
-            # update moves ahead of the dispatch.
-            pre_committed = False
-            if ci + 1 < len(chunks) and all(wres.clean):
-                for k, (_block, plans) in enumerate(chunk):
-                    calls = [pl for pl in plans if pl.kind == "call"]
-                    writes: Dict[Tuple[bytes, bytes], int] = {}
-                    for pl, res in zip(calls, wres.results[k]):
-                        if res.status == M.STOP:
-                            for key, v in res.writes.items():
-                                writes[(pl.to, key)] = v
-                    runner.commit_block(writes)
-                pre_committed = True
-                if early is not None:
-                    inflight = early
-                else:
+                next_items = None
+                if ci + 1 < len(chunks):
+                    # the next window's lanes, built while this one is
+                    # still on the chip
+                    with e.account.enter("machine/prepare"):
+                        next_items = self._window_items(chunks[ci + 1])
+                if next_items is not None and hasattr(runner, "poll_clean"):
                     t0 = time.monotonic()
-                    inflight = runner.issue(next_items)
+                    if (runner.poll_clean(inflight)
+                            and runner.can_pipeline(next_items)):
+                        early = self._issue(runner, items=next_items)
                     e.stats.t_device += time.monotonic() - t0
-            for k, (block, plans) in enumerate(chunk):
-                if wres.clean[k]:
-                    call_idx = [i for i, pl in enumerate(plans)
-                                if pl.kind == "call"]
-                    results = {i: wres.results[k][n]
-                               for n, i in enumerate(call_idx)}
-                    self.rounds += max(0, wres.rounds[k] - 1)
-                    # deferred: the whole window's writes dedupe to
-                    # last-value-per-(contract, slot) and fold in ONE
-                    # batch per contract below, after the next
-                    # window's dispatch is already in flight
-                    self._finish_block(block, plans, results,
-                                       defer=True)
-                    if not pre_committed:
-                        # mirror already learned this chunk's writes
-                        # ahead of the pipelined issue() above
+                t0 = time.monotonic()
+                with obs.span("machine/window_complete",
+                              blocks=len(chunk)):
+                    wres = runner.complete(inflight)
+                e.stats.t_device += time.monotonic() - t0
+                inflight = None
+                self.windows += 1
+                self.window_attempts += wres.attempts
+                # lane fill of every dispatch so far, re-dispatches and
+                # discarded runners included: machine_counters() owns
+                # the count, the stats carry its last reading
+                mc = self.machine_counters()
+                e.stats.machine_lanes_real = mc["lanes_real"]
+                e.stats.machine_lanes_padded = mc["lanes_padded"]
+                imb_w = (self._runner_totals["load_imb_windows"]
+                         + runner.load_imb_windows)
+                if imb_w:
+                    # max/mean per-shard lane occupancy (permille counts),
+                    # averaged over EVERY sharded window this executor has
+                    # run — including runners a fault rebuild discarded
+                    # (ReplayStats -> metrics registry -> bench
+                    # multichip/hot_contract sections)
+                    e.stats.load_imbalance = round(
+                        (self._runner_totals["load_imb_sum"]
+                         + runner.load_imb_sum) / imb_w / 1000, 3)
+                if early is not None and not all(wres.clean):
+                    # cannot happen (a clean exchange implies clean packed
+                    # results); distrust the device table if it ever does
+                    runner.invalidate()
+                    early = None
+                # pipeline: issue the NEXT chunk before folding this one —
+                # its base state is the device-resident table, so the
+                # dispatch needs nothing from the folds below.  The
+                # runner's HOST MIRROR must still learn this chunk's
+                # committed writes FIRST: if the next chunk's premap grows
+                # the table past its pow2 cap, issue() rebuilds the device
+                # table from the mirror, and a mirror lagging one chunk
+                # would resurrect pre-chunk values (root mismatch).  The
+                # trie folds below stay deferred — only the cheap dict
+                # update moves ahead of the dispatch.
+                pre_committed = False
+                if ci + 1 < len(chunks) and all(wres.clean):
+                    for k, (_block, plans) in enumerate(chunk):
+                        calls = [pl for pl in plans if pl.kind == "call"]
+                        writes: Dict[Tuple[bytes, bytes], int] = {}
+                        for pl, res in zip(calls, wres.results[k]):
+                            if res.status == M.STOP:
+                                for key, v in res.writes.items():
+                                    writes[(pl.to, key)] = v
+                        runner.commit_block(writes)
+                    pre_committed = True
+                    if early is not None:
+                        inflight = early
+                    else:
+                        t0 = time.monotonic()
+                        inflight = self._issue(runner, items=next_items)
+                        e.stats.t_device += time.monotonic() - t0
+                for k, (block, plans) in enumerate(chunk):
+                    if wres.clean[k]:
+                        call_idx = [i for i, pl in enumerate(plans)
+                                    if pl.kind == "call"]
+                        results = {i: wres.results[k][n]
+                                   for n, i in enumerate(call_idx)}
+                        self.rounds += max(0, wres.rounds[k] - 1)
+                        # deferred: the whole window's writes dedupe to
+                        # last-value-per-(contract, slot) and fold in ONE
+                        # batch per contract below, after the next
+                        # window's dispatch is already in flight
+                        self._finish_block(block, plans, results,
+                                           defer=True)
+                        if not pre_committed:
+                            # mirror already learned this chunk's writes
+                            # ahead of the pipelined issue() above
+                            runner.commit_block(self.last_writes)
+                        consumed += 1
+                        self._inflight_consumed = consumed
+                        continue
+                    # dirty: partial commits may sit in the device table,
+                    # and every later block of the window ran against a
+                    # speculative base — escalate THIS block to the legacy
+                    # path and hand the rest back for re-classification
+                    # (execute() flushes the staged clean prefix first)
+                    self.dirty_blocks += 1
+                    obs.instant("machine/dirty_block", number=block.number)
+                    runner.invalidate()
+                    root = self.execute(block, plans)
+                    if root is None:
+                        if consumed == 0:
+                            return 0  # caller owns the first block's fate
+                        e._fallback(block)
+                    else:
                         runner.commit_block(self.last_writes)
-                    consumed += 1
-                    self._inflight_consumed = consumed
-                    continue
-                # dirty: partial commits may sit in the device table,
-                # and every later block of the window ran against a
-                # speculative base — escalate THIS block to the legacy
-                # path and hand the rest back for re-classification
-                # (execute() flushes the staged clean prefix first)
-                self.dirty_blocks += 1
-                obs.instant("machine/dirty_block", number=block.number)
-                runner.invalidate()
-                root = self.execute(block, plans)
-                if root is None:
-                    if consumed == 0:
-                        return 0  # caller owns the first block's fate
-                    e._fallback(block)
-                else:
-                    runner.commit_block(self.last_writes)
-                return consumed + 1
+                    return consumed + 1
             # ONE deduped fold + root check per fused window — the
             # commit-phase analog of the O(1)-dispatch execute phase
             e.commit_pipe.flush()

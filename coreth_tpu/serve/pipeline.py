@@ -126,7 +126,8 @@ class StreamReport:
     account: dict = field(default_factory=dict)
     # lane fill of the engine's transfer windows (ReplayStats
     # lanes_real / lanes_padded): transactions packed against lanes
-    # uploaded and scanned
+    # uploaded and scanned; machine_real / machine_padded: the same
+    # for its fused machine windows
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -712,7 +713,9 @@ class StreamingPipeline:
     # ------------------------------------------------------------ report
     def _lanes(self) -> dict:
         st = self.engine.stats
-        return {"real": st.lanes_real, "padded": st.lanes_padded}
+        return {"real": st.lanes_real, "padded": st.lanes_padded,
+                "machine_real": st.machine_lanes_real,
+                "machine_padded": st.machine_lanes_padded}
 
     def _publish(self, wall: float) -> None:
         s = self.stats

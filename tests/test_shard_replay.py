@@ -604,6 +604,7 @@ def test_keyrange_specialize_retrace_gate(monkeypatch):
                for e in list(tr._ring)), "placement instant not traced"
 
 
+@pytest.mark.alone   # a ratio of two wall times: no other worker beside it
 def test_two_device_hot_contract_smoke(monkeypatch):
     """Tier-1 ISSUE-14 scaling gate: on the single-hot-contract shape
     (machine path, DEFAULT key-range env) a 2-device mesh must sustain

@@ -24,7 +24,9 @@ kept here — the single-chip ladder below is the expensive case; it was
 compiled by hand for the four-chip run recorded in CHANGES.md.
 """
 
+import os
 import re
+import sys
 
 import numpy as np
 import jax
@@ -178,10 +180,23 @@ def test_keccak_blocks(one_chip):
         s((4096, 2, 34), jnp.uint32), s((4096,))).compile()
 
 
+# the benchmark's own token (benchmarks/chains/p2p_token.py), as the
+# window runner specializes it: a file loaded by path, no device touched
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks"))
+from benchlib import names as _bench_names  # noqa: E402
+BENCH_TOKEN_SPEC = (SpecProgram(code=_bench_names.load_named(
+    "chains", "p2p_token")[0].TOKEN_RUNTIME, fork=FORK),)
+
+
 @pytest.mark.parametrize("lanes,table_cap,spec", [
     (ERC20_TXS, 4096, TOKEN_SPEC),   # ERC-20 chain, specialized program
     (128, 2048, ()),                 # hot-contract width, generic kernel
-], ids=["erc20-specialized", "hot-generic"])
+    # p2p-token-1k.catchup: a gas-full block of 445 calls buckets to
+    # 512 lanes, 1,000 holders' slots to the 8,192-row arena the lead
+    # window projects
+    (512, 8192, BENCH_TOKEN_SPEC),
+], ids=["erc20-specialized", "hot-generic", "p2p-token-cell"])
 def test_occ_machine_donates_its_table(one_chip, lanes, table_cap, spec):
     """The fused OCC machine (lax.while_loop step machine inside the
     block scan) as the window runner buckets it, with the slot table
