@@ -44,12 +44,6 @@ compile_cache.configure()
 # BASELINE config[2]) with 1024 senders and a
 # growing account table (half of every block's recipients are fresh
 # addresses, ~65k accounts by the end of the chain).
-# Recovery split re-measured round 4 on the uncontended host AFTER the
-# pipelining changes (256-block sweep, best-of-2): transfer 5639 tps
-# @0.8 -> 6666 @0.95; erc20 5090 @0.8 -> 5352 @0.95 (5564 @1.0).  The
-# 1-core host is the straggler, so nearly all signatures belong on the
-# device ladder; 0.95 keeps a small host share that still overlaps.
-os.environ.setdefault("CORETH_RECOVER_SPLIT", "0.95")
 N_BLOCKS = int(os.environ.get("BENCH_BLOCKS", "1024"))
 TXS_PER_BLOCK = int(os.environ.get("BENCH_TXS", "128"))
 # >=64 blocks so the extrapolated py-host denominator is not a ~1s

@@ -10,7 +10,8 @@ Widths are never cut; chain LENGTH is, and every phase says by how much
 under ``reduced``.  The plain reference is the Python host processor:
 it built the chains, so its roots are in the headers.
 
-    python chip_smoke.py             # one chip, phases 1-6
+    python chip_smoke.py             # one chip, every phase
+    python chip_smoke.py --phase recover   # one chip, that phase only
     python chip_smoke.py --chips 4   # ONLY the mesh-vs-single-device
                                      # comparison, on four chips
 
@@ -320,6 +321,12 @@ def _engine_row(engine) -> dict:
         "t_sender_device_s": round(st.t_sender_device, 3),
         "t_sender_host_s": round(st.t_sender_host, 3),
         "recover_degraded": st.recover_degraded,
+        # batches by engine; the cost model's seconds beside seen
+        "recover_segs": [st.segs_device, st.segs_host],
+        "recover_model_seen_s": {
+            k: [round(getattr(st, f"t_recover_{k}_model"), 3),
+                round(getattr(st, f"t_recover_{k}_seen"), 3)]
+            for k in ("device", "host")},
         "t_s": {k: round(getattr(st, k), 3) for k in (
             "t_classify", "t_sender", "t_device", "t_trie",
             "t_fallback")},
@@ -348,11 +355,15 @@ def _engine_row(engine) -> dict:
 
 def replay_failures(row: dict, *, machine: bool = False,
                     conflicts: bool = False, retraces_ok: bool = False,
-                    device_sigs: bool = True) -> List[str]:
+                    device_sigs: bool = False) -> List[str]:
     """What a healthy device run must show, by the engine's own
     counters.  Anything listed here means the run LOOKED fine (the
     root may even match) while the device did less than claimed.
-    ``retraces_ok`` reports kernel_retraces without failing on it."""
+    ``retraces_ok`` reports kernel_retraces without failing on it.
+    ``device_sigs`` demands signatures recovered on the ladder: only
+    under CORETH_RECOVER_FORCE_DEVICE=1 — at its defaults the engine
+    sends a segment to the engine that has it done first, and the
+    ladder's proof is phase_recover."""
     bad = []
     n = row["blocks"]
     if not row["root_ok"]:
@@ -417,12 +428,16 @@ def run_replay_phase(meter: CompileMeter, genesis, blocks, engine_kw,
     return row
 
 
-def recover_probe(chain_id: int, blocks, n: int) -> dict:
+def recover_probe(chain_id: int, blocks, n: int, runs: int = 3) -> dict:
     """The device ECDSA ladder against the native C++ batch on the same
-    ``n`` signatures: equal addresses, and each side's wall time — what
-    a retune of the recover split (not done here) needs."""
+    ``n`` signatures (one launch of bucket ``pad``): equal addresses —
+    the ladder's proof, whatever the engine's routing sends it — and
+    each side's wall seconds over ``runs`` warm runs, after a first
+    that loads or compiles the bucket's executable.  The medians are
+    what ``replay/recover_cost.py``'s table is made of."""
     from coreth_tpu.crypto import native
-    from coreth_tpu.crypto.secp_device import recover_addresses_device
+    from coreth_tpu.crypto.secp_device import (
+        _pad_pow2, recover_addresses_device)
     from coreth_tpu.types import LatestSigner
     signer = LatestSigner(chain_id)
     hashes, rs, ss, recids = [], [], [], bytearray()
@@ -433,14 +448,24 @@ def recover_probe(chain_id: int, blocks, n: int) -> dict:
         ss.append(s.to_bytes(32, "big"))
         recids.append(recid)
     args = (b"".join(hashes), b"".join(rs), b"".join(ss), bytes(recids))
-    t0 = time.monotonic()
-    dev = recover_addresses_device(*args)
-    t1 = time.monotonic()
-    host = native.recover_addresses_batch(*args)
-    t2 = time.monotonic()
-    return {"n": len(recids), "device_s": round(t1 - t0, 4),
-            "host_s": round(t2 - t1, 4), "host_cores": os.cpu_count(),
-            "equal": dev == host and all(host[1])}
+
+    def timed(fn):
+        t0 = time.monotonic()
+        out = fn(*args)
+        return out, round(time.monotonic() - t0, 5)
+
+    dev, first_s = timed(recover_addresses_device)
+    device_s, host_s, equal = [], [], True
+    for _ in range(runs):
+        dev, dt = timed(recover_addresses_device)
+        device_s.append(dt)
+        host, dt = timed(native.recover_addresses_batch)
+        host_s.append(dt)
+        equal &= dev == host and all(host[1])
+    return {"n": len(recids), "pad": _pad_pow2(len(recids)),
+            "device_first_s": first_s, "device_s": device_s,
+            "host_s": host_s, "host_cores": os.cpu_count(),
+            "equal": equal}
 
 
 # ----------------------------------------------------------------- phases
@@ -485,14 +510,39 @@ def phase_transfer(meter, sizes: Sizes = FULL, **expect) -> dict:
     genesis, blocks = transfer_chain(sizes, sizes.chain_blocks)
     out = run_replay_phase(meter, genesis, blocks, _transfer_kw(sizes),
                            **expect)
-    probe = recover_probe(genesis.config.chain_id, blocks,
-                          min(4096, out["txs"]))
-    out["recover_probe"] = probe
-    if not probe["equal"]:
-        out["failures"].append("device recovery != native recovery")
     out["reduced"] = _length_cut(sizes.chain_blocks,
                                  sizes.transfer_default_blocks)
     return out
+
+
+def phase_recover(meter, sizes: Sizes = FULL) -> dict:
+    """The device ladder's proof, once per pow2 bucket the transfer
+    chain can fill (64 ... MAX_CHUNK): equal addresses against the
+    native batch.  The replay phases prove nothing about the ladder:
+    the engine sends a segment to whichever engine has it done first,
+    which on a many-core host is never the ladder.  ``table`` is the
+    cost table of ``replay/recover_cost.py`` as this run reads it."""
+    from statistics import median
+    from coreth_tpu.crypto.secp_device import MAX_CHUNK
+    genesis, blocks = transfer_chain(sizes, sizes.chain_blocks)
+    have = sum(len(b.transactions) for b in blocks)
+    m0 = meter.mark()
+    probes, failures = [], []
+    n = 64
+    while n <= MAX_CHUNK and (n <= have or not probes):
+        probe = recover_probe(genesis.config.chain_id, blocks, n)
+        probes.append(probe)
+        if not probe["equal"]:
+            failures.append(f"device recovery != native recovery "
+                            f"at {probe['n']} signatures")
+        n *= 2
+    return {"probes": probes, "failures": failures,
+            "table": {"launch_s": {p["pad"]: median(p["device_s"])
+                                   for p in probes},
+                      "host_s": {p["n"]: median(p["host_s"])
+                                 for p in probes},
+                      "host_cores": os.cpu_count()},
+            "compile": meter.since(m0), "reduced": {}}
 
 
 def phase_erc20(meter, sizes: Sizes = FULL, **expect) -> dict:
@@ -703,11 +753,19 @@ def _run_phase(name: str, fn, *args) -> bool:
     return _emit(name, row)
 
 
+PHASES = {"transfer": phase_transfer, "recover": phase_recover,
+          "erc20": phase_erc20, "erc20_machine": phase_erc20_machine,
+          "conflicts": phase_conflicts, "streaming": phase_streaming}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run ONLY the mesh-vs-single-device "
                          "comparison on four chips")
+    ap.add_argument("--phase", action="append", choices=sorted(PHASES),
+                    help="one chip: run only this phase (repeatable); "
+                         "default: all of them")
     args = ap.parse_args(argv)
     t0 = time.monotonic()
 
@@ -728,12 +786,8 @@ def main(argv=None) -> int:
     if args.chips == 4:
         ok &= _run_phase("mesh", phase_mesh, meter)
     else:
-        for name, fn in (("transfer", phase_transfer),
-                         ("erc20", phase_erc20),
-                         ("erc20_machine", phase_erc20_machine),
-                         ("conflicts", phase_conflicts),
-                         ("streaming", phase_streaming)):
-            ok &= _run_phase(name, fn, meter)
+        for name in args.phase or PHASES:
+            ok &= _run_phase(name, PHASES[name], meter)
     print(json.dumps({"phase": "total",
                       "wall_s": round(time.monotonic() - t0, 1)}),
           flush=True)

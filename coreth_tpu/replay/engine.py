@@ -46,6 +46,7 @@ from coreth_tpu.ops import u256
 from coreth_tpu.params import ChainConfig
 from coreth_tpu.params import protocol as P
 from coreth_tpu.processor.state_processor import Processor
+from coreth_tpu.replay.recover_cost import MEASURED as RECOVER_COST
 from coreth_tpu.state import Database, StateDB
 from coreth_tpu.state.flat import DELETED as FLAT_DELETED
 from coreth_tpu.workloads.erc20 import (
@@ -104,14 +105,20 @@ _EAGER_FLUSH = bool(int(
 
 
 def _has_accelerator() -> bool:
-    """True when a non-CPU jax backend is live — the device ECDSA kernel
-    on XLA-CPU is slower than the native C++ batch, so only real chips
-    take that path (CORETH_RECOVER_FORCE_DEVICE=1 overrides for tests).
+    """True when a non-CPU jax backend is live: only a real chip's
+    ladder is ever weighed against the native C++ batch (the XLA-CPU
+    one runs under CORETH_RECOVER_FORCE_DEVICE=1 alone, for tests).
     A backend that cannot be probed RAISES: a broken chip must not read
     as "no chip" and route recovery to the host in silence."""
-    if os.environ.get("CORETH_RECOVER_FORCE_DEVICE"):
-        return True
     return jax.default_backend() != "cpu"
+
+
+def _timed(fn, *args):
+    """``fn``'s result and when it had it: run on the recovery worker,
+    so the replay thread learns when a host batch was DONE, not when it
+    came to ask."""
+    out = fn(*args)
+    return out, time.monotonic()
 
 
 def secp_half_n() -> int:
@@ -161,6 +168,19 @@ class ReplayStats:
     # txs fell to per-tx recovery in signer.sender — correct, but not
     # the path the counters above describe
     recover_degraded: int = 0
+    # routing by earliest finish (replay/recover_cost.py): batches
+    # completed on each engine and, for them, the seconds the cost
+    # model gave from issue to done beside the seconds seen — a table
+    # that no longer fits the machine shows as the two drifting apart.
+    # Host: seen to the worker's own finish; device: to the result's
+    # read and host finish, an upper bound where the ladder was done
+    # before the replay thread came for it
+    segs_device: int = 0
+    segs_host: int = 0
+    t_recover_device_model: float = 0.0
+    t_recover_device_seen: float = 0.0
+    t_recover_host_model: float = 0.0
+    t_recover_host_seen: float = 0.0
     # max/mean per-shard lane occupancy of the sharded OCC windows
     # (1.0 = flat; n_shards = the one-hot-contract collapse key-range
     # placement removes).  0.0 until a sharded machine window ran.
@@ -168,6 +188,18 @@ class ReplayStats:
 
     def row(self) -> dict:
         return dict(self.__dict__)
+
+    def note_recover(self, kind: str, model_s: float,
+                     seen_s: float) -> None:
+        """One batch completed on engine ``kind`` (device / host)."""
+        if kind == "device":
+            self.segs_device += 1
+            self.t_recover_device_model += model_s
+            self.t_recover_device_seen += seen_s
+        else:
+            self.segs_host += 1
+            self.t_recover_host_model += model_s
+            self.t_recover_host_seen += seen_s
 
 
 def _in_phase(name: str):
@@ -631,22 +663,28 @@ class _SenderPipeline:
 
     The synchronous warm_senders() recovers every signature before the
     first window scan, serializing seconds of ECDSA ahead of execution.
-    This pipeline cuts the input into device-chunk-sized segments of
-    blocks and keeps AHEAD segments issued past the replay cursor:
+    This pipeline cuts the input into segments of whole blocks, each at
+    most one ladder launch (a segment closes BEFORE the block that
+    would take it past MAX_CHUNK; a single larger block is chunked by
+    issue_recover), and keeps AHEAD segments issued past the replay
+    cursor.  Each segment goes whole to the engine that, by the cost
+    model (replay/recover_cost.py) and this pipeline's own book of
+    what it has in flight, has it DONE first:
 
-    - device segments dispatch asynchronously into the same FIFO device
-      queue as the window scans, so the chip alternates recovery chunks
-      and scans without idling — a window's senders recover ON DEVICE
-      while the previous window executes;
-    - host segments run whole in the engine's recovery worker thread
-      (the ctypes C++ batch releases the GIL), sized by the measured
-      device/host split — routing whole segments avoids the pow2
-      padding waste of splitting each one;
-    - with a dp mesh and ``CORETH_SHARD_RECOVER=1`` the device segments
-      ride the MESH-SHARDED ECDSA ladder (parallel/mesh.py
-      sharded_recover via engine._recover_kernel) even without a real
-      accelerator — batch replay's analog of the serve prefetcher's
-      sharded recovery, with the same parity contract;
+    - the native host batch runs in the engine's recovery worker thread
+      (one worker, in order; the ctypes C++ batch releases the GIL);
+    - the device ladder dispatches asynchronously into the same FIFO
+      device queue as the window scans — where it also delays every
+      window queued behind it, which the model does not count: so the
+      ladder gets a segment only where it is STRICTLY earlier, and
+      ties and what the model cannot tell go to the host;
+    - without the native library the ladder is the only batch engine;
+      CORETH_RECOVER_FORCE_DEVICE=1 (tests, the smoke) and, with a dp
+      mesh, ``CORETH_SHARD_RECOVER=1`` (the MESH-SHARDED ladder,
+      parallel/mesh.py sharded_recover via engine._recover_kernel,
+      even without a real accelerator — batch replay's analog of the
+      serve prefetcher's sharded recovery, with the same parity
+      contract) send every segment to it, whatever the model says;
     - ensure(i) blocks only until block i's segment is applied.
     """
 
@@ -657,34 +695,45 @@ class _SenderPipeline:
         from coreth_tpu.crypto.secp_device import MAX_CHUNK
         self.engine = engine
         self.have_native = native.load() is not None
-        # opt-in mesh-sharded recovery in the replay loop (parity with
-        # the native batch pinned by tests/test_batch_recovery.py)
-        self.force_shard = engine._force_shard_recover()
-        self.use_device = _has_accelerator() or self.force_shard
-        if self.force_shard and not _has_accelerator():
-            # virtual mesh on CPU: the point is the sharded ladder, so
-            # give it the whole batch instead of the host-rate split
-            self.split = 1.0
-        else:
-            self.split = engine._default_recover_split() \
-                if self.use_device else 0.0
+        self.forced = engine._ladder_forced()
+        self.use_device = self.forced or _has_accelerator()
         self.block_seg: List[int] = []
         self.segments: List[List[Block]] = []
         cur: List[Block] = []
         count = 0
         for b in blocks:
-            self.block_seg.append(len(self.segments))
-            cur.append(b)
-            count += len(b.transactions)
-            if count >= MAX_CHUNK:
+            n = len(b.transactions)
+            if cur and count + n > MAX_CHUNK:
                 self.segments.append(cur)
                 cur, count = [], 0
+            self.block_seg.append(len(self.segments))
+            cur.append(b)
+            count += n
         if cur:
             self.segments.append(cur)
         self.issued: List[dict] = []
         self.done = 0
         self.dev_sigs = 0
-        self.host_sigs = 0
+        # the book: when each engine's queue is next free, by the model
+        # and by what _complete has seen since (time.monotonic)
+        self.free_at = {"device": 0.0, "host": 0.0}
+
+    def _route(self, n: int, now: float) -> Tuple[str, float]:
+        """(engine, seconds the model gives it) for ``n`` signatures
+        issued at ``now``: the engine that is done first."""
+        cost = self.engine.recover_cost
+        if n == 0 or not (self.use_device or self.have_native):
+            return "empty", 0.0
+        if not self.use_device:
+            return "host", cost.host_s(n)
+        ladder_s = cost.ladder_s(n)
+        if self.forced or not self.have_native:
+            return "device", ladder_s
+        host_s = cost.host_s(n)
+        if max(now, self.free_at["device"]) + ladder_s \
+                < max(now, self.free_at["host"]) + host_s:
+            return "device", ladder_s
+        return "host", host_s
 
     def _issue(self, s: int) -> None:
         eng = self.engine
@@ -698,33 +747,30 @@ class _SenderPipeline:
                 self.segments[s])
             n = len(recids)
             h["todo"] = todo
-            if n:
-                # the sharded-ladder opt-in skips the min-batch/split
-                # gates: its segments must actually exercise the mesh
-                small = n < eng.DEVICE_RECOVER_MIN
-                to_host = self.have_native and not self.force_shard \
-                    and (not self.use_device or small
-                         or self.host_sigs + n <= (1 - self.split)
-                         * (self.dev_sigs + self.host_sigs + n))
-                if to_host:
-                    from coreth_tpu.crypto import native
-                    self.host_sigs += n
-                    h["kind"] = "host"
-                    h["fut"] = eng._recover_pool_get().submit(
-                        native.recover_addresses_batch, hashes, rs, ss,
-                        recids)
-                elif self.use_device:
-                    from coreth_tpu.crypto.secp_device import (
-                        issue_recover)
-                    self.dev_sigs += n
-                    h["kind"] = "device"
-                    acct.switch("sender/issue_device")
-                    h["ctxs"] = issue_recover(
-                        hashes, rs, ss, recids,
-                        kernel=eng._recover_kernel())
-                    h["ticket"] = obs.device_issue(acct)
-                # else: no native lib, no accelerator — signer.sender's
-                # per-tx python path recovers lazily
+            now = time.monotonic()
+            kind, cost_s = self._route(n, now)
+            if kind == "host":
+                from coreth_tpu.crypto import native
+                h["kind"] = "host"
+                h["fut"] = eng._recover_pool_get().submit(
+                    _timed, native.recover_addresses_batch, hashes, rs,
+                    ss, recids)
+            elif kind == "device":
+                from coreth_tpu.crypto.secp_device import issue_recover
+                self.dev_sigs += n
+                h["kind"] = "device"
+                acct.switch("sender/issue_device")
+                h["ctxs"] = issue_recover(
+                    hashes, rs, ss, recids,
+                    kernel=eng._recover_kernel())
+                h["ticket"] = obs.device_issue(acct)
+            # else: nothing to recover, or no native lib and no
+            # accelerator — signer.sender's per-tx python path recovers
+            # lazily
+            if kind != "empty":
+                h["t_issue"], h["cost_s"] = now, cost_s
+                h["due"] = self.free_at[kind] = \
+                    max(now, self.free_at[kind]) + cost_s
         except Exception:  # noqa: BLE001 — degrade to lazy per-tx
             h["kind"] = "empty"
             eng.stats.recover_degraded += 1
@@ -732,6 +778,20 @@ class _SenderPipeline:
             acct.exit()
         self.issued.append(h)
         self._account(h["kind"], time.monotonic() - t0)
+
+    def _seen_done(self, s: int, t_done: float) -> None:
+        """Segment ``s`` was done at ``t_done``: count it, model beside
+        seen, and correct the book — an engine runs its queue in order,
+        so what it still holds starts no sooner than this."""
+        h = self.issued[s]
+        self.engine.stats.note_recover(
+            h["kind"], h["due"] - h["t_issue"], t_done - h["t_issue"])
+        free = t_done
+        for later in self.issued[s + 1:]:
+            if later["kind"] == h["kind"]:
+                free = later["due"] = \
+                    max(free, later["t_issue"]) + later["cost_s"]
+        self.free_at[h["kind"]] = free
 
     def _account(self, kind: str, dt: float) -> None:
         stats = self.engine.stats
@@ -751,13 +811,15 @@ class _SenderPipeline:
             out = ok = None
             if h["kind"] == "host":
                 acct.switch("sender/wait_host")
-                out, ok = h["fut"].result()
+                (out, ok), t_done = h["fut"].result()
                 eng.stats.sigs_host += len(h["todo"])
             elif h["kind"] == "device":
                 out, ok = eng._complete_device_recover(
                     h["ctxs"], h["ticket"], acct)
+                t_done = time.monotonic()
                 eng.stats.sigs_device += len(h["todo"])
             if out is not None:
+                self._seen_done(s, t_done)
                 acct.switch("sender/apply")
                 eng._apply_recovered(h["todo"], out, ok)
         except Exception:  # noqa: BLE001 — per-tx python path later
@@ -1035,11 +1097,9 @@ class ReplayEngine:
         return self.state.ensure_slot(contract, key, value)
 
     # -------------------------------------------------------------- senders
-    # Below this batch size the device round trip loses to the native
-    # C++ loop (~0.3ms/signature); not re-measured on a locally
-    # attached chip.
-    DEVICE_RECOVER_MIN = int(
-        __import__("os").environ.get("CORETH_RECOVER_MIN_BATCH", "1024"))
+    # when a batch is done on the ladder and on the native batch: what
+    # every routing decision below reads (measured on the chip)
+    recover_cost = RECOVER_COST
 
     def _pack_sigs(self, blocks):
         """Collect + pack uncached signatures for batched recovery.
@@ -1075,10 +1135,9 @@ class ReplayEngine:
 
     def warm_senders(self, blocks) -> None:
         """Batched sender recovery across a whole run of blocks
-        (reference core/sender_cacher.go role).  Large batches go to the
-        device ECDSA kernel (crypto/secp_device — one Shamir-ladder call
-        for every signature in the window); small ones to the native C++
-        batch.  Accepts a single block or a list.
+        (reference core/sender_cacher.go role), divided between the
+        device ECDSA kernel (crypto/secp_device) and the native C++
+        batch by _recover_packed.  Accepts a single block or a list.
 
         This is the synchronous form; replay() uses _SenderPipeline to
         overlap segmented recovery with window execution."""
@@ -1125,63 +1184,50 @@ class ReplayEngine:
         acct.switch("sender/apply")
         return complete_recover(ctxs)
 
-    # Device share of the hybrid recovery split.  The device ladder and
-    # the host C++ batch run CONCURRENTLY (the ctypes call releases the
-    # GIL; jax kernel dispatch is async), so total recovery time is
-    # max(device_share/device_rate, host_share/host_rate) instead of
-    # the whole batch on one engine — the TPU-era version of the
-    # reference's sender_cacher parallelism (core/sender_cacher.go:49).
-    @staticmethod
-    def _default_recover_split() -> float:
-        """Device share that equalizes finish times, from an assumed
-        ~0.083 ms/sig for the device ladder (4096-chunks) and ~0.26
-        ms/sig PER CORE for the host C++ batch (it stripes across
-        hardware_concurrency threads):
-        split = dev_rate / (dev_rate + cores * host_rate_per_core).
-        Neither rate is re-measured on a locally attached chip;
-        ReplayStats.sigs_*/t_sender_* and chip_smoke.py's recover
-        probe print what a retune needs."""
-        import os
-        env = os.environ.get("CORETH_RECOVER_SPLIT")
-        if env is not None:
-            return float(env)
-        cores = os.cpu_count() or 1
-        dev_rate = 1.0 / 0.083
-        host_rate = cores / 0.26
-        return dev_rate / (dev_rate + host_rate)
-
     def _recover_packed(self, hashes: bytes, rs: bytes, ss: bytes,
                         recids: bytes, acct):
         """Hybrid batched recovery over packed buffers -> (addrs, ok).
-        Runs inside the caller's ``sender/*`` phase and switches it."""
+        Runs inside the caller's ``sender/*`` phase and switches it.
+
+        The ladder and the native batch run CONCURRENTLY (jax dispatch
+        is async; the ctypes call releases the GIL in the recovery
+        worker — the TPU-era version of the reference's sender_cacher
+        parallelism, core/sender_cacher.go:49), so the batch is done
+        when the later of the two is: the ladder takes the first
+        ``recover_cost.split(n)`` signatures, the count that has both
+        done soonest, which is none where no launch beats the native
+        batch over the whole.  The overrides (_ladder_forced) and a
+        process without the native library give the ladder all."""
         faults.fire(PT_RECOVER)  # callers degrade to per-tx recovery
         from coreth_tpu.crypto import native
         n = len(recids)
+        cost = self.recover_cost
         have_native = native.load() is not None
-        force_shard = self._force_shard_recover()
-        use_device = force_shard or (
-            n >= self.DEVICE_RECOVER_MIN and _has_accelerator())
-        if not use_device:
+        forced = self._ladder_forced()
+        if not (forced or _has_accelerator()):
+            n_dev = 0
+        elif forced or not have_native:
+            n_dev = n
+        else:
+            n_dev = cost.split(n)
+        t0 = time.monotonic()
+        if n_dev == 0:
             if not have_native:
                 return None, None  # per-tx python path in signer.sender
-            t0 = time.monotonic()
             # the native batch runs ON this thread: work, not a wait
             out = native.recover_addresses_batch(hashes, rs, ss, recids)
+            dt = time.monotonic() - t0
             self.stats.sigs_host += n
-            self.stats.t_sender_host += time.monotonic() - t0
+            self.stats.t_sender_host += dt
+            self.stats.note_recover("host", cost.host_s(n), dt)
             return out
-        # the sharded opt-in routes the WHOLE batch to the ladder
-        # (matching _SenderPipeline — stats.sigs_device == packed count
-        # is the test/verify contract); otherwise the measured split
-        n_dev = n if (not have_native or force_shard) \
-            else int(n * self._default_recover_split())
         host_fut = None
         if n_dev < n:
             host_fut = self._recover_pool_get().submit(
-                native.recover_addresses_batch, hashes[32 * n_dev:],
-                rs[32 * n_dev:], ss[32 * n_dev:], recids[n_dev:])
+                _timed, native.recover_addresses_batch,
+                hashes[32 * n_dev:], rs[32 * n_dev:], ss[32 * n_dev:],
+                recids[n_dev:])
         from coreth_tpu.crypto.secp_device import issue_recover
-        t0 = time.monotonic()
         acct.switch("sender/issue_device")
         ctxs = issue_recover(hashes[:32 * n_dev], rs[:32 * n_dev],
                              ss[:32 * n_dev], recids[:n_dev],
@@ -1191,12 +1237,15 @@ class ReplayEngine:
         t1 = time.monotonic()
         self.stats.sigs_device += n_dev
         self.stats.t_sender_device += t1 - t0
+        self.stats.note_recover("device", cost.ladder_s(n_dev), t1 - t0)
         if host_fut is None:
             return out_dev, ok_dev
         acct.switch("sender/wait_host")
-        out_host, ok_host = host_fut.result()
+        (out_host, ok_host), t_done = host_fut.result()
         self.stats.sigs_host += n - n_dev
         self.stats.t_sender_host += time.monotonic() - t1
+        self.stats.note_recover("host", cost.host_s(n - n_dev),
+                                t_done - t0)
         return out_dev + out_host, ok_dev + ok_host
 
     def _recover_pool_get(self):
@@ -1204,6 +1253,14 @@ class ReplayEngine:
             from concurrent.futures import ThreadPoolExecutor
             self._recover_pool = ThreadPoolExecutor(max_workers=1)
         return self._recover_pool
+
+    def _ladder_forced(self) -> bool:
+        """Every batch to the ladder, whatever the cost model says:
+        CORETH_RECOVER_FORCE_DEVICE=1 (tests and the smoke's toy
+        rehearsal: the XLA-CPU ladder stands in for the chip) or the
+        sharded opt-in below."""
+        return bool(os.environ.get("CORETH_RECOVER_FORCE_DEVICE")) \
+            or self._force_shard_recover()
 
     def _force_shard_recover(self) -> bool:
         """CORETH_SHARD_RECOVER=1 + a usable mesh ladder: the ONE

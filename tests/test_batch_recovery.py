@@ -99,7 +99,7 @@ def test_batch_replay_shard_recover_parity(monkeypatch):
 
 def test_batch_replay_shard_recover_default_off(monkeypatch):
     """Default (env unset): even with a mesh, replay's sender pipeline
-    stays on the measured host/device split (no sharded forcing)."""
+    stays on its routing rule (no sharded forcing)."""
     monkeypatch.delenv("CORETH_SHARD_RECOVER", raising=False)
     blocks = _build_chain(1)
     eng = _engine(mesh=make_mesh(jax.devices("cpu")[:2]))
@@ -114,8 +114,6 @@ def test_device_recover_malformed_lane_no_poison(monkeypatch):
     — signer.sender raises the canonical rejection instead of the
     batch aborting or mis-recovering neighbors."""
     monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    monkeypatch.setenv("CORETH_RECOVER_SPLIT", "1.0")
-    monkeypatch.setattr(ReplayEngine, "DEVICE_RECOVER_MIN", 1)
     blocks = _fresh(_build_chain(2))
     bad = blocks[0].transactions[2]
     bad.inner.s = secp256k1.N  # out of range: never a valid signature
@@ -146,8 +144,6 @@ def test_failed_device_recovery_is_not_counted_as_device_work(
     slower, never wrong, and never silently."""
     from coreth_tpu.crypto import secp_device
     monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    monkeypatch.setenv("CORETH_RECOVER_SPLIT", "1.0")
-    monkeypatch.setattr(ReplayEngine, "DEVICE_RECOVER_MIN", 1)
 
     def boom(*_a, **_k):
         raise RuntimeError("device lost")
@@ -165,6 +161,151 @@ def test_failed_device_recovery_is_not_counted_as_device_work(
     eng2.warm_senders(_fresh(blocks))
     assert eng2.stats.sigs_device == 0
     assert eng2.stats.recover_degraded == 1
+
+
+# ------------------------------------------- routing by earliest finish
+# A stub cost table (the ledger's numbers before the chip was asked:
+# 0.14-0.29 s a launch at every bucket, 0.009 ms a signature on 13
+# cores) and stub engines: pure host Python, nothing compiles.
+STUB_LAUNCH_S = {64: 0.14, 128: 0.14, 256: 0.267, 512: 0.169,
+                 1024: 0.14, 2048: 0.142, 4096: 0.285}
+
+
+def _stub_cost(cores):
+    from coreth_tpu.replay.recover_cost import RecoverCost
+    return RecoverCost(launch_s=STUB_LAUNCH_S, host_fixed_s=0.001,
+                       host_sig_core_s=0.000117, cores=cores)
+
+
+def _stub_engines(monkeypatch, eng):
+    """Both batch engines and the packing replaced by counters: a
+    "block" is any object with a ``transactions`` list.  Returns the
+    chunk contexts each ladder issue made."""
+    from coreth_tpu.crypto import native, secp_device
+    from coreth_tpu.replay import engine as E
+    issues = []
+
+    def pack(blocks):
+        n = sum(len(b.transactions) for b in blocks)
+        return [None] * n, bytes(32 * n), bytes(32 * n), bytes(32 * n), \
+            bytes(n)
+
+    def issue_chunk(hashes, rs, ss, recids, kernel=None):
+        return dict(n=len(recids), out=None)
+
+    real_issue = secp_device.issue_recover
+
+    def issue(*a, **k):
+        ctxs = real_issue(*a, **k)     # the chunk loop is the real one
+        issues.append(ctxs)
+        return ctxs
+
+    monkeypatch.setattr(E, "_has_accelerator", lambda: True)
+    monkeypatch.setattr(eng, "_pack_sigs", pack)
+    monkeypatch.setattr(eng, "_apply_recovered", lambda *a: None)
+    monkeypatch.setattr(native, "recover_addresses_batch",
+                        lambda h, r, s, v: (bytes(20 * len(v)),
+                                            b"\x01" * len(v)))
+    monkeypatch.setattr(secp_device, "_issue_chunk", issue_chunk)
+    monkeypatch.setattr(secp_device, "issue_recover", issue)
+    monkeypatch.setattr(secp_device, "fetch_recover", lambda ctxs: None)
+    monkeypatch.setattr(
+        secp_device, "complete_recover",
+        lambda ctxs: (bytes(20 * sum(c["n"] for c in ctxs)),
+                      b"\x01" * sum(c["n"] for c in ctxs)))
+    return issues
+
+
+ROUTING = {
+    # the three cells' chains after the lead block, on the chip's host:
+    # segment 0 and every later one to the native batch
+    "p2p-1k on 13 cores": dict(cores=13, blocks=[714] * 32,
+                               kinds="h" * 7),
+    "p2p-token-1k on 13 cores": dict(cores=13, blocks=[445] * 32,
+                                     kinds="h" * 4),
+    "valuetx on 13 cores": dict(cores=13, blocks=[1] * 9999,
+                                kinds="h" * 3),
+    # one core does 4,096 signatures in 0.48 s, a launch in 0.285 s
+    "a full launch on 1 core": dict(cores=1, blocks=[4096], kinds="d"),
+    # ... and the book then sends the next segment to the idle engine:
+    # the first four issue before any completes, by the model alone
+    "the book alternates on 1 core": dict(cores=1, blocks=[714] * 32,
+                                          kinds="dhdh", first=4),
+    "a block larger than a launch": dict(cores=1, blocks=[5000],
+                                         kinds="d", chunks=2),
+    "CORETH_RECOVER_FORCE_DEVICE=1": dict(cores=13, blocks=[714] * 32,
+                                          kinds="d" * 7, force=True),
+    "_recover_packed on 13 cores": dict(cores=13, packed=4284, n_dev=0),
+    "_recover_packed on 1 core": dict(cores=1, packed=4284, n_dev=2048),
+    "_recover_packed forced": dict(cores=13, packed=4284, n_dev=4284,
+                                   force=True),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTING), ids=list(ROUTING))
+def test_recovery_goes_to_the_engine_that_finishes_first(monkeypatch,
+                                                         case):
+    """One rule, read from the input and the machine: a segment (or a
+    synchronous batch's share) goes to the ladder only where the cost
+    model has it done strictly earlier there.  No segment of whole
+    blocks exceeds one launch, a ladder segment is ONE chunk context,
+    and the per-engine counters add up to what was issued."""
+    from types import SimpleNamespace
+    from coreth_tpu import obs
+    from coreth_tpu.crypto.secp_device import MAX_CHUNK
+    c = ROUTING[case]
+    monkeypatch.delenv("CORETH_SHARD_RECOVER", raising=False)
+    monkeypatch.delenv("CORETH_RECOVER_FORCE_DEVICE", raising=False)
+    if c.get("force"):
+        monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
+    eng = _engine()
+    monkeypatch.setattr(eng, "recover_cost", _stub_cost(c["cores"]))
+    issues = _stub_engines(monkeypatch, eng)
+    st = eng.stats
+
+    if "packed" in c:
+        n = c["packed"]
+        out, ok = eng._recover_packed(bytes(32 * n), bytes(32 * n),
+                                      bytes(32 * n), bytes(n),
+                                      obs.NULL_ACCOUNT)
+        assert (len(out), len(ok)) == (20 * n, n)
+        assert (st.sigs_device, st.sigs_host) == (c["n_dev"],
+                                                  n - c["n_dev"])
+        assert st.segs_device == (c["n_dev"] > 0)
+        assert st.segs_host == (c["n_dev"] < n)
+        assert eng.recover_cost.split(n) == (
+            c["n_dev"] if not c.get("force") else 0)
+        return
+
+    blocks = [SimpleNamespace(transactions=[None] * k)
+              for k in c["blocks"]]
+    pipe = _SenderPipeline(eng, blocks)
+    sizes = [sum(len(b.transactions) for b in seg)
+             for seg in pipe.segments]
+    assert sum(sizes) == sum(c["blocks"])
+    assert all(k <= MAX_CHUNK or len(seg) == 1
+               for k, seg in zip(sizes, pipe.segments))
+    for i in range(len(blocks)):       # block by block, as replay()
+        pipe.ensure(i)
+    kinds = "".join(h["kind"][0] for h in pipe.issued)
+    first = c.get("first", len(kinds))
+    assert kinds[:first] == c["kinds"], kinds
+    # a routed segment is one launch (one chunk context) unless a
+    # single block alone is larger
+    assert len(issues) == kinds.count("d")
+    assert all(len(ctxs) == c.get("chunks", 1) for ctxs in issues)
+    # the counters add up to the segments issued, engine by engine
+    assert st.segs_device == kinds.count("d")
+    assert st.segs_host == kinds.count("h")
+    assert st.segs_device + st.segs_host == len(pipe.segments)
+    assert st.sigs_device + st.sigs_host == sum(c["blocks"])
+    assert st.sigs_device == pipe.dev_sigs
+    assert st.recover_degraded == 0
+    for kind, n_segs in (("device", st.segs_device),
+                         ("host", st.segs_host)):
+        model = getattr(st, f"t_recover_{kind}_model")
+        seen = getattr(st, f"t_recover_{kind}_seen")
+        assert (model > 0) == (n_segs > 0) and seen >= 0
 
 
 def test_accelerator_probe_does_not_swallow_a_broken_backend(monkeypatch):

@@ -5,7 +5,10 @@ driver runs it there); these cases call its phase FUNCTIONS so a change
 to an engine counter, a chain builder or the pipeline report breaks a
 tier-1 test instead of the next chip run.  Device sender recovery is
 forced onto the XLA-CPU ladder the way tests/test_batch_recovery.py
-does, so the "completed on the device" checks run for real.
+does (CORETH_RECOVER_FORCE_DEVICE=1, and ``device_sigs=True`` asks the
+phases to demand it), so the "completed on the device" checks run for
+real; at the engine's defaults the smoke leaves the ladder's proof to
+its ``recover`` phase.
 """
 
 import json
@@ -15,7 +18,6 @@ import pytest
 
 import chip_smoke as cs
 from coreth_tpu import nativebuild
-from coreth_tpu.replay import ReplayEngine
 
 TOY = cs.Sizes(n_keys=16, txs=8, window=2, capacity=256,
                slot_capacity=64, erc20_txs=8, machine_window=2,
@@ -31,7 +33,6 @@ def meter():
 @pytest.fixture
 def toy(monkeypatch):
     monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    monkeypatch.setattr(ReplayEngine, "DEVICE_RECOVER_MIN", 1)
     # chains are rebuilt, never written into the checkout's cache
     monkeypatch.setattr(cs, "_cached_chain", lambda name, build: build())
     return TOY
@@ -40,7 +41,7 @@ def toy(monkeypatch):
 @pytest.mark.parametrize("phase", [
     "transfer", "erc20", "erc20_machine", "conflicts", "streaming"])
 def test_phase_passes_at_toy_size(phase, toy, meter):
-    row = getattr(cs, "phase_" + phase)(meter, toy)
+    row = getattr(cs, "phase_" + phase)(meter, toy, device_sigs=True)
     assert row["failures"] == [], row
     assert row["root_ok"] and row["blocks_fallback"] == 0
     assert row["sigs_device"] > 0 and row["recover_degraded"] == 0
@@ -55,9 +56,24 @@ def test_phase_passes_at_toy_size(phase, toy, meter):
         assert row["machine"]["occ_rounds"] > 0
         assert row["machine"]["serial_blocks"] == 0
     if phase == "transfer":
-        assert row["recover_probe"]["equal"]
         assert row["reduced"]["chain_blocks"]["smoke"] == TOY.chain_blocks
     json.dumps({k: v for k, v in row.items() if not k.startswith("_")})
+
+
+def test_recover_phase_proves_the_ladder_bucket_by_bucket(toy, meter):
+    """The toy chain fills one bucket: one probe, equal addresses, and
+    the cost table's two columns read from its runs."""
+    row = cs.phase_recover(meter, toy)
+    assert row["failures"] == [], row
+    have = TOY.chain_blocks * TOY.txs
+    assert [(p["n"], p["pad"]) for p in row["probes"]] == [(have, 64)]
+    probe = row["probes"][0]
+    assert probe["equal"] and len(probe["device_s"]) == 3
+    assert set(row["table"]["launch_s"]) == {64}
+    assert set(row["table"]["host_s"]) == {have}
+    assert set(cs.PHASES) == {"transfer", "recover", "erc20",
+                              "erc20_machine", "conflicts", "streaming"}
+    json.dumps(row)
 
 
 def test_mesh_phase_on_four_virtual_devices(toy, meter):
@@ -82,7 +98,9 @@ def test_failures_name_a_run_that_only_looked_healthy():
            "blocks_device": 2, "sigs_device": 0, "recover_degraded": 2,
            "supervisor": {"retries": 1, "strikes": 0, "demotions": 0},
            "dispatches": 0}
-    bad = cs.replay_failures(row, machine=True)
+    bad = cs.replay_failures(row, machine=True, device_sigs=True)
+    assert not any("no signature completed" in b
+                   for b in cs.replay_failures(row, machine=True))
     for needle in ("blocks_fallback=1", "blocks_device=2",
                    "no signature completed", "recover_degraded=2",
                    "supervisor.retries=1", "machine path never ran"):

@@ -517,7 +517,6 @@ def test_device_recovery_wait_is_told_from_its_finish(toy_chain,
     blocking read alone is ``sender/wait_device``, and the ticket is
     retired at the read."""
     from coreth_tpu.crypto import native, secp_device
-    from coreth_tpu.replay.engine import ReplayEngine
     if native.load() is None:
         pytest.skip("no native library: nothing to stand in for the "
                     "ladder")
@@ -538,8 +537,6 @@ def test_device_recovery_wait_is_told_from_its_finish(toy_chain,
     monkeypatch.setattr(secp_device, "fetch_recover", fetch)
     monkeypatch.setattr(secp_device, "complete_recover", complete)
     monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    monkeypatch.setenv("CORETH_RECOVER_SPLIT", "1.0")
-    monkeypatch.setattr(ReplayEngine, "DEVICE_RECOVER_MIN", 1)
     # tickets other tests of this process left unretired
     monkeypatch.setattr(A.DEVICE, "retired", A.DEVICE.issued)
     monkeypatch.setattr(A.DEVICE, "busy", False)

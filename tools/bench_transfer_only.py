@@ -2,7 +2,7 @@
 """Quick transfer-workload TPU pass for perf iteration (no baselines).
 
 Usage: python tools/bench_transfer_only.py [reps]
-Honors BENCH_WINDOW / CORETH_RECOVER_MAX_CHUNK / CORETH_RECOVER_SPLIT.
+Honors BENCH_WINDOW / CORETH_RECOVER_MAX_CHUNK.
 """
 import os
 import sys
