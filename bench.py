@@ -571,8 +571,8 @@ def run_tpu(genesis, wire_blocks, txs_per_block, machine_stats=None,
     from coreth_tpu.types import Block
 
     # Warm-up pass on throwaway blocks/engine: compiles (or cache-loads)
-    # every device executable this workload shape needs — the recover
-    # kernel bucket, the window scan buckets, the rehash kernel.  XLA
+    # every device executable this workload shape needs — the window
+    # scan buckets, the rehash kernel.  XLA
     # compile/load is a per-process one-time cost, excluded from timing
     # exactly like the first-block warm-up the round-1 bench did.
     # A PREFIX suffices: every bucket the full chain exercises appears

@@ -321,12 +321,6 @@ def _engine_row(engine) -> dict:
         "t_sender_device_s": round(st.t_sender_device, 3),
         "t_sender_host_s": round(st.t_sender_host, 3),
         "recover_degraded": st.recover_degraded,
-        # batches by engine; the cost model's seconds beside seen
-        "recover_segs": [st.segs_device, st.segs_host],
-        "recover_model_seen_s": {
-            k: [round(getattr(st, f"t_recover_{k}_model"), 3),
-                round(getattr(st, f"t_recover_{k}_seen"), 3)]
-            for k in ("device", "host")},
         "t_s": {k: round(getattr(st, k), 3) for k in (
             "t_classify", "t_sender", "t_device", "t_trie",
             "t_fallback")},
@@ -354,16 +348,15 @@ def _engine_row(engine) -> dict:
 
 
 def replay_failures(row: dict, *, machine: bool = False,
-                    conflicts: bool = False, retraces_ok: bool = False,
-                    device_sigs: bool = False) -> List[str]:
+                    conflicts: bool = False,
+                    retraces_ok: bool = False) -> List[str]:
     """What a healthy device run must show, by the engine's own
     counters.  Anything listed here means the run LOOKED fine (the
     root may even match) while the device did less than claimed.
     ``retraces_ok`` reports kernel_retraces without failing on it.
-    ``device_sigs`` demands signatures recovered on the ladder: only
-    under CORETH_RECOVER_FORCE_DEVICE=1 — at its defaults the engine
-    sends a segment to the engine that has it done first, and the
-    ladder's proof is phase_recover."""
+    Senders are recovered by the native batch alone (the device
+    ladder's proof is phase_recover): a run whose signatures did not
+    all complete there fell to per-tx recovery."""
     bad = []
     n = row["blocks"]
     if not row["root_ok"]:
@@ -372,8 +365,10 @@ def replay_failures(row: dict, *, machine: bool = False,
         bad.append(f"blocks_fallback={row['blocks_fallback']}")
     if row["blocks_device"] != n:
         bad.append(f"blocks_device={row['blocks_device']} != {n}")
-    if device_sigs and row["sigs_device"] <= 0:
-        bad.append("no signature completed recovery on the device")
+    if row["sigs_host"] <= 0 or row["sigs_device"] != 0:
+        bad.append(f"sigs_host={row['sigs_host']} sigs_device="
+                   f"{row['sigs_device']}: senders not recovered by "
+                   f"the native batch")
     if row["recover_degraded"] != 0:
         bad.append(f"recover_degraded={row['recover_degraded']}")
     for k, v in row["supervisor"].items():
@@ -431,10 +426,10 @@ def run_replay_phase(meter: CompileMeter, genesis, blocks, engine_kw,
 def recover_probe(chain_id: int, blocks, n: int, runs: int = 3) -> dict:
     """The device ECDSA ladder against the native C++ batch on the same
     ``n`` signatures (one launch of bucket ``pad``): equal addresses —
-    the ladder's proof, whatever the engine's routing sends it — and
-    each side's wall seconds over ``runs`` warm runs, after a first
-    that loads or compiles the bucket's executable.  The medians are
-    what ``replay/recover_cost.py``'s table is made of."""
+    the ladder's proof; the program itself never calls it — and each
+    side's wall seconds over ``runs`` warm runs, after a first that
+    loads or compiles the bucket's executable (a report: nothing reads
+    the seconds)."""
     from coreth_tpu.crypto import native
     from coreth_tpu.crypto.secp_device import (
         _pad_pow2, recover_addresses_device)
@@ -506,10 +501,9 @@ def _length_cut(smoke: int, default: int) -> dict:
         if smoke < default else {}
 
 
-def phase_transfer(meter, sizes: Sizes = FULL, **expect) -> dict:
+def phase_transfer(meter, sizes: Sizes = FULL) -> dict:
     genesis, blocks = transfer_chain(sizes, sizes.chain_blocks)
-    out = run_replay_phase(meter, genesis, blocks, _transfer_kw(sizes),
-                           **expect)
+    out = run_replay_phase(meter, genesis, blocks, _transfer_kw(sizes))
     out["reduced"] = _length_cut(sizes.chain_blocks,
                                  sizes.transfer_default_blocks)
     return out
@@ -519,9 +513,8 @@ def phase_recover(meter, sizes: Sizes = FULL) -> dict:
     """The device ladder's proof, once per pow2 bucket the transfer
     chain can fill (64 ... MAX_CHUNK): equal addresses against the
     native batch.  The replay phases prove nothing about the ladder:
-    the engine sends a segment to whichever engine has it done first,
-    which on a many-core host is never the ladder.  ``table`` is the
-    cost table of ``replay/recover_cost.py`` as this run reads it."""
+    the engine recovers on the native batch alone.  ``table`` is the
+    seconds a launch and a native batch took in this run, as a report."""
     from statistics import median
     from coreth_tpu.crypto.secp_device import MAX_CHUNK
     genesis, blocks = transfer_chain(sizes, sizes.chain_blocks)
@@ -545,18 +538,17 @@ def phase_recover(meter, sizes: Sizes = FULL) -> dict:
             "compile": meter.since(m0), "reduced": {}}
 
 
-def phase_erc20(meter, sizes: Sizes = FULL, **expect) -> dict:
+def phase_erc20(meter, sizes: Sizes = FULL) -> dict:
     """ERC-20 spam through the token fast path (_slot_step)."""
     genesis, blocks = erc20_chain(sizes, sizes.chain_blocks)
     out = run_replay_phase(
         meter, genesis, blocks, _transfer_kw(sizes,
-                                             batch_pad=sizes.erc20_txs),
-        **expect)
+                                             batch_pad=sizes.erc20_txs))
     out["reduced"] = {}
     return out
 
 
-def phase_erc20_machine(meter, sizes: Sizes = FULL, **expect) -> dict:
+def phase_erc20_machine(meter, sizes: Sizes = FULL) -> dict:
     """The same ERC-20 chain through the GENERAL step machine — the
     path every other contract takes — forced the way bench.py does.
     The head of the fast-path chain is reused (two machine windows
@@ -567,12 +559,12 @@ def phase_erc20_machine(meter, sizes: Sizes = FULL, **expect) -> dict:
         out = run_replay_phase(
             meter, genesis, blocks[:sizes.machine_blocks],
             _transfer_kw(sizes, batch_pad=sizes.erc20_txs),
-            machine=True, **expect)
+            machine=True)
     out["reduced"] = {}
     return out
 
 
-def phase_conflicts(meter, sizes: Sizes = FULL, **expect) -> dict:
+def phase_conflicts(meter, sizes: Sizes = FULL) -> dict:
     """Conflicts ON THE DEVICE: the Zipf hot-contract chain with the
     serial short-circuit left at its default (computed-key conflicts
     stay on device OCC), so re-execution rounds must be > 0."""
@@ -584,7 +576,7 @@ def phase_conflicts(meter, sizes: Sizes = FULL, **expect) -> dict:
             dict(batch_pad=sizes.hot_txs, capacity=sizes.hot_capacity,
                  slot_capacity=sizes.hot_capacity,
                  window=sizes.hot_window),
-            machine=True, conflicts=True, **expect)
+            machine=True, conflicts=True)
     out["reduced"] = {}
     return out
 
@@ -621,23 +613,22 @@ def stream_once(genesis, wire: List[bytes], sizes: Sizes):
     return row, failures
 
 
-def phase_streaming(meter, sizes: Sizes = FULL, **expect) -> dict:
+def phase_streaming(meter, sizes: Sizes = FULL) -> dict:
     """The transfer chain through StreamingPipeline(engine,
     ChainFeed(...)) in backlog mode, as bench.run_streaming does:
-    once cold (the prefetch thread's recoveries land in recover-kernel
-    buckets batch replay never used, so it compiles again) and once
-    warm, whose latencies are the ones worth reading."""
+    once cold and once warm, whose latencies are the ones worth
+    reading."""
     genesis, blocks = transfer_chain(sizes, sizes.chain_blocks)
     wire = [b.encode() for b in blocks]
     m0 = meter.mark()
     cold, failures = stream_once(genesis, wire, sizes)
     failures = ["cold: " + f
-                for f in replay_failures(cold, **expect) + failures]
+                for f in replay_failures(cold) + failures]
     comp_cold = meter.since(m0)
     m1 = meter.mark()
     row, warm_failures = stream_once(genesis, wire, sizes)
     failures += ["warm: " + f
-                 for f in replay_failures(row, **expect) + warm_failures]
+                 for f in replay_failures(row) + warm_failures]
     row.update(cold={k: cold[k] for k in ("wall_s", "latency_ms",
                                           "sustained_txs_s")},
                compile=comp_cold, compile_warm=meter.since(m1),
@@ -665,8 +656,7 @@ def _quartered(name: str, arr, n_dev: int) -> List[str]:
     return []
 
 
-def phase_mesh(meter, sizes: Sizes = FULL, devices=None,
-               **expect) -> dict:
+def phase_mesh(meter, sizes: Sizes = FULL, devices=None) -> dict:
     """The four-chip path and what it is compared with, nothing else:
     the transfer chain and the machine-path hot-contract chain through
     ReplayEngine(mesh=make_mesh(4 devices)) and through the
@@ -711,7 +701,7 @@ def phase_mesh(meter, sizes: Sizes = FULL, devices=None,
     compare("transfer", genesis, blocks, _transfer_kw(sizes),
             lambda e: {"balances": e.state.balances,
                        "nonces": e.state.nonces,
-                       "slot_vals": e.state.slot_vals}, **expect)
+                       "slot_vals": e.state.slot_vals})
     genesis, blocks = hot_chain(sizes)
     with _env(CORETH_NO_TOKEN_FASTPATH="1",
               CORETH_MACHINE_WINDOW=str(sizes.machine_window)):
@@ -728,7 +718,7 @@ def phase_mesh(meter, sizes: Sizes = FULL, devices=None,
                 # pre-warm once (also on the virtual CPU mesh): the
                 # count is printed, the comparison is about roots and
                 # placement
-                machine=True, conflicts=True, retraces_ok=True, **expect)
+                machine=True, conflicts=True, retraces_ok=True)
     out["reduced"] = _length_cut(sizes.chain_blocks,
                                  sizes.transfer_default_blocks)
     return out

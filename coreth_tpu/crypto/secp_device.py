@@ -15,9 +15,13 @@ Inputs and outputs of the device call are byte-packed (~2.6 MB per 16k
 signatures round trip): one upload and one download per chunk.  What a
 sync and a byte cost on a locally attached chip is not measured.
 
-ABI mirrors crypto.native.recover_addresses_batch so callers can switch
-between the C++ and device paths transparently:
+ABI mirrors crypto.native.recover_addresses_batch:
   recover_addresses_device(hashes, rs, ss, recids) -> (addrs20, ok)
+
+A LIBRARY: nothing in the program calls it.  The serving path (replay/,
+serve/) recovers on the native batch alone, which finishes first at
+every batch size on any host with two or more cores; this kernel keeps
+its own tests and its proof on the chip (chip_smoke.py --phase recover).
 
 Rows the branchless ladder flags as doubling collisions (addend ==
 accumulator; statistically negligible, constructible adversarially) are
@@ -73,8 +77,7 @@ def _pad_pow2(n: int, floor: int = 64) -> int:
 # 4096 was chosen where launches stopped being dispatch-bound and pow2
 # padding waste was still small; not re-measured on a locally attached
 # chip.
-MAX_CHUNK = int(__import__("os").environ.get(
-    "CORETH_RECOVER_MAX_CHUNK", str(4096)))
+MAX_CHUNK = 4096
 
 
 def issue_recover(hashes: bytes, rs: bytes, ss: bytes,
@@ -97,15 +100,6 @@ def issue_recover(hashes: bytes, rs: bytes, ss: bytes,
             hashes[32 * lo:32 * hi], rs[32 * lo:32 * hi],
             ss[32 * lo:32 * hi], recids[lo:hi], kernel))
     return ctxs
-
-
-def fetch_recover(ctxs: list) -> None:
-    """Block until every issued chunk's result is on the host: the
-    device wait alone.  ``complete_recover`` after it only finishes on
-    the host, so a caller can tell waiting from work."""
-    for ctx in ctxs:
-        if ctx is not None and "out" not in ctx:
-            ctx["out"] = np.asarray(ctx["dev_out"])
 
 
 def complete_recover(ctxs: list) -> Tuple[bytes, bytes]:
@@ -210,8 +204,7 @@ def _complete_chunk(ctx) -> Tuple[bytes, bytes]:
     ok = ctx["ok"]
     hashes, rs, ss = ctx["hashes"], ctx["rs"], ctx["ss"]
     recids = ctx["recids"]
-    fetch_recover([ctx])
-    out = ctx["out"][:n]
+    out = np.asarray(ctx["dev_out"])[:n]
 
     from coreth_tpu.crypto import native
     if native.load() is not None:

@@ -46,7 +46,6 @@ from coreth_tpu.ops import u256
 from coreth_tpu.params import ChainConfig
 from coreth_tpu.params import protocol as P
 from coreth_tpu.processor.state_processor import Processor
-from coreth_tpu.replay.recover_cost import MEASURED as RECOVER_COST
 from coreth_tpu.state import Database, StateDB
 from coreth_tpu.state.flat import DELETED as FLAT_DELETED
 from coreth_tpu.workloads.erc20 import (
@@ -94,7 +93,7 @@ def _receipt_rows(receipts) -> list:
 PT_DISPATCH = faults.declare(
     "device/dispatch", "raise at window dispatch (transfer + fused OCC)")
 PT_RECOVER = faults.declare(
-    "recover/fault", "batched sender recovery failure (device or host)")
+    "recover/fault", "batched sender recovery failure")
 
 
 # Blocking on uploads at issue time syncs the whole stream, so eager
@@ -102,23 +101,6 @@ PT_RECOVER = faults.declare(
 # chip).
 _EAGER_FLUSH = bool(int(
     __import__("os").environ.get("CORETH_EAGER_FLUSH", "0")))
-
-
-def _has_accelerator() -> bool:
-    """True when a non-CPU jax backend is live: only a real chip's
-    ladder is ever weighed against the native C++ batch (the XLA-CPU
-    one runs under CORETH_RECOVER_FORCE_DEVICE=1 alone, for tests).
-    A backend that cannot be probed RAISES: a broken chip must not read
-    as "no chip" and route recovery to the host in silence."""
-    return jax.default_backend() != "cpu"
-
-
-def _timed(fn, *args):
-    """``fn``'s result and when it had it: run on the recovery worker,
-    so the replay thread learns when a host batch was DONE, not when it
-    came to ask."""
-    out = fn(*args)
-    return out, time.monotonic()
 
 
 def secp_half_n() -> int:
@@ -154,33 +136,22 @@ class ReplayStats:
     # quarantined blocks later popped again via rollback_block (the
     # reorg primitive over the flat layer's generational diffs)
     blocks_rolled_back: int = 0
-    # where batched sender recovery ran: the device ECDSA ladder
-    # (single-chip or mesh-sharded — overlapping window execution in
-    # the replay loop) vs the native host batch.  A signature counts
-    # only once its batch COMPLETED there; t_sender_* is the replay
-    # thread's time in issue + completion of each kind (the host batch
-    # itself runs in the recovery worker)
+    # batched sender recovery: signatures whose native batch COMPLETED
+    # (sigs_host) and the replay thread's time packing, submitting,
+    # waiting and applying (t_sender, t_sender_host; the batch itself
+    # runs in the recovery worker).  sigs_device / t_sender_device are
+    # VESTIGIAL: constant 0 since the device ladder left the serving
+    # path, kept because benchmarks/benchlib/replay_pass.py and
+    # metrics/sigs_device_share.py read them by attribute — the next
+    # `benchmark` issue retires them (PERF.md §7 follow-up 5; D20)
     sigs_device: int = 0
     sigs_host: int = 0
     t_sender_device: float = 0.0
     t_sender_host: float = 0.0
-    # batched recoveries that raised (at issue or completion): their
-    # txs fell to per-tx recovery in signer.sender — correct, but not
-    # the path the counters above describe
+    # batched recoveries that raised (packing, the worker's batch, its
+    # result): their txs fell to per-tx recovery in signer.sender —
+    # correct, but not the path the counters above describe
     recover_degraded: int = 0
-    # routing by earliest finish (replay/recover_cost.py): batches
-    # completed on each engine and, for them, the seconds the cost
-    # model gave from issue to done beside the seconds seen — a table
-    # that no longer fits the machine shows as the two drifting apart.
-    # Host: seen to the worker's own finish; device: to the result's
-    # read and host finish, an upper bound where the ladder was done
-    # before the replay thread came for it
-    segs_device: int = 0
-    segs_host: int = 0
-    t_recover_device_model: float = 0.0
-    t_recover_device_seen: float = 0.0
-    t_recover_host_model: float = 0.0
-    t_recover_host_seen: float = 0.0
     # max/mean per-shard lane occupancy of the sharded OCC windows
     # (1.0 = flat; n_shards = the one-hot-contract collapse key-range
     # placement removes).  0.0 until a sharded machine window ran.
@@ -188,18 +159,6 @@ class ReplayStats:
 
     def row(self) -> dict:
         return dict(self.__dict__)
-
-    def note_recover(self, kind: str, model_s: float,
-                     seen_s: float) -> None:
-        """One batch completed on engine ``kind`` (device / host)."""
-        if kind == "device":
-            self.segs_device += 1
-            self.t_recover_device_model += model_s
-            self.t_recover_device_seen += seen_s
-        else:
-            self.segs_host += 1
-            self.t_recover_host_model += model_s
-            self.t_recover_host_seen += seen_s
 
 
 def _in_phase(name: str):
@@ -662,48 +621,31 @@ class _SenderPipeline:
     """Segmented, look-ahead sender recovery for replay().
 
     The synchronous warm_senders() recovers every signature before the
-    first window scan, serializing seconds of ECDSA ahead of execution.
-    This pipeline cuts the input into segments of whole blocks, each at
-    most one ladder launch (a segment closes BEFORE the block that
-    would take it past MAX_CHUNK; a single larger block is chunked by
-    issue_recover), and keeps AHEAD segments issued past the replay
-    cursor.  Each segment goes whole to the engine that, by the cost
-    model (replay/recover_cost.py) and this pipeline's own book of
-    what it has in flight, has it DONE first:
-
-    - the native host batch runs in the engine's recovery worker thread
-      (one worker, in order; the ctypes C++ batch releases the GIL);
-    - the device ladder dispatches asynchronously into the same FIFO
-      device queue as the window scans — where it also delays every
-      window queued behind it, which the model does not count: so the
-      ladder gets a segment only where it is STRICTLY earlier, and
-      ties and what the model cannot tell go to the host;
-    - without the native library the ladder is the only batch engine;
-      CORETH_RECOVER_FORCE_DEVICE=1 (tests, the smoke) and, with a dp
-      mesh, ``CORETH_SHARD_RECOVER=1`` (the MESH-SHARDED ladder,
-      parallel/mesh.py sharded_recover via engine._recover_kernel,
-      even without a real accelerator — batch replay's analog of the
-      serve prefetcher's sharded recovery, with the same parity
-      contract) send every segment to it, whatever the model says;
-    - ensure(i) blocks only until block i's segment is applied.
+    first window scan, serializing the whole chain's ECDSA ahead of
+    execution.  This pipeline cuts the input into segments of whole
+    blocks (a segment closes BEFORE the block that would take it past
+    SEGMENT_SIGS; a single larger block is a segment of its own) and
+    keeps AHEAD segments issued past the replay cursor.  A segment is
+    packed on the replay thread and recovered by the native C++ batch
+    (crypto/native.recover_addresses_batch — the one batch engine) in
+    the engine's recovery worker thread: one worker, in order; the
+    ctypes call releases the GIL.  Without the native library a segment
+    stays lazy: signer.sender recovers per tx.  ensure(i) blocks only
+    until block i's segment is applied.
     """
 
     AHEAD = 3
+    SEGMENT_SIGS = 4096
 
     def __init__(self, engine: "ReplayEngine", blocks: List[Block]):
-        from coreth_tpu.crypto import native
-        from coreth_tpu.crypto.secp_device import MAX_CHUNK
         self.engine = engine
-        self.have_native = native.load() is not None
-        self.forced = engine._ladder_forced()
-        self.use_device = self.forced or _has_accelerator()
         self.block_seg: List[int] = []
         self.segments: List[List[Block]] = []
         cur: List[Block] = []
         count = 0
         for b in blocks:
             n = len(b.transactions)
-            if cur and count + n > MAX_CHUNK:
+            if cur and count + n > self.SEGMENT_SIGS:
                 self.segments.append(cur)
                 cur, count = [], 0
             self.block_seg.append(len(self.segments))
@@ -711,122 +653,55 @@ class _SenderPipeline:
             count += n
         if cur:
             self.segments.append(cur)
-        self.issued: List[dict] = []
+        # (txs packed, the worker's Future) a segment issued; Future
+        # None: nothing to recover, no native library, or issuing
+        # raised — signer.sender's per-tx path recovers lazily
+        self.issued: List[tuple] = []
         self.done = 0
-        self.dev_sigs = 0
-        # the book: when each engine's queue is next free, by the model
-        # and by what _complete has seen since (time.monotonic)
-        self.free_at = {"device": 0.0, "host": 0.0}
-
-    def _route(self, n: int, now: float) -> Tuple[str, float]:
-        """(engine, seconds the model gives it) for ``n`` signatures
-        issued at ``now``: the engine that is done first."""
-        cost = self.engine.recover_cost
-        if n == 0 or not (self.use_device or self.have_native):
-            return "empty", 0.0
-        if not self.use_device:
-            return "host", cost.host_s(n)
-        ladder_s = cost.ladder_s(n)
-        if self.forced or not self.have_native:
-            return "device", ladder_s
-        host_s = cost.host_s(n)
-        if max(now, self.free_at["device"]) + ladder_s \
-                < max(now, self.free_at["host"]) + host_s:
-            return "device", ladder_s
-        return "host", host_s
 
     def _issue(self, s: int) -> None:
+        from coreth_tpu.crypto import native
         eng = self.engine
-        acct = eng.account
         t0 = time.monotonic()
-        h = {"todo": [], "kind": "empty"}
-        acct.enter("sender/pack")
-        try:
-            faults.fire(PT_RECOVER)  # degrade: lazy per-tx recovery
-            todo, hashes, rs, ss, recids = eng._pack_sigs(
-                self.segments[s])
-            n = len(recids)
-            h["todo"] = todo
-            now = time.monotonic()
-            kind, cost_s = self._route(n, now)
-            if kind == "host":
-                from coreth_tpu.crypto import native
-                h["kind"] = "host"
-                h["fut"] = eng._recover_pool_get().submit(
-                    _timed, native.recover_addresses_batch, hashes, rs,
-                    ss, recids)
-            elif kind == "device":
-                from coreth_tpu.crypto.secp_device import issue_recover
-                self.dev_sigs += n
-                h["kind"] = "device"
-                acct.switch("sender/issue_device")
-                h["ctxs"] = issue_recover(
-                    hashes, rs, ss, recids,
-                    kernel=eng._recover_kernel())
-                h["ticket"] = obs.device_issue(acct)
-            # else: nothing to recover, or no native lib and no
-            # accelerator — signer.sender's per-tx python path recovers
-            # lazily
-            if kind != "empty":
-                h["t_issue"], h["cost_s"] = now, cost_s
-                h["due"] = self.free_at[kind] = \
-                    max(now, self.free_at[kind]) + cost_s
-        except Exception:  # noqa: BLE001 — degrade to lazy per-tx
-            h["kind"] = "empty"
-            eng.stats.recover_degraded += 1
-        finally:
-            acct.exit()
-        self.issued.append(h)
-        self._account(h["kind"], time.monotonic() - t0)
+        todo, fut = [], None
+        with eng.account.enter("sender/pack"):
+            try:
+                faults.fire(PT_RECOVER)  # degrade: lazy per-tx recovery
+                todo, hashes, rs, ss, recids = eng._pack_sigs(
+                    self.segments[s])
+                if todo and native.load() is not None:
+                    fut = eng._recover_pool_get().submit(
+                        native.recover_addresses_batch, hashes, rs, ss,
+                        recids)
+            except Exception:  # noqa: BLE001 — degrade to lazy per-tx
+                eng.stats.recover_degraded += 1
+        self.issued.append((todo, fut))
+        self._account(fut, time.monotonic() - t0)
 
-    def _seen_done(self, s: int, t_done: float) -> None:
-        """Segment ``s`` was done at ``t_done``: count it, model beside
-        seen, and correct the book — an engine runs its queue in order,
-        so what it still holds starts no sooner than this."""
-        h = self.issued[s]
-        self.engine.stats.note_recover(
-            h["kind"], h["due"] - h["t_issue"], t_done - h["t_issue"])
-        free = t_done
-        for later in self.issued[s + 1:]:
-            if later["kind"] == h["kind"]:
-                free = later["due"] = \
-                    max(free, later["t_issue"]) + later["cost_s"]
-        self.free_at[h["kind"]] = free
-
-    def _account(self, kind: str, dt: float) -> None:
+    def _account(self, fut, dt: float) -> None:
         stats = self.engine.stats
         stats.t_sender += dt
-        if kind == "device":
-            stats.t_sender_device += dt
-        elif kind == "host":
+        if fut is not None:
             stats.t_sender_host += dt
 
     def _complete(self, s: int) -> None:
+        todo, fut = self.issued[s]
+        if fut is None:
+            return
         eng = self.engine
-        h = self.issued[s]
         acct = eng.account
         t0 = time.monotonic()
-        acct.enter("sender/apply")
+        acct.enter("sender/wait_host")
         try:
-            out = ok = None
-            if h["kind"] == "host":
-                acct.switch("sender/wait_host")
-                (out, ok), t_done = h["fut"].result()
-                eng.stats.sigs_host += len(h["todo"])
-            elif h["kind"] == "device":
-                out, ok = eng._complete_device_recover(
-                    h["ctxs"], h["ticket"], acct)
-                t_done = time.monotonic()
-                eng.stats.sigs_device += len(h["todo"])
-            if out is not None:
-                self._seen_done(s, t_done)
-                acct.switch("sender/apply")
-                eng._apply_recovered(h["todo"], out, ok)
+            out, ok = fut.result()
+            eng.stats.sigs_host += len(todo)
+            acct.switch("sender/apply")
+            eng._apply_recovered(todo, out, ok)
         except Exception:  # noqa: BLE001 — per-tx python path later
             eng.stats.recover_degraded += 1
         finally:
             acct.exit()
-            self._account(h["kind"], time.monotonic() - t0)
+            self._account(fut, time.monotonic() - t0)
 
     def ensure(self, block_idx: int) -> None:
         """Senders for block_idx's segment are recovered on return;
@@ -856,9 +731,8 @@ class ReplayEngine:
         mesh: a jax.sharding.Mesh with >1 device switches execution
         to the mesh-sharded kernels (parallel/mesh.py): tx batches and
         state rows shard over the ``dp`` axis, per-account/per-slot
-        totals reduce with psum_scatter over ICI, and sender recovery
-        fans out across chips.  Bit-identical to the single-device path
-        (pinned by tests/test_parallel.py)."""
+        totals reduce with psum_scatter over ICI.  Bit-identical to the
+        single-device path (pinned by tests/test_parallel.py)."""
         # CORETH_TRACE=1 installs the span tracer; then the self-time
         # account of this engine's life (obs/account.py), opened first
         # so that construction is its first phase
@@ -870,7 +744,6 @@ class ReplayEngine:
         self.mesh = None
         self._n_shards = 1
         if mesh is not None and mesh.devices.size > 1:
-            from coreth_tpu.parallel import sharded_recover
             cap = capacity
             scap = slot_capacity or capacity
             n_dev = mesh.devices.size
@@ -886,7 +759,6 @@ class ReplayEngine:
             self._n_shards = n_dev
             # the transfer-window kernel itself is fetched per window
             # (_issue_window_mesh picks the exchange mode by density)
-            self._mesh_recover = sharded_recover(mesh)
         from coreth_tpu.mpt import native_trie
         # commit-path backend: CORETH_TRIE=native|py (default: native
         # when the library loads); CORETH_TRIE_CHECK=1 arms the
@@ -1097,10 +969,6 @@ class ReplayEngine:
         return self.state.ensure_slot(contract, key, value)
 
     # -------------------------------------------------------------- senders
-    # when a batch is done on the ladder and on the native batch: what
-    # every routing decision below reads (measured on the chip)
-    recover_cost = RECOVER_COST
-
     def _pack_sigs(self, blocks):
         """Collect + pack uncached signatures for batched recovery.
         Packed per-tx so one malformed signature (oversized v/r/s,
@@ -1135,9 +1003,11 @@ class ReplayEngine:
 
     def warm_senders(self, blocks) -> None:
         """Batched sender recovery across a whole run of blocks
-        (reference core/sender_cacher.go role), divided between the
-        device ECDSA kernel (crypto/secp_device) and the native C++
-        batch by _recover_packed.  Accepts a single block or a list.
+        (reference core/sender_cacher.go role): ONE native C++ batch
+        (crypto/native.recover_addresses_batch) on the calling thread.
+        Without the native library, or where the batch raises, the txs
+        stay uncached and signer.sender recovers them one by one.
+        Accepts a single block or a list.
 
         This is the synchronous form; replay() uses _SenderPipeline to
         overlap segmented recovery with window execution."""
@@ -1154,133 +1024,35 @@ class ReplayEngine:
             self.account.end(tok)
 
     def _warm_senders_run(self, blocks, acct) -> None:
+        from coreth_tpu.crypto import native
         t0 = time.monotonic()
         acct.enter("sender/pack")
         try:
             todo, hashes, rs, ss, recids = self._pack_sigs(blocks)
             if not todo:
                 return
-            try:
-                out, ok = self._recover_packed(hashes, rs, ss, recids,
-                                               acct)
-                if out is not None:
-                    acct.switch("sender/apply")
-                    self._apply_recovered(todo, out, ok)
-            except Exception:  # noqa: BLE001 — fall back to per-tx path
-                self.stats.recover_degraded += 1
+            faults.fire(PT_RECOVER)  # degrade to per-tx recovery
+            if native.load() is None:
+                return  # per-tx python path in signer.sender
+            # the batch runs ON this thread: work, not a wait
+            t1 = time.monotonic()
+            out, ok = native.recover_addresses_batch(hashes, rs, ss,
+                                                     recids)
+            self.stats.sigs_host += len(todo)
+            self.stats.t_sender_host += time.monotonic() - t1
+            acct.switch("sender/apply")
+            self._apply_recovered(todo, out, ok)
+        except Exception:  # noqa: BLE001 — fall back to per-tx path
+            self.stats.recover_degraded += 1
         finally:
             acct.exit()
             self.stats.t_sender += time.monotonic() - t0
-
-    def _complete_device_recover(self, ctxs, ticket, acct):
-        """Read an issued device recovery back (``sender/wait_device``:
-        the blocking read alone), retire its ticket, and finish on the
-        host (``sender/apply``).  Returns (addresses, ok)."""
-        from coreth_tpu.crypto.secp_device import (
-            complete_recover, fetch_recover)
-        acct.switch("sender/wait_device")
-        fetch_recover(ctxs)
-        obs.device_done(ticket, acct)
-        acct.switch("sender/apply")
-        return complete_recover(ctxs)
-
-    def _recover_packed(self, hashes: bytes, rs: bytes, ss: bytes,
-                        recids: bytes, acct):
-        """Hybrid batched recovery over packed buffers -> (addrs, ok).
-        Runs inside the caller's ``sender/*`` phase and switches it.
-
-        The ladder and the native batch run CONCURRENTLY (jax dispatch
-        is async; the ctypes call releases the GIL in the recovery
-        worker — the TPU-era version of the reference's sender_cacher
-        parallelism, core/sender_cacher.go:49), so the batch is done
-        when the later of the two is: the ladder takes the first
-        ``recover_cost.split(n)`` signatures, the count that has both
-        done soonest, which is none where no launch beats the native
-        batch over the whole.  The overrides (_ladder_forced) and a
-        process without the native library give the ladder all."""
-        faults.fire(PT_RECOVER)  # callers degrade to per-tx recovery
-        from coreth_tpu.crypto import native
-        n = len(recids)
-        cost = self.recover_cost
-        have_native = native.load() is not None
-        forced = self._ladder_forced()
-        if not (forced or _has_accelerator()):
-            n_dev = 0
-        elif forced or not have_native:
-            n_dev = n
-        else:
-            n_dev = cost.split(n)
-        t0 = time.monotonic()
-        if n_dev == 0:
-            if not have_native:
-                return None, None  # per-tx python path in signer.sender
-            # the native batch runs ON this thread: work, not a wait
-            out = native.recover_addresses_batch(hashes, rs, ss, recids)
-            dt = time.monotonic() - t0
-            self.stats.sigs_host += n
-            self.stats.t_sender_host += dt
-            self.stats.note_recover("host", cost.host_s(n), dt)
-            return out
-        host_fut = None
-        if n_dev < n:
-            host_fut = self._recover_pool_get().submit(
-                _timed, native.recover_addresses_batch,
-                hashes[32 * n_dev:], rs[32 * n_dev:], ss[32 * n_dev:],
-                recids[n_dev:])
-        from coreth_tpu.crypto.secp_device import issue_recover
-        acct.switch("sender/issue_device")
-        ctxs = issue_recover(hashes[:32 * n_dev], rs[:32 * n_dev],
-                             ss[:32 * n_dev], recids[:n_dev],
-                             kernel=self._recover_kernel())
-        out_dev, ok_dev = self._complete_device_recover(
-            ctxs, obs.device_issue(acct), acct)
-        t1 = time.monotonic()
-        self.stats.sigs_device += n_dev
-        self.stats.t_sender_device += t1 - t0
-        self.stats.note_recover("device", cost.ladder_s(n_dev), t1 - t0)
-        if host_fut is None:
-            return out_dev, ok_dev
-        acct.switch("sender/wait_host")
-        (out_host, ok_host), t_done = host_fut.result()
-        self.stats.sigs_host += n - n_dev
-        self.stats.t_sender_host += time.monotonic() - t1
-        self.stats.note_recover("host", cost.host_s(n - n_dev),
-                                t_done - t0)
-        return out_dev + out_host, ok_dev + ok_host
 
     def _recover_pool_get(self):
         if not hasattr(self, "_recover_pool"):
             from concurrent.futures import ThreadPoolExecutor
             self._recover_pool = ThreadPoolExecutor(max_workers=1)
         return self._recover_pool
-
-    def _ladder_forced(self) -> bool:
-        """Every batch to the ladder, whatever the cost model says:
-        CORETH_RECOVER_FORCE_DEVICE=1 (tests and the smoke's toy
-        rehearsal: the XLA-CPU ladder stands in for the chip) or the
-        sharded opt-in below."""
-        return bool(os.environ.get("CORETH_RECOVER_FORCE_DEVICE")) \
-            or self._force_shard_recover()
-
-    def _force_shard_recover(self) -> bool:
-        """CORETH_SHARD_RECOVER=1 + a usable mesh ladder: the ONE
-        definition of the sharded-recovery opt-in, shared by the replay
-        loop's _SenderPipeline and the packed warm_senders path (the
-        serve prefetcher routes through its own counter but honors the
-        same env)."""
-        return bool(int(os.environ.get(
-            "CORETH_SHARD_RECOVER", "0"))) \
-            and self._recover_kernel() is not None
-
-    def _recover_kernel(self):
-        """The device recovery kernel: mesh-sharded fan-out when a mesh
-        is configured (sender_cacher across chips), else the single-chip
-        ladder (None = secp_device default).  The recover pad is a pow2
-        with floor 64 (secp_device._pad_pow2), so a mesh whose size does
-        not divide 64 cannot shard it — fall back to single-device."""
-        if self.mesh is not None and 64 % self.mesh.devices.size == 0:
-            return self._mesh_recover
-        return None
 
     # ------------------------------------------------------------- classify
     def _classify(self, block: Block) -> Optional[dict]:
@@ -2101,10 +1873,9 @@ class ReplayEngine:
         reference's sender_cacher + prefetcher + acceptor pipeline,
         core/sender_cacher.go:49 / blockchain.go:566):
 
-        - sender recovery runs in look-ahead segments (_SenderPipeline):
-          device segments ride the same FIFO device queue as the window
-          scans, host segments run in the recovery worker thread — so
-          ECDSA no longer serializes ahead of the first scan;
+        - sender recovery runs in look-ahead segments (_SenderPipeline)
+          on the native batch in the recovery worker thread — so ECDSA
+          no longer serializes ahead of the first scan;
         - window k+1 is classified (host) and issued (device) BEFORE
           window k is validated, keeping the chip busy while the host
           folds tries;

@@ -129,11 +129,6 @@ class StreamReport:
     # uploaded and scanned; machine_real / machine_padded: the same
     # for its fused machine windows
     lanes: dict = field(default_factory=dict)
-    # where the engine's batched sender recovery went (ReplayStats
-    # sigs_* / segs_* / t_recover_*): signatures and batches by engine
-    # and, per engine, the cost model's seconds beside the seconds seen
-    # from issue to done (replay/recover_cost.py)
-    recover: dict = field(default_factory=dict)
 
     def row(self) -> dict:
         return dict(self.__dict__)
@@ -673,7 +668,6 @@ class StreamingPipeline:
         # as of the execute stage's last phase boundary
         row["account"] = self.engine.account.row()
         row["lanes"] = self._lanes()
-        row["recover"] = self._recover()
         rec = forensics.recorder()
         if rec is not None:
             # quarantine forensics, live: counters + bundle paths for
@@ -723,14 +717,6 @@ class StreamingPipeline:
                 "machine_real": st.machine_lanes_real,
                 "machine_padded": st.machine_lanes_padded}
 
-    def _recover(self) -> dict:
-        st = self.engine.stats
-        return {kind: {"sigs": getattr(st, f"sigs_{kind}"),
-                       "segs": getattr(st, f"segs_{kind}"),
-                       "model_s": getattr(st, f"t_recover_{kind}_model"),
-                       "seen_s": getattr(st, f"t_recover_{kind}_seen")}
-                for kind in ("device", "host")}
-
     def _publish(self, wall: float) -> None:
         s = self.stats
         s.wall_s = round(wall, 3)
@@ -779,7 +765,6 @@ class StreamingPipeline:
             s.flat = flat.snapshot()
         s.account = self.engine.account.row()
         s.lanes = self._lanes()
-        s.recover = self._recover()
         if self._stages is not None:
             # per-stage share of enqueue->committed time (sums to ~1.0
             # across queue_feed/prefetch/queue_exec/execute/commit) —

@@ -5,15 +5,11 @@ streaming bench is "prefetch-hit or overlap counter > 0"):
 
 - **Sender recovery** (host, GIL-releasing): arriving blocks are
   batched through the engine's packed ECDSA recovery
-  (``ReplayEngine.warm_senders`` — native C++ batch or the device
-  ladder) on the prefetch thread, so by the time the execute stage
+  (``ReplayEngine.warm_senders`` — the native C++ batch, the one batch
+  engine) on the prefetch thread, so by the time the execute stage
   classifies a block its senders are already cached.  ``sigs`` counts
   signatures recovered here; the pipeline's ``prefetch_hits`` counts
-  the txs whose sender the execute stage found pre-cached.  The
-  device/mesh-sharded ladder is no longer serve-only: batch replay's
-  ``_SenderPipeline`` honors the same ``CORETH_SHARD_RECOVER`` opt-in
-  and overlaps a window's recovery with the previous window's
-  execution (replay/engine.py).
+  the txs whose sender the execute stage found pre-cached.
 
 - **Bytecode** : call-shaped txs touch ``db.contract_code`` for their
   callee's code hash so the machine classifier's first read hits the
@@ -30,7 +26,6 @@ streaming bench is "prefetch-hit or overlap counter > 0"):
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import List
@@ -48,7 +43,6 @@ class Prefetcher:
         # pipeline report reads them — writes hold _mu
         self._mu = threading.Lock()
         self.sigs = 0
-        self.shard_sigs = 0   # recovered via the mesh-sharded ladder
         self.code_touches = 0
         self.busy_s = 0.0
 
@@ -58,54 +52,13 @@ class Prefetcher:
             todo = sum(1 for b in blocks for tx in b.transactions
                        if tx.cached_sender() is None)
             if todo:
-                if not self._shard_recover(blocks):
-                    self.e.warm_senders(blocks)
+                self.e.warm_senders(blocks)
                 with self._mu:
                     self.sigs += todo
             self._touch_code(blocks)
         dt = time.monotonic() - t0
         with self._mu:
             self.busy_s += dt
-
-    def _shard_recover(self, blocks: List[Block]) -> bool:
-        """CORETH_SHARD_RECOVER=1 + a dp mesh: recover this chunk's
-        senders on the device-sharded ECDSA ladder (parallel/mesh.py
-        sharded_recover — the signature batch fans out across shards)
-        instead of the native host batch.  Falls back (returns False)
-        whenever the mesh path cannot serve the batch, so recovery
-        semantics never change — only the engine doing the work.
-        Parity with the native path is pinned by tests/test_shard_replay."""
-        if not bool(int(os.environ.get("CORETH_SHARD_RECOVER", "0"))):
-            return False
-        e = self.e
-        # _recover_kernel owns the eligibility rule (mesh present,
-        # pad-floor divisibility): None means no sharded ladder
-        kernel = e._recover_kernel() if hasattr(e, "_recover_kernel") \
-            else None
-        if kernel is None:
-            return False
-        t0 = time.monotonic()
-        try:
-            todo, hashes, rs, ss, recids = e._pack_sigs(blocks)
-            if not todo:
-                return True
-            from coreth_tpu.crypto.secp_device import (
-                complete_recover, issue_recover)
-            ctxs = issue_recover(hashes, rs, ss, recids, kernel=kernel)
-            out, ok = complete_recover(ctxs)
-            if out is None:
-                return False
-            e._apply_recovered(todo, out, ok)
-            with self._mu:
-                self.shard_sigs += len(todo)
-            return True
-        except Exception:  # noqa: BLE001 — advisory: host path recovers
-            e.stats.recover_degraded += 1
-            return False
-        finally:
-            # keep the engine's phase attribution honest: this IS
-            # sender-recovery time, same as warm_senders accounts it
-            e.stats.t_sender += time.monotonic() - t0
 
     def _touch_code(self, blocks: List[Block]) -> None:
         """Pull callee bytecode for call-shaped txs into the rawdb read

@@ -10,12 +10,8 @@ topology, and the chip itself by ``chip_smoke.py`` through the chip
 tool.
 """
 
-import fcntl
 import os
 import sys
-import tempfile
-
-import pytest
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -32,31 +28,3 @@ def pytest_configure(config):
     # persistent XLA compilation cache: the keccak/replay kernels
     # compile once per machine instead of once per pytest run
     compile_cache.configure()
-
-
-@pytest.hookimpl(hookwrapper=True)
-def pytest_runtest_protocol(item, nextitem):
-    """``@pytest.mark.alone``: under xdist the case runs with every
-    other worker waiting between two cases, so a gate on wall time (the
-    two-device smoke's ratio) reads the box and not its neighbours.
-
-    Two locks in the run's temp directory, as a fair readers-writer
-    pair: every case takes ``box`` shared for its whole protocol
-    (fixtures included), an ``alone`` case takes it exclusive, and each
-    asks while holding ``gate`` — so an ``alone`` case that waits for
-    the cases in progress keeps new ones from starting.  One process,
-    or a pytest that a case spawns (no ``workerinput``), takes
-    neither."""
-    worker = getattr(item.config, "workerinput", None)
-    if worker is None:
-        yield
-        return
-    base = os.path.join(tempfile.gettempdir(),
-                        "coreth-tests-%s" % worker["testrunuid"])
-    alone = item.get_closest_marker("alone") is not None
-    with open(base + ".gate", "w") as gate, \
-            open(base + ".box", "w") as box:
-        fcntl.flock(gate, fcntl.LOCK_EX)
-        fcntl.flock(box, fcntl.LOCK_EX if alone else fcntl.LOCK_SH)
-        fcntl.flock(gate, fcntl.LOCK_UN)
-        yield

@@ -1,30 +1,39 @@
-"""On-device sender recovery in BATCH replay (not just serve prefetch).
+"""Sender recovery in batch replay: ONE batch engine.
 
-The replay loop's _SenderPipeline now routes segments through the
-device ECDSA ladder — mesh-sharded under CORETH_SHARD_RECOVER=1 — so a
-window's senders recover on device while the previous window executes.
-These tests pin:
+``ReplayEngine.replay`` recovers senders in look-ahead segments of
+whole blocks (``_SenderPipeline``), each packed on the replay thread
+and recovered by the native C++ batch on one worker thread;
+``warm_senders`` (``replay_block``, the serve prefetcher) is the same
+batch on the calling thread.  Where the native library is missing or a
+batch raises, ``signer.sender`` recovers per transaction.  These tests
+pin:
 
-- parity: a mesh-driven batch replay with CORETH_SHARD_RECOVER=1
-  recovers every sender on the sharded ladder inside the replay loop
-  (ReplayStats.sigs_device) and lands roots bit-identical to the
-  host-recovered replay;
-- fault isolation: a malformed-signature lane routed through the
-  device ladder is rejected WITHOUT poisoning the batch — every valid
-  lane's sender is cached, and the malformed tx falls back to the host
-  per-tx path (signer.sender), which raises the canonical rejection.
+- the segmenting the benchmark's rows were measured with, the
+  look-ahead, and the counters (``sigs_host`` = signatures whose batch
+  completed, ``sigs_device`` constant 0, ``recover_degraded`` = batches
+  that raised);
+- fault isolation: a malformed signature is rejected WITHOUT poisoning
+  its segment, and a batch that raises at any of its three seams falls
+  to per-tx recovery — slower, never wrong, never silently;
+- that nothing selects another engine: not a mesh, not the retired
+  knobs, and neither ``crypto.secp_device`` nor ``ops.secp`` is even
+  imported by a process that replays.
 """
 
 import os
+import subprocess
 import sys
+import threading
+from types import SimpleNamespace
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 import pytest
 import jax
 
 from coreth_tpu.chain import Genesis, GenesisAccount, generate_chain
-from coreth_tpu.crypto import secp256k1
+from coreth_tpu.crypto import native, secp256k1
 from coreth_tpu.crypto.secp256k1 import priv_to_address
 from coreth_tpu.params import TEST_CHAIN_CONFIG as CFG
 from coreth_tpu.parallel import make_mesh
@@ -62,6 +71,11 @@ def _build_chain(n_blocks):
     return blocks
 
 
+@pytest.fixture(scope="module")
+def chain3():
+    return _build_chain(3)
+
+
 def _engine(mesh=None):
     genesis = Genesis(config=CFG, gas_limit=8_000_000, alloc=_alloc())
     db = Database()
@@ -75,248 +89,269 @@ def _fresh(blocks):
     return [Block.decode(b.encode()) for b in blocks]
 
 
-def test_batch_replay_shard_recover_parity(monkeypatch):
-    """CORETH_SHARD_RECOVER=1 + a dp mesh: batch replay recovers its
-    senders on the mesh-sharded ladder INSIDE the replay loop
-    (sigs_device > 0), bit-identical roots vs host recovery."""
-    blocks = _build_chain(3)
-
-    monkeypatch.delenv("CORETH_SHARD_RECOVER", raising=False)
-    host_eng = _engine()
-    host_root = host_eng.replay(_fresh(blocks))
-    assert host_root == blocks[-1].root
-    assert host_eng.stats.sigs_device == 0
-
-    monkeypatch.setenv("CORETH_SHARD_RECOVER", "1")
-    mesh_eng = _engine(mesh=make_mesh(jax.devices("cpu")[:2]))
-    mesh_root = mesh_eng.replay(_fresh(blocks))
-    assert mesh_root == host_root == blocks[-1].root
-    # the sharded ladder served the whole batch in the replay loop
-    assert mesh_eng.stats.sigs_device == sum(
-        len(b.transactions) for b in blocks)
-    assert mesh_eng.stats.blocks_fallback == 0
+def _n_txs(blocks):
+    return sum(len(b.transactions) for b in blocks)
 
 
-def test_batch_replay_shard_recover_default_off(monkeypatch):
-    """Default (env unset): even with a mesh, replay's sender pipeline
-    stays on its routing rule (no sharded forcing)."""
-    monkeypatch.delenv("CORETH_SHARD_RECOVER", raising=False)
-    blocks = _build_chain(1)
+def test_mesh_engine_recovers_on_the_native_batch(chain3):
+    """A dp mesh shards execution, not sender recovery: the mesh engine
+    sends every signature to the native batch like any other."""
+    blocks = _fresh(chain3[:1])
     eng = _engine(mesh=make_mesh(jax.devices("cpu")[:2]))
-    assert eng.replay(_fresh(blocks)) == blocks[-1].root
-    assert eng.stats.sigs_device == 0  # CPU backend: host batch
+    assert eng.replay(blocks) == blocks[-1].root
+    assert eng.stats.sigs_host == _n_txs(blocks)
+    assert eng.stats.sigs_device == 0
+    assert eng.stats.recover_degraded == 0
 
 
-def test_device_recover_malformed_lane_no_poison(monkeypatch):
-    """One corrupted signature in a device-routed segment: the device
-    prep flags the lane invalid, every OTHER lane's sender lands in
-    the cache, and the malformed tx falls back to the host per-tx path
-    — signer.sender raises the canonical rejection instead of the
-    batch aborting or mis-recovering neighbors."""
-    monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    blocks = _fresh(_build_chain(2))
+def test_malformed_lane_does_not_poison_its_segment(chain3):
+    """One corrupted signature in a pipelined segment: the native batch
+    flags the lane invalid, every OTHER lane's sender lands in the
+    cache, and the malformed tx falls to the per-tx path — signer.sender
+    raises the canonical rejection instead of the batch aborting or
+    mis-recovering neighbours."""
+    blocks = _fresh(chain3[:2])
     bad = blocks[0].transactions[2]
     bad.inner.s = secp256k1.N  # out of range: never a valid signature
 
     eng = _engine()
     pipe = _SenderPipeline(eng, blocks)
     pipe.ensure(len(blocks) - 1)
-    assert pipe.dev_sigs > 0
-    assert eng.stats.sigs_device == pipe.dev_sigs
+    assert len(pipe.segments) == 1
+    assert eng.stats.sigs_host == _n_txs(blocks)
+    assert eng.stats.recover_degraded == 0
 
     for b in blocks:
         for tx in b.transactions:
-            if tx is bad:
-                continue
-            assert tx.cached_sender() in ADDRS
+            if tx is not bad:
+                assert tx.cached_sender() in ADDRS
     assert bad.cached_sender() is None
     with pytest.raises(ValueError, match="invalid signature"):
         eng.signer.sender(bad)
 
 
-@pytest.mark.parametrize("seam", ["issue_recover", "complete_recover"])
-def test_failed_device_recovery_is_not_counted_as_device_work(
-        monkeypatch, seam):
-    """A device recovery that RAISES (at dispatch or at the result
-    read) must not read as device work: sigs_device counts only what
-    complete_recover returned, the failed batch shows in
-    recover_degraded, and the txs still recover per-tx on the host —
-    slower, never wrong, and never silently."""
-    from coreth_tpu.crypto import secp_device
-    monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
+def _boom(*_a, **_k):
+    raise RuntimeError("batch lost")
 
-    def boom(*_a, **_k):
-        raise RuntimeError("device lost")
 
-    monkeypatch.setattr(secp_device, seam, boom)
-    blocks = _fresh(_build_chain(3))
+class _LostResultPool:
+    """A recovery pool whose futures raise at ``result()``."""
+
+    def submit(self, fn, *args):
+        return SimpleNamespace(result=_boom)
+
+
+# seam -> (what to break, batches the synchronous form loses: it has no
+# Future, so a pool that loses results costs it nothing)
+SEAMS = {
+    "packing raises": (
+        lambda mp: mp.setattr(ReplayEngine, "_pack_sigs", _boom), 1),
+    "the worker's batch raises": (
+        lambda mp: mp.setattr(native, "recover_addresses_batch", _boom),
+        1),
+    "Future.result() raises": (
+        lambda mp: mp.setattr(ReplayEngine, "_recover_pool_get",
+                              lambda self: _LostResultPool()), 0),
+}
+
+
+@pytest.mark.parametrize("seam", list(SEAMS))
+def test_failed_batch_is_not_counted_as_recovered(monkeypatch, chain3,
+                                                  seam):
+    """A batch that RAISES — while packing, in the worker, or when its
+    result is read — must not read as recovered: sigs_host counts only
+    batches that completed, the failed one shows in recover_degraded,
+    and its txs recover per-tx in signer.sender: the root still lands,
+    on the device path."""
+    arm, sync_lost = SEAMS[seam]
+    arm(monkeypatch)
+    blocks = _fresh(chain3)
     eng = _engine()
     assert eng.replay(blocks) == blocks[-1].root
-    assert eng.stats.sigs_device == 0
+    assert eng.stats.sigs_host == 0 and eng.stats.sigs_device == 0
     assert eng.stats.recover_degraded >= 1
     assert eng.stats.blocks_fallback == 0
 
     # the synchronous form (serve prefetch, replay_block) counts the same
+    again = _fresh(chain3)
     eng2 = _engine()
-    eng2.warm_senders(_fresh(blocks))
-    assert eng2.stats.sigs_device == 0
-    assert eng2.stats.recover_degraded == 1
+    eng2.warm_senders(again)
+    assert eng2.stats.recover_degraded == sync_lost
+    assert eng2.stats.sigs_host == (0 if sync_lost else _n_txs(again))
 
 
-# ------------------------------------------- routing by earliest finish
-# A stub cost table (the ledger's numbers before the chip was asked:
-# 0.14-0.29 s a launch at every bucket, 0.009 ms a signature on 13
-# cores) and stub engines: pure host Python, nothing compiles.
-STUB_LAUNCH_S = {64: 0.14, 128: 0.14, 256: 0.267, 512: 0.169,
-                 1024: 0.14, 2048: 0.142, 4096: 0.285}
-
-
-def _stub_cost(cores):
-    from coreth_tpu.replay.recover_cost import RecoverCost
-    return RecoverCost(launch_s=STUB_LAUNCH_S, host_fixed_s=0.001,
-                       host_sig_core_s=0.000117, cores=cores)
-
-
-def _stub_engines(monkeypatch, eng):
-    """Both batch engines and the packing replaced by counters: a
-    "block" is any object with a ``transactions`` list.  Returns the
-    chunk contexts each ladder issue made."""
-    from coreth_tpu.crypto import native, secp_device
-    from coreth_tpu.replay import engine as E
-    issues = []
+# ------------------------------------------------- segments, look-ahead
+def _stub_batch(monkeypatch, eng):
+    """The native batch and the packing replaced by counters — pure host
+    Python, nothing compiles; a "block" is any object with a
+    ``transactions`` list.  Returns the (signatures, thread) of every
+    batch run, in the order run."""
+    batches = []
 
     def pack(blocks):
-        n = sum(len(b.transactions) for b in blocks)
+        n = _n_txs(blocks)
         return [None] * n, bytes(32 * n), bytes(32 * n), bytes(32 * n), \
             bytes(n)
 
-    def issue_chunk(hashes, rs, ss, recids, kernel=None):
-        return dict(n=len(recids), out=None)
+    def batch(hashes, rs, ss, recids):
+        batches.append((len(recids), threading.get_ident()))
+        return bytes(20 * len(recids)), b"\x01" * len(recids)
 
-    real_issue = secp_device.issue_recover
-
-    def issue(*a, **k):
-        ctxs = real_issue(*a, **k)     # the chunk loop is the real one
-        issues.append(ctxs)
-        return ctxs
-
-    monkeypatch.setattr(E, "_has_accelerator", lambda: True)
     monkeypatch.setattr(eng, "_pack_sigs", pack)
     monkeypatch.setattr(eng, "_apply_recovered", lambda *a: None)
-    monkeypatch.setattr(native, "recover_addresses_batch",
-                        lambda h, r, s, v: (bytes(20 * len(v)),
-                                            b"\x01" * len(v)))
-    monkeypatch.setattr(secp_device, "_issue_chunk", issue_chunk)
-    monkeypatch.setattr(secp_device, "issue_recover", issue)
-    monkeypatch.setattr(secp_device, "fetch_recover", lambda ctxs: None)
-    monkeypatch.setattr(
-        secp_device, "complete_recover",
-        lambda ctxs: (bytes(20 * sum(c["n"] for c in ctxs)),
-                      b"\x01" * sum(c["n"] for c in ctxs)))
-    return issues
+    monkeypatch.setattr(native, "recover_addresses_batch", batch)
+    return batches
 
 
-ROUTING = {
-    # the three cells' chains after the lead block, on the chip's host:
-    # segment 0 and every later one to the native batch
-    "p2p-1k on 13 cores": dict(cores=13, blocks=[714] * 32,
-                               kinds="h" * 7),
-    "p2p-token-1k on 13 cores": dict(cores=13, blocks=[445] * 32,
-                                     kinds="h" * 4),
-    "valuetx on 13 cores": dict(cores=13, blocks=[1] * 9999,
-                                kinds="h" * 3),
-    # one core does 4,096 signatures in 0.48 s, a launch in 0.285 s
-    "a full launch on 1 core": dict(cores=1, blocks=[4096], kinds="d"),
-    # ... and the book then sends the next segment to the idle engine:
-    # the first four issue before any completes, by the model alone
-    "the book alternates on 1 core": dict(cores=1, blocks=[714] * 32,
-                                          kinds="dhdh", first=4),
-    "a block larger than a launch": dict(cores=1, blocks=[5000],
-                                         kinds="d", chunks=2),
-    "CORETH_RECOVER_FORCE_DEVICE=1": dict(cores=13, blocks=[714] * 32,
-                                          kinds="d" * 7, force=True),
-    "_recover_packed on 13 cores": dict(cores=13, packed=4284, n_dev=0),
-    "_recover_packed on 1 core": dict(cores=1, packed=4284, n_dev=2048),
-    "_recover_packed forced": dict(cores=13, packed=4284, n_dev=4284,
-                                   force=True),
+def _stub_blocks(sizes):
+    return [SimpleNamespace(transactions=[None] * k) for k in sizes]
+
+
+SEGMENTING = {
+    # the three cells' chains after the lead block: the segment sizes
+    # the benchmark's rows were measured with
+    "p2p-1k": ([714] * 32, [3570] * 6 + [1428]),
+    "p2p-token-1k": ([445] * 32, [4005] * 3 + [2225]),
+    "valuetx": ([1] * 9999, [4096, 4096, 1807]),
+    "a block larger than a segment": ([5000], [5000]),
+    "empty blocks between full ones": ([0, 2000, 0, 2000, 0, 2000, 0, 0],
+                                       [4000, 2000]),
+    "an empty chain": ([], []),
 }
 
 
-@pytest.mark.parametrize("case", list(ROUTING), ids=list(ROUTING))
-def test_recovery_goes_to_the_engine_that_finishes_first(monkeypatch,
-                                                         case):
-    """One rule, read from the input and the machine: a segment (or a
-    synchronous batch's share) goes to the ladder only where the cost
-    model has it done strictly earlier there.  No segment of whole
-    blocks exceeds one launch, a ladder segment is ONE chunk context,
-    and the per-engine counters add up to what was issued."""
-    from types import SimpleNamespace
-    from coreth_tpu import obs
-    from coreth_tpu.crypto.secp_device import MAX_CHUNK
-    c = ROUTING[case]
-    monkeypatch.delenv("CORETH_SHARD_RECOVER", raising=False)
-    monkeypatch.delenv("CORETH_RECOVER_FORCE_DEVICE", raising=False)
-    if c.get("force"):
-        monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
+@pytest.mark.parametrize("case", list(SEGMENTING))
+def test_segments_are_whole_blocks_of_at_most_4096_signatures(
+        monkeypatch, case):
+    """A segment closes BEFORE the block that would take it past
+    SEGMENT_SIGS; a single larger block is a segment, and a batch, of
+    its own.  Every signature completes on the native batch."""
+    sizes, want = SEGMENTING[case]
     eng = _engine()
-    monkeypatch.setattr(eng, "recover_cost", _stub_cost(c["cores"]))
-    issues = _stub_engines(monkeypatch, eng)
-    st = eng.stats
-
-    if "packed" in c:
-        n = c["packed"]
-        out, ok = eng._recover_packed(bytes(32 * n), bytes(32 * n),
-                                      bytes(32 * n), bytes(n),
-                                      obs.NULL_ACCOUNT)
-        assert (len(out), len(ok)) == (20 * n, n)
-        assert (st.sigs_device, st.sigs_host) == (c["n_dev"],
-                                                  n - c["n_dev"])
-        assert st.segs_device == (c["n_dev"] > 0)
-        assert st.segs_host == (c["n_dev"] < n)
-        assert eng.recover_cost.split(n) == (
-            c["n_dev"] if not c.get("force") else 0)
-        return
-
-    blocks = [SimpleNamespace(transactions=[None] * k)
-              for k in c["blocks"]]
+    batches = _stub_batch(monkeypatch, eng)
+    blocks = _stub_blocks(sizes)
     pipe = _SenderPipeline(eng, blocks)
-    sizes = [sum(len(b.transactions) for b in seg)
-             for seg in pipe.segments]
-    assert sum(sizes) == sum(c["blocks"])
-    assert all(k <= MAX_CHUNK or len(seg) == 1
-               for k, seg in zip(sizes, pipe.segments))
+    assert [b for seg in pipe.segments for b in seg] == blocks
+    assert [_n_txs(seg) for seg in pipe.segments] == want
+    assert all(n <= pipe.SEGMENT_SIGS == 4096 or len(seg) == 1
+               for n, seg in zip(want, pipe.segments))
     for i in range(len(blocks)):       # block by block, as replay()
         pipe.ensure(i)
-    kinds = "".join(h["kind"][0] for h in pipe.issued)
-    first = c.get("first", len(kinds))
-    assert kinds[:first] == c["kinds"], kinds
-    # a routed segment is one launch (one chunk context) unless a
-    # single block alone is larger
-    assert len(issues) == kinds.count("d")
-    assert all(len(ctxs) == c.get("chunks", 1) for ctxs in issues)
-    # the counters add up to the segments issued, engine by engine
-    assert st.segs_device == kinds.count("d")
-    assert st.segs_host == kinds.count("h")
-    assert st.segs_device + st.segs_host == len(pipe.segments)
-    assert st.sigs_device + st.sigs_host == sum(c["blocks"])
-    assert st.sigs_device == pipe.dev_sigs
+        assert pipe.done == pipe.block_seg[i] + 1
+    assert [n for n, _ in batches] == want
+    st = eng.stats
+    assert st.sigs_host == sum(sizes) and st.sigs_device == 0
     assert st.recover_degraded == 0
-    for kind, n_segs in (("device", st.segs_device),
-                         ("host", st.segs_host)):
-        model = getattr(st, f"t_recover_{kind}_model")
-        seen = getattr(st, f"t_recover_{kind}_seen")
-        assert (model > 0) == (n_segs > 0) and seen >= 0
+    assert st.t_sender_device == 0.0
 
 
-def test_accelerator_probe_does_not_swallow_a_broken_backend(monkeypatch):
-    """A backend probe that raises must propagate: returning False
-    would route every signature to the host and look healthy."""
-    from coreth_tpu.replay import engine as E
-    monkeypatch.delenv("CORETH_RECOVER_FORCE_DEVICE", raising=False)
+def test_lookahead_issues_ahead_and_completes_in_order(monkeypatch):
+    """ensure(0) applies segment 0 and leaves exactly AHEAD more
+    issued; the one worker runs them in the order issued, off the
+    replay thread."""
+    eng = _engine()
+    batches = _stub_batch(monkeypatch, eng)
+    sizes = [4000, 3000, 2500, 4001, 3500, 2600]  # one block a segment
+    pipe = _SenderPipeline(eng, _stub_blocks(sizes))
+    assert len(pipe.segments) == len(sizes)
+    pipe.ensure(0)
+    assert len(pipe.issued) == pipe.AHEAD + 1 == 4
+    assert pipe.done == 1 and eng.stats.sigs_host == sizes[0]
+    pipe.ensure(1)
+    assert len(pipe.issued) == 5 and pipe.done == 2
+    pipe.ensure(len(sizes) - 1)
+    assert len(pipe.issued) == len(sizes) == pipe.done
+    assert [n for n, _ in batches] == sizes
+    workers = {t for _, t in batches}
+    assert len(workers) == 1 and threading.get_ident() not in workers
+    n = eng.account.row()["n"]
+    assert n["sender/pack"] == n["sender/wait_host"] == len(sizes)
 
-    def broken():
-        raise RuntimeError("Unable to initialize backend 'tpu'")
 
-    monkeypatch.setattr(E.jax, "default_backend", broken)
-    with pytest.raises(RuntimeError, match="Unable to initialize"):
-        E._has_accelerator()
+def test_warm_senders_runs_the_batch_on_the_calling_thread(monkeypatch):
+    """The synchronous form has no worker and no wait: the batch is
+    work of the thread that called, inside ``sender/pack``."""
+    eng = _engine()
+    batches = _stub_batch(monkeypatch, eng)
+    eng.warm_senders(_stub_blocks([5, 7]))
+    assert batches == [(12, threading.get_ident())]
+    assert eng.stats.sigs_host == 12 and eng.stats.sigs_device == 0
+    n = eng.account.row()["n"]
+    assert n.get("sender/pack") == 1 and n.get("sender/apply") == 1
+    assert not n.get("sender/wait_host")
+
+
+def test_without_the_native_library_senders_recover_per_tx(monkeypatch,
+                                                           chain3):
+    """No native library: no batch engine at all.  Every segment stays
+    lazy and signer.sender recovers per tx; the header's root lands and
+    nothing reads as a batch, done or degraded."""
+    real_load = native.load
+
+    def load():
+        # the engine alone finds no library: keccak and the tries of
+        # this process were bound to it at import
+        asks = sys._getframe(1).f_globals["__name__"]
+        return None if asks == "coreth_tpu.replay.engine" else real_load()
+
+    monkeypatch.setattr(native, "load", load)
+    monkeypatch.setattr(native, "recover_addresses_batch", _boom)
+    blocks = _fresh(chain3)
+    eng = _engine()
+    assert eng.replay(blocks) == blocks[-1].root
+    eng.warm_senders(_fresh(chain3))
+    st = eng.stats
+    assert st.sigs_host == 0 and st.sigs_device == 0
+    assert st.recover_degraded == 0 and st.blocks_fallback == 0
+
+
+@pytest.mark.parametrize("knob,mesh", [
+    ("CORETH_RECOVER_FORCE_DEVICE", False),
+    ("CORETH_SHARD_RECOVER", True)])
+def test_retired_recover_knobs_select_nothing(monkeypatch, chain3, knob,
+                                              mesh):
+    """The variables that used to send recovery to the device ladder
+    are read by nothing: same root, every signature on the native
+    batch."""
+    monkeypatch.setenv(knob, "1")
+    blocks = _fresh(chain3)
+    eng = _engine(mesh=make_mesh(jax.devices("cpu")[:2]) if mesh else None)
+    assert eng.replay(blocks) == blocks[-1].root
+    assert eng.stats.sigs_device == 0
+    assert eng.stats.sigs_host == _n_txs(blocks)
+    eng.warm_senders(_fresh(chain3))
+    assert eng.stats.sigs_device == 0
+    assert eng.stats.sigs_host == 2 * _n_txs(blocks)
+
+
+_FRESH_REPLAY = """
+import sys
+sys.path.insert(0, {repo!r})
+sys.path.insert(0, {tests!r})
+import coreth_tpu.replay.engine
+import coreth_tpu.serve
+from coreth_tpu.serve.prefetch import Prefetcher
+import test_batch_recovery as T
+blocks = T._build_chain(2)
+eng = T._engine()
+Prefetcher(eng).warm(T._fresh(blocks)[:1])
+assert eng.replay(T._fresh(blocks)) == blocks[-1].root
+assert eng.stats.sigs_host > 0 and eng.stats.recover_degraded == 0
+ladder = [m for m in ("coreth_tpu.crypto.secp_device",
+                      "coreth_tpu.ops.secp") if m in sys.modules]
+assert not ladder, ladder
+print("replayed without", "the ladder")
+"""
+
+
+def test_a_replaying_process_never_imports_the_ladder():
+    """A fresh interpreter imports the engine and the serve package,
+    prefetches and replays a toy chain: the device ladder's modules are
+    not even loaded (tools/lint LAY005 holds the same line statically)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", _FRESH_REPLAY.format(
+            repo=REPO, tests=os.path.join(REPO, "tests"))],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "replayed without the ladder" in proc.stdout

@@ -3,12 +3,9 @@
 The script itself only runs on a TPU (it refuses anything else, and the
 driver runs it there); these cases call its phase FUNCTIONS so a change
 to an engine counter, a chain builder or the pipeline report breaks a
-tier-1 test instead of the next chip run.  Device sender recovery is
-forced onto the XLA-CPU ladder the way tests/test_batch_recovery.py
-does (CORETH_RECOVER_FORCE_DEVICE=1, and ``device_sigs=True`` asks the
-phases to demand it), so the "completed on the device" checks run for
-real; at the engine's defaults the smoke leaves the ladder's proof to
-its ``recover`` phase.
+tier-1 test instead of the next chip run.  Senders are recovered by
+the native batch, as on the chip; the device ladder's proof is the
+smoke's ``recover`` phase, here at its smallest bucket.
 """
 
 import json
@@ -32,7 +29,6 @@ def meter():
 
 @pytest.fixture
 def toy(monkeypatch):
-    monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
     # chains are rebuilt, never written into the checkout's cache
     monkeypatch.setattr(cs, "_cached_chain", lambda name, build: build())
     return TOY
@@ -41,10 +37,11 @@ def toy(monkeypatch):
 @pytest.mark.parametrize("phase", [
     "transfer", "erc20", "erc20_machine", "conflicts", "streaming"])
 def test_phase_passes_at_toy_size(phase, toy, meter):
-    row = getattr(cs, "phase_" + phase)(meter, toy, device_sigs=True)
+    row = getattr(cs, "phase_" + phase)(meter, toy)
     assert row["failures"] == [], row
     assert row["root_ok"] and row["blocks_fallback"] == 0
-    assert row["sigs_device"] > 0 and row["recover_degraded"] == 0
+    assert row["sigs_host"] > 0 and row["sigs_device"] == 0
+    assert row["recover_degraded"] == 0
     assert row["compile"]["compiles"] >= 0 and "reduced" in row
     if phase in ("erc20_machine", "conflicts"):
         m = row["machine"]
@@ -62,7 +59,7 @@ def test_phase_passes_at_toy_size(phase, toy, meter):
 
 def test_recover_phase_proves_the_ladder_bucket_by_bucket(toy, meter):
     """The toy chain fills one bucket: one probe, equal addresses, and
-    the cost table's two columns read from its runs."""
+    the report's two columns read from its runs."""
     row = cs.phase_recover(meter, toy)
     assert row["failures"] == [], row
     have = TOY.chain_blocks * TOY.txs
@@ -92,18 +89,20 @@ def test_mesh_phase_on_four_virtual_devices(toy, meter):
 
 def test_failures_name_a_run_that_only_looked_healthy():
     """A matching root is not enough: host fallbacks, degraded
-    recoveries, supervisor activity and a device that recovered nothing
-    each fail the phase."""
+    recoveries, supervisor activity and senders the native batch did
+    not recover each fail the phase."""
     row = {"blocks": 3, "root_ok": True, "blocks_fallback": 1,
-           "blocks_device": 2, "sigs_device": 0, "recover_degraded": 2,
+           "blocks_device": 2, "sigs_device": 0, "sigs_host": 0,
+           "recover_degraded": 2,
            "supervisor": {"retries": 1, "strikes": 0, "demotions": 0},
            "dispatches": 0}
-    bad = cs.replay_failures(row, machine=True, device_sigs=True)
-    assert not any("no signature completed" in b
-                   for b in cs.replay_failures(row, machine=True))
+    bad = cs.replay_failures(row, machine=True)
+    assert not any("native batch" in b for b in cs.replay_failures(
+        dict(row, sigs_host=24), machine=True))
     for needle in ("blocks_fallback=1", "blocks_device=2",
-                   "no signature completed", "recover_degraded=2",
-                   "supervisor.retries=1", "machine path never ran"):
+                   "not recovered by the native batch",
+                   "recover_degraded=2", "supervisor.retries=1",
+                   "machine path never ran"):
         assert any(needle in b for b in bad), (needle, bad)
 
 

@@ -100,6 +100,32 @@ def test_ctypes_outside_native_boundary_flagged():
     assert codes(check_layers([s], CONFIG)) == ["LAY004"]
 
 
+@pytest.mark.parametrize("snippet", [
+    "from coreth_tpu.crypto.secp_device import issue_recover\n",
+    "from coreth_tpu.crypto import native, secp_device\n",
+    "import coreth_tpu.ops.secp as S\n",
+    "def f():\n    from ..ops.secp import recover_kernel\n",
+])
+@pytest.mark.parametrize("path", [
+    "coreth_tpu/replay/x.py", "coreth_tpu/serve/x.py",
+    "coreth_tpu/serve/cluster/x.py"])
+def test_serving_path_may_not_import_the_device_ladder(snippet, path):
+    """LAY005: replay/ and serve/ recover senders on the native batch
+    alone — the ladder's modules are closed to them by every spelling,
+    lazy imports included, while their neighbours and other packages
+    stay free to import them."""
+    if "cluster" in path:
+        snippet = snippet.replace("..ops", "...ops")
+    assert codes(check_layers([src(snippet, path=path)], CONFIG)) \
+        == ["LAY005"]
+    ok = src("from coreth_tpu.crypto import native\n"
+             "from coreth_tpu.ops import u256\n", path=path)
+    assert check_layers([ok], CONFIG) == []
+    lib = src(snippet.replace("...ops", "..ops"),
+              path="coreth_tpu/parallel/x.py")
+    assert check_layers([lib], CONFIG) == []
+
+
 def test_ctypes_inside_native_boundary_allowed():
     for path in ("coreth_tpu/mpt/native_trie2.py",
                  "coreth_tpu/crypto/x.py",

@@ -510,50 +510,38 @@ def test_engine_accounts_for_its_whole_replay(toy_chain):
     assert st.t_classify > 0 and st.t_trie > 0 and st.t_fallback == 0
 
 
-def test_device_recovery_wait_is_told_from_its_finish(toy_chain,
-                                                      monkeypatch):
-    """The sender pipeline's device segment: packing and the host
-    finish are work (``sender/issue_device``, ``sender/apply``), the
-    blocking read alone is ``sender/wait_device``, and the ticket is
-    retired at the read."""
-    from coreth_tpu.crypto import native, secp_device
+def test_sender_recovery_issues_no_device_work(toy_chain, monkeypatch):
+    """The sender pipeline's segments: packing and applying are work
+    (``sender/pack``, ``sender/apply``), the worker's result is waited
+    for in ``sender/wait_host``; the device phases are never entered
+    and sender recovery puts nothing in flight on the device."""
+    from coreth_tpu.crypto import native
+    from coreth_tpu.replay import engine as E
     if native.load() is None:
-        pytest.skip("no native library: nothing to stand in for the "
-                    "ladder")
-    calls = []
+        pytest.skip("no native library: no batch engine")
+    seen = []
+    real_issue = E._SenderPipeline._issue
+    real_complete = E._SenderPipeline._complete
 
-    def issue(hashes, rs, ss, recids, kernel=None):
-        calls.append("issue")
-        return [dict(args=(hashes, rs, ss, recids))]
+    def tickets(real):
+        def around(self, s):
+            before = A.DEVICE.issued
+            real(self, s)
+            seen.append(A.DEVICE.issued - before)
+        return around
 
-    def fetch(ctxs):
-        calls.append(("fetch", A.DEVICE.busy))
-
-    def complete(ctxs):
-        calls.append(("complete", A.DEVICE.busy))
-        return native.recover_addresses_batch(*ctxs[0]["args"])
-
-    monkeypatch.setattr(secp_device, "issue_recover", issue)
-    monkeypatch.setattr(secp_device, "fetch_recover", fetch)
-    monkeypatch.setattr(secp_device, "complete_recover", complete)
-    monkeypatch.setenv("CORETH_RECOVER_FORCE_DEVICE", "1")
-    # tickets other tests of this process left unretired
-    monkeypatch.setattr(A.DEVICE, "retired", A.DEVICE.issued)
-    monkeypatch.setattr(A.DEVICE, "busy", False)
+    monkeypatch.setattr(E._SenderPipeline, "_issue", tickets(real_issue))
+    monkeypatch.setattr(E._SenderPipeline, "_complete",
+                        tickets(real_complete))
     engine, _built, _wall = _pass(*toy_chain)
     row = engine.account.row()
-    assert engine.stats.sigs_device > 0 and engine.stats.sigs_host == 0
+    assert engine.stats.sigs_host > 0 and engine.stats.sigs_device == 0
     assert engine.stats.recover_degraded == 0
-    for phase in ("sender/pack", "sender/issue_device",
-                  "sender/wait_device", "sender/apply"):
+    for phase in ("sender/pack", "sender/wait_host", "sender/apply"):
         assert row["n"].get(phase, 0) > 0, phase
-    assert "sender/wait_host" not in row["n"]
-    # in flight while the read blocks, retired before the host finish
-    # of the LAST segment (a window may be in flight under the others)
-    assert ("fetch", True) in calls
-    assert [c for c in calls if c[0] == "complete"][-1] \
-        == ("complete", False) or A.DEVICE.in_flight == 0
-    assert A.DEVICE.in_flight == 0
+    assert "sender/issue_device" not in row["n"]
+    assert "sender/wait_device" not in row["n"]
+    assert seen and not any(seen)
 
 
 def test_streaming_report_carries_the_account():

@@ -329,10 +329,8 @@ def test_engine_publishes_metrics():
     snap = reg.snapshot()
     assert "replay/t_device" in snap and "replay/blocks_device" in snap
     assert "replay/lanes_real" in snap and "replay/lanes_padded" in snap
-    for kind in ("device", "host"):
-        assert f"replay/segs_{kind}" in snap
-        assert f"replay/t_recover_{kind}_model" in snap
-        assert f"replay/t_recover_{kind}_seen" in snap
+    assert "replay/sigs_host" in snap and "replay/recover_degraded" in snap
+    assert not [k for k in snap if "segs_" in k or "t_recover_" in k]
 
 
 def test_avax_service_queries(tmp_path):

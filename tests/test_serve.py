@@ -204,15 +204,10 @@ def test_stream_prefetch_overlap_counters():
     assert report.lanes["padded"] == stream_eng.stats.lanes_padded \
         >= report.lanes["real"]
     assert pipe._live_report()["lanes"] == report.lanes
-    # and where the prefetcher's sender recovery went: on the CPU the
-    # native batch, every signature, model seconds beside seen
-    host = report.recover["host"]
-    assert host["sigs"] == stream_eng.stats.sigs_host \
-        == report.prefetch["sigs"]
-    assert host["segs"] == stream_eng.stats.segs_host > 0
-    assert host["model_s"] > 0 and host["seen_s"] > 0
-    assert report.recover["device"]["segs"] == 0
-    assert pipe._live_report()["recover"] == report.recover
+    # and the prefetcher's sender recovery went to the native batch,
+    # every signature
+    assert stream_eng.stats.sigs_host == report.prefetch["sigs"]
+    assert stream_eng.stats.sigs_device == 0
     assert report.latency_ms["p99"] >= report.latency_ms["p50"] > 0
     assert report.sustained_txs_s > 0
 

@@ -2,7 +2,7 @@
 """Quick transfer-workload TPU pass for perf iteration (no baselines).
 
 Usage: python tools/bench_transfer_only.py [reps]
-Honors BENCH_WINDOW / CORETH_RECOVER_MAX_CHUNK.
+Honors BENCH_WINDOW.
 """
 import os
 import sys

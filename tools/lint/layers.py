@@ -6,8 +6,11 @@ SURVEY §1, L0 storage → top API).  A package may import packages at its
 own layer or below; an upward import is LAY001, a package missing from
 the map (source or target) is LAY002, and a bare ``import coreth_tpu``
 (which executes the root __init__ and thus the whole upper tree) is
-LAY003.  *All* imports count, including function-local lazy ones —
-laziness changes import time, not the architecture.
+LAY003, a raw ``ctypes`` import outside the binder packages is LAY004,
+and a module that ``[[forbid]]`` closes to a package is LAY005 (a legal
+downward import the architecture has decided against).  *All* imports
+count, including function-local lazy ones — laziness changes import
+time, not the architecture.
 """
 
 from __future__ import annotations
@@ -32,6 +35,10 @@ class Config:
     # packages allowed to bind the C++ runtime directly via ctypes
     # ([native] ctypes_packages); an import elsewhere is LAY004
     ctypes_packages: List[str] = field(default_factory=list)
+    # [[forbid]] tables: {"packages": [...], "modules": [...]} — the
+    # listed packages (and their subpackages) may not import the
+    # listed modules ("crypto.secp_device"), LAY005
+    forbidden: List[dict] = field(default_factory=list)
 
 
 def _parse_minitoml(text: str) -> dict:
@@ -101,6 +108,7 @@ def load_config(toml_path: str = DEFAULT_TOML) -> Config:
             cfg.levels[pkg] = layer["level"]
     cfg.determinism_packages = data.get("determinism", {}).get("packages", [])
     cfg.ctypes_packages = data.get("native", {}).get("ctypes_packages", [])
+    cfg.forbidden = data.get("forbid", [])
     return cfg
 
 
@@ -132,18 +140,15 @@ def _prefixes_desc(nested: str) -> List[str]:
     return ["/".join(parts[:k]) for k in range(len(parts), 1, -1)]
 
 
-def _import_targets(src: Source, levels: Optional[Dict[str, int]] = None):
-    """Yield (node, target_package, name_form) for every coreth_tpu
-    import, module-level or nested.  Relative imports are resolved
+def _import_paths(src: Source):
+    """Yield (node, tail, names) for every coreth_tpu import,
+    module-level or nested: ``tail`` is the module path after the root
+    (``[]`` for the root itself), ``names`` the names a from-import
+    binds (None for ``import a.b``).  Relative imports are resolved
     against the source file's own package — ``from ..state import X``
     inside ``coreth_tpu/mpt/`` targets ``state`` exactly like the
     absolute form, so the standard relative idiom cannot dodge the
-    gate.  ``name_form`` marks ``from coreth_tpu import X`` aliases,
-    where X may be a plain re-exported symbol rather than a package.
-    With ``levels``, dotted targets resolve to the most specific
-    configured nested package (``coreth_tpu.state.flat.store`` ->
-    ``state/flat``)."""
-    levels = levels or {}
+    gate."""
     parts = src.path.split("/")
     pkg_parts = None  # the file's containing package, e.g. [root, "mpt"]
     if ROOT_PACKAGE in parts:
@@ -154,11 +159,7 @@ def _import_targets(src: Source, levels: Optional[Dict[str, int]] = None):
             for alias in node.names:
                 mod = alias.name.split(".")
                 if mod[0] == ROOT_PACKAGE:
-                    # len==1: bare root import — target is the root
-                    # itself (check_layers turns it into LAY003)
-                    yield node, (_resolve_nested(mod[1:], levels)
-                                 if len(mod) > 1
-                                 else ROOT_PACKAGE), False
+                    yield node, mod[1:], None
         elif isinstance(node, ast.ImportFrom):
             if node.level:
                 if pkg_parts is None or node.level > len(pkg_parts):
@@ -167,13 +168,47 @@ def _import_targets(src: Source, levels: Optional[Dict[str, int]] = None):
                 mod = base + (node.module.split(".") if node.module else [])
             else:
                 mod = (node.module or "").split(".")
-            if mod[0] != ROOT_PACKAGE:
-                continue
-            if len(mod) > 1:
-                yield node, _resolve_nested(mod[1:], levels), False
-            else:  # from coreth_tpu import rlp, wire  /  from .. import rlp
-                for alias in node.names:
-                    yield node, alias.name, True
+            if mod[0] == ROOT_PACKAGE:
+                yield node, mod[1:], [a.name for a in node.names]
+
+
+def _import_targets(src: Source, levels: Optional[Dict[str, int]] = None):
+    """Yield (node, target_package, name_form) for every coreth_tpu
+    import.  ``name_form`` marks ``from coreth_tpu import X`` aliases,
+    where X may be a plain re-exported symbol rather than a package.
+    With ``levels``, dotted targets resolve to the most specific
+    configured nested package (``coreth_tpu.state.flat.store`` ->
+    ``state/flat``)."""
+    levels = levels or {}
+    for node, tail, names in _import_paths(src):
+        if tail:
+            yield node, _resolve_nested(tail, levels), False
+        elif names is None:
+            # bare root import — target is the root itself
+            # (check_layers turns it into LAY003)
+            yield node, ROOT_PACKAGE, False
+        else:  # from coreth_tpu import rlp, wire  /  from .. import rlp
+            for name in names:
+                yield node, name, True
+
+
+def _forbidden_imports(src: Source, pkg: str, config: Config):
+    """Yield (node, module) for every import by ``pkg`` of a module a
+    ``[[forbid]]`` table closes to it — by either spelling: ``from
+    coreth_tpu.crypto import secp_device`` and ``import
+    coreth_tpu.crypto.secp_device`` name the same module."""
+    closed = [m for rule in config.forbidden
+              if pkg.split("/")[0] in rule.get("packages", [])
+              for m in rule.get("modules", [])]
+    if not closed:
+        return
+    for node, tail, names in _import_paths(src):
+        base = ".".join(tail)
+        seen = [base] + [f"{base}.{n}" if base else n
+                         for n in names or []]
+        for mod in closed:
+            if any(c == mod or c.startswith(mod + ".") for c in seen):
+                yield node, mod
 
 
 def check_layers(sources: List[Source], config: Config) -> List[Finding]:
@@ -209,6 +244,13 @@ def check_layers(sources: List[Source], config: Config) -> List[Finding]:
                         f"{sorted(config.ctypes_packages)} bind the "
                         f"native runtime; go through their wrappers",
                         "ctypes-outside-boundary"))
+        # LAY005 — a downward import the architecture closed
+        for node, mod in _forbidden_imports(src, pkg, config):
+            findings.append(Finding(
+                src.path, node.lineno, "LAY005",
+                f"'{pkg}' may not import {ROOT_PACKAGE}.{mod} "
+                f"(tools/lint/layers.toml [[forbid]])",
+                f"forbidden:{pkg}->{mod}"))
         seen = set()
         for node, target, name_form in _import_targets(src,
                                                        config.levels):
