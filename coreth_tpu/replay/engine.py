@@ -29,6 +29,7 @@ both engines can interleave over one chain.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import time
 from dataclasses import dataclass, field
@@ -125,6 +126,12 @@ class ReplayStats:
     # (real) against K x pad lanes uploaded and scanned (padded)
     lanes_real: int = 0
     lanes_padded: int = 0
+    # host->device transfers issued for transfer windows and the bytes
+    # they carried: ONE staging buffer a window on a single device
+    # (window_uploads == windows issued, re-applies included), five
+    # arrays a window on a mesh (_issue_window_mesh)
+    window_uploads: int = 0
+    window_upload_bytes: int = 0
     # the same for the fused machine windows dispatched (every
     # attempt): call lanes packed against blocks x lanes uploaded and
     # scanned (the window runner counts; machine_block._chunk_loop)
@@ -216,8 +223,9 @@ def lane_bucket(n_txs: int) -> int:
     return pad
 
 
-def pack_txd(batch: dict, B: int, pad: int) -> np.ndarray:
-    txd = np.zeros((pad, TXD_COLS), dtype=np.int32)
+def pack_txd(txd: np.ndarray, batch: dict, B: int) -> None:
+    """Fill ``txd`` — one block's ZEROED [pad, TXD_COLS] rows of the
+    window's staging buffer — in place."""
     txd[:B, 0] = batch["senders"]
     txd[:B, 1] = batch["recips"]
     txd[:B, 2] = batch["nonces"]
@@ -230,7 +238,6 @@ def pack_txd(batch: dict, B: int, pad: int) -> np.ndarray:
     txd[:B, 54] = batch["from_slots"]
     txd[:B, 55] = batch["to_slots"]
     txd[:B, 56:72] = u256.pack_np(batch["amounts"])
-    return txd
 
 
 def txd_cols(txd):
@@ -331,6 +338,46 @@ def _transfer_window(balances, nonces, slot_vals, acct_gids, slot_gids,
     nn = nonces.at[acct_gids].set(ln, mode="drop")
     nsv = slot_vals.at[slot_gids].set(ls, mode="drop")
     return nb, nn, nsv, fetches
+
+
+def _window_shapes(dims):
+    K, pad, t_pad, s_pad, L, SL = dims
+    return ((L,), (SL,), (K, pad, TXD_COLS), (K, t_pad), (K, s_pad))
+
+
+def window_words(dims) -> int:
+    """int32 words in the staging buffer of a window of ``dims``."""
+    return sum(math.prod(shape) for shape in _window_shapes(dims))
+
+
+def window_views(buf, dims):
+    """The five int32 inputs of a transfer window as slices of its ONE
+    flat staging buffer — ``acct_gids [L]``, ``slot_gids [SL]``,
+    ``txds [K, pad, TXD_COLS]``, ``t_idxs [K, t_pad]``,
+    ``s_idxs [K, s_pad]``, in that order, end to end.  The one
+    definition of the layout: _prepare_window fills the numpy views,
+    _transfer_window_packed cuts the device buffer at the same (static)
+    offsets.  ``dims`` = (K, pad, t_pad, s_pad, L, SL)."""
+    if window_words(dims) != buf.shape[0]:
+        raise ValueError(f"window buffer of {buf.shape[0]} words, dims "
+                         f"{dims} tile {window_words(dims)}")
+    views, at = [], 0
+    for shape in _window_shapes(dims):
+        n = math.prod(shape)
+        views.append(buf[at:at + n].reshape(shape))
+        at += n
+    return views
+
+
+@partial(jax.jit, static_argnames=("dims",))
+def _transfer_window_packed(balances, nonces, slot_vals, buf, dims):
+    """The entry the engine calls: _transfer_window on a window that
+    arrived as ONE buffer in ONE host->device transfer (a transfer
+    costs the host 0.14-0.29 ms on the chip whatever it carries,
+    PERF.md §6: five a window were 28% of a one-tx-a-block pass).  The
+    compile key is the six dims, as it was the five shapes."""
+    return _transfer_window(balances, nonces, slot_vals,
+                            *window_views(buf, dims))
 
 
 @partial(jax.jit, static_argnames=("num_accounts",))
@@ -1272,7 +1319,12 @@ class ReplayEngine:
         compiles K=16); keep ``window`` a power of two to avoid the
         extra padded slots.  The lane axis is lane_bucket() of the
         window's largest block: the masked-out lanes it leaves off
-        contributed zeros to every segment sum."""
+        contributed zeros to every segment sum.
+
+        Returns the five device inputs, the per-block touched lists,
+        what flush_staged flushed and, last, ``(buf, dims)``: the one
+        staging buffer the five are views into and the six sizes that
+        cut it (window_views) — what _ship_window sends."""
         flushed = self.state.flush_staged()
         K = 1
         while K < len(items):
@@ -1330,26 +1382,30 @@ class ReplayEngine:
             SL *= 2
         cap = self.state.capacity
         scap = self.state.slot_capacity
+        # ONE staging buffer a window, the five inputs views into it
+        # (window_views), FRESH each time: the runtime may read a numpy
+        # buffer after the upload call returns, and replay() prepares
+        # window k+1 while window k is in flight
+        dims = (K, pad, t_pad, s_pad, L, SL)
+        buf = np.zeros(window_words(dims), dtype=np.int32)
+        acct_gids, slot_gids, txds, t_idxs, s_idxs = window_views(
+            buf, dims)
         # device-table ROWS of the window-locals (row == gid unsharded;
         # bucketed arena row on a mesh); OOB pad: fill/drop
-        acct_gids = np.full(L, cap, dtype=np.int32)
+        acct_gids[:] = cap
         for g, l in acct_local.items():
             acct_gids[l] = self.state.row_of[g]
-        slot_gids = np.full(SL, scap, dtype=np.int32)
+        slot_gids[:] = scap
         for g, l in slot_local.items():
             slot_gids[l] = self.state.slot_row_of[g]
-        txds = np.zeros((K, pad, TXD_COLS), dtype=np.int32)
-        t_idxs = np.zeros((K, t_pad), dtype=np.int32)
-        s_idxs = np.zeros((K, s_pad), dtype=np.int32)
         for k, (block, batch) in enumerate(items):
-            B = len(block.transactions)
-            txds[k] = pack_txd(local_batches[k], B, pad)
+            pack_txd(txds[k], local_batches[k], len(block.transactions))
             t_idxs[k, :len(touched_lists[k])] = \
                 [acct_local[g] for g in touched_lists[k]]
             s_idxs[k, :len(slot_lists[k])] = \
                 [slot_local[g] for g in slot_lists[k]]
         return (txds, t_idxs, s_idxs, acct_gids, slot_gids,
-                touched_lists, slot_lists, flushed)
+                touched_lists, slot_lists, flushed, (buf, dims))
 
     def _count_lanes(self, items, txds) -> None:
         self.stats.lanes_real += sum(
@@ -1375,7 +1431,7 @@ class ReplayEngine:
         t0 = time.monotonic()
         acct = self.account
         (txds, t_idxs, s_idxs, acct_rows, slot_rows, touched_lists,
-         slot_lists, flushed) = self._prepare_window(items)
+         slot_lists, flushed, _) = self._prepare_window(items)
         prev = (self.state.balances, self.state.nonces,
                 self.state.slot_vals)
         perm = interleave_txs(txds.shape[1], self._n_shards)
@@ -1389,9 +1445,15 @@ class ReplayEngine:
             self._n_shards)
         win = sharded_transfer_window(self.mesh, mode)
         acct.switch("window/upload")
+        # five uploads, not the single device's one staging buffer:
+        # txds is sharded over dp on the lane axis after the interleave
+        # while the other four are replicated, and one buffer cannot
+        # carry both placements
         ups = (jnp.asarray(acct_rows), jnp.asarray(slot_rows),
                jnp.asarray(txds[:, perm]), jnp.asarray(t_idxs),
                jnp.asarray(s_idxs))
+        self.stats.window_uploads += len(ups)
+        self.stats.window_upload_bytes += sum(u.nbytes for u in ups)
         acct.switch("window/dispatch")
         new_bal, new_non, new_sv, fetches = win(
             prev[0], prev[1], prev[2], *ups)
@@ -1450,32 +1512,16 @@ class ReplayEngine:
     @_in_phase("window/prepare")
     def _issue_window_run(self, items: List[Tuple[Block, dict]]) -> dict:
         """One device call for a whole run of transfer blocks: upload the
-        stacked batches, lax.scan the steps, download one stacked fetch
-        tensor.  Round-trip latency amortizes over the window.  Three
-        phases — pack, upload, dispatch — so host packing and a
-        supervised retry's backoff never read as device time."""
+        window's one staging buffer, lax.scan the steps, download one
+        stacked fetch tensor.  Round-trip latency amortizes over the
+        window.  Three phases — pack, upload, dispatch — so host packing
+        and a supervised retry's backoff never read as device time."""
         t0 = time.monotonic()
-        acct = self.account
-        (txds, t_idxs, s_idxs, acct_gids, slot_gids, touched_lists,
-         slot_lists, flushed) = self._prepare_window(items)
+        (txds, t_idxs, _, _, _, touched_lists, slot_lists, flushed,
+         packed) = self._prepare_window(items)
         prev = (self.state.balances, self.state.nonces,
                 self.state.slot_vals)
-        acct.switch("window/upload")
-        ups = (jnp.asarray(acct_gids), jnp.asarray(slot_gids),
-               jnp.asarray(txds), jnp.asarray(t_idxs),
-               jnp.asarray(s_idxs))
-        if _EAGER_FLUSH:
-            # uploads/dispatch may sit unflushed until the next
-            # blocking sync — which would serialize the chip behind the
-            # host's fold work; shipping the inputs here lets the scan
-            # start while the host validates the previous window
-            jax.block_until_ready(ups)
-        acct.switch("window/dispatch")
-        new_bal, new_non, new_sv, fetches = _transfer_window(
-            prev[0], prev[1], prev[2], *ups)
-        self.state.balances = new_bal
-        self.state.nonces = new_non
-        self.state.slot_vals = new_sv
+        fetches = self._ship_window(packed)
         # windowed device READ: start the whole window's fetch-tensor
         # device->host copy now (async — it begins the moment the scan
         # finishes), so _complete_window's np.asarray lands on an
@@ -1488,12 +1534,44 @@ class ReplayEngine:
         except AttributeError:
             pass  # non-jax array (mesh path fetches are already np)
         self._count_lanes(items, txds)
-        ticket = obs.device_issue(acct)
+        ticket = obs.device_issue(self.account)
         self.stats.t_device += time.monotonic() - t0
         return dict(items=items, prev=prev, fetches=fetches,
                     touched_lists=touched_lists, slot_lists=slot_lists,
                     t_pad=t_idxs.shape[1], flushed=flushed,
                     ticket=ticket)
+
+    def _ship_window(self, packed):
+        """Ship a prepared window on a single device — its staging
+        buffer up in ONE transfer, the jitted window over the state
+        tables, which become its outputs — and return the fetch tensor.
+        The one copy of it: a window's issue and _recover_window's
+        re-apply both come here.  Switches the account phase on top to
+        ``window/upload``, then ``window/dispatch``; the caller closes
+        it.
+
+        The numpy buffer goes into the jitted call as it is and the
+        call's own argument path makes the transfer: 0.135 ms a window
+        on the chip against 0.285 for ``jnp.asarray`` first (PERF.md
+        §6, PR 34).  So ``window/upload`` holds seconds only under
+        CORETH_EAGER_FLUSH, which needs the device buffer to wait on."""
+        buf, dims = packed
+        acct = self.account
+        acct.switch("window/upload")
+        self.stats.window_uploads += 1
+        self.stats.window_upload_bytes += buf.nbytes
+        if _EAGER_FLUSH:
+            # the upload and the dispatch may sit unflushed until the
+            # next blocking sync — which would serialize the chip
+            # behind the host's fold work; shipping the buffer here lets
+            # the scan start while the host validates the window before
+            buf = jax.block_until_ready(jnp.asarray(buf))
+        acct.switch("window/dispatch")
+        st = self.state
+        st.balances, st.nonces, st.slot_vals, fetches = \
+            _transfer_window_packed(st.balances, st.nonces, st.slot_vals,
+                                    buf, dims=dims)
+        return fetches
 
     def _discard_window(self, win: dict) -> None:
         """Drop a speculatively issued window whose base state was
@@ -1636,16 +1714,8 @@ class ReplayEngine:
                     # state-only re-apply; no per-block host downloads
                     self._issue_window_mesh(items, fetch=False)
                 else:
-                    (txds, t_idxs, s_idxs, acct_gids, slot_gids, _,
-                     _, _) = self._prepare_window(items)
-                    new_bal, new_non, new_sv, _ = _transfer_window(
-                        self.state.balances, self.state.nonces,
-                        self.state.slot_vals, jnp.asarray(acct_gids),
-                        jnp.asarray(slot_gids), jnp.asarray(txds),
-                        jnp.asarray(t_idxs), jnp.asarray(s_idxs))
-                    self.state.balances = new_bal
-                    self.state.nonces = new_non
-                    self.state.slot_vals = new_sv
+                    with self.account.enter("window/prepare"):
+                        self._ship_window(self._prepare_window(items)[-1])
         self._fallback(blocks[start_idx + k])
         return start_idx + k + 1
 

@@ -127,7 +127,9 @@ class StreamReport:
     # lane fill of the engine's transfer windows (ReplayStats
     # lanes_real / lanes_padded): transactions packed against lanes
     # uploaded and scanned; machine_real / machine_padded: the same
-    # for its fused machine windows
+    # for its fused machine windows; window_uploads /
+    # window_upload_bytes: the host->device transfers that carried the
+    # transfer windows (one staging buffer a window on one device)
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -715,7 +717,9 @@ class StreamingPipeline:
         st = self.engine.stats
         return {"real": st.lanes_real, "padded": st.lanes_padded,
                 "machine_real": st.machine_lanes_real,
-                "machine_padded": st.machine_lanes_padded}
+                "machine_padded": st.machine_lanes_padded,
+                "window_uploads": st.window_uploads,
+                "window_upload_bytes": st.window_upload_bytes}
 
     def _publish(self, wall: float) -> None:
         s = self.stats

@@ -567,11 +567,13 @@ def test_streaming_report_carries_the_account():
 def _lower_transfer_window():
     from coreth_tpu.replay import engine as E
     i32 = np.int32
-    return E._transfer_window.lower(
+    # the entry the engine calls: (K, pad, t_pad, s_pad, L, SL) cut
+    # from one staging buffer
+    dims = (2, 8, 16, 8, 16, 8)
+    return E._transfer_window_packed.lower(
         np.zeros((64, 16), i32), np.zeros((64,), i32),
-        np.zeros((8, 16), i32), np.zeros((16,), i32),
-        np.zeros((8,), i32), np.zeros((2, 8, E.TXD_COLS), i32),
-        np.zeros((2, 16), i32), np.zeros((2, 8), i32))
+        np.zeros((8, 16), i32), np.zeros((E.window_words(dims),), i32),
+        dims=dims)
 
 
 def _lower_recover_kernel():

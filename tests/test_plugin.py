@@ -329,6 +329,8 @@ def test_engine_publishes_metrics():
     snap = reg.snapshot()
     assert "replay/t_device" in snap and "replay/blocks_device" in snap
     assert "replay/lanes_real" in snap and "replay/lanes_padded" in snap
+    assert "replay/window_uploads" in snap \
+        and "replay/window_upload_bytes" in snap
     assert "replay/sigs_host" in snap and "replay/recover_degraded" in snap
     assert not [k for k in snap if "segs_" in k or "t_recover_" in k]
 

@@ -217,16 +217,25 @@ def sized_chain():
     return genesis, blocks
 
 
-def _prepared_shapes(genesis, blocks, **engine_kw):
-    """(txds, t_idxs) shapes of ONE window holding ``blocks``."""
+def _classified_window(genesis, blocks, lead=(), **engine_kw):
+    """A fresh engine that replayed ``lead``, and ``blocks`` classified
+    as ONE window's items."""
     db = Database()
     gb = genesis.to_block(db)
     engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
                           capacity=256, window=16, **engine_kw)
+    if lead:
+        engine.replay(list(lead))
     items = []
     for block in blocks:
         engine.warm_senders(block)
         items.append((block, engine._classify(block)))
+    return engine, items
+
+
+def _prepared_shapes(genesis, blocks, **engine_kw):
+    """(txds, t_idxs) shapes of ONE window holding ``blocks``."""
+    engine, items = _classified_window(genesis, blocks, **engine_kw)
     txds, t_idxs, *_ = engine._prepare_window(items)
     return txds.shape, t_idxs.shape
 
@@ -578,8 +587,9 @@ def test_replay_mid_window_failure_recovery(monkeypatch, shape):
     gb = genesis.to_block(db)
     engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
                           capacity=256, window=16)
-    lanes, recovered = [], []
+    lanes, recovered, reapply_uploads = [], [], []
     prepare, recover = engine._prepare_window, engine._recover_window
+    fallback = engine._fallback
     validate = engine._validate_and_advance
 
     def spy_prepare(items):
@@ -589,7 +599,13 @@ def test_replay_mid_window_failure_recovery(monkeypatch, shape):
 
     def spy_recover(win, arr, k, *rest):
         recovered.append(k)
+        reapply_uploads.append(engine.stats.window_uploads)
         return recover(win, arr, k, *rest)
+
+    def spy_fallback(block, *rest):
+        # _recover_window re-applies the prefix, THEN falls back
+        reapply_uploads.append(engine.stats.window_uploads)
+        return fallback(block, *rest)
 
     def fail_once(block, *rest):
         if block is blocks[failed] and not recovered:
@@ -598,6 +614,7 @@ def test_replay_mid_window_failure_recovery(monkeypatch, shape):
 
     monkeypatch.setattr(engine, "_prepare_window", spy_prepare)
     monkeypatch.setattr(engine, "_recover_window", spy_recover)
+    monkeypatch.setattr(engine, "_fallback", spy_fallback)
     if shape == "one_tx_blocks":
         monkeypatch.setattr(engine, "_validate_and_advance", fail_once)
     root = engine.replay(blocks)
@@ -609,3 +626,125 @@ def test_replay_mid_window_failure_recovery(monkeypatch, shape):
     # pow2 of each run, every one of them 16 lanes wide
     assert [l[1] for l in lanes] == [16, 16, 16], lanes
     assert lanes[1][0] >= failed
+    # the re-apply went through the shared upload-and-call helper: ONE
+    # transfer between _recover_window's entry and its fallback, and
+    # one a prepared window over the whole replay
+    assert reapply_uploads[1] - reapply_uploads[0] == 1
+    assert engine.stats.window_uploads == len(lanes) == 3
+    assert engine.account.row()["n"]["window/upload"] == 3
+
+
+# ------------------------------------------ one staging buffer a window
+
+def _packed_window(shape, sized_chain):
+    """(engine, items) of the three windows the benchmark's cells and
+    the token fast path meet, each on the state its blocks expect."""
+    if shape == "one_tx_blocks":      # valuetx: 16 x 16 lanes
+        genesis, _, blocks = build_sized_chain([1] * 16)
+        return _classified_window(genesis, blocks)
+    if shape == "full_block":         # p2p-1k: 714 txs, 1,024 lanes
+        genesis, blocks = sized_chain
+        assert len(blocks[-1].transactions) == 714
+        return _classified_window(genesis, blocks[-1:], lead=blocks[:-1])
+    genesis, _, blocks, _ = build_token_chain(4, 16)
+    return _classified_window(genesis, blocks)
+
+
+PACKED_SHAPES = {
+    # shape: (K, pad, t_pad, s_pad, L, SL) of its window
+    "one_tx_blocks": (16, 16, 256, 8, 256, 8),
+    "full_block": (1, 1024, 256, 8, 256, 8),
+    "token_window": (4, 16, 256, 32, 256, 32),
+}
+
+
+@pytest.mark.parametrize("shape", list(PACKED_SHAPES))
+def test_packed_window_equals_eight_argument_body(sized_chain, shape):
+    """The entry the engine calls — one staging buffer, cut inside the
+    jitted program — returns bit for bit what the eight-argument
+    _transfer_window returns on the same _prepare_window output."""
+    from coreth_tpu.replay import engine as E
+    engine, items = _packed_window(shape, sized_chain)
+    (txds, t_idxs, s_idxs, acct_gids, slot_gids, _, slot_lists, _,
+     (buf, dims)) = engine._prepare_window(items)
+    assert dims == PACKED_SHAPES[shape]
+    st = engine.state
+    tables = (st.balances, st.nonces, st.slot_vals)
+    ref = E._transfer_window(*tables, acct_gids, slot_gids, txds,
+                             t_idxs, s_idxs)
+    got = E._transfer_window_packed(*tables, buf, dims=dims)
+    for r, g in zip(ref, got):
+        assert r.shape == g.shape and r.dtype == g.dtype
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+    # and it is the real thing: every block passed the device's checks,
+    # the tables moved, the token window has slot locals of its own
+    assert (np.asarray(got[3])[:len(items), -1, 0] == 1).all()
+    assert not np.array_equal(np.asarray(got[0]), np.asarray(tables[0]))
+    assert any(slot_lists) == (shape == "token_window")
+    if shape == "token_window":
+        assert (slot_gids < st.slot_capacity).sum() > 1
+        assert not np.array_equal(np.asarray(got[2]),
+                                  np.asarray(tables[2]))
+
+
+@pytest.mark.parametrize("shape", list(PACKED_SHAPES))
+def test_window_views_tile_one_fresh_buffer(sized_chain, shape):
+    """The five inputs are views that tile the staging buffer end to
+    end — acct_gids, slot_gids, txds, t_idxs, s_idxs: no gap, no
+    overlap, no copy — and every _prepare_window call allocates its
+    own: window k+1 is packed while window k's upload may still be
+    read by the runtime."""
+    from coreth_tpu.replay import engine as E
+    engine, items = _packed_window(shape, sized_chain)
+    out = engine._prepare_window(items)
+    txds, t_idxs, s_idxs, acct_gids, slot_gids = out[:5]
+    buf, dims = out[-1]
+    K, pad, t_pad, s_pad, L, SL = dims
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    assert buf.size == L + SL + K * (pad * E.TXD_COLS + t_pad + s_pad)
+    at = buf.__array_interface__["data"][0]
+    for view, shp in ((acct_gids, (L,)), (slot_gids, (SL,)),
+                      (txds, (K, pad, E.TXD_COLS)), (t_idxs, (K, t_pad)),
+                      (s_idxs, (K, s_pad))):
+        assert view.shape == shp and view.dtype == np.int32
+        assert view.flags["C_CONTIGUOUS"] and view.base is buf
+        assert view.__array_interface__["data"][0] == at
+        at += view.nbytes
+    assert at == buf.__array_interface__["data"][0] + buf.nbytes
+    # what the device cuts is what the host filled
+    for mine, cut in zip((acct_gids, slot_gids, txds, t_idxs, s_idxs),
+                         E.window_views(np.array(buf), dims)):
+        np.testing.assert_array_equal(mine, cut)
+    with pytest.raises(ValueError, match="window buffer"):
+        E.window_views(buf[:-1], dims)
+    again = engine._prepare_window(items)
+    assert not np.shares_memory(again[-1][0], buf)
+    np.testing.assert_array_equal(again[-1][0], buf)
+    assert again[-1][1] == dims
+
+
+def test_eager_flush_waits_on_the_one_buffer(monkeypatch):
+    """CORETH_EAGER_FLUSH makes the transfer explicit so that it has a
+    device buffer to wait on: ONE a window, the whole staging buffer."""
+    import jax
+    from coreth_tpu.replay import engine as E
+    genesis, _, blocks = build_transfer_chain(5, 8)
+    db = Database()
+    gb = genesis.to_block(db)
+    engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
+                          capacity=256, window=4)
+    waited = []
+    ready = jax.block_until_ready
+
+    def spy_ready(x):
+        waited.append(x)
+        return ready(x)
+
+    monkeypatch.setattr(E, "_EAGER_FLUSH", True)
+    monkeypatch.setattr(E.jax, "block_until_ready", spy_ready)
+    assert engine.replay(blocks) == blocks[-1].header.root
+    assert engine.stats.blocks_fallback == 0
+    assert len(waited) == engine.stats.window_uploads == 2
+    assert all(isinstance(x, jax.Array) and x.ndim == 1 for x in waited)
+    assert sum(x.nbytes for x in waited) \
+        == engine.stats.window_upload_bytes

@@ -203,6 +203,11 @@ def test_stream_prefetch_overlap_counters():
     assert report.lanes["real"] == sum(len(b.transactions) for b in blocks)
     assert report.lanes["padded"] == stream_eng.stats.lanes_padded \
         >= report.lanes["real"]
+    assert report.lanes["window_uploads"] \
+        == stream_eng.stats.window_uploads \
+        == stream_eng.stats.reads_prefetched
+    assert report.lanes["window_upload_bytes"] \
+        == stream_eng.stats.window_upload_bytes > 0
     assert pipe._live_report()["lanes"] == report.lanes
     # and the prefetcher's sender recovery went to the native batch,
     # every signature

@@ -28,6 +28,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import numpy as np
 import pytest
 import jax
 
@@ -370,8 +371,8 @@ def _count_window_kernel_calls(monkeypatch):
         return call
 
     real_sharded = S.sharded_transfer_window
-    monkeypatch.setattr(E, "_transfer_window",
-                        counted(E._transfer_window))
+    monkeypatch.setattr(E, "_transfer_window_packed",
+                        counted(E._transfer_window_packed))
     monkeypatch.setattr(S, "sharded_transfer_window",
                         lambda mesh, mode: counted(real_sharded(mesh,
                                                                 mode)))
@@ -705,7 +706,8 @@ def test_lane_buckets_exact_across_edges(monkeypatch, fresh_jit_caches,
     replays to the header roots on one device and on a 2-device mesh,
     wholly on the device, and the lane counters say what was packed
     and what was scanned.  On one device the same run counts the
-    _transfer_window variants it compiled (PERF.md §7, Speed 8)."""
+    variants it compiled of the entry the engine calls,
+    _transfer_window_packed (PERF.md §7, Speed 8)."""
     from coreth_tpu.replay import engine as engine_mod
     blocks = edge_chain
     mesh = make_mesh(jax.devices("cpu")[:n_dev]) if n_dev > 1 else None
@@ -718,9 +720,9 @@ def test_lane_buckets_exact_across_edges(monkeypatch, fresh_jit_caches,
         return out
 
     monkeypatch.setattr(ReplayEngine, "_prepare_window", spy)
-    before = engine_mod._transfer_window._cache_size()
+    before = engine_mod._transfer_window_packed._cache_size()
     root, eng = _replay(blocks, mesh, window=window)
-    compiled = engine_mod._transfer_window._cache_size() - before
+    compiled = engine_mod._transfer_window_packed._cache_size() - before
     assert root == blocks[-1].root
     assert eng.stats.blocks_device == len(blocks)
     assert eng.stats.blocks_fallback == 0
@@ -731,3 +733,35 @@ def test_lane_buckets_exact_across_edges(monkeypatch, fresh_jit_caches,
     # window 4, 3 a block at a time (the mesh path has a jit of its own)
     assert len(set(seen)) == len(set(shapes))
     assert compiled == (len(set(shapes)) if n_dev == 1 else 0)
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_window_upload_counters(monkeypatch, edge_chain, n_dev):
+    """ONE host->device transfer a window on a single device —
+    window_uploads == windows issued, window_upload_bytes the sum of
+    their staging buffers — and on a mesh five a window of the same
+    bytes (txds sharded over dp, the other four replicated: an upload
+    of its own), to the same roots."""
+    blocks = edge_chain
+    mesh = make_mesh(jax.devices("cpu")[:n_dev]) if n_dev > 1 else None
+    bufs = []
+    prepare = ReplayEngine._prepare_window
+
+    def spy(self, items):
+        out = prepare(self, items)
+        bufs.append(out[-1][0])
+        return out
+
+    monkeypatch.setattr(ReplayEngine, "_prepare_window", spy)
+    root, eng = _replay(blocks, mesh, window=4)
+    assert root == blocks[-1].root
+    assert eng.stats.blocks_fallback == 0
+    windows = eng.stats.reads_prefetched
+    assert windows == len(bufs) == 2
+    assert eng.account.row()["n"]["window/upload"] == windows
+    assert eng.stats.window_uploads == (windows if n_dev == 1
+                                        else 5 * windows)
+    assert eng.stats.window_upload_bytes == sum(b.nbytes for b in bufs)
+    # fresh buffers: the second window was packed while the first was
+    # in flight
+    assert not np.shares_memory(bufs[0], bufs[1])

@@ -109,15 +109,22 @@ def _like(arrays, shape):
 
 
 # ------------------------------------------------------------- one chip
+def _lower_window(s, accounts, slots, dims):
+    """The entry the engine calls (engine._transfer_window_packed: ONE
+    staging buffer, cut inside the program) lowered for a window of
+    ``dims`` = (K, pad, t_pad, s_pad, L, SL)."""
+    return E._transfer_window_packed.lower(
+        s((accounts, 16)), s((accounts,)), s((slots, 16)),
+        s((E.window_words(dims),)), dims=dims)
+
+
 def test_transfer_window_full_width(one_chip):
-    """engine._transfer_window at the steady window of the transfer
+    """The transfer window at the steady window of the transfer
     chain: 128 blocks x 128 txs over a 2^17-row account table, the
     window's touched set bucketed to 16384 locals."""
-    s = one_chip
-    compiled = E._transfer_window.lower(
-        s((ACCOUNTS, 16)), s((ACCOUNTS,)), s((SLOTS, 16)),
-        s((16384,)), s((8,)), s((WINDOW, TXS, E.TXD_COLS)),
-        s((WINDOW, 512)), s((WINDOW, 8))).compile()
+    compiled = _lower_window(
+        one_chip, ACCOUNTS, SLOTS,
+        (WINDOW, TXS, 512, 8, 16384, 8)).compile()
     mem = compiled.memory_analysis()
     # the three tables in, the three tables + the fetch tensor out
     assert mem.argument_size_in_bytes >= ACCOUNTS * 17 * 4
@@ -125,20 +132,17 @@ def test_transfer_window_full_width(one_chip):
 
 
 def test_transfer_window_one_tx_blocks(one_chip):
-    """engine._transfer_window at the steady window of a one-tx-a-block
+    """The transfer window at the steady window of a one-tx-a-block
     chain at the engine's defaults (the benchmark's ``valuetx`` cell):
     16 blocks in the 16-lane floor bucket (engine.LANE_FLOOR), 256
     account locals, the 2^14-row tables."""
-    s = one_chip
     cap = 1 << 14
-    compiled = E._transfer_window.lower(
-        s((cap, 16)), s((cap,)), s((cap, 16)),
-        s((256,)), s((8,)), s((16, E.LANE_FLOOR, E.TXD_COLS)),
-        s((16, 256)), s((16, 8))).compile()
+    compiled = _lower_window(
+        one_chip, cap, cap, (16, E.LANE_FLOOR, 256, 8, 256, 8)).compile()
     mem = compiled.memory_analysis()
-    # the three tables and a 73 KB window (16 x 16 x 72 int32), not
-    # the 4.7 MB of a 1,024-lane one
-    assert mem.argument_size_in_bytes < cap * 33 * 4 + (1 << 20)
+    # the three tables and ONE 92 KB staging buffer (73 KB of it the
+    # 16 x 16 x 72 int32 tx rows), not the 4.7 MB of a 1,024-lane one
+    assert mem.argument_size_in_bytes < cap * 33 * 4 + (1 << 17)
 
 
 def test_erc20_window_and_block_steps(one_chip):
@@ -146,10 +150,8 @@ def test_erc20_window_and_block_steps(one_chip):
     and the per-block _transfer_step / _slot_step it is built from
     (__graft_entry__.entry() calls those directly)."""
     s = one_chip
-    E._transfer_window.lower(
-        s((ACCOUNTS, 16)), s((ACCOUNTS,)), s((SLOTS, 16)),
-        s((2048,)), s((4096,)), s((WINDOW, ERC20_TXS, E.TXD_COLS)),
-        s((WINDOW, 512)), s((WINDOW, 512))).compile()
+    _lower_window(s, ACCOUNTS, SLOTS,
+                  (WINDOW, ERC20_TXS, 512, 512, 2048, 4096)).compile()
     b = TXS
     E._transfer_step.lower(
         s((ACCOUNTS, 16)), s((ACCOUNTS,)), s((b,)), s((b,)),
