@@ -79,6 +79,15 @@ def sharded_transfer_step(mesh: Mesh, num_accounts: int):
     Nonce-sequence validation is computed against gathered nonce rows for
     the local tx shard (an all_gather of one i32 row — cheap vs the limb
     traffic saved by psum_scatter on the totals).
+
+    Solvency is the CONSERVATIVE pre-block rule — every sender's
+    pre-block balance against the sum of all it will need in the block,
+    credits ignored — not the single device's in-order rule
+    (replay/engine.py _order_solvent): lanes are dealt round-robin over
+    the devices and accounts are sharded, so the credits a sender was
+    paid earlier in the block lie on other shards in another order.
+    The rule is still exact end to end: ok=True implies the sequential
+    outcome, ok=False sends the block to the host path.
     """
     n_dev = mesh.devices.size
     assert num_accounts % n_dev == 0

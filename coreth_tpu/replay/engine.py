@@ -132,6 +132,10 @@ class ReplayStats:
     # arrays a window on a mesh (_issue_window_mesh)
     window_uploads: int = 0
     window_upload_bytes: int = 0
+    # blocks the device committed that the pre-block solvency rule
+    # would have refused: some sender spent what an earlier transaction
+    # of the same block paid it (_transfer_step's ok & ~pre_ok)
+    blocks_order_dependent: int = 0
     # the same for the fused machine windows dispatched (every
     # attempt): call lanes packed against blocks x lanes uploaded and
     # scanned (the window runner counts; machine_block._chunk_loop)
@@ -252,16 +256,21 @@ def txd_cols(txd):
             txd[:, 56:72])
 
 
-def _gather_fetch(balances, nonces, slot_vals, ok, t_idx, s_idx):
+def _gather_fetch(balances, nonces, slot_vals, ok, t_idx, s_idx,
+                  pre_ok=None):
     """[t_pad+s_pad+1, 17] fetch tensor: touched (balance, nonce) rows,
-    touched storage-slot value rows, and the ok flag."""
+    touched storage-slot value rows, and a last row of flags: column 0
+    the ok flag, column 1 the pre-block solvency rule's verdict
+    (_transfer_step's pre_ok; a step that has one rule only — the mesh
+    window — leaves it out and the column repeats ok)."""
     g = jnp.concatenate([balances[t_idx],
                          nonces[t_idx][:, None]], axis=1)
     s = jnp.concatenate([slot_vals[s_idx],
                          jnp.zeros((s_idx.shape[0], 1), dtype=jnp.int32)],
                         axis=1)
+    flags = jnp.stack([ok, ok if pre_ok is None else pre_ok])
     ok_row = jnp.zeros((1, u256.LIMBS + 1), dtype=jnp.int32)
-    ok_row = ok_row.at[0, 0].set(ok.astype(jnp.int32))
+    ok_row = ok_row.at[0, :2].set(flags.astype(jnp.int32))
     return jnp.concatenate([g, s, ok_row], axis=0)
 
 
@@ -270,13 +279,13 @@ def _step_core(balances, nonces, slot_vals, txd, num_accounts: int,
     """One block of transfers (native + token) from a packed batch."""
     (senders, recips, values, fees, required, tx_nonce, offsets, mask,
      coinbase, from_slots, to_slots, amounts) = txd_cols(txd)
-    nb, nn, ok = _transfer_step(
+    nb, nn, ok, pre_ok = _transfer_step(
         balances, nonces, senders, recips, values, fees, required,
         tx_nonce, offsets, mask, coinbase, num_accounts=num_accounts)
     sv, ok_slots = _slot_step(
         slot_vals, from_slots, to_slots, amounts, mask,
         num_slots=num_slots)
-    return nb, nn, sv, ok & ok_slots
+    return nb, nn, sv, ok & ok_slots, pre_ok & ok_slots
 
 
 @partial(jax.jit, static_argnames=("num_slots",))
@@ -328,8 +337,8 @@ def _transfer_window(balances, nonces, slot_vals, acct_gids, slot_gids,
         txd, t_idx, s_idx = inp
         # the scanned step's stable name in a device trace
         with jax.named_scope("coreth/transfer_step"):
-            nb, nn, nsv, ok = _step_core(bal, non, sv, txd, L, SL)
-            fetch = _gather_fetch(nb, nn, nsv, ok, t_idx, s_idx)
+            nb, nn, nsv, ok, pre_ok = _step_core(bal, non, sv, txd, L, SL)
+            fetch = _gather_fetch(nb, nn, nsv, ok, t_idx, s_idx, pre_ok)
         return (nb, nn, nsv), fetch
 
     (lb, ln, ls), fetches = jax.lax.scan(
@@ -380,6 +389,46 @@ def _transfer_window_packed(balances, nonces, slot_vals, buf, dims):
                             *window_views(buf, dims))
 
 
+# Widest lane axis the in-order solvency check builds its [B, B] lane
+# masks for; past it _transfer_step keeps the pre-block rule alone.
+ORDER_CHECK_MAX_LANES = 4096
+# jnp.pad widths that give a [n, 16] limb array a 17th limb: sums of
+# u256 values carry out of 2^256, and a compare must see the carry
+_CARRY_LIMB = ((0, 0), (0, 1))
+
+
+def _order_solvent(balances, sender_idx, recip_idx, credit, debit,
+                   required):
+    """[B] bool: lane j's sender holds, AT LANE j's PLACE IN THE BLOCK,
+    what buyGas asks of it (state_transition.go:286) —
+    ``bal0[s_j] + sum(value_i : i < j, recip_i == s_j)
+      >= sum(value_i + fee_i : i < j, sender_i == s_j) + required_j``.
+    A transfer's value, fee and requirement are in the transaction, not
+    in the state, so the sequential check is two prefix sums and no
+    loop: two strictly-lower-triangular equality masks [B, B] times the
+    [B, 16] limb arrays, as int32 products (a limb sum stays under
+    B x 65,535 < 2^28 at B <= 4,096).  ``credit`` / ``debit`` /
+    ``required`` are already zero on masked-out lanes, so padding lanes
+    add nothing and pass.  Both sides can pass 2^256 (1,024 credits of
+    2^255): a 17th limb carries that through the compare.
+
+    Measured on the chip beside two other forms (PERF.md §6, PR 35):
+    the limbs split in bytes for an exact bf16 -> f32 product read the
+    same at 1,024 lanes and 3 us a block more at 16; a sort by
+    (account, lane) with a segmented running sum 78 us more at 1,024."""
+    with jax.named_scope("coreth/transfer_order_check"):
+        lane = jnp.arange(sender_idx.shape[0])
+        earlier = lane[None, :] < lane[:, None]           # [j, i]: i < j
+        mine = sender_idx[:, None]
+        paid = (earlier & (recip_idx[None, :] == mine)).astype(jnp.int32)
+        spent = (earlier & (sender_idx[None, :] == mine)).astype(jnp.int32)
+        have = u256.normalize(jnp.pad(
+            balances[sender_idx] + jnp.dot(paid, credit), _CARRY_LIMB))
+        need = u256.normalize(jnp.pad(
+            jnp.dot(spent, debit) + required, _CARRY_LIMB))
+        return u256.gte(have, need)
+
+
 @partial(jax.jit, static_argnames=("num_accounts",))
 def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
                    required16, tx_nonce, nonce_offset, mask, coinbase_idx,
@@ -387,11 +436,34 @@ def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
     """One block of pure transfers, batched.
 
     required16 carries the buyGas balance requirement per tx
-    (gas_limit * gas_fee_cap + value, state_transition.go:286) — checked
-    against the pre-block balance summed per sender, which is
-    conservative vs the sequential per-tx check (credits only help), so
-    ok=True implies the sequential outcome.  Returns
-    (new_balances, new_nonces, ok); ok False => caller falls back.
+    (gas_limit * gas_fee_cap + value, state_transition.go:286).  The
+    solvency rule is the reference's own: every transaction is held,
+    IN BLOCK ORDER (lane j is transaction j: pack_txd), to what its
+    sender holds at that point — its pre-block balance, plus what
+    earlier transactions of the block paid it, less what it spent in
+    them (_order_solvent).  By induction no balance then goes below 0
+    and the finals are the segment sums below, so ok=True is the
+    sequential outcome and, for credits that arrive by transfer, ok=False
+    is the sequential failure.  Fees credited to the block's coinbase
+    stay OUTSIDE the check: a coinbase that sends what it earned
+    earlier in the same block is refused and the caller falls back (on
+    the C-Chain the coinbase is the blackhole address, which has no
+    key).
+
+    The check's two [B, B] int32 masks are at most 4 MB each at 1,024
+    lanes — the widest bucket transfers in a 15M-gas block can reach
+    (714); the TPU compiler fuses them into the products, 2.6 MB of
+    temporaries for the whole window program there
+    (tests/test_tpu_compile.py) — and 64 MB each at 4,096; past
+    ORDER_CHECK_MAX_LANES the step keeps the pre-block rule below alone
+    (conservative, so still exact: the caller falls back).
+
+    Returns (new_balances, new_nonces, ok, pre_ok); ok False => caller
+    falls back.  pre_ok is the PRE-BLOCK rule's verdict — every sender's
+    pre-block balance against the sum of all it will need in the block,
+    credits ignored — which implies ok; ``ok & ~pre_ok`` marks a block
+    only the in-order rule could commit (ReplayStats.
+    blocks_order_dependent).
     """
     mask_i = mask.astype(jnp.int32)
     debit = u256.add(value16, fee16)                      # [B, 16]
@@ -404,8 +476,10 @@ def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
     # per-account totals (16-bit limbs give segment-sum headroom)
     debit_tot = u256.normalize(jax.ops.segment_sum(
         debit, sender_idx, num_segments=num_accounts))
-    required_tot = u256.normalize(jax.ops.segment_sum(
-        required, sender_idx, num_segments=num_accounts))
+    # (a sender's requirements can add up past 2^256: the carry limb
+    # keeps the pre-block rule from wrapping into a pass)
+    required_tot = u256.normalize(jnp.pad(jax.ops.segment_sum(
+        required, sender_idx, num_segments=num_accounts), _CARRY_LIMB))
     credit_tot = u256.normalize(jax.ops.segment_sum(
         credit, recip_idx, num_segments=num_accounts))
     fee_total = u256.normalize(jnp.sum(fee16 * mask_i[:, None], axis=0))
@@ -413,11 +487,17 @@ def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
     credit_tot = u256.normalize(credit_tot)
     send_counts = jax.ops.segment_sum(mask_i, sender_idx,
                                       num_segments=num_accounts)
-    solvent = u256.gte(balances, required_tot)            # [A]
-    ok = nonce_ok & jnp.all(solvent | (send_counts == 0))
+    solvent = u256.gte(jnp.pad(balances, _CARRY_LIMB),
+                       required_tot)                      # [A]
+    pre_ok = nonce_ok & jnp.all(solvent | (send_counts == 0))
+    if sender_idx.shape[0] <= ORDER_CHECK_MAX_LANES:
+        ok = nonce_ok & jnp.all(_order_solvent(
+            balances, sender_idx, recip_idx, credit, debit, required))
+    else:
+        ok = pre_ok
     new_balances = u256.sub(u256.add(balances, credit_tot), debit_tot)
     new_nonces = nonces + send_counts
-    return new_balances, new_nonces, ok
+    return new_balances, new_nonces, ok, pre_ok
 
 
 @jax.jit
@@ -1656,6 +1736,10 @@ class ReplayEngine:
                     # _validate_and_advance raises before staging, so
                     # the staged set is exactly the valid prefix [0, k).
                     return k
+                # the device committed it; would the pre-block rule
+                # have? (column 1 of the flag row: _gather_fetch)
+                self.stats.blocks_order_dependent += int(
+                    arr[k, -1, 1] != 1)
             return None
         finally:
             self.account.move("validate", "commit/stage",

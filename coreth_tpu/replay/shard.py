@@ -155,7 +155,11 @@ def _build_window(mesh, mode: str = "psum"):
             sdeb_t = u256.normalize(pack_s[:, 0:16])
             scred_t = u256.normalize(pack_s[:, 16:32])
             # validation on the (replicated) owning rows — identical on
-            # every device, so ok needs no further collective
+            # every device, so ok needs no further collective.  The
+            # CONSERVATIVE pre-block solvency rule, as
+            # parallel.sharded_transfer_step says and why: a block whose
+            # sender was funded earlier in the same block comes back
+            # ok=False here and goes to the host path
             solvent = u256.gte(cb_bal, req_t)
             ok = (nonce_n == n_dev) \
                 & jnp.all(solvent | (counts == 0)) \

@@ -129,7 +129,9 @@ class StreamReport:
     # uploaded and scanned; machine_real / machine_padded: the same
     # for its fused machine windows; window_uploads /
     # window_upload_bytes: the host->device transfers that carried the
-    # transfer windows (one staging buffer a window on one device)
+    # transfer windows (one staging buffer a window on one device);
+    # blocks_order_dependent: device blocks only the in-order solvency
+    # rule could commit (a sender funded earlier in the same block)
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -719,7 +721,8 @@ class StreamingPipeline:
                 "machine_real": st.machine_lanes_real,
                 "machine_padded": st.machine_lanes_padded,
                 "window_uploads": st.window_uploads,
-                "window_upload_bytes": st.window_upload_bytes}
+                "window_upload_bytes": st.window_upload_bytes,
+                "blocks_order_dependent": st.blocks_order_dependent}
 
     def _publish(self, wall: float) -> None:
         s = self.stats

@@ -487,40 +487,55 @@ def test_native_receipt_root_parity():
         assert bloom == create_bloom(receipts)
 
 
+def _coinbase_spends_its_fees_chain(first_to: int = 0x52):
+    """Block 1 is sequentially valid and the DEVICE REFUSES it: its
+    coinbase is ADDRS[1], whose second transaction spends the fee the
+    first one just paid it.  Fees credited to the coinbase are outside
+    the transfer step's in-order solvency check (_transfer_step), so the
+    block comes back ok=False and goes to the host path.  (Until the
+    check went in-order these tests used "A -> B big, then B -> C more
+    than B held before the block", which the device now commits:
+    tests/test_transfer_order.py has that block.)"""
+    genesis = Genesis(config=CFG, gas_limit=8_000_000,
+                      alloc={ADDRS[0]: GenesisAccount(balance=10**24),
+                             ADDRS[1]: GenesisAccount(balance=10**12),
+                             ADDRS[2]: GenesisAccount(balance=10**24)})
+    db0 = Database()
+    gblock = genesis.to_block(db0)
+
+    def gen(i, bg):
+        if i == 1:
+            bg.set_coinbase(ADDRS[1])
+            # A pays the coinbase 21,000 x (base fee + tip) in fees ...
+            bg.add_tx(sign_tx(DynamicFeeTx(
+                chain_id_=CFG.chain_id, nonce=1, gas_tip_cap_=GWEI,
+                gas_fee_cap_=300 * GWEI, gas=21_000, to=ADDRS[2],
+                value=5 * 10**23), KEYS[0], CFG.chain_id))
+            # ... and the coinbase, which held 10^12 wei before the
+            # block, needs all of that to buy its own gas
+            bg.add_tx(sign_tx(DynamicFeeTx(
+                chain_id_=CFG.chain_id, nonce=0, gas_tip_cap_=GWEI,
+                gas_fee_cap_=bg.base_fee + GWEI, gas=21_000,
+                to=ADDRS[2], value=777), KEYS[1], CFG.chain_id))
+        else:
+            nonce = {0: 0, 2: 2}[i]
+            bg.add_tx(sign_tx(DynamicFeeTx(
+                chain_id_=CFG.chain_id, nonce=nonce, gas_tip_cap_=GWEI,
+                gas_fee_cap_=300 * GWEI, gas=21_000,
+                to=bytes([first_to + i]) * 20, value=777),
+                KEYS[0], CFG.chain_id))
+
+    blocks, _ = generate_chain(CFG, gblock, db0, 3, gen, gap=2)
+    return genesis, blocks
+
+
 def test_replay_speculative_window_discard():
     """The pipelined replay issues window k+1 before validating window
     k.  With window=1, block 1's validation failure must discard the
     already-issued speculative window for block 2 (computed on the
     now-stale device state), rewind, run block 1 on the host path, and
     re-derive block 2 — landing on the exact sequential root."""
-    genesis = Genesis(config=CFG, gas_limit=8_000_000,
-                      alloc={ADDRS[0]: GenesisAccount(balance=10**24),
-                             ADDRS[1]: GenesisAccount(balance=10**17),
-                             ADDRS[2]: GenesisAccount(balance=10**24)})
-    db0 = Database()
-    gblock = genesis.to_block(db0)
-    big = 5 * 10**23
-
-    def gen(i, bg):
-        if i == 1:
-            # sequentially valid, fails the conservative device check
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=1, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000, to=ADDRS[1],
-                value=big), KEYS[0], CFG.chain_id))
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=0, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000, to=ADDRS[2],
-                value=big // 2), KEYS[1], CFG.chain_id))
-        else:
-            nonce = {0: 0, 2: 2}[i]
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=nonce, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000,
-                to=bytes([0x52 + i]) * 20, value=777),
-                KEYS[0], CFG.chain_id))
-
-    blocks, _ = generate_chain(CFG, gblock, db0, 3, gen, gap=2)
+    genesis, blocks = _coinbase_spends_its_fees_chain()
     db = Database()
     gb = genesis.to_block(db)
     engine = ReplayEngine(CFG, db, gb.root, parent_header=gb.header,
@@ -532,39 +547,10 @@ def test_replay_speculative_window_discard():
 
 
 def _insolvent_mid_block_chain():
-    """Block 1 is sequentially valid but fails the conservative device
-    check (its second sender spends credits received earlier in the
-    same block)."""
-    genesis = Genesis(config=CFG, gas_limit=8_000_000,
-                      alloc={ADDRS[0]: GenesisAccount(balance=10**24),
-                             ADDRS[1]: GenesisAccount(balance=10**17),
-                             ADDRS[2]: GenesisAccount(balance=10**24)})
-    db0 = Database()
-    gblock = genesis.to_block(db0)
-    big = 5 * 10**23  # far exceeds ADDRS[1]'s own 1e17 balance
-
-    def gen(i, bg):
-        if i == 1:
-            # A -> B big, then B -> C bigger-than-B's-pre-block balance:
-            # valid sequentially, insolvent under the conservative check
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=1, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000, to=ADDRS[1],
-                value=big), KEYS[0], CFG.chain_id))
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=0, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000, to=ADDRS[2],
-                value=big // 2), KEYS[1], CFG.chain_id))
-        else:
-            nonce = {0: 0, 2: 2}[i]
-            bg.add_tx(sign_tx(DynamicFeeTx(
-                chain_id_=CFG.chain_id, nonce=nonce, gas_tip_cap_=GWEI,
-                gas_fee_cap_=300 * GWEI, gas=21_000,
-                to=bytes([0x42 + i]) * 20, value=777),
-                KEYS[0], CFG.chain_id))
-
-    blocks, _ = generate_chain(CFG, gblock, db0, 3, gen, gap=2)
-    return genesis, blocks
+    """Block 1 is sequentially valid but fails the device's solvency
+    check (its second sender, the block's coinbase, spends fees it
+    earned earlier in the same block)."""
+    return _coinbase_spends_its_fees_chain(first_to=0x42)
 
 
 @pytest.mark.parametrize("shape", ["insolvent_two_tx", "one_tx_blocks"])
@@ -572,7 +558,8 @@ def test_replay_mid_window_failure_recovery(monkeypatch, shape):
     """A block that fails the device path at k>0 of its window triggers
     the rewind/re-apply/fallback/resume path (_recover_window),
     producing the exact sequential result.  ``insolvent_two_tx``: the
-    device's own conservative check refuses block 1.  ``one_tx_blocks``:
+    device's own solvency check refuses block 1 (fees earned in the
+    block are outside it).  ``one_tx_blocks``:
     a one-tx-a-block chain, every window in the 16-lane floor bucket,
     block 3 made to fail its device validation once — the valid prefix
     [0, 3) is re-applied through _prepare_window in that bucket."""

@@ -145,6 +145,27 @@ def test_transfer_window_one_tx_blocks(one_chip):
     assert mem.argument_size_in_bytes < cap * 33 * 4 + (1 << 17)
 
 
+def test_transfer_window_gas_full_blocks_order_check(one_chip):
+    """The transfer window of a gas-full ring or p2p block at the
+    engine's defaults (the benchmark's ``ring1k`` / ``p2p-1k`` cells):
+    16 blocks x 1,024 lanes, 1,024 account locals.  The in-order
+    solvency check's two [1,024, 1,024] lane masks go into int32
+    products under their own scope (engine._order_solvent), and the
+    program's temporaries stay a few MB: the masks are per scanned
+    block, not per window, and the compiler fuses them into the
+    products."""
+    cap = 1 << 14
+    compiled = _lower_window(
+        one_chip, cap, cap, (16, 1024, 1024, 8, 1024, 8)).compile()
+    text = compiled.as_text()
+    check = [line for line in text.split("\n")
+             if "coreth/transfer_order_check" in line]
+    assert any("convolution(" in line and "s32[1024,16]" in line
+               for line in check)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+
+
 def test_erc20_window_and_block_steps(one_chip):
     """The ERC-20 fast path's window (256-tx blocks, 4096 slot locals)
     and the per-block _transfer_step / _slot_step it is built from
