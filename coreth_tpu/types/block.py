@@ -15,7 +15,8 @@ from typing import List, Optional
 
 from coreth_tpu import rlp
 from coreth_tpu.crypto import keccak256
-from coreth_tpu.types.transaction import Transaction
+from coreth_tpu.types.transaction import (
+    Transaction, legacy_fields, typed_inner)
 
 HASH_ZERO = b"\x00" * 32
 ADDR_ZERO = b"\x00" * 20
@@ -122,6 +123,12 @@ class Header:
         return Header(**{k: getattr(self, k) for k in self.__dataclass_fields__})
 
 
+def _uncle_header(items) -> Header:
+    if type(items) is not list or list in map(type, items):
+        raise ValueError("malformed uncle header")
+    return Header.from_rlp_items(items)
+
+
 class Block:
     """A block: header + txs + uncles + coreth (version, extdata)."""
 
@@ -186,17 +193,28 @@ class Block:
 
     @classmethod
     def decode(cls, data: bytes) -> "Block":
-        items = rlp.decode(data)
-        if not isinstance(items, list) or len(items) != 5:
-            raise ValueError("malformed block RLP")
-        header = Header.from_rlp_items(items[0])
+        """One pass over the wire bytes, by offsets: every string is sliced
+        from ``data`` once, a legacy transaction is read where it lies."""
+        data = bytes(data)
+        pos, end = rlp.list_span(data, 0, len(data))
+        if end != len(data):
+            raise ValueError("trailing bytes after block RLP")
+        pos, stop = rlp.list_span(data, pos, end)
+        header = Header.from_rlp_items(rlp.span_items(data, pos, stop))
+        pos, stop = rlp.list_span(data, stop, end)
         txs = []
-        for t in items[1]:
-            if isinstance(t, list):  # legacy tx as nested list
-                txs.append(Transaction.decode(rlp.encode(t)))
-            else:  # typed tx as byte string
-                txs.append(Transaction.decode(t))
-        uncles = [Header.from_rlp_items(u) for u in items[2]]
-        version = rlp.decode_uint(items[3])
-        extdata = items[4] if items[4] else None
-        return cls(header, txs, uncles, version, extdata)
+        while pos < stop:
+            start, nxt = rlp.payload_span(data, pos, stop)
+            if data[pos] >= 0xC0:  # a list: a legacy tx, read in place
+                inner = legacy_fields(data, start, nxt)
+                inner._wire = data[pos:nxt]
+            else:  # a string: a typed tx's wire form
+                inner = typed_inner(data, start, nxt)
+            txs.append(Transaction(inner))
+            pos = nxt
+        rest = rlp.span_items(data, stop, end, 0)
+        if len(rest) != 3 or type(rest[0]) is not list:
+            raise ValueError("malformed block RLP")
+        uncles = [_uncle_header(u) for u in rest[0]]
+        return cls(header, txs, uncles, rlp.decode_uint(rest[1]),
+                   rest[2] or None)

@@ -24,13 +24,24 @@ DYNAMIC_FEE_TX_TYPE = 0x02
 
 AccessList = List[Tuple[bytes, List[bytes]]]
 
+_uint = rlp.decode_uint
+
 
 def _al_rlp(access_list: AccessList) -> list:
     return [[addr, list(keys)] for addr, keys in access_list]
 
 
 def _al_from_rlp(items) -> AccessList:
-    return [(tup[0], list(tup[1])) for tup in items]
+    """The access list from its decoded RLP: a list of [address, [key,
+    ...]] pairs of strings, or ValueError."""
+    out = []
+    for tup in items:
+        if (type(tup) is not list or len(tup) != 2
+                or type(tup[0]) is not bytes or type(tup[1]) is not list
+                or list in map(type, tup[1])):
+            raise ValueError("malformed access list")
+        out.append((tup[0], tup[1]))
+    return out
 
 
 def _rlp_item_end(buf: bytes, pos: int) -> int:
@@ -290,6 +301,59 @@ class DynamicFeeTx:
                             self.data, list(self.al), recid, r, s)
 
 
+# One field builder per transaction type, over the payload span of the
+# type's field list inside ``buf``: Transaction.decode hands them its own
+# bytes, Block.decode the block's buffer (a legacy tx is read in place).
+
+def legacy_fields(buf: bytes, start: int, end: int) -> LegacyTx:
+    items = rlp.span_items(buf, start, end)
+    if len(items) != 9:
+        raise ValueError("malformed legacy tx")
+    nonce, gas_price, gas, to, value, data, v, r, s = items
+    return LegacyTx(_uint(nonce), _uint(gas_price), _uint(gas), to or None,
+                    _uint(value), data, _uint(v), _uint(r), _uint(s))
+
+
+def _access_list_fields(buf: bytes, start: int, end: int) -> AccessListTx:
+    items = rlp.span_items(buf, start, end, 7)
+    if len(items) != 11 or type(items[7]) is not list:
+        raise ValueError("malformed access-list tx")
+    chain_id, nonce, gas_price, gas, to, value, data, al, v, r, s = items
+    return AccessListTx(_uint(chain_id), _uint(nonce), _uint(gas_price),
+                        _uint(gas), to or None, _uint(value), data,
+                        _al_from_rlp(al), _uint(v), _uint(r), _uint(s))
+
+
+def _dynamic_fee_fields(buf: bytes, start: int, end: int) -> DynamicFeeTx:
+    items = rlp.span_items(buf, start, end, 8)
+    if len(items) != 12 or type(items[8]) is not list:
+        raise ValueError("malformed dynamic-fee tx")
+    chain_id, nonce, tip, fee, gas, to, value, data, al, v, r, s = items
+    return DynamicFeeTx(_uint(chain_id), _uint(nonce), _uint(tip), _uint(fee),
+                        _uint(gas), to or None, _uint(value), data,
+                        _al_from_rlp(al), _uint(v), _uint(r), _uint(s))
+
+
+_TYPED_FIELDS = {ACCESS_LIST_TX_TYPE: _access_list_fields,
+                 DYNAMIC_FEE_TX_TYPE: _dynamic_fee_fields}
+
+
+def typed_inner(buf: bytes, pos: int, end: int):
+    """The typed transaction whose wire form (type byte, payload list) is
+    ``buf[pos:end]``: the list has to end exactly where the span does."""
+    if pos >= end:
+        raise ValueError("empty tx bytes")
+    fields = _TYPED_FIELDS.get(buf[pos])
+    if fields is None:
+        raise ValueError(f"unknown tx type {buf[pos]:#x}")
+    start, lend = rlp.list_span(buf, pos + 1, end)
+    if lend != end:
+        raise ValueError("trailing bytes after tx payload")
+    inner = fields(buf, start, end)
+    inner._wire = buf[pos:end]  # sighash slices the original bytes
+    return inner
+
+
 class Transaction:
     """Wrapper with cached hash/size/sender (reference transaction.go:53)."""
 
@@ -361,63 +425,15 @@ class Transaction:
 
     @classmethod
     def decode(cls, data: bytes) -> "Transaction":
-        if not data:
-            raise ValueError("empty tx bytes")
-        if data[0] >= 0xC0:  # RLP list => legacy
-            items = rlp.decode(data)
-            if len(items) != 9:
-                raise ValueError("malformed legacy tx")
-            return cls(LegacyTx(
-                nonce=rlp.decode_uint(items[0]),
-                gas_price=rlp.decode_uint(items[1]),
-                gas=rlp.decode_uint(items[2]),
-                to=items[3] if items[3] else None,
-                value=rlp.decode_uint(items[4]),
-                data=items[5],
-                v=rlp.decode_uint(items[6]),
-                r=rlp.decode_uint(items[7]),
-                s=rlp.decode_uint(items[8]),
-            ))
-        typ = data[0]
-        items = rlp.decode(data[1:])
-        if typ == ACCESS_LIST_TX_TYPE:
-            if len(items) != 11:
-                raise ValueError("malformed access-list tx")
-            inner = AccessListTx(
-                chain_id_=rlp.decode_uint(items[0]),
-                nonce=rlp.decode_uint(items[1]),
-                gas_price=rlp.decode_uint(items[2]),
-                gas=rlp.decode_uint(items[3]),
-                to=items[4] if items[4] else None,
-                value=rlp.decode_uint(items[5]),
-                data=items[6],
-                al=_al_from_rlp(items[7]),
-                v=rlp.decode_uint(items[8]),
-                r=rlp.decode_uint(items[9]),
-                s=rlp.decode_uint(items[10]),
-            )
-            inner._wire = data  # sighash slices the original bytes
-            return cls(inner)
-        if typ == DYNAMIC_FEE_TX_TYPE:
-            if len(items) != 12:
-                raise ValueError("malformed dynamic-fee tx")
-            inner = DynamicFeeTx(
-                chain_id_=rlp.decode_uint(items[0]),
-                nonce=rlp.decode_uint(items[1]),
-                gas_tip_cap_=rlp.decode_uint(items[2]),
-                gas_fee_cap_=rlp.decode_uint(items[3]),
-                gas=rlp.decode_uint(items[4]),
-                to=items[5] if items[5] else None,
-                value=rlp.decode_uint(items[6]),
-                data=items[7],
-                al=_al_from_rlp(items[8]),
-                v=rlp.decode_uint(items[9]),
-                r=rlp.decode_uint(items[10]),
-                s=rlp.decode_uint(items[11]),
-            )
-            inner._wire = data  # sighash slices the original bytes
-            return cls(inner)
-        raise ValueError(f"unknown tx type {typ:#x}")
+        data = bytes(data)
+        if data[:1] < b"\xc0":  # a type byte (or nothing) => typed
+            return cls(typed_inner(data, 0, len(data)))
+        start, end = rlp.list_span(data, 0, len(data))  # RLP list => legacy
+        if end != len(data):
+            raise ValueError("trailing bytes after tx payload")
+        inner = legacy_fields(data, start, end)
+        inner._wire = data
+        return cls(inner)
 
     def hash(self) -> bytes:
         if self._hash is None:
