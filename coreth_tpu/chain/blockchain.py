@@ -17,6 +17,7 @@ import time as _time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from coreth_tpu import obs
 from coreth_tpu.chain.genesis import Genesis
 from coreth_tpu.consensus.engine import ConsensusError, DummyEngine
 from coreth_tpu.params import ChainConfig
@@ -61,8 +62,17 @@ class BlockChain:
                  chain_kv=None, commit_interval: int = 4096,
                  archive: bool = False, snapshots: bool = True,
                  prefetch: bool = False, freezer_dir=None,
-                 freeze_threshold: int = 90_000):
-        """chain_kv: optional rawdb.KVStore making the chain durable —
+                 freeze_threshold: int = 90_000, state_processor=None):
+        """state_processor: None — every block runs on the host
+        ``Processor`` — or a factory ``f(chain)`` of the backend that
+        executes the blocks extending its tip instead (eth hands over
+        replay/device_processor.DeviceProcessor, where the contract is
+        written down; chain/ sits below replay/ and imports none of
+        it).  A block beside that tip still takes the host path here,
+        on the trie alone: the flat-state snapshot tree is the HOST
+        backend's processing layer and is not built.
+
+        chain_kv: optional rawdb.KVStore making the chain durable —
         accepted blocks/receipts/canonical index persist immediately,
         trie nodes every `commit_interval` accepts (state_manager.go
         policy); reopening on the same store resumes at the last
@@ -71,6 +81,9 @@ class BlockChain:
         self.chain_kv = chain_kv
         self.commit_interval = commit_interval
         self.trie_writer = None
+        # built last (below), on the last accepted block: a reopened
+        # store's tail re-executes on the host path before it exists
+        self.state_processor = None
         if chain_kv is not None:
             if db is not None:
                 raise ValueError(
@@ -112,6 +125,7 @@ class BlockChain:
         # layer per processed block over a disk layer at the accepted
         # base; StateDB reads go through it, bypassing trie traversal
         self.snaps = None
+        snapshots = snapshots and state_processor is None
         self._want_snapshots = snapshots
         # one persistent path-warming worker per chain (KV-backed only;
         # measured OFF by default on the 1-core eval host, where the
@@ -137,6 +151,8 @@ class BlockChain:
         elif snapshots:
             from coreth_tpu.state.snapshot import generate_from_trie
             self.snaps = generate_from_trie(self.db, g.root, g.hash())
+        if state_processor is not None:
+            self.state_processor = state_processor(self)
 
     # ---------------------------------------------------------- durability
     def _load_last_state(self) -> None:
@@ -369,7 +385,9 @@ class BlockChain:
     # --------------------------------------------------------------- insert
     def insert_block(self, block: Block) -> None:
         """InsertBlockManual (blockchain.go:1241-1357): verify + execute +
-        keep resident; canonicality is decided later by accept()."""
+        keep resident; canonicality is decided later by accept().  With
+        a ``state_processor`` a block that extends its tip is executed
+        there and every other block here, on the host path."""
         t_start = _time.monotonic()
         if block.hash() in self._blocks:
             return
@@ -377,8 +395,43 @@ class BlockChain:
         if parent_entry is None:
             raise BadBlockError("unknown ancestor")
         parent = parent_entry.block
-        self.engine.verify_header(self.config, block.header, parent.header)
-        self._validate_body(block)
+        backend = self.state_processor
+        # the chain's bookkeeping round the backend's call, as a phase
+        # of the backend's account (its own phases take their time out)
+        acct = backend.account if backend is not None \
+            else obs.NULL_ACCOUNT
+        acct.enter("vm/insert")
+        try:
+            self.engine.verify_header(self.config, block.header,
+                                      parent.header)
+            self._validate_body(block)
+            if backend is not None and backend.extends_tip(block):
+                t0 = _time.monotonic()
+                receipts = backend.execute(block)
+                self.timers.execution += _time.monotonic() - t0
+            else:
+                receipts = self._execute_on_host(block, parent)
+                if backend is not None:
+                    backend.note_host_verified()
+            for i, r in enumerate(receipts):
+                r.block_hash = block.hash()
+                r.transaction_index = i
+            self._blocks[block.hash()] = _Entry(block, receipts)
+            # writeBlockAndSetHead (blockchain.go:1134): a block extending
+            # the current head optimistically becomes the new canonical
+            # tip; a competing sibling stays a side block until consensus
+            # prefers or accepts it (newTip check, :1127)
+            if block.parent_hash == self._head.hash():
+                self._write_head_block(block)
+        finally:
+            acct.exit()
+        self.timers.total += _time.monotonic() - t_start
+        self.timers.blocks += 1
+
+    def _execute_on_host(self, block: Block, parent: Block
+                         ) -> List[Receipt]:
+        """The host ``Processor`` on a StateDB at the parent's root,
+        held to the header, committed; returns the receipts."""
         t0 = _time.monotonic()
         # warm the sender cache (senderCacher.Recover analog; the TPU
         # path batches this through the native/ecrecover kernel)
@@ -430,18 +483,7 @@ class BlockChain:
                 # this block executed: reads just degrade to the trie
                 pass
         self.timers.write += _time.monotonic() - t0
-        for i, r in enumerate(receipts):
-            r.block_hash = block.hash()
-            r.transaction_index = i
-        self._blocks[block.hash()] = _Entry(block, receipts)
-        # writeBlockAndSetHead (blockchain.go:1134): a block extending
-        # the current head optimistically becomes the new canonical
-        # tip; a competing sibling stays a side block until consensus
-        # prefers or accepts it (newTip check, :1127)
-        if block.parent_hash == self._head.hash():
-            self._write_head_block(block)
-        self.timers.total += _time.monotonic() - t_start
-        self.timers.blocks += 1
+        return receipts
 
     def insert_chain(self, blocks: List[Block]) -> int:
         for i, b in enumerate(blocks):
@@ -524,6 +566,10 @@ class BlockChain:
         if block.parent_hash != self.last_accepted.hash():
             raise BadBlockError(
                 "accepted block is not a child of the last accepted block")
+        if self.state_processor is not None:
+            # on its branch: the undo record goes; beside it: back to
+            # the fork point and this block runs there
+            self.state_processor.accept(block)
         # accepting a non-canonical sibling reorgs preference to it
         # (blockchain.go:1059)
         if self._canonical.get(block.number) != block_hash:
@@ -550,6 +596,9 @@ class BlockChain:
             entry.receipts = []
         if self.snaps is not None:
             self.snaps.discard(block_hash)
+        if self.state_processor is not None:
+            # on its branch: undone, with whatever was verified on it
+            self.state_processor.reject(block_hash)
 
     # -------------------------------------------------------- acceptor queue
     def _add_acceptor_queue(self, entry: _Entry) -> None:
@@ -654,6 +703,8 @@ class BlockChain:
             from coreth_tpu.state.snapshot import generate_from_trie
             self.snaps = generate_from_trie(self.db, tip.root,
                                             tip.hash())
+        if self.state_processor is not None:
+            self.state_processor.reset(tip)
         for cb in self._head_subs:
             cb(tip)
 

@@ -23,20 +23,35 @@ from coreth_tpu.txpool.pool import TxPoolConfig
 class Ethereum:
     def __init__(self, genesis: Genesis,
                  config: Optional[EthConfig] = None,
-                 chain_kv=None, clock=None, engine=None):
+                 chain_kv=None, clock=None, engine=None,
+                 engine_kw=None):
         """eth.New (backend.go:117).  engine: an optional consensus
         engine with callbacks (the plugin VM passes its atomic-wired
-        DummyEngine, the way vm.go hands callbacks into eth.New)."""
+        DummyEngine, the way vm.go hands callbacks into eth.New).
+        engine_kw: ReplayEngine's constructor arguments where
+        ``config.state_processor`` is "device" (programmatic, like
+        ``clock``: no config key)."""
         import time as _time
         self.config = config or DEFAULTS
         cfg = self.config
+        state_processor = None
+        if cfg.state_processor == "device":
+            import functools
+            from coreth_tpu.replay.device_processor import DeviceProcessor
+            state_processor = functools.partial(DeviceProcessor,
+                                                **(engine_kw or {}))
+        elif cfg.state_processor != "host":
+            raise ValueError(
+                f"state_processor {cfg.state_processor!r}: "
+                "\"host\" or \"device\"")
         self.chain = BlockChain(
             genesis, chain_kv=chain_kv, engine=engine,
             commit_interval=cfg.commit_interval,
             archive=not cfg.pruning,
             snapshots=cfg.snapshot_cache > 0,
             freezer_dir=cfg.freezer_dir,
-            freeze_threshold=cfg.freeze_threshold)
+            freeze_threshold=cfg.freeze_threshold,
+            state_processor=state_processor)
         self.txpool = TxPool(genesis.config, self.chain, TxPoolConfig(
             price_limit=cfg.tx_pool.price_limit,
             account_slots=cfg.tx_pool.account_slots,

@@ -42,6 +42,10 @@ class EthConfig:
     keystore_dir: Optional[str] = None
     allow_unfinalized_queries: bool = False
     rpc_gas_cap: int = 50_000_000
+    # "host": core's Processor on a StateDB (the default); "device":
+    # ReplayEngine behind insert / accept / reject
+    # (replay/device_processor.py)
+    state_processor: str = "host"
     tx_pool: TxPoolDefaults = field(default_factory=TxPoolDefaults)
     gpo: GPODefaults = field(default_factory=GPODefaults)
 

@@ -287,9 +287,17 @@ class Account:
 
 class _NullAccount:
     """What a public call made from another thread than the account's
-    gets: every phase site is a no-op."""
+    gets, and what a caller with no engine behind it (the VM on the
+    host processor) uses in an account's place: every phase site is a
+    no-op."""
 
     __slots__ = ()
+
+    def begin(self, root: str = LOOP) -> int:
+        return 0  # claims nothing: end(0) is a no-op on any account
+
+    def end(self, token: int) -> None:
+        return None
 
     def enter(self, name: str) -> "_NullAccount":
         return self

@@ -62,17 +62,24 @@ class PluginBlock:
             return
         vm = self.vm
         block = self.block
-        rules = vm.chain.config.rules(block.number, block.time)
-        atomic_txs = []
-        if vm.atomic_backend is not None:
-            from coreth_tpu.atomic import decode_ext_data
-            atomic_txs = decode_ext_data(block.ext_data())
-        if block.hash() != vm.chain.genesis_block.hash():
-            vm.block_validator.syntactic_verify(
-                block, rules, atomic_txs, now=int(vm.clock()))
-        self._verify_predicates(rules)
-        self._verify_utxos_present(atomic_txs)
-        vm.chain.insert_block(block)
+        # phase vm/verify is the ladder before the insert; the chain's
+        # insert (vm/insert) and the engine's phases nest inside it
+        acct = vm.account()
+        tok = acct.begin("vm/verify")
+        try:
+            rules = vm.chain.config.rules(block.number, block.time)
+            atomic_txs = []
+            if vm.atomic_backend is not None:
+                from coreth_tpu.atomic import decode_ext_data
+                atomic_txs = decode_ext_data(block.ext_data())
+            if block.hash() != vm.chain.genesis_block.hash():
+                vm.block_validator.syntactic_verify(
+                    block, rules, atomic_txs, now=int(vm.clock()))
+            self._verify_predicates(rules)
+            self._verify_utxos_present(atomic_txs)
+            vm.chain.insert_block(block)
+        finally:
+            acct.end(tok)
         self.status = Status.PROCESSING
         vm._register(self)
 
@@ -128,15 +135,25 @@ class PluginBlock:
 
     def accept(self) -> None:
         """Consensus accepted this block (block.go:177)."""
-        self.vm.chain.accept(self.id)
-        self.status = Status.ACCEPTED
-        self.vm._on_accept(self)
+        acct = self.vm.account()
+        tok = acct.begin("vm/accept")
+        try:
+            self.vm.chain.accept(self.id)
+            self.status = Status.ACCEPTED
+            self.vm._on_accept(self)
+        finally:
+            acct.end(tok)
 
     def reject(self) -> None:
         """Consensus rejected this block (block.go:269)."""
-        self.vm.chain.reject(self.id)
-        self.status = Status.REJECTED
-        self.vm._on_reject(self)
+        acct = self.vm.account()
+        tok = acct.begin("vm/reject")
+        try:
+            self.vm.chain.reject(self.id)
+            self.status = Status.REJECTED
+            self.vm._on_reject(self)
+        finally:
+            acct.end(tok)
 
     def __repr__(self) -> str:  # debugging aid
         return (f"PluginBlock(height={self.height}, "

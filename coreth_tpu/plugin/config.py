@@ -40,6 +40,12 @@ class Config:
     commit_interval: int = 4096
     state_sync_enabled: bool = False
     state_sync_min_blocks: int = 300_000
+    # what executes a verified block: "host" — the Python twin of the
+    # reference's StateProcessor on a StateDB, the default — or
+    # "device": ReplayEngine behind Verify / Accept / Reject
+    # (replay/device_processor.py).  The one switch; no environment
+    # variable selects it
+    state_processor: str = "host"
     # txpool
     tx_pool_price_limit: int = 1
     tx_pool_account_slots: int = 16
@@ -77,6 +83,7 @@ _KEYMAP = {
     "commit-interval": "commit_interval",
     "state-sync-enabled": "state_sync_enabled",
     "state-sync-min-blocks": "state_sync_min_blocks",
+    "state-processor": "state_processor",
     "tx-pool-price-limit": "tx_pool_price_limit",
     "tx-pool-account-slots": "tx_pool_account_slots",
     "tx-pool-global-slots": "tx_pool_global_slots",

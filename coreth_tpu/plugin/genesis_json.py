@@ -24,7 +24,7 @@ from coreth_tpu.params import ChainConfig
 # JSON key -> ChainConfig field.  Block-number forks use geth names;
 # Avalanche forks use the network-upgrade timestamp names
 # (params/config.go:419-470).
-_CONFIG_KEYS = {
+CONFIG_KEYS = {
     "chainId": "chain_id",
     "homesteadBlock": "homestead_block",
     "eip150Block": "eip150_block",
@@ -68,7 +68,7 @@ def _hexb(v: str) -> bytes:
 
 def parse_chain_config(d: dict) -> ChainConfig:
     kwargs = {}
-    for json_key, field in _CONFIG_KEYS.items():
+    for json_key, field in CONFIG_KEYS.items():
         if json_key in d:
             v = d[json_key]
             kwargs[field] = _num(v) if field == "chain_id" else _opt_num(v)

@@ -291,8 +291,9 @@ class VMClient:
         return resp["result"]
 
     # convenience wrappers mirroring the ChainVM surface
-    def initialize(self, genesis_json: str):
-        return self.call("initialize", genesisBytes=genesis_json)
+    def initialize(self, genesis_json: str, config_bytes: bytes = b""):
+        return self.call("initialize", genesisBytes=genesis_json,
+                         configBytes=config_bytes.hex())
 
     def build_block(self):
         return self.call("buildBlock")

@@ -18,6 +18,7 @@ import time as _time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Union
 
+from coreth_tpu import obs
 from coreth_tpu.chain import BlockChain
 from coreth_tpu.miner import Miner
 from coreth_tpu.plugin.block import PluginBlock, Status
@@ -37,8 +38,12 @@ class VM:
     """Consensus-driven EVM execution engine (vm.go:242)."""
 
     def __init__(self, clock=_time.time, shared_memory=None,
-                 chain_ctx=None, atomic_store=None):
-        """shared_memory/chain_ctx: supplying an atomic.SharedMemory
+                 chain_ctx=None, atomic_store=None, engine_kw=None):
+        """engine_kw: ReplayEngine's constructor arguments for the
+        device state processor (config key ``state-processor``), handed
+        over programmatically like ``clock``; None: its defaults.
+
+        shared_memory/chain_ctx: supplying an atomic.SharedMemory
         (and optionally a ChainContext) wires the full atomic subsystem
         — backend, mempool, ExtData packing at build, accept-time
         shared-memory application (vm.go:986 / :979 / block.go:177).
@@ -46,6 +51,7 @@ class VM:
         repository + the shared-memory apply cursor (the versiondb
         role); pass the same store across restarts for recovery."""
         self.clock = clock
+        self.engine_kw = engine_kw
         self.atomic_store = atomic_store if atomic_store is not None \
             else {}
         self.atomic_repository = None
@@ -84,6 +90,14 @@ class VM:
             raise VMError("already initialized")
         genesis = parse_genesis_json(genesis_bytes)
         self.config = parse_config(config_bytes)
+        if self.config.state_processor == "device" \
+                and self.shared_memory is not None:
+            # atomic ExtData runs through consensus callbacks the
+            # engine's device paths do not make (ROADMAP R5): refuse,
+            # never compute on another path silently
+            raise VMError(
+                "state-processor \"device\" does not run the atomic "
+                "subsystem yet (shared_memory given): use \"host\"")
         engine = None
         if self.shared_memory is not None:
             from coreth_tpu.atomic import (
@@ -132,18 +146,24 @@ class VM:
         # reset + miner + the assembled RPC surface
         from coreth_tpu.eth import EthConfig, Ethereum
         from coreth_tpu.eth.ethconfig import TxPoolDefaults
-        self.eth = Ethereum(
-            genesis,
-            EthConfig(
-                network_id=genesis.config.chain_id,
-                commit_interval=self.config.commit_interval,
-                tx_pool=TxPoolDefaults(
-                    price_limit=self.config.tx_pool_price_limit,
-                    account_slots=self.config.tx_pool_account_slots,
-                    global_slots=self.config.tx_pool_global_slots,
-                    account_queue=self.config.tx_pool_account_queue,
-                    global_queue=self.config.tx_pool_global_queue)),
-            engine=engine, clock=self.clock)
+        try:
+            self.eth = Ethereum(
+                genesis,
+                EthConfig(
+                    network_id=genesis.config.chain_id,
+                    commit_interval=self.config.commit_interval,
+                    state_processor=self.config.state_processor,
+                    tx_pool=TxPoolDefaults(
+                        price_limit=self.config.tx_pool_price_limit,
+                        account_slots=self.config.tx_pool_account_slots,
+                        global_slots=self.config.tx_pool_global_slots,
+                        account_queue=self.config.tx_pool_account_queue,
+                        global_queue=self.config.tx_pool_global_queue)),
+                engine=engine, clock=self.clock, engine_kw=self.engine_kw)
+        except ValueError as exc:
+            # eth owns the choice of state processor and refuses a
+            # value it does not know
+            raise VMError(str(exc)) from exc
         self.chain = self.eth.chain
         self.txpool = self.eth.txpool
         self.miner = self.eth.miner
@@ -330,13 +350,26 @@ class VM:
         """parseBlock (vm.go:1317): decode wire bytes; returns the
         cached adapter when the block is already known."""
         self._require_init()
-        block = Block.decode(data)
-        existing = self._blocks.get(block.hash())
-        if existing is not None:
-            return existing
-        blk = PluginBlock(self, block)
-        self._blocks[blk.id] = blk
-        return blk
+        acct = self.account()
+        tok = acct.begin("vm/parse")
+        try:
+            block = Block.decode(data)
+            existing = self._blocks.get(block.hash())
+            if existing is not None:
+                return existing
+            blk = PluginBlock(self, block)
+            self._blocks[blk.id] = blk
+            return blk
+        finally:
+            acct.end(tok)
+
+    def account(self):
+        """The self-time account the consensus calls are phases of
+        (``vm/parse``, ``vm/verify``, ``vm/insert``, ``vm/accept``,
+        ``vm/rollback``): the device state processor's engine's, so
+        they sum with its own; no account under the host processor."""
+        backend = self.chain.state_processor
+        return obs.NULL_ACCOUNT if backend is None else backend.account
 
     def get_block(self, block_id: bytes) -> PluginBlock:
         """getBlock (vm.go:1347)."""

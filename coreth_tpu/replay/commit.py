@@ -185,7 +185,7 @@ class CommitPipeline:
 
     def _flush(self) -> bytes:
         e = self.e
-        from coreth_tpu.replay.engine import ReplayError
+        from coreth_tpu.replay.engine import StateRootMismatch
         sup = getattr(e, "supervisor", None)
         if sup is not None:
             # the injected gate retries transient faults with backoff
@@ -231,10 +231,13 @@ class CommitPipeline:
                 f"window fold root mismatch at block {number} "
                 f"({n_blocks} staged)", number=number,
                 got=root.hex(), want=expected.hex())
-            raise ReplayError(
+            # the tries hold the fold all the same: the error carries
+            # the keys it wrote, for a caller that can take them back
+            # out (ReplayEngine.replay_block(hold=True))
+            raise StateRootMismatch(
                 f"state root mismatch at block {number} "
                 f"(commit window of {n_blocks}): {root.hex()} != "
-                f"{expected.hex()}")
+                f"{expected.hex()}", accounts, writes)
         e.root = root
         flat = getattr(e, "flat", None)
         if flat is not None:

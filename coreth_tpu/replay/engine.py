@@ -34,7 +34,7 @@ import os
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
@@ -63,6 +63,29 @@ from coreth_tpu.types.account import EMPTY_CODE_HASH, EMPTY_ROOT_HASH
 
 class ReplayError(Exception):
     pass
+
+
+class _Held(NamedTuple):
+    """The undo record of one processing block (replay_block(hold=
+    True)): where the engine stood before it, and how many flat
+    generations it sealed."""
+    block_hash: bytes
+    prev_root: bytes
+    prev_header: object
+    generations: int
+
+
+class StateRootMismatch(ReplayError):
+    """A commit window folded to another state root than its last
+    header's (CommitPipeline.flush).  The engine's tries, device rows
+    and mirrors hold the fold; ``accounts`` and ``writes`` are the keys
+    it staged, so whoever can recover (a processing block) repairs
+    exactly those."""
+
+    def __init__(self, msg: str, accounts, writes):
+        super().__init__(msg)
+        self.accounts = accounts
+        self.writes = writes
 
 
 def _block_error(msg: str, block) -> ReplayError:
@@ -144,9 +167,25 @@ class ReplayStats:
     # blocks applied tolerantly after failing validation on every
     # backend (supervisor quarantine — streaming callers only)
     blocks_quarantined: int = 0
-    # quarantined blocks later popped again via rollback_block (the
-    # reorg primitive over the flat layer's generational diffs)
+    # blocks popped again via rollback_block (the reorg primitive over
+    # the flat layer's generational diffs): quarantined ones, and
+    # processing blocks consensus decided against
     blocks_rolled_back: int = 0
+    # the engine behind Snowman's Verify / Accept / Reject
+    # (replay/device_processor.py counts; 0 everywhere else): blocks
+    # verified on the engine's tip / on the host path beside it (a
+    # sibling, a side branch); blocks consensus accepted / rejected;
+    # times a decision brought the engine back to a fork point, and
+    # accepted blocks it then ran again there; accepted blocks whose
+    # state the engine could NOT execute (it was rebuilt on the
+    # host's: has to stay 0)
+    blocks_verified_device: int = 0
+    blocks_verified_host: int = 0
+    blocks_accepted: int = 0
+    blocks_rejected: int = 0
+    engine_rollbacks: int = 0
+    blocks_reapplied: int = 0
+    accepted_off_engine: int = 0
     # batched sender recovery: signatures whose native batch COMPLETED
     # (sigs_host) and the replay thread's time packing, submitting,
     # waiting and applying (t_sender, t_sender_host; the batch itself
@@ -985,6 +1024,17 @@ class ReplayEngine:
         # zeros where no machine block ran, never a missing attribute
         from coreth_tpu.replay.machine_block import MachineBlockExecutor
         self._machine = MachineBlockExecutor(self)
+        # processing blocks (replay_block(hold=True): consensus has
+        # verified them and not decided), oldest first, one undo record
+        # each.  retire_block drops the oldest, rollback_block pops the
+        # newest
+        self._held: List[_Held] = []
+        # set by a caller that serves receipts (the chain behind the
+        # VM): every path then leaves the block's in last_receipts
+        # (consensus fields alone; replay() builds none where one
+        # native call checks the receipt root)
+        self.keep_receipts = False
+        self.last_receipts: Optional[List[Receipt]] = None
         self.account.end(build)
 
     # ---------------------------------------------------------------- index
@@ -1855,6 +1905,12 @@ class ReplayEngine:
                 raise ReplayError("receipt root mismatch")
             if create_bloom(receipts) != block.header.bloom:
                 raise ReplayError("bloom mismatch")
+        if self.keep_receipts:
+            self.last_receipts = receipts or [Receipt(
+                tx_type=tx.tx_type, status=1, cumulative_gas_used=cums[i],
+                gas_used=gas_list[i],
+                logs=[logs[i]] if logs[i] is not None else [])
+                for i, tx in enumerate(block.transactions)]
         if self.config.is_apricot_phase4(block.time):
             if receipts is None:
                 # verify_block_fee reads only gas_used per receipt
@@ -1999,8 +2055,47 @@ class ReplayEngine:
         return consumed
 
     @_public_call
-    def replay_block(self, block: Block) -> bytes:
-        """Process one block synchronously (tests; replay() windows)."""
+    def replay_block(self, block: Block, hold: bool = False) -> bytes:
+        """Process one block synchronously (tests; replay() windows).
+
+        ``hold=True`` is consensus's Verify: the block is PROCESSING —
+        executed, held to its header, its nodes committed (a root this
+        engine hands out is one a StateDB can open), and revertible
+        until ``retire_block`` (Accept) or ``rollback_block`` (Reject,
+        or the Accept of a sibling) decides it.  A block the engine
+        refuses raises ReplayError and leaves the engine at the
+        parent, ready for the next."""
+        if not hold:
+            return self._replay_one(block)
+        if self.flat is None:
+            raise ReplayError(
+                "a processing block needs the flat layer (CORETH_FLAT=1)")
+        prev_root, prev_header = self.root, self.parent_header
+        sealed = self.flat.generations
+        self.flat.pin_new = True
+        try:
+            self._replay_one(block)
+            # the window's flush has run; the node commit is the same
+            # layer's work, not the caller's bookkeeping
+            with self.account.enter("commit/flush"):
+                root = self.commit()
+        except StateRootMismatch as exc:
+            # every other refusal comes before the engine's state moves
+            # (a window that fails its checks is restored and retried
+            # on the host path, which raises before it commits); this
+            # one comes after the fold
+            self.stats.blocks_device -= 1
+            self.stats.txs -= len(block.transactions)
+            self._restore(prev_root, prev_header, sorted(exc.accounts),
+                          sorted(exc.writes))
+            raise
+        finally:
+            self.flat.pin_new = False
+        self._held.append(_Held(block.hash(), prev_root, prev_header,
+                                self.flat.generations - sealed))
+        return root
+
+    def _replay_one(self, block: Block) -> bytes:
         self.warm_senders(block)
         t0 = time.monotonic()
         with self.account.enter("classify"):
@@ -2015,8 +2110,17 @@ class ReplayEngine:
             win = self._issue_window([(block, batch)])
         except BackendFault:
             return self._fallback(block)
-        resume = self._complete_window(win, [block], 0)
-        return self.root if resume is None else self.root
+        self._complete_window(win, [block], 0)
+        return self.root
+
+    def retire_block(self, block_hash: bytes) -> None:
+        """Consensus accepted the OLDEST processing block: its undo
+        record goes, and its flat generations become ordinary sealed
+        ones (exportable, prunable)."""
+        if not self._held or self._held[0].block_hash != block_hash:
+            raise ReplayError(
+                "retire target is not the oldest processing block")
+        self.flat.unpin_oldest(self._held.pop(0).generations)
 
     @_public_call
     def replay(self, blocks: List[Block],
@@ -2127,56 +2231,85 @@ class ReplayEngine:
 
     @_public_call
     def rollback_block(self, block: Block) -> bytes:
-        """Reorg primitive: pop a quarantined block's generation and
-        re-converge the engine to the pre-block (strict-mode) state.
+        """Reorg primitive: pop the NEWEST revertible block and
+        re-converge the engine to its pre-block state.
 
-        The flat layer's undo log restores the flat view; the engine
-        tries reopen at the generation's recorded ``prev_root`` (whose
-        node closure the quarantine path committed before executing
-        the block); device-state metadata and slot mirrors repair from
-        the reopened tries for exactly the keys the block touched.
-        Only the NEWEST generation — a quarantined block — is
-        revertible: strict blocks validated against their headers and
-        never need to come back out."""
+        Revertible are the processing blocks (``replay_block(hold=
+        True)``, as deep as consensus holds them undecided, newest
+        first) and a quarantined block at the tip.  The flat layer's
+        undo log restores the flat view; the tries go back to the
+        recorded ``prev_root`` (whose node closure was committed
+        before the block ran); device rows, account metadata and slot
+        mirrors are repaired from there for exactly the keys the block
+        touched.  A strict block of replay() validated against its
+        header and never comes back out."""
         if self.flat is None:
             raise ReplayError(
                 "rollback requires the flat layer (CORETH_FLAT=1)")
         if self.commit_pipe.pending():
             raise ReplayError(
                 "rollback with staged commits pending (flush first)")
-        # checkpoint markers stamped on the doomed tip carry no diff;
-        # discard them so the quarantine generation is the target
-        gen = self.flat.last_generation()
-        while gen is not None and gen.kind == "checkpoint" \
-                and not gen.exported:
-            self.flat.rollback_last()
+        if self._held and self._held[-1].block_hash == block.hash():
+            _, prev_root, prev_header, n_gens = self._held.pop()
+        else:
+            prev_root = prev_header = None
+            n_gens = 1
+        addrs, slots = set(), set()
+        for _ in range(n_gens):
+            # checkpoint markers stamped on the doomed tip carry no
+            # diff; discard them so the block's generation is the target
             gen = self.flat.last_generation()
-        if gen is None or gen.kind != "quarantine" \
-                or gen.number != block.number \
-                or gen.block_hash != block.hash():
-            raise ReplayError(
-                "rollback target is not the newest quarantined "
-                "generation")
-        gen = self.flat.rollback_last()
-        prev_root = gen.prev_root
+            while gen is not None and gen.kind == "checkpoint" \
+                    and not gen.exported:
+                self.flat.rollback_last()
+                gen = self.flat.last_generation()
+            if prev_root is None and (
+                    gen is None or gen.kind != "quarantine"
+                    or gen.number != block.number
+                    or gen.block_hash != block.hash()):
+                raise ReplayError(
+                    "rollback target is not the newest processing or "
+                    "quarantined block")
+            gen = self.flat.rollback_last()
+            if prev_root is None:
+                prev_root, prev_header = gen.prev_root, gen.prev_header
+            addrs.update(gen.accounts, gen.destructs)
+            slots.update(gen.storage)
+        self._restore(prev_root, prev_header, sorted(addrs),
+                      sorted(slots))
+        self.stats.blocks_rolled_back += 1
+        return prev_root
+
+    def _restore(self, prev_root: bytes, prev_header, addrs,
+                 slot_keys) -> None:
+        """Bring the tries, the device rows, the account metadata and
+        the slot mirrors back to ``prev_root`` for exactly these keys
+        (everything else is as it was there).  ``prev_root``'s nodes
+        are in the database."""
         base = self.db.open_trie(prev_root)
-        if self._native:
-            from coreth_tpu.mpt.native_trie import (
-                CheckedSecureTrie, NativeSecureTrie)
-            if self._trie_check:
-                self.trie = CheckedSecureTrie(base)
-            else:
-                self.trie = NativeSecureTrie.from_python_trie(base)
+        if self._native and not self._trie_check:
+            # the resident C++ trie takes the old values key by key:
+            # O(keys touched), not a reseed of the whole state
+            for addr in addrs:
+                raw = base.get(addr)
+                if raw is None:
+                    self.trie.delete(addr)
+                else:
+                    self.trie.update(addr, raw)
+        elif self._native:
+            from coreth_tpu.mpt.native_trie import CheckedSecureTrie
+            self.trie = CheckedSecureTrie(base)
         else:
             self.trie = base
-        self.storage_tries.clear()
+        # storage tries reopen lazily at the restored account roots
+        for contract in set(addrs).union(c for c, _ in slot_keys):
+            self.storage_tries.pop(contract, None)
         self._slot_overlay.clear()
-        # the window runner's mirror/table saw the quarantined writes
+        # the window runner's mirror/table saw the undone writes
         self.storage_epoch += 1
         st = self.state
         st.flush_staged()
-        touched = sorted(set(gen.accounts) | set(gen.destructs))
-        for addr in touched:
+        for addr in addrs:
             idx = st.index.get(addr)
             if idx is None:
                 continue
@@ -2189,7 +2322,7 @@ class ReplayEngine:
             st.code_hashes[idx] = account.code_hash
             st.roots[idx] = account.root
         from coreth_tpu import rlp as _rlp
-        for (contract, key) in sorted(gen.storage):
+        for (contract, key) in slot_keys:
             s_idx = st.slot_index.get((contract, key))
             if s_idx is None or contract not in st.index:
                 continue
@@ -2205,9 +2338,7 @@ class ReplayEngine:
                 "rollback: trie did not re-converge to the pre-block "
                 "root")
         self.root = prev_root
-        self.parent_header = gen.prev_header
-        self.stats.blocks_rolled_back += 1
-        return prev_root
+        self.parent_header = prev_header
 
     def _harvest_prestate(self, statedb, complete: bool = True,
                           failed_tx_index: Optional[int] = None) -> dict:
@@ -2384,8 +2515,8 @@ class ReplayEngine:
         if self.flat is not None:
             # one generation per host-path block: the flat view learns
             # the block's diff (keeping cold reads current) and the
-            # undo log makes a QUARANTINED block revertible
-            # (rollback_block) — quarantine generations are applied
+            # undo log makes a quarantined or processing block
+            # revertible (rollback_block) — quarantine generations are applied
             # with hold=True so the background exporter cannot make
             # them durable before the chain accepts past them
             from coreth_tpu.state.flat import flat_diff_from_statedb
@@ -2400,6 +2531,8 @@ class ReplayEngine:
                 hold=not strict)
         self.root = root
         self.parent_header = block.header
+        if self.keep_receipts:
+            self.last_receipts = receipts
         self.stats.blocks_fallback += 1
         self.stats.txs += len(block.transactions)
         self.stats.t_fallback += time.monotonic() - t0

@@ -548,6 +548,11 @@ class MachineBlockExecutor:
             if create_bloom(receipts) != block.header.bloom:
                 raise _block_error("machine block: bloom mismatch",
                                    block)
+        if e.keep_receipts:
+            e.last_receipts = receipts or [
+                Receipt(tx_type=t, status=st, cumulative_gas_used=c,
+                        gas_used=u, logs=lgs)
+                for t, st, u, c, lgs in rows]
         if e.config.is_apricot_phase4(block.time):
             if receipts is None:
                 # verify_block_fee reads only gas_used per receipt

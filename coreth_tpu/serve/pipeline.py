@@ -687,10 +687,11 @@ class StreamingPipeline:
         return row
 
     def rollback_quarantined(self) -> dict:
-        """Reorg primitive: pop the NEWEST quarantined block (its
-        tolerantly-applied state transition reverts through the flat
-        layer's generational undo log, engine.rollback_block) so a
-        corrected block can be streamed in its place.  Call after
+        """Pop the NEWEST quarantined block (its tolerantly-applied
+        state transition reverts through engine.rollback_block: the one
+        rollback primitive, which consensus's Reject of a processing
+        block takes too) so a corrected block can be streamed in its
+        place.  Call after
         run() returned (the engine is single-owner again).  Returns
         the popped quarantine report entry."""
         if not self._quarantined_blocks:

@@ -27,12 +27,21 @@ Three value classes per key:
 
 Generations are the rollback and export unit.  ``apply_generation``
 captures per-key undo; ``rollback_last`` pops the newest generation
-and restores the pre-block flat view (the engine separately reopens
-its tries at the generation's ``prev_root``).  The background exporter
-(exporter.py) drains sealed generations in order; a generation from a
-quarantined block is applied with ``hold=True`` and the exporter stops
-in front of it until a later commit accepts the chain past it (or the
-stream drains) — so rollback never races a durable export.
+and restores the pre-block flat view (the engine separately brings
+its tries back to the generation's ``prev_root``).  The background
+exporter (exporter.py) drains sealed generations in order; a
+generation from a quarantined block is applied with ``hold=True`` and
+the exporter stops in front of it until a later commit accepts the
+chain past it (or the stream drains) — so rollback never races a
+durable export.
+
+A PROCESSING block (verified by consensus, not yet accepted or
+rejected: the engine's ``replay_block(hold=True)``) seals its
+generations PINNED (``pin_new``): held like a quarantined one, but a
+later commit does not release it and the log is never pruned past it —
+only ``unpin_oldest`` (the block was accepted) or ``rollback_last``
+(rejected, newest first) ends the pin.  So every processing block stays
+revertible, as deep as consensus holds blocks undecided.
 """
 
 from __future__ import annotations
@@ -77,7 +86,7 @@ class FlatGeneration:
         "number", "block_hash", "root", "header", "prev_root",
         "prev_header", "accounts", "storage", "destructs",
         "undo_accounts", "undo_storage", "undo_destructs", "kind",
-        "checkpoint", "hold", "exported", "rolled_back",
+        "checkpoint", "hold", "pinned", "exported", "rolled_back",
     )
 
     def __init__(self, number: int, block_hash: bytes, root: bytes,
@@ -102,6 +111,8 @@ class FlatGeneration:
         self.kind = kind
         self.checkpoint = checkpoint
         self.hold = hold
+        # a processing block's: held until unpin_oldest / rollback_last
+        self.pinned = False
         self.exported = False
         self.rolled_back = False
 
@@ -124,6 +135,9 @@ class FlatStore:
         # generation — the tip a checkpoint marker stamps
         self.tip: Optional[tuple] = None
         self.base_number: Optional[int] = None  # persisted-base stamp
+        # True while the engine executes a processing block: the
+        # generations sealed meanwhile are pinned (module docstring)
+        self.pin_new = False
         self._exporter_attached = False
         # most recent exported generation (payloads dropped): the
         # flat/stale_generation fault hands it back to model a queue
@@ -209,13 +223,15 @@ class FlatStore:
             sub = self.storage.setdefault(addr, {})
             gen.undo_storage[(addr, key)] = sub.get(key, _ABSENT)
             sub[key] = val
+        if self.pin_new and kind != "checkpoint":
+            gen.pinned = gen.hold = True
         with self._cv:
             if kind != "checkpoint":
                 # the chain moved past any held (quarantined)
                 # generation: the quarantine was accepted, release it
-                # to the exporter
+                # to the exporter — a processing block's stays held
                 for g in self.gens:
-                    g.hold = False
+                    g.hold = g.pinned
                 self.tip = (number, block_hash, root, header)
             self.gens.append(gen)
             self.generations += 1
@@ -284,6 +300,20 @@ class FlatStore:
     def last_generation(self) -> Optional[FlatGeneration]:
         with self._lock:
             return self.gens[-1] if self.gens else None
+
+    def unpin_oldest(self, n: int) -> None:
+        """The oldest processing block was accepted: its ``n``
+        generations become ordinary sealed ones (exportable, prunable,
+        no longer revertible)."""
+        with self._cv:
+            for g in self.gens:
+                if n == 0:
+                    break
+                if g.pinned:
+                    g.pinned = g.hold = False
+                    n -= 1
+            self._prune_locked()
+            self._cv.notify_all()
 
     # ------------------------------------------------------- export queue
     def attach_exporter(self) -> None:
@@ -367,11 +397,13 @@ class FlatStore:
         """Bound the generation log: exported generations leave from
         the front; without an exporter, old generations beyond KEEP
         drop their payloads (rollback depth is bounded either way —
-        the newest generation always survives)."""
+        the newest generation always survives, and so does every
+        pinned one: a processing block is revertible however deep)."""
         while len(self.gens) > 1 and self.gens[0].exported:
             self.gens.pop(0)
         if not self._exporter_attached:
-            while len(self.gens) > self.KEEP:
+            while len(self.gens) > self.KEEP \
+                    and not self.gens[0].pinned:
                 self.gens.pop(0)
 
     # -------------------------------------------------------- persistence

@@ -161,15 +161,19 @@ def test_vm_reject_sibling():
     assert vm.last_accepted().id == a.id
 
 
-def test_vm_service_over_socket(tmp_path):
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_vm_service_over_socket(tmp_path, backend):
     """Drive the full cycle through the rpcchainvm-twin local-socket
     service: initialize -> issueTx -> buildBlock -> parse on a second
-    served VM -> verify -> accept."""
+    served VM -> verify -> accept; on the host processor and with the
+    per-chain config selecting the device engine."""
     sock1 = str(tmp_path / "vm1.sock")
-    server = serve(VM(), sock1)
+    server = serve(VM(engine_kw=dict(capacity=256, window=2)), sock1)
     try:
         client = VMClient(sock1)
-        genesis_info = client.initialize(genesis_json())
+        genesis_info = client.initialize(
+            genesis_json(),
+            json.dumps({"state-processor": backend}).encode())
         assert genesis_info["height"] == 0
         tx = make_tx(0)
         client.issue_tx(tx.encode())
@@ -182,6 +186,11 @@ def test_vm_service_over_socket(tmp_path):
         assert accepted["status"] == "accepted"
         last = client.last_accepted()
         assert last["id"] == built["id"]
+        stats = getattr(server.vm.chain.state_processor, "stats", None)
+        assert (stats is not None) == (backend == "device")
+        if stats is not None:
+            assert (stats.blocks_verified_device, stats.blocks_accepted,
+                    stats.blocks_fallback) == (1, 1, 0)
         # errors cross the wire as failures, not hangs
         with pytest.raises(VMError):
             client.build_block()  # empty mempool again
