@@ -62,6 +62,10 @@ def load():
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
         ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
     lib.coreth_ecrecover_batch.restype = None
+    lib.coreth_recover_wire.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_uint64, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
+    lib.coreth_recover_wire.restype = None
     lib.coreth_recover_prep.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
         ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
@@ -140,6 +144,29 @@ def recover_addresses_batch(hashes: bytes, rs: bytes, ss: bytes,
     out = ctypes.create_string_buffer(20 * n)
     ok = ctypes.create_string_buffer(n)
     _require().coreth_ecrecover_batch(hashes, rs, ss, recids, n, out, ok)
+    return out.raw, ok.raw
+
+
+def recover_senders_wire(wire: bytes, offsets, chain_id: int):
+    """Batched recovery from transactions' wire encodings: ``wire`` holds
+    them end to end, transaction i is ``wire[offsets[i]:offsets[i + 1]]``
+    (legacy RLP list, or type byte 1 / 2 and its list).  The native walk
+    derives signing hash, r, s and recovery id by the rules of
+    ``LatestSigner(chain_id)`` and feeds the batch above.  Returns
+    (addresses, ok) bytes; ``ok[i] == 0`` leaves transaction i to
+    ``signer.sender``: malformed or truncated bytes, an offset outside
+    ``wire``, a foreign chain id, high s, recovery id past 1, r or s
+    out of range."""
+    n = len(offsets) - 1
+    if n < 0:
+        raise ValueError("offsets must hold at least [0]")
+    if not 0 <= chain_id < 1 << 64:
+        raise ValueError(f"chain id {chain_id} does not fit 64 bits")
+    off = (ctypes.c_uint64 * (n + 1))(*offsets)
+    out = ctypes.create_string_buffer(20 * n)
+    ok = ctypes.create_string_buffer(n)
+    _require().coreth_recover_wire(wire, len(wire), off, n, chain_id,
+                                   out, ok)
     return out.raw, ok.raw
 
 

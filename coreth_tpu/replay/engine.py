@@ -29,6 +29,7 @@ both engines can interleave over one chain.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import os
 import time
@@ -127,9 +128,14 @@ _EAGER_FLUSH = bool(int(
     __import__("os").environ.get("CORETH_EAGER_FLUSH", "0")))
 
 
-def secp_half_n() -> int:
-    from coreth_tpu.crypto.secp256k1 import N
-    return N // 2
+def _encoded(tx) -> bytes:
+    """The wire bytes of a transaction built in process.  One that does
+    not encode contributes none: the native walk answers ``ok = 0`` for
+    it and signer.sender decides it, its segment untouched."""
+    try:
+        return tx.encode()
+    except Exception:  # noqa: BLE001 — per-tx python path later
+        return b""
 
 
 @dataclass
@@ -198,6 +204,11 @@ class ReplayStats:
     sigs_host: int = 0
     t_sender_device: float = 0.0
     t_sender_host: float = 0.0
+    # lanes of completed batches the native walk did not vouch for
+    # (ok = 0: malformed bytes, foreign chain id, high s, recovery id
+    # past 1, r or s out of range): signer.sender's per-transaction
+    # path decided them.  0 on every chain the benchmark replays
+    sigs_left_to_signer: int = 0
     # batched recoveries that raised (packing, the worker's batch, its
     # result): their txs fell to per-tx recovery in signer.sender —
     # correct, but not the path the counters above describe
@@ -791,13 +802,15 @@ class _SenderPipeline:
     execution.  This pipeline cuts the input into segments of whole
     blocks (a segment closes BEFORE the block that would take it past
     SEGMENT_SIGS; a single larger block is a segment of its own) and
-    keeps AHEAD segments issued past the replay cursor.  A segment is
-    packed on the replay thread and recovered by the native C++ batch
-    (crypto/native.recover_addresses_batch — the one batch engine) in
-    the engine's recovery worker thread: one worker, in order; the
-    ctypes call releases the GIL.  Without the native library a segment
-    stays lazy: signer.sender recovers per tx.  ensure(i) blocks only
-    until block i's segment is applied.
+    keeps AHEAD segments issued past the replay cursor.  The replay
+    thread hands a segment over as ONE buffer — its transactions' wire
+    bytes end to end (_pack_sigs) — and the native C++ batch
+    (crypto/native.recover_senders_wire — the one batch engine) derives
+    each signing hash, r, s and recovery id from the bytes and recovers
+    the keys in the engine's recovery worker thread: one worker, in
+    order; the ctypes call releases the GIL.  Without the native library
+    a segment stays lazy: signer.sender recovers per tx.  ensure(i)
+    blocks only until block i's segment is applied.
     """
 
     AHEAD = 3
@@ -833,12 +846,11 @@ class _SenderPipeline:
         with eng.account.enter("sender/pack"):
             try:
                 faults.fire(PT_RECOVER)  # degrade: lazy per-tx recovery
-                todo, hashes, rs, ss, recids = eng._pack_sigs(
-                    self.segments[s])
+                todo, wire, offsets = eng._pack_sigs(self.segments[s])
                 if todo and native.load() is not None:
                     fut = eng._recover_pool_get().submit(
-                        native.recover_addresses_batch, hashes, rs, ss,
-                        recids)
+                        native.recover_senders_wire, wire, offsets,
+                        eng.signer.chain_id)
             except Exception:  # noqa: BLE001 — degrade to lazy per-tx
                 eng.stats.recover_degraded += 1
         self.issued.append((todo, fut))
@@ -1147,41 +1159,38 @@ class ReplayEngine:
 
     # -------------------------------------------------------------- senders
     def _pack_sigs(self, blocks):
-        """Collect + pack uncached signatures for batched recovery.
-        Packed per-tx so one malformed signature (oversized v/r/s,
-        foreign chain id) skips that tx instead of aborting the batch."""
-        todo, hashes, rs, ss, recids = [], [], [], [], []
-        for b in blocks:
-            for tx in b.transactions:
-                if tx.cached_sender() is not None:
-                    continue
-                try:
-                    r, s, recid = tx.inner.raw_signature()
-                    h = self.signer.sig_hash(tx)
-                    rs.append(r.to_bytes(32, "big"))
-                    ss.append(s.to_bytes(32, "big"))
-                    recids.append(recid if 0 <= recid <= 3 else 255)
-                    hashes.append(h)
-                    todo.append(tx)
-                except Exception:  # noqa: BLE001 — per-tx python later
-                    continue
-        return todo, b"".join(hashes), b"".join(rs), b"".join(ss), \
-            bytes(recids)
+        """The transactions without a cached sender, and what the native
+        batch recovers them from (crypto/native.recover_senders_wire):
+        their wire encodings end to end, and the offsets that cut them.
+        A decoded transaction kept its bytes (``inner._wire``); one
+        built in process is encoded here.  Signing hash, r, s and
+        recovery id are the native walk's to derive, on the thread that
+        runs the batch: nothing is computed per transaction here."""
+        todo = [tx for b in blocks for tx in b.transactions
+                if tx.cached_sender() is None]
+        wires = []
+        for tx in todo:
+            try:
+                wires.append(tx.inner._wire)
+            except AttributeError:
+                wires.append(_encoded(tx))
+        return todo, b"".join(wires), \
+            list(itertools.accumulate(map(len, wires), initial=0))
 
     def _apply_recovered(self, todo, out, ok) -> None:
-        half_n = secp_half_n()
+        """Prime the sender caches the native batch vouches for.  A lane
+        it answered ``ok = 0`` (the refusals crypto/native.
+        recover_senders_wire lists) stays uncached: signer.sender's
+        per-transaction path decides it, as it did before."""
+        self.stats.sigs_left_to_signer += ok.count(0)
         for i, tx in enumerate(todo):
             if ok[i]:
-                # signer.sender re-validates chain id + low-s before
-                # trusting the cache; prime it only
-                r, s, recid = tx.inner.raw_signature()
-                if recid in (0, 1) and 0 < s <= half_n:
-                    tx.set_sender(out[i * 20:(i + 1) * 20])
+                tx.set_sender(out[20 * i:20 * i + 20])
 
     def warm_senders(self, blocks) -> None:
         """Batched sender recovery across a whole run of blocks
         (reference core/sender_cacher.go role): ONE native C++ batch
-        (crypto/native.recover_addresses_batch) on the calling thread.
+        (crypto/native.recover_senders_wire) on the calling thread.
         Without the native library, or where the batch raises, the txs
         stay uncached and signer.sender recovers them one by one.
         Accepts a single block or a list.
@@ -1205,7 +1214,7 @@ class ReplayEngine:
         t0 = time.monotonic()
         acct.enter("sender/pack")
         try:
-            todo, hashes, rs, ss, recids = self._pack_sigs(blocks)
+            todo, wire, offsets = self._pack_sigs(blocks)
             if not todo:
                 return
             faults.fire(PT_RECOVER)  # degrade to per-tx recovery
@@ -1213,8 +1222,8 @@ class ReplayEngine:
                 return  # per-tx python path in signer.sender
             # the batch runs ON this thread: work, not a wait
             t1 = time.monotonic()
-            out, ok = native.recover_addresses_batch(hashes, rs, ss,
-                                                     recids)
+            out, ok = native.recover_senders_wire(wire, offsets,
+                                                  self.signer.chain_id)
             self.stats.sigs_host += len(todo)
             self.stats.t_sender_host += time.monotonic() - t1
             acct.switch("sender/apply")

@@ -131,7 +131,9 @@ class StreamReport:
     # window_upload_bytes: the host->device transfers that carried the
     # transfer windows (one staging buffer a window on one device);
     # blocks_order_dependent: device blocks only the in-order solvency
-    # rule could commit (a sender funded earlier in the same block)
+    # rule could commit (a sender funded earlier in the same block);
+    # sigs_left_to_signer: lanes of the native sender batch it did not
+    # vouch for, so signer.sender's per-tx path decided them
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -723,7 +725,8 @@ class StreamingPipeline:
                 "machine_padded": st.machine_lanes_padded,
                 "window_uploads": st.window_uploads,
                 "window_upload_bytes": st.window_upload_bytes,
-                "blocks_order_dependent": st.blocks_order_dependent}
+                "blocks_order_dependent": st.blocks_order_dependent,
+                "sigs_left_to_signer": st.sigs_left_to_signer}
 
     def _publish(self, wall: float) -> None:
         s = self.stats

@@ -933,6 +933,229 @@ bool fast_recover_disabled() {
   return v && v[0] == '0' && v[1] == '\0';
 }
 
+// fn(lo, hi) over [0, n): on the calling thread under 16 signatures a
+// hardware thread, else in contiguous chunks (not strides: each worker
+// runs its own batch inversions over a dense range), a thread each.
+template <class Fn>
+void in_chunks(uint64_t n, Fn fn) {
+  unsigned nthreads = std::thread::hardware_concurrency();
+  if (nthreads < 2 || n < 16 * nthreads) {
+    fn(0, n);
+    return;
+  }
+  std::vector<std::thread> workers;
+  workers.reserve(nthreads);
+  uint64_t chunk = (n + nthreads - 1) / nthreads;
+  for (unsigned w = 0; w < nthreads; ++w) {
+    uint64_t lo = (uint64_t)w * chunk;
+    uint64_t hi = lo + chunk < n ? lo + chunk : n;
+    if (lo >= hi) break;
+    workers.emplace_back([=]() { fn(lo, hi); });
+  }
+  for (auto& t : workers) t.join();
+}
+
+// ---- signing hashes from the wire bytes (coreth_recover_wire) ----
+//
+// A transaction's wire encoding holds everything its sender is
+// recovered from: v, r, s are the last three items of its list, and the
+// unsigned payload the signing hash is made of is the items before
+// them — ONE contiguous span of the wire, hashed under a fresh list
+// header (after the type byte of a typed transaction; before the chain
+// id and two empty items of an EIP-155 one).  The walk goes by lengths
+// alone: nothing is decoded or built, an access list is stepped over
+// and never entered.  It refuses what the Python decoder refuses
+// (rlp.payload_span, rlp.decode_uint): an item that is truncated or
+// runs past its list, a long-form length where the short form fits, a
+// wrapped single byte under 0x80, an integer with a leading zero byte.
+// So a lane answered here hashed, bit for bit, what signer.sig_hash
+// hashes for the decoded transaction, and every other lane is left to
+// the per-transaction path (types/transaction.py LatestSigner.sender).
+
+struct RlpItem {
+  const uint8_t* at;       // its prefix
+  const uint8_t* payload;
+  uint64_t len;            // of the payload
+  bool list;
+};
+
+// The item whose prefix is at p and which has to end by `end`.
+bool rlp_item(const uint8_t* p, const uint8_t* end, RlpItem& it) {
+  if (p >= end) return false;
+  const uint8_t b0 = *p;
+  it.at = p;
+  it.list = b0 >= 0xC0;
+  if (b0 < 0x80) {
+    it.payload = p;
+    it.len = 1;
+    return true;
+  }
+  // strings from 0x80 and lists from 0xC0 share the low six bits: a
+  // payload length up to 55, or 55 + the width of a length that follows
+  const unsigned low = b0 & 0x3F;
+  if (low < 56) {
+    it.payload = p + 1;
+    it.len = low;
+  } else {
+    const unsigned width = low - 55;
+    if ((uint64_t)(end - p - 1) < width || p[1] == 0) return false;
+    uint64_t len = 0;
+    for (unsigned i = 0; i < width; ++i) len = (len << 8) | p[1 + i];
+    if (len < 56) return false;
+    it.payload = p + 1 + width;
+    it.len = len;
+  }
+  if ((uint64_t)(end - it.payload) < it.len) return false;
+  return !(b0 == 0x81 && *it.payload < 0x80);
+}
+
+inline bool rlp_is_uint(const RlpItem& it) {
+  return !it.list && (it.len == 0 || it.payload[0] != 0);
+}
+
+// An integer item that fits 64 bits.
+bool rlp_u64(const RlpItem& it, uint64_t& v) {
+  if (!rlp_is_uint(it) || it.len > 8) return false;
+  v = 0;
+  for (uint64_t i = 0; i < it.len; ++i) v = (v << 8) | it.payload[i];
+  return true;
+}
+
+// v big-endian with no leading zero byte (nothing for 0); the count.
+unsigned put_be(uint8_t* out, uint64_t v) {
+  unsigned n = 0;
+  for (uint64_t t = v; t; t >>= 8) ++n;
+  for (unsigned i = 0; i < n; ++i) out[i] = (uint8_t)(v >> (8 * (n - 1 - i)));
+  return n;
+}
+
+// The RLP header of a list whose payload is `len` bytes.  At most 9.
+unsigned rlp_put_list_header(uint8_t* out, uint64_t len) {
+  if (len < 56) {
+    out[0] = (uint8_t)(0xC0 + len);
+    return 1;
+  }
+  unsigned n = put_be(out + 1, len);
+  out[0] = (uint8_t)(0xF7 + n);
+  return 1 + n;
+}
+
+// An integer as an RLP item.  At most 9 bytes.
+unsigned rlp_put_u64(uint8_t* out, uint64_t v) {
+  if (v && v < 0x80) {
+    out[0] = (uint8_t)v;
+    return 1;
+  }
+  unsigned n = put_be(out + 1, v);
+  out[0] = (uint8_t)(0x80 + n);
+  return 1 + n;
+}
+
+// Items of a transaction's list by type: how many, which one is the
+// access list (-1: none) and which two are byte strings of any content
+// (to, data); every other item is an integer.
+struct TxLayout {
+  int items, access_list, to, data;
+};
+const TxLayout LEGACY_TX = {9, -1, 3, 5};
+const TxLayout ACCESS_LIST_TX = {11, 7, 4, 6};
+const TxLayout DYNAMIC_FEE_TX = {12, 8, 5, 7};
+
+// n / 2: the largest s of a canonical (low-s, EIP-2) signature
+U256 order_half() {
+  U256 h;
+  for (int i = 0; i < 4; ++i)
+    h.v[i] = (ORDER.v[i] >> 1) | (i < 3 ? ORDER.v[i + 1] << 63 : 0);
+  return h;
+}
+const U256 HALF_ORDER = order_half();
+
+// r or s, at most 32 bytes and not 0 (the walk has already refused a
+// leading zero byte): as a 32-byte big-endian field and as a number.
+bool sig_scalar(const RlpItem& it, uint8_t* out32, U256& x) {
+  if (it.len == 0 || it.len > 32) return false;
+  std::memset(out32, 0, 32);
+  std::memcpy(out32 + 32 - it.len, it.payload, it.len);
+  load_be(x, out32);
+  return true;
+}
+
+// One transaction's signing hash, r, s and recovery id from its wire
+// bytes [p, end), by the rules of LatestSigner(chain_id); false leaves
+// the transaction to the per-transaction path.  `pre` is scratch for
+// the hash's preimage, kept between transactions.
+bool wire_sig(const uint8_t* p, const uint8_t* end, uint64_t chain_id,
+              std::vector<uint8_t>& pre, uint8_t* hash32, uint8_t* r32,
+              uint8_t* s32, uint8_t* recid) {
+  if (p >= end) return false;
+  const uint8_t type = *p;
+  const TxLayout* lay;
+  if (type >= 0xC0) {
+    lay = &LEGACY_TX;
+  } else if (type == 0x01 || type == 0x02) {
+    lay = type == 0x01 ? &ACCESS_LIST_TX : &DYNAMIC_FEE_TX;
+    ++p;
+  } else {
+    return false;
+  }
+  RlpItem outer, it[12];
+  if (!rlp_item(p, end, outer) || !outer.list ||
+      outer.payload + outer.len != end)
+    return false;
+  const uint8_t* q = outer.payload;
+  for (int k = 0; k < lay->items; ++k) {
+    if (!rlp_item(q, end, it[k])) return false;
+    if (k == lay->access_list) {
+      if (!it[k].list) return false;
+    } else if (k == lay->to || k == lay->data) {
+      if (it[k].list) return false;
+    } else if (!rlp_is_uint(it[k])) {
+      return false;
+    }
+    q = it[k].payload + it[k].len;
+  }
+  if (q != end) return false;
+  const RlpItem& v_item = it[lay->items - 3];
+  uint64_t v;
+  if (!rlp_u64(v_item, v)) return false;
+  uint8_t tail[11];  // hashed after the span: EIP-155's three items
+  unsigned tail_len = 0;
+  if (lay == &LEGACY_TX) {
+    if (v == 27 || v == 28) {
+      *recid = (uint8_t)(v - 27);
+    } else {  // EIP-155: v = 35 + 2 x chain id + {0, 1}
+      if (chain_id > (UINT64_MAX - 36) / 2) return false;
+      const uint64_t v0 = 35 + 2 * chain_id;
+      if (v != v0 && v != v0 + 1) return false;
+      *recid = (uint8_t)(v - v0);
+      tail_len = rlp_put_u64(tail, chain_id);
+      tail[tail_len++] = 0x80;
+      tail[tail_len++] = 0x80;
+    }
+  } else {
+    uint64_t tx_chain;
+    if (!rlp_u64(it[0], tx_chain) || tx_chain != chain_id || v > 1)
+      return false;
+    *recid = (uint8_t)v;
+  }
+  U256 r, s;
+  if (!sig_scalar(it[lay->items - 2], r32, r) ||
+      !sig_scalar(it[lay->items - 1], s32, s) ||
+      cmp(r, ORDER) >= 0 || cmp(s, HALF_ORDER) > 0)
+    return false;
+  const uint64_t span = (uint64_t)(v_item.at - outer.payload);
+  uint8_t head[10];
+  unsigned head_len = 0;
+  if (lay != &LEGACY_TX) head[head_len++] = type;
+  head_len += rlp_put_list_header(head + head_len, span + tail_len);
+  pre.clear();
+  pre.insert(pre.end(), head, head + head_len);
+  pre.insert(pre.end(), outer.payload, v_item.at);
+  pre.insert(pre.end(), tail, tail + tail_len);
+  coreth_keccak256(pre.data(), pre.size(), hash32);
+  return true;
+}
+
 }  // namespace
 
 extern "C" {
@@ -1196,25 +1419,46 @@ void coreth_ecrecover_batch(const uint8_t* hashes, const uint8_t* rs,
     for (auto& t : workers) t.join();
     return;
   }
-  unsigned nthreads = std::thread::hardware_concurrency();
-  if (nthreads < 2 || n < 16 * nthreads) {
-    fast_recover_range(hashes, rs, ss, recids, 0, n, out, ok);
-    return;
-  }
-  // contiguous chunks (not strides): each worker runs its own batch
-  // inversions over a dense range
-  std::vector<std::thread> workers;
-  workers.reserve(nthreads);
-  uint64_t chunk = (n + nthreads - 1) / nthreads;
-  for (unsigned w = 0; w < nthreads; ++w) {
-    uint64_t lo = (uint64_t)w * chunk;
-    uint64_t hi = lo + chunk < n ? lo + chunk : n;
-    if (lo >= hi) break;
-    workers.emplace_back([=]() {
-      fast_recover_range(hashes, rs, ss, recids, lo, hi, out, ok);
-    });
-  }
-  for (auto& t : workers) t.join();
+  in_chunks(n, [=](uint64_t lo, uint64_t hi) {
+    fast_recover_range(hashes, rs, ss, recids, lo, hi, out, ok);
+  });
+}
+
+// Batched recovery from the transactions' wire encodings, laid end to
+// end in `wire` and cut by offsets[n + 1].  Each chunk's thread derives
+// its lanes' signing hash, r, s and recovery id (wire_sig, by the rules
+// of LatestSigner(chain_id)) and recovers them as the batch above does.
+// ok[i] = 0 says this transaction is not vouched for — malformed,
+// truncated, cut outside [0, wire_len], foreign chain id, high s,
+// recovery id past 1, r or s out of range, no such point — and is left
+// to the per-transaction path; its neighbours are untouched.
+void coreth_recover_wire(const uint8_t* wire, uint64_t wire_len,
+                         const uint64_t* offsets, uint64_t n,
+                         uint64_t chain_id, uint8_t* out, uint8_t* ok) {
+  if (n == 0) return;
+  std::vector<uint8_t> hashes(32 * n), rs(32 * n), ss(32 * n),
+      recids(n, 255);  // 255: the ladder answers ok = 0
+  uint8_t *h = hashes.data(), *r = rs.data(), *s = ss.data(),
+          *rec = recids.data();
+  const bool per_sig = fast_recover_disabled();
+  in_chunks(n, [=](uint64_t lo, uint64_t hi) {
+    std::vector<uint8_t> pre;
+    for (uint64_t i = lo; i < hi; ++i) {
+      const uint64_t at = offsets[i], end = offsets[i + 1];
+      uint8_t recid;
+      if (at <= end && end <= wire_len &&
+          wire_sig(wire + at, wire + end, chain_id, pre, h + 32 * i,
+                   r + 32 * i, s + 32 * i, &recid))
+        rec[i] = recid;
+    }
+    if (!per_sig) {
+      fast_recover_range(h, r, s, rec, lo, hi, out, ok);
+      return;
+    }
+    for (uint64_t i = lo; i < hi; ++i)  // the A/B knob, as above
+      ok[i] = (uint8_t)coreth_ecrecover(h + 32 * i, r + 32 * i,
+                                        s + 32 * i, rec[i], out + 20 * i);
+  });
 }
 
 }  // extern "C"

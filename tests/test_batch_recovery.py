@@ -1,8 +1,9 @@
 """Sender recovery in batch replay: ONE batch engine.
 
 ``ReplayEngine.replay`` recovers senders in look-ahead segments of
-whole blocks (``_SenderPipeline``), each packed on the replay thread
-and recovered by the native C++ batch on one worker thread;
+whole blocks (``_SenderPipeline``), each handed over by the replay
+thread as one buffer of wire bytes and recovered from it by the native
+C++ batch on one worker thread;
 ``warm_senders`` (``replay_block``, the serve prefetcher) is the same
 batch on the calling thread.  Where the native library is missing or a
 batch raises, ``signer.sender`` recovers per transaction.  These tests
@@ -20,6 +21,7 @@ pin:
   imported by a process that replays.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -40,7 +42,7 @@ from coreth_tpu.parallel import make_mesh
 from coreth_tpu.replay import ReplayEngine
 from coreth_tpu.replay.engine import _SenderPipeline
 from coreth_tpu.state import Database
-from coreth_tpu.types import Block, DynamicFeeTx, sign_tx
+from coreth_tpu.types import Block, DynamicFeeTx, Transaction, sign_tx
 
 GWEI = 10**9
 KEYS = [0x7A00 + i for i in range(8)]
@@ -111,14 +113,19 @@ def test_malformed_lane_does_not_poison_its_segment(chain3):
     raises the canonical rejection instead of the batch aborting or
     mis-recovering neighbours."""
     blocks = _fresh(chain3[:2])
-    bad = blocks[0].transactions[2]
-    bad.inner.s = secp256k1.N  # out of range: never a valid signature
+    # out of range: never a valid signature.  Built in process (no wire
+    # bytes kept), so the segment's buffer holds its encode(): a decoded
+    # transaction IS its wire bytes, editing its fields changes nothing
+    good = blocks[0].transactions[2]
+    bad = Transaction(dataclasses.replace(good.inner, s=secp256k1.N))
+    blocks[0].transactions[2] = bad
 
     eng = _engine()
     pipe = _SenderPipeline(eng, blocks)
     pipe.ensure(len(blocks) - 1)
     assert len(pipe.segments) == 1
     assert eng.stats.sigs_host == _n_txs(blocks)
+    assert eng.stats.sigs_left_to_signer == 1
     assert eng.stats.recover_degraded == 0
 
     for b in blocks:
@@ -147,7 +154,7 @@ SEAMS = {
     "packing raises": (
         lambda mp: mp.setattr(ReplayEngine, "_pack_sigs", _boom), 1),
     "the worker's batch raises": (
-        lambda mp: mp.setattr(native, "recover_addresses_batch", _boom),
+        lambda mp: mp.setattr(native, "recover_senders_wire", _boom),
         1),
     "Future.result() raises": (
         lambda mp: mp.setattr(ReplayEngine, "_recover_pool_get",
@@ -190,16 +197,17 @@ def _stub_batch(monkeypatch, eng):
 
     def pack(blocks):
         n = _n_txs(blocks)
-        return [None] * n, bytes(32 * n), bytes(32 * n), bytes(32 * n), \
-            bytes(n)
+        return [None] * n, bytes(n), list(range(n + 1))
 
-    def batch(hashes, rs, ss, recids):
-        batches.append((len(recids), threading.get_ident()))
-        return bytes(20 * len(recids)), b"\x01" * len(recids)
+    def batch(wire, offsets, chain_id):
+        n = len(offsets) - 1
+        assert len(wire) == offsets[-1] and chain_id == CFG.chain_id
+        batches.append((n, threading.get_ident()))
+        return bytes(20 * n), b"\x01" * n
 
     monkeypatch.setattr(eng, "_pack_sigs", pack)
     monkeypatch.setattr(eng, "_apply_recovered", lambda *a: None)
-    monkeypatch.setattr(native, "recover_addresses_batch", batch)
+    monkeypatch.setattr(native, "recover_senders_wire", batch)
     return batches
 
 
@@ -295,7 +303,7 @@ def test_without_the_native_library_senders_recover_per_tx(monkeypatch,
         return None if asks == "coreth_tpu.replay.engine" else real_load()
 
     monkeypatch.setattr(native, "load", load)
-    monkeypatch.setattr(native, "recover_addresses_batch", _boom)
+    monkeypatch.setattr(native, "recover_senders_wire", _boom)
     blocks = _fresh(chain3)
     eng = _engine()
     assert eng.replay(blocks) == blocks[-1].root

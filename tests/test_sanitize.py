@@ -92,6 +92,21 @@ def test_replay_decoder_length_prefix_fuzz_under_asan():
     assert "OK baseline_rejected=" in r.stdout, out[-3000:]
 
 
+def test_sender_wire_walk_hostile_encodings_under_asan():
+    """The native sender batch's wire walk (coreth_recover_wire)
+    parses untrusted bytes on a worker thread: every prefix of a
+    transaction as the last lane of its buffer, length prefixes that
+    claim more than their offsets hold, offsets that run backwards or
+    past the buffer, and seeded byte edits, with ASan armed — a read
+    past the buffer aborts the run; the script's own assertions hold
+    each refusal to its lane and each accepted mutant to the Python
+    decoder and signer."""
+    r = _run(["tests/fuzz_sender_wire.py"])
+    out = r.stdout + r.stderr
+    assert r.returncode == 0, out[-3000:]
+    assert "OK refused=" in r.stdout, out[-3000:]
+
+
 def test_hostexec_vectors_and_trie_differential_under_asan():
     """The real boundary drives: 13 hand-derived hostexec vectors
     (gas/refund/returndata/static-protection) + the randomized

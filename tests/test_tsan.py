@@ -87,6 +87,20 @@ def test_smoke_helper_locked_is_clean():
     assert r.stdout.strip() == "100000", r.stdout + r.stderr
 
 
+def test_two_threads_in_the_sender_wire_batch_are_clean():
+    """The native sender batch from the wire bytes
+    (coreth_recover_wire) entered by two threads at once, as the tip's
+    prefetcher (warm_senders on its own thread) and the replay
+    thread's recovery worker can be: each call's scratch is its own,
+    the comb table is built once under call_once — any shared write
+    reports and exits 66; rc 0 with both threads' answers right is
+    the clean bill."""
+    r = _run(["tests/fuzz_sender_wire.py", "threads"])
+    assert r.returncode == 0, \
+        f"rc {r.returncode}: " + r.stdout[-2000:] + r.stderr[-2000:]
+    assert "OK calls=" in r.stdout, r.stdout + r.stderr
+
+
 def test_streaming_and_hostexec_seams_replay_clean():
     """The real concurrency seams against the instrumented library:
 
