@@ -10,12 +10,14 @@ timing evidence through it, so it imports nothing of the tree above
   per-block :class:`BlockTrace` contexts whose stage intervals become
   ``StreamReport.stage_breakdown``, a bounded ring, and Chrome
   trace-event / Perfetto JSON export (CORETH_TRACE_OUT).
-- ``obs.account`` — the ALWAYS-ON self-time account of the replay
-  thread (:class:`Account`: a phase stack whose seconds sum to the
-  engine's age) and the in-flight count of device work
-  (``device_issue`` / ``device_done``) that says which phase the host
-  was in while the chip had nothing to do; the same sites feed the
-  tracer's ring and the profiler's host plane when the tracer is armed.
+- ``obs.account`` — the ALWAYS-ON self-time accounts, one a thread
+  (:class:`Account`: a phase stack whose wall seconds sum to its age,
+  with the thread's CPU seconds beside them; an engine opens its
+  replay thread's, ``thread_account(role)`` a worker's own) and the
+  in-flight count of device work (``device_issue`` / ``device_done``)
+  that says which phase the host was in while the chip had nothing to
+  do; the same sites feed the tracer's ring and the profiler's host
+  plane when the tracer is armed.
 - ``obs.server`` — the zero-dependency live telemetry endpoint
   (CORETH_TELEMETRY_PORT): /metrics, /trace, /report.
 - ``obs.recorder`` — the divergence flight recorder
@@ -26,8 +28,8 @@ timing evidence through it, so it imports nothing of the tree above
 """
 
 from coreth_tpu.obs.account import (
-    NULL as NULL_ACCOUNT, Account, InFlight, accounts_between,
-    device_done, device_issue,
+    NULL as NULL_ACCOUNT, Account, InFlight, accounts_between, current,
+    device_done, device_issue, thread_account,
 )
 from coreth_tpu.obs.trace import (
     PT_EXPORT_FAIL, BlockTrace, EventRing, SpanTracer,
@@ -39,7 +41,8 @@ from coreth_tpu.obs import recorder  # noqa: F401 — re-export the forensics mo
 __all__ = [
     "NULL_ACCOUNT", "PT_EXPORT_FAIL", "Account", "BlockTrace",
     "EventRing", "InFlight", "SpanTracer", "StageAccumulator",
-    "accounts_between", "arm_from_env", "block_begin", "device_done",
-    "device_issue", "enabled", "install", "instant", "recorder",
-    "self_times", "span", "tracer", "uninstall", "write_out",
+    "accounts_between", "arm_from_env", "block_begin", "current",
+    "device_done", "device_issue", "enabled", "install", "instant",
+    "recorder", "self_times", "span", "thread_account", "tracer",
+    "uninstall", "write_out",
 ]

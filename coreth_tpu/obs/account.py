@@ -1,20 +1,44 @@
-"""One self-time account of the replay thread, and who starved the chip.
+"""One self-time account a thread, and who starved the chip.
 
-An :class:`Account` is a stack of named phases on one clock.  Every
-boundary (``enter`` / ``switch`` / ``exit`` / ``tick``) reads the clock
-once and charges the time since the last boundary to the phase on top
-of the stack, so every instant of the account's life belongs to exactly
-one phase — the innermost — and the phases' seconds sum to the
-account's age.  It is ALWAYS on: a ``ReplayEngine`` opens one first
-thing in its constructor and every hot-path site pays one clock read
-and a few list updates (no allocation, no contextvar, no ring event).
+An :class:`Account` is a stack of named phases owned by ONE thread.
+Every boundary (``enter`` / ``switch`` / ``exit`` / ``tick``) reads the
+clock once and charges the time since the last boundary to the phase on
+top of the stack, so every instant of the account's life belongs to
+exactly one phase — the innermost — and the phases' seconds sum to the
+account's age.  It is ALWAYS on: every hot-path site pays one clock
+read and a few list updates (no allocation, no contextvar, no ring
+event).
 
-The root phase is ``idle`` (account alive, no call in progress).  A
-public call of the engine claims the account for its thread with
-``begin()`` and runs under ``loop``.  A public call made by ANOTHER
-thread while the claim stands (the serve prefetcher's ``warm_senders``)
-is handed ``NULL`` in its place: this is the account of one thread's
-time.
+CPU seconds are read at MARKS, not at boundaries: ``mark_cpu()`` reads
+the owner thread's CPU clock and charges the CPU seconds since the last
+mark to the phase on top.  ``time.thread_time()`` is a system call —
+5.6 us on the chip's host, where the wall clock costs 0.07 (PERF.md) —
+so a boundary cannot carry it.  A site that marks just before both
+boundaries of a phase gets that phase's ``cpu_s`` exactly (the recovery
+worker's ``sender/native`` a segment, the serve pipeline's
+``stream/wait`` a window); the phases between two marks are charged as
+ONE, to the phase on top at the second (the execute stage's work of a
+window, to ``loop``).  Where the marked phases do not block,
+``self_s - cpu_s`` over them is the time the thread stood runnable and
+did not run (the GIL, or the machine); in one that blocks it is the
+wait.
+
+The threads of a pass keep one each, told apart by ``role``:
+
+- ``replay`` — a ``ReplayEngine`` opens one first thing in its
+  constructor.  Its root phase is ``idle`` (engine alive, no call in
+  progress; the thread is its caller's, so ``idle`` carries no CPU
+  seconds).  A public call of the engine claims the account for its
+  thread with ``begin()`` and runs under ``loop``;
+- ``recover`` (the engine's recovery worker), ``feed``, ``prefetch``
+  (the serve pipeline's threads) — opened by the thread itself with
+  :func:`thread_account`, which binds the account to it: root ``idle``
+  (thread alive, no job), :func:`current` finds it.
+
+A public call made by ANOTHER thread while an engine's claim stands
+(the serve prefetcher's ``warm_senders``) runs on THAT thread's own
+account where it has one, and is handed ``NULL`` only where it has
+none: no account ever holds a second thread's time.
 
 :class:`InFlight` counts device work dispatched and not yet read back.
 One chip runs its queue in order, so seeing ticket *k* finished retires
@@ -105,24 +129,45 @@ DEVICE = InFlight()
 
 # Accounts opened in this process, newest last: the small Account
 # objects only, never their engines (an engine must be free to die).
+# The cap holds a benchmark window's accounts of every role (31 passes
+# a window at most, up to four accounts a pass).
 _ACCOUNTS: deque = deque(maxlen=256)
 _ACCOUNTS_MU = threading.Lock()
 
-# the account whose public call is in progress on this thread: what a
-# device seam with no engine at hand (evm/device/adapter.py) ticks
+# the account of this thread: its own (thread_account), or the
+# engine's whose public call is in progress on it — what a device seam
+# with no engine at hand (evm/device/adapter.py) ticks, and where a
+# public call from a thread that is not the engine's lands
 _LOCAL = threading.local()
+
+REPLAY = "replay"  # the role of an engine's account
 
 
 def current() -> Optional["Account"]:
     return getattr(_LOCAL, "account", None)
 
 
-def accounts_between(t_lo: float, t_hi: float) -> List["Account"]:
-    """The accounts opened in ``[t_lo, t_hi]`` on ``time.monotonic``
-    (the default clock), oldest first."""
+def thread_account(role: str) -> "Account":
+    """A new account of the CALLING thread's own: the thread holds its
+    claim from the first instant (``t_open``) and :func:`current`
+    returns it there.  For a thread that runs one stage of a pass and
+    never claims an engine's account."""
+    acct = Account(role)
+    acct._owner = threading.get_ident()
+    _LOCAL.account = acct
+    return acct
+
+
+def accounts_between(t_lo: float, t_hi: float,
+                     role: Optional[str] = REPLAY) -> List["Account"]:
+    """The accounts of ``role`` (None: of every role) opened in
+    ``[t_lo, t_hi]`` on ``time.monotonic`` (the default clock), oldest
+    first.  The default is the engines' own, so that a reader that
+    sums a pass's accounts sums one thread's time."""
     with _ACCOUNTS_MU:
         snap = list(_ACCOUNTS)
-    return [a for a in snap if t_lo <= a.t_open <= t_hi]
+    return [a for a in snap if t_lo <= a.t_open <= t_hi
+            and (role is None or a.role == role)]
 
 
 device_issue = DEVICE.issue
@@ -132,18 +177,26 @@ device_done = DEVICE.done
 class Account:
     """Phase stack with self times; see the module docstring."""
 
-    __slots__ = ("_clock", "_dev", "t_open", "t_last", "_recs", "_stack",
+    __slots__ = ("role", "thread", "_clock", "_cpu", "_dev", "t_open",
+                 "t_last", "_c_last", "_marked", "_recs", "_stack",
                  "_owner", "_mu", "_frames")
 
-    def __init__(self, clock=time.monotonic,
+    def __init__(self, role: str = REPLAY, clock=time.monotonic,
+                 cpu_clock=time.thread_time,
                  device: Optional[InFlight] = None, register: bool = True):
+        self.role = role
+        # the owner's name: the opener's, then whoever claims it
+        self.thread = threading.current_thread().name
         self._clock = clock
+        self._cpu = cpu_clock   # the calling thread's, read at marks
+        self._c_last: Optional[float] = None   # at the last mark
+        self._marked = False    # ever: row() tells unread from 0
         self._dev = DEVICE if device is None else device
         self.t_open = self.t_last = clock()
-        # phase name -> [self seconds, starved seconds, entries]; the
-        # stack holds these records, so a boundary costs no dict lookup
-        # for the phase it closes
-        root = [0.0, 0.0, 1]
+        # phase name -> [self seconds, starved seconds, entries, CPU
+        # seconds]; the stack holds these records, so a boundary costs
+        # no dict lookup for the phase it closes
+        root = [0.0, 0.0, 1, 0.0]
         self._recs: Dict[str, list] = {IDLE: root}
         self._stack = [root]
         self._owner: Optional[int] = None   # thread inside a public call
@@ -168,6 +221,8 @@ class Account:
                 return -1
             self._owner = me
         _LOCAL.account = self
+        self.thread = threading.current_thread().name
+        self._c_last = None   # the CPU clock is THIS thread's now
         self.enter(root)
         return len(self._stack)
 
@@ -192,10 +247,21 @@ class Account:
         if not self._dev.busy:
             top[1] += dt
 
+    def mark_cpu(self) -> None:
+        """Read the owner thread's CPU clock (a system call: never a
+        block) and charge the CPU seconds since the last mark of this
+        claim to the phase on top; the first mark only starts the
+        count."""
+        c = self._cpu()
+        if self._c_last is not None:
+            self._stack[-1][3] += c - self._c_last
+        self._c_last = c
+        self._marked = True
+
     def _push(self, name: str) -> None:
         rec = self._recs.get(name)
         if rec is None:
-            rec = self._recs[name] = [0.0, 0.0, 0]
+            rec = self._recs[name] = [0.0, 0.0, 0, 0.0]
         rec[2] += 1
         self._stack.append(rec)
         if _trace.TRACER is not None:
@@ -224,7 +290,8 @@ class Account:
         """Reclassify ``seconds`` of ``src``'s self time as ``dst``'s:
         a sub-phase that recurs per block, timed by clock pairs the
         caller already pays and too short to carry boundaries of its
-        own.  Its starved part moves in proportion; the sum stays."""
+        own.  Its starved part moves in proportion; the sum stays.
+        Wall seconds only: the CPU seconds stay with ``src``."""
         self.tick()
         a = self._recs.get(src)
         if a is None or a[0] <= 0.0 or seconds <= 0.0:
@@ -233,7 +300,7 @@ class Account:
         starved = a[1] * seconds / a[0]
         b = self._recs.get(dst)
         if b is None:
-            b = self._recs[dst] = [0.0, 0.0, 0]
+            b = self._recs[dst] = [0.0, 0.0, 0, 0.0]
         a[0] -= seconds
         a[1] -= starved
         b[0] += seconds
@@ -274,22 +341,28 @@ class Account:
 
     # ------------------------------------------------------------ reading
     def row(self) -> dict:
-        """The account as of its last boundary (``t_last``): seconds
-        and entries per phase, and the seconds of each phase in which
-        nothing was in flight on the device.  ``sum(self_s.values())``
-        is ``t_last - t_open``."""
+        """The account as of its last boundary (``t_last``): whose it
+        is, wall seconds, CPU seconds and entries per phase, and the
+        seconds of each phase in which nothing was in flight on the
+        device.  ``sum(self_s.values())`` is ``t_last - t_open``;
+        ``sum(cpu_s.values())`` is no more: CPU seconds by the phase
+        on top at each ``mark_cpu()``, None for an account that never
+        marked."""
         recs = dict(self._recs)
-        return {"t_open": self.t_open, "t_last": self.t_last,
+        return {"role": self.role, "thread": self.thread,
+                "t_open": self.t_open, "t_last": self.t_last,
                 "self_s": {k: r[0] for k, r in recs.items()},
+                "cpu_s": {k: r[3] for k, r in recs.items()}
+                if self._marked else None,
                 "n": {k: r[2] for k, r in recs.items()},
                 "starved_s": {k: r[1] for k, r in recs.items()}}
 
 
 class _NullAccount:
-    """What a public call made from another thread than the account's
-    gets, and what a caller with no engine behind it (the VM on the
-    host processor) uses in an account's place: every phase site is a
-    no-op."""
+    """What a public call made from a thread that is not the
+    account's and has none of its own gets, and what a caller with no
+    engine behind it (the VM on the host processor) uses in an
+    account's place: every phase site is a no-op."""
 
     __slots__ = ()
 
@@ -308,7 +381,7 @@ class _NullAccount:
     def exit(self) -> None:
         return None
 
-    tick = exit
+    tick = mark_cpu = exit
 
     def __enter__(self) -> None:
         return None

@@ -794,6 +794,19 @@ class DeviceState:
         return [(balances[i], int(non[i])) for i in range(len(indices))]
 
 
+def _recover_segment(wire, offsets, chain_id: int):
+    """One segment's native batch, on the recovery worker: phase
+    ``sender/native`` of the worker's own account, its CPU seconds
+    marked at both ends (two system calls a segment)."""
+    from coreth_tpu.crypto import native
+    acct = obs.current() or obs.NULL_ACCOUNT
+    acct.mark_cpu()
+    with acct.enter("sender/native"):
+        out = native.recover_senders_wire(wire, offsets, chain_id)
+        acct.mark_cpu()
+    return out
+
+
 class _SenderPipeline:
     """Segmented, look-ahead sender recovery for replay().
 
@@ -808,9 +821,13 @@ class _SenderPipeline:
     (crypto/native.recover_senders_wire — the one batch engine) derives
     each signing hash, r, s and recovery id from the bytes and recovers
     the keys in the engine's recovery worker thread: one worker, in
-    order; the ctypes call releases the GIL.  Without the native library
-    a segment stays lazy: signer.sender recovers per tx.  ensure(i)
-    blocks only until block i's segment is applied.
+    order; the ctypes call releases the GIL.  The worker keeps an
+    account of its own (role ``recover``: ``sender/native`` round each
+    segment's batch, ``idle`` between them), so the replay thread's
+    ``sender/wait_host`` can be set beside what the worker was doing.
+    Without the native library a segment stays lazy: signer.sender
+    recovers per tx.  ensure(i) blocks only until block i's segment is
+    applied.
     """
 
     AHEAD = 3
@@ -849,7 +866,7 @@ class _SenderPipeline:
                 todo, wire, offsets = eng._pack_sigs(self.segments[s])
                 if todo and native.load() is not None:
                     fut = eng._recover_pool_get().submit(
-                        native.recover_senders_wire, wire, offsets,
+                        _recover_segment, wire, offsets,
                         eng.signer.chain_id)
             except Exception:  # noqa: BLE001 — degrade to lazy per-tx
                 eng.stats.recover_degraded += 1
@@ -1199,9 +1216,14 @@ class ReplayEngine:
         overlap segmented recovery with window execution."""
         if isinstance(blocks, Block):
             blocks = [blocks]
-        # the serve prefetcher calls this from ITS thread while the
-        # replay thread holds the account: that time is not the replay
-        # thread's, so its phases go nowhere
+        # a thread with an account of its own (the serve prefetcher)
+        # keeps this time there: it is not the replay thread's
+        own = obs.current()
+        if own is not None and own is not self.account:
+            self._warm_senders_run(blocks, own)
+            return
+        # a thread with none gets the engine's, or nothing while the
+        # replay thread holds it
         tok = self.account.begin()
         try:
             self._warm_senders_run(
@@ -1221,6 +1243,7 @@ class ReplayEngine:
             if native.load() is None:
                 return  # per-tx python path in signer.sender
             # the batch runs ON this thread: work, not a wait
+            acct.switch("sender/native")
             t1 = time.monotonic()
             out, ok = native.recover_senders_wire(wire, offsets,
                                                   self.signer.chain_id)
@@ -1237,7 +1260,11 @@ class ReplayEngine:
     def _recover_pool_get(self):
         if not hasattr(self, "_recover_pool"):
             from concurrent.futures import ThreadPoolExecutor
-            self._recover_pool = ThreadPoolExecutor(max_workers=1)
+            # the worker opens its own account as it starts: inside
+            # the call that issued the first segment
+            self._recover_pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="coreth-recover",
+                initializer=obs.thread_account, initargs=("recover",))
         return self._recover_pool
 
     # ------------------------------------------------------------- classify

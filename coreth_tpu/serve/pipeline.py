@@ -27,6 +27,16 @@ Stage model
   execute stage, the bounded queues fill, and the feed blocks: latency
   degrades measurably, queues stay bounded.
 
+Each stage's thread keeps a self-time account (obs/account.py): the
+execute stage the engine's (role ``replay``; its wait for blocks is
+phase ``stream/wait``), the feed and prefetch threads their own (roles
+``feed``, ``prefetch``): wall seconds by phase, and each thread's CPU
+seconds marked once a window's worth of blocks.  No phase
+recurs per block: the feed's two intervals a block are clock pairs,
+moved over once a window's worth of blocks; the prefetch thread's
+phases are per chunk.  The report's ``feed_blocked_s``,
+``prefetch_blocked_s`` and ``overlap_s`` are read from them.
+
 Every block's enqueue->committed latency lands in a
 :class:`~coreth_tpu.metrics.Histogram` (p50/p99/max), and the report
 carries sustained txs/s over the wall of the run — the SLO surface the
@@ -214,8 +224,10 @@ class StreamingPipeline:
         self._max_inflight = 0
         self._t_first_enqueue: Optional[float] = None
         self._t_last_commit: Optional[float] = None
-        self._feed_blocked_s = 0.0
-        self._prefetch_blocked_s = 0.0
+        # the feed and prefetch threads' own accounts: each opened by
+        # its thread first thing, read by _publish after the join
+        self._feed_acct = None
+        self._prefetch_acct = None
         self._t_commit = 0.0
         # commit time already attributed to committed blocks' traces
         # (the delta since the last _mark_committed amortizes over
@@ -251,12 +263,35 @@ class StreamingPipeline:
 
     # ------------------------------------------------------------ stages
     def _feed_loop(self) -> None:
+        # what no phase names is ``loop``; the two intervals a block —
+        # inside the source, parked on the queue — are clock pairs,
+        # moved out of it once a window's worth of blocks
+        acct = obs.thread_account("feed")
+        self._feed_acct = acct  # corethlint: shared written once, by this thread as it starts; run() reads it after the join
+        acct.enter("loop")
+        acct.mark_cpu()
+        window = self.engine.window
+        source_s = put_s = 0.0
+        pulls = puts = 0
+
+        def settle():
+            nonlocal source_s, put_s, pulls, puts
+            acct.mark_cpu()   # the thread's CPU seconds, to ``loop``
+            acct.move("loop", "feed/source", source_s, entries=pulls)
+            acct.move("loop", "feed/put", put_s, entries=puts)
+            source_s = put_s = 0.0
+            pulls = puts = 0
+
         try:
             while not self._stop.is_set():
+                t_src = time.monotonic()
                 try:
                     b = self.feed.next_block(timeout=0.05)
                 except FeedExhausted:
                     break
+                finally:
+                    source_s += time.monotonic() - t_src
+                    pulls += 1
                 if b is None:
                     self.stats.feed_stalls += 1
                     continue
@@ -291,8 +326,11 @@ class StreamingPipeline:
                 blocked = self._put(self._q_feed, it)
                 if blocked < 0:
                     break
+                put_s += blocked
+                puts += 1
+                if puts >= window:
+                    settle()
                 with self._mu:
-                    self._feed_blocked_s += blocked
                     self._enqueued += 1
                     inflight = self._enqueued - self._committed_blocks
                     if inflight > self._max_inflight:
@@ -301,10 +339,20 @@ class StreamingPipeline:
             self._errors.append(exc)
             self._stop.set()
         finally:
+            settle()
+            acct.exit()
             self._feed_done.set()
 
     def _prefetch_loop(self) -> None:
+        # one chain of phases a CHUNK: prefetch/wait -> prefetch/
+        # touch_code (warm_senders' sender/pack -> sender/native ->
+        # sender/apply inside it) -> prefetch/put -> prefetch/wait
+        acct = obs.thread_account("prefetch")
+        self._prefetch_acct = acct  # corethlint: shared written once, by this thread as it starts; run() reads it after the join
+        acct.enter("prefetch/wait")
+        acct.mark_cpu()
         window = self.engine.window
+        unmarked = 0   # blocks since the thread's CPU clock was read
         try:
             while True:
                 chunk: List[_Item] = []
@@ -321,6 +369,14 @@ class StreamingPipeline:
                         chunk.append(self._q_feed.get_nowait())
                     except queue.Empty:
                         break
+                acct.switch("prefetch/touch_code")
+                unmarked += len(chunk)
+                if unmarked >= window:
+                    # the thread's CPU seconds since the last mark, as
+                    # one, to this phase: a system call, so once a
+                    # window's worth of blocks and not once a chunk
+                    acct.mark_cpu()
+                    unmarked = 0
                 t_pf = time.monotonic()
                 self.prefetcher.warm([c.block for c in chunk])
                 if obs.enabled():
@@ -330,16 +386,16 @@ class StreamingPipeline:
                     for c in chunk:
                         if c.bt is not None:
                             c.bt.prefetched(t_pf, share)
+                acct.switch("prefetch/put")
                 for c in chunk:
-                    blocked = self._put(self._q_exec, c)
-                    if blocked < 0:
+                    if self._put(self._q_exec, c) < 0:
                         return
-                    with self._mu:
-                        self._prefetch_blocked_s += blocked
+                acct.switch("prefetch/wait")
         except BaseException as exc:  # noqa: BLE001 — surfaced by run()
             self._errors.append(exc)
             self._stop.set()
         finally:
+            acct.exit()
             self._pre_done.set()
 
     # ----------------------------------------------------------- commit
@@ -491,12 +547,21 @@ class StreamingPipeline:
         pending = None  # (win, its items) — issued, not yet validated
         while True:
             # top up the working buffer; wait only when idle, and only
-            # window_wait when a partial window could run instead
-            while len(buf) < e.window:
-                it = self._next_item(idle=not buf and pending is None)
-                if it is None:
-                    break
-                buf.append(it)
+            # window_wait when a partial window could run instead.
+            # ``stream/wait`` once a WINDOW, round the whole top-up: the
+            # queue's blocking get, with the loop's bookkeeping an item
+            # (continuity gate, prefetch-hit count) riding inside it
+            # (the thread's CPU seconds are marked at its two ends: the
+            # work since the last wait goes to ``loop`` as one)
+            e.account.mark_cpu()
+            with e.account.enter("stream/wait"):
+                while len(buf) < e.window:
+                    it = self._next_item(
+                        idle=not buf and pending is None)
+                    if it is None:
+                        break
+                    buf.append(it)
+                e.account.mark_cpu()
             if not buf and pending is None:
                 if self._eos():
                     break
@@ -625,6 +690,7 @@ class StreamingPipeline:
                     # anything still staged belongs to completed blocks
                     self.engine.commit_pipe.flush()
                     restore()
+                    acct.mark_cpu()   # the drain since the last wait
                     acct.end(claim)
                 if self._errors:
                     raise self._errors[0]
@@ -728,6 +794,12 @@ class StreamingPipeline:
                 "blocks_order_dependent": st.blocks_order_dependent,
                 "sigs_left_to_signer": st.sigs_left_to_signer}
 
+    @staticmethod
+    def _thread_seconds(acct) -> dict:
+        """Wall seconds by phase of a stage thread's account ({}: the
+        thread never started)."""
+        return {} if acct is None else acct.row()["self_s"]
+
     def _publish(self, wall: float) -> None:
         s = self.stats
         s.wall_s = round(wall, 3)
@@ -742,11 +814,17 @@ class StreamingPipeline:
             "p99": round(1000 * snap["p99"], 3),
             "max": round(1000 * snap["max"], 3),
         }
+        # the other two threads' seconds, from their own accounts
+        feed = self._thread_seconds(self._feed_acct)
+        pre = self._thread_seconds(self._prefetch_acct)
+        warm_s = sum(v for k, v in pre.items()
+                     if k.startswith("sender/")) \
+            + pre.get("prefetch/touch_code", 0.0)
         s.prefetch = {
             "hits": self._prefetch_hits,
             "sigs": self.prefetcher.sigs,
             "code_touches": self.prefetcher.code_touches,
-            "overlap_s": round(self.prefetcher.busy_s, 3),
+            "overlap_s": round(warm_s, 3),
             "reads_prefetched": self.engine.stats.reads_prefetched,
         }
         s.queues = {
@@ -754,12 +832,12 @@ class StreamingPipeline:
             "max_inflight": self._max_inflight,
         }
         s.stages_s = {
-            "prefetch": round(self.prefetcher.busy_s, 3),
+            "prefetch": round(warm_s, 3),
             "commit": round(self._t_commit, 3),
         }
         s.backpressure = {
-            "feed_blocked_s": round(self._feed_blocked_s, 3),
-            "prefetch_blocked_s": round(self._prefetch_blocked_s, 3),
+            "feed_blocked_s": round(feed.get("feed/put", 0.0), 3),
+            "prefetch_blocked_s": round(pre.get("prefetch/put", 0.0), 3),
             "commit_flushes": self._commit_flushes,
         }
         s.shutdown = self._shutdown_called

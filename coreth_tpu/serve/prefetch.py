@@ -27,7 +27,6 @@ streaming bench is "prefetch-hit or overlap counter > 0"):
 from __future__ import annotations
 
 import threading
-import time
 from typing import List
 
 from coreth_tpu import obs
@@ -44,10 +43,11 @@ class Prefetcher:
         self._mu = threading.Lock()
         self.sigs = 0
         self.code_touches = 0
-        self.busy_s = 0.0
 
     def warm(self, blocks: List[Block]) -> None:
-        t0 = time.monotonic()
+        """On the pipeline's prefetch thread this is phase
+        ``prefetch/touch_code`` of that thread's account, with
+        ``warm_senders``' ``sender/*`` phases inside it."""
         with obs.span("serve/prefetch_warm", blocks=len(blocks)):
             todo = sum(1 for b in blocks for tx in b.transactions
                        if tx.cached_sender() is None)
@@ -56,9 +56,6 @@ class Prefetcher:
                 with self._mu:
                     self.sigs += todo
             self._touch_code(blocks)
-        dt = time.monotonic() - t0
-        with self._mu:
-            self.busy_s += dt
 
     def _touch_code(self, blocks: List[Block]) -> None:
         """Pull callee bytecode for call-shaped txs into the rawdb read

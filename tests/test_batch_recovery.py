@@ -26,6 +26,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 from types import SimpleNamespace
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -34,6 +35,7 @@ sys.path.insert(0, REPO)
 import pytest
 import jax
 
+from coreth_tpu import obs
 from coreth_tpu.chain import Genesis, GenesisAccount, generate_chain
 from coreth_tpu.crypto import native, secp256k1
 from coreth_tpu.crypto.secp256k1 import priv_to_address
@@ -274,19 +276,70 @@ def test_lookahead_issues_ahead_and_completes_in_order(monkeypatch):
     assert len(workers) == 1 and threading.get_ident() not in workers
     n = eng.account.row()["n"]
     assert n["sender/pack"] == n["sender/wait_host"] == len(sizes)
+    # the batches are the WORKER's phase, one entry a segment, on an
+    # account the worker opened itself inside this call
+    assert "sender/native" not in n
+    (worker,) = [a for a in obs.accounts_between(
+        eng.account.t_open, time.monotonic(), role="recover")]
+    row = worker.row()
+    assert row["n"]["sender/native"] == len(sizes)
+    assert row["thread"].startswith("coreth-recover")
+    assert worker.t_open > eng.account.t_open
+    assert worker not in obs.accounts_between(eng.account.t_open,
+                                              time.monotonic())
 
 
 def test_warm_senders_runs_the_batch_on_the_calling_thread(monkeypatch):
     """The synchronous form has no worker and no wait: the batch is
-    work of the thread that called, inside ``sender/pack``."""
+    work of the thread that called, phase ``sender/native`` between
+    its packing and its applying."""
     eng = _engine()
     batches = _stub_batch(monkeypatch, eng)
     eng.warm_senders(_stub_blocks([5, 7]))
     assert batches == [(12, threading.get_ident())]
     assert eng.stats.sigs_host == 12 and eng.stats.sigs_device == 0
     n = eng.account.row()["n"]
-    assert n.get("sender/pack") == 1 and n.get("sender/apply") == 1
+    assert n.get("sender/pack") == 1 and n.get("sender/native") == 1
+    assert n.get("sender/apply") == 1
     assert not n.get("sender/wait_host")
+
+
+def test_warm_senders_from_another_thread_lands_on_that_threads_account(
+        monkeypatch):
+    """While the replay thread holds the engine's account, a call from
+    a thread with an account of its own is that account's; from a
+    thread with none it is nobody's."""
+    eng = _engine()
+    _stub_batch(monkeypatch, eng)
+    tok = eng.account.begin()
+    before = dict(eng.account.row()["n"])
+    seen = {}
+
+    def with_account():
+        acct = obs.thread_account("prefetch")
+        eng.warm_senders(_stub_blocks([3]))
+        seen["own"] = acct.row()
+        seen["current"] = obs.current() is acct
+
+    def without():
+        eng.warm_senders(_stub_blocks([4]))
+        seen["none"] = obs.current()
+
+    for fn in (with_account, without):
+        th = threading.Thread(target=fn, name="other-" + fn.__name__)
+        th.start()
+        th.join(30)
+        assert not th.is_alive()
+    eng.account.end(tok)
+    own = seen["own"]
+    assert own["role"] == "prefetch" and own["thread"] == "other-with_account"
+    assert [own["n"].get(p) for p in ("sender/pack", "sender/native",
+                                      "sender/apply")] == [1, 1, 1]
+    assert seen["current"] and seen["none"] is None
+    after = eng.account.row()["n"]
+    assert {k: after[k] for k in before} == before
+    assert not any(k.startswith("sender/") for k in after)
+    assert eng.stats.sigs_host == 7    # both batches ran
 
 
 def test_without_the_native_library_senders_recover_per_tx(monkeypatch,

@@ -1,6 +1,6 @@
-"""The replay thread's self-time account (coreth_tpu/obs/account.py).
+"""The self-time accounts, one a thread (coreth_tpu/obs/account.py).
 
-Four surfaces under test:
+Five surfaces under test:
 
 1. the account itself on a hand-driven clock: nested phases sum to its
    age EXACTLY, a phase's self time excludes its children, ``switch``
@@ -19,7 +19,12 @@ Four surfaces under test:
    calls, ``sender/*`` and ``window/*`` add up to ``stats.t_sender``
    and ``stats.t_device`` (whose lines are untouched), the device
    recovery's wait is told from its host finish, the streaming report
-   carries the account, and the jitted steps carry their scope names.
+   carries the account, and the jitted steps carry their scope names;
+5. the other threads of a pass: CPU seconds beside the wall seconds
+   (a sleep reads none, a spin reads its own), a thread's own account
+   (``thread_account``), roles in the registry, the recovery worker's
+   ``sender/native`` a segment, the serve pipeline's feed and prefetch
+   accounts and the execute thread's ``stream/wait``.
 
 No host-clock assertion beyond "two readings of one interval agree".
 """
@@ -61,10 +66,11 @@ def _no_tracer_leaks():
     obs.uninstall()
 
 
-def fresh(clock=None):
+def fresh(clock=None, **kw):
     clock = clock or Clock()
     dev = A.InFlight()
-    return clock, dev, A.Account(clock=clock, device=dev, register=False)
+    return clock, dev, A.Account(clock=clock, device=dev, register=False,
+                                 **kw)
 
 
 # ---------------------------------------------------------- the account
@@ -180,6 +186,143 @@ def test_public_call_from_another_thread_is_not_this_accounts():
     with obs.NULL_ACCOUNT.enter("x"):
         obs.NULL_ACCOUNT.switch("y")
         obs.NULL_ACCOUNT.tick()
+
+
+# --------------------------------------------- CPU seconds, thread accounts
+def test_cpu_seconds_are_charged_at_marks_on_hand_clocks():
+    """``mark_cpu`` charges the CPU seconds since the last mark to the
+    phase on top: exact for a phase marked at both ends, one lump for
+    the phases between two marks; the first mark of a claim only
+    starts the count (the stretch before was the caller's, maybe
+    another thread's); ``move`` moves wall seconds only."""
+    cpu = Clock(7.0)
+    clock, _dev, acct = fresh(cpu_clock=cpu)
+    assert acct.row()["cpu_s"] is None          # never marked
+    clock.step(4.0)
+    cpu.step(64.0)                     # another thread's clock, say
+    tok = acct.begin()
+    acct.mark_cpu()                    # starts the count: charges none
+    clock.step(1.0)
+    cpu.step(0.5)                      # loop: ran half of it
+    acct.mark_cpu()
+    with acct.enter("wait"):
+        clock.step(2.0)                # blocked: no CPU
+        acct.mark_cpu()
+    with acct.enter("a"):
+        clock.step(2.0)
+        cpu.step(2.0)
+        acct.move("a", "moved", 0.5, entries=3)
+    with acct.enter("b"):
+        clock.step(1.0)
+        cpu.step(0.25)
+    acct.mark_cpu()                    # a + b as one, to the top: loop
+    acct.end(tok)
+    row = acct.row()
+    assert row["cpu_s"] == {"idle": 0.0, "loop": 2.75, "wait": 0.0,
+                            "a": 0.0, "moved": 0.0, "b": 0.0}
+    assert row["self_s"] == {"idle": 4.0, "loop": 1.0, "wait": 2.0,
+                             "a": 1.5, "moved": 0.5, "b": 1.0}
+    assert sum(row["self_s"].values()) == row["t_last"] - row["t_open"]
+    assert row["role"] == "replay"
+    assert row["thread"] == threading.current_thread().name
+    # the next claim starts its own count
+    cpu.step(100.0)
+    tok = acct.begin()
+    acct.mark_cpu()
+    acct.end(tok)
+    assert sum(acct.row()["cpu_s"].values()) == 2.75
+
+
+def test_no_boundary_reads_the_cpu_clock():
+    """The CPU clock is a system call (5.6 us on the chip's host): a
+    boundary never reads it, a mark reads it once."""
+    reads = []
+    _clock, _dev, acct = fresh(
+        cpu_clock=lambda: (reads.append(1), 0.0)[1])
+    tok = acct.begin()
+    with acct.enter("a"):
+        acct.switch("b")
+    acct.tick()
+    acct.move("b", "c", 0.0)
+    acct.end(tok)
+    assert reads == [] and acct.row()["cpu_s"] is None
+    acct.mark_cpu()
+    assert reads == [1] and set(acct.row()["cpu_s"]) == set(
+        acct.row()["self_s"])
+    obs.NULL_ACCOUNT.mark_cpu()        # a no-op there, like the rest
+
+
+def test_a_sleep_reads_no_cpu_and_a_spin_reads_its_own():
+    """On the real clocks, marked at both ends of each phase:
+    ``cpu_s <= self_s`` by phase, a phase that sleeps ran nothing, one
+    that spins until the thread has burnt 50 ms reads those 50 ms."""
+    acct = A.Account(role="test", register=False)
+    acct.mark_cpu()
+    with acct.enter("sleep"):
+        time.sleep(0.05)
+        acct.mark_cpu()
+    with acct.enter("spin"):
+        until = time.thread_time() + 0.05
+        while time.thread_time() < until:
+            pass
+        acct.mark_cpu()
+    row = acct.row()
+    assert sum(row["self_s"].values()) == pytest.approx(
+        row["t_last"] - row["t_open"], rel=1e-9)
+    for phase, wall in row["self_s"].items():
+        assert row["cpu_s"][phase] <= wall + 1e-4, phase
+    assert row["self_s"]["sleep"] >= 0.05
+    assert row["cpu_s"]["sleep"] < 0.01
+    assert 0.05 <= row["cpu_s"]["spin"] < 0.06
+
+
+def test_thread_account_is_the_threads_own():
+    """``thread_account``: opened by the thread itself, found by
+    ``current()`` there and nowhere else, nested ``begin`` is its
+    own, and its phases sum to its age."""
+    seen = {}
+
+    def worker():
+        acct = obs.thread_account("recover")
+        seen["acct"] = acct
+        seen["current"] = obs.current() is acct
+        seen["begin"] = acct.begin()   # the owner's: nested, no claim
+        acct.mark_cpu()
+        with acct.enter("sender/native"):
+            time.sleep(0.01)
+            acct.mark_cpu()
+        acct.end(seen["begin"])
+        seen["still"] = obs.current() is acct
+
+    t0 = time.monotonic()
+    th = threading.Thread(target=worker, name="a-worker")
+    th.start()
+    th.join(10)
+    assert not th.is_alive()
+    acct = seen["acct"]
+    assert seen["current"] and seen["still"] and seen["begin"] == 0
+    assert obs.current() is None       # not this thread's
+    assert acct.begin() == -1          # nor this thread's to claim
+    row = acct.row()
+    assert row["role"] == "recover" and row["thread"] == "a-worker"
+    assert t0 <= row["t_open"] <= time.monotonic()
+    assert row["n"] == {"idle": 1, "sender/native": 1}
+    assert sum(row["self_s"].values()) == pytest.approx(
+        row["t_last"] - row["t_open"], rel=1e-9)
+    assert row["cpu_s"]["sender/native"] <= row["self_s"]["sender/native"]
+
+
+def test_accounts_between_by_role():
+    """Without ``role`` the registry answers what it answered before
+    the other threads had accounts: the engines' alone."""
+    t0 = time.monotonic()
+    engine = A.Account()
+    others = [A.Account(role=r) for r in ("recover", "feed", "prefetch")]
+    t1 = time.monotonic()
+    assert A.accounts_between(t0, t1) == [engine]
+    assert A.accounts_between(t0, t1, role="replay") == [engine]
+    assert A.accounts_between(t0, t1, role="feed") == [others[1]]
+    assert A.accounts_between(t0, t1, role=None) == [engine] + others
 
 
 # ------------------------------------------------------ device in flight
@@ -444,8 +587,8 @@ def toy_chain():
 ENGINE_KW = dict(batch_pad=8, window=2, capacity=256, slot_capacity=64)
 
 TRANSFER_PHASES = (
-    "engine/build", "loop", "sender/pack", "sender/wait_host",
-    "sender/apply", "classify", "window/prepare", "window/upload",
+    "engine/build", "loop", "sender/pack", "sender/native",
+    "sender/wait_host", "sender/apply", "classify", "window/prepare", "window/upload",
     "window/dispatch", "window/fetch_wait", "validate", "commit/stage",
     "commit/flush")
 
@@ -473,6 +616,7 @@ def test_engine_accounts_for_its_whole_replay(toy_chain):
     row = engine.account.row()
     assert engine.account in obs.accounts_between(
         row["t_open"], row["t_open"])
+    assert row["cpu_s"] is None        # batch replay marks no CPU
     for phase in TRANSFER_PHASES:
         assert row["n"].get(phase, 0) > 0, phase
     assert row["n"]["engine/build"] == 1
@@ -542,6 +686,47 @@ def test_sender_recovery_issues_no_device_work(toy_chain, monkeypatch):
     assert "sender/issue_device" not in row["n"]
     assert "sender/wait_device" not in row["n"]
     assert seen and not any(seen)
+    # the batches themselves: the lead block's on this thread
+    # (replay_block -> warm_senders), every segment's on the worker,
+    # whose account it opened itself inside the pass
+    assert row["n"]["sender/native"] == 1
+    (worker,) = obs.accounts_between(row["t_open"], row["t_last"],
+                                     role="recover")
+    wrow = worker.row()
+    segments = row["n"]["sender/wait_host"]
+    assert wrow["n"] == {"idle": 1, "sender/native": segments}
+    assert wrow["thread"] != row["thread"]
+    assert row["t_open"] < wrow["t_open"] < row["t_last"]
+    assert 0 < wrow["self_s"]["sender/native"] \
+        <= wrow["t_last"] - wrow["t_open"]
+    assert wrow["cpu_s"]["sender/native"] > 0
+
+
+def test_armed_tracer_rows_the_workers_batches_under_its_thread(
+        toy_chain, annotations):
+    """One sink for every thread: with the tracer armed the worker's
+    ``sender/native`` is an event on the worker's own thread row and
+    an annotation in the profiler's host plane, as the engine's are."""
+    from coreth_tpu.crypto import native
+    if native.load() is None:
+        pytest.skip("no native library: no batch engine")
+    tr = obs.install()
+    engine, _built, _wall = _pass(*toy_chain)
+    doc = tr.export()["traceEvents"]
+    rows = {e["tid"]: e["args"]["name"] for e in doc
+            if e["ph"] == "M" and e["name"] == "thread_name"}
+    natives = [e for e in doc if e["ph"] == "X"
+               and e["name"] == "sender/native"]
+    by_thread = {}
+    for e in natives:
+        by_thread.setdefault(rows[e["tid"]], []).append(e)
+    here = threading.current_thread().name
+    (worker,) = [name for name in by_thread if name != here]
+    assert worker.startswith("coreth-recover")
+    assert len(by_thread[worker]) \
+        == engine.account.row()["n"]["sender/wait_host"]
+    assert len(by_thread[here]) == 1   # the lead block's
+    assert annotations.count("coreth/sender/native") == len(natives)
 
 
 def test_streaming_report_carries_the_account():
@@ -554,13 +739,129 @@ def test_streaming_report_carries_the_account():
     rep = pipe.run()
     assert eng.root == blocks[-1].header.root
     acct = rep.account
-    assert set(acct) == {"t_open", "t_last", "self_s", "n", "starved_s"}
+    assert set(acct) == {"role", "thread", "t_open", "t_last", "self_s",
+                         "cpu_s", "n", "starved_s"}
+    assert acct["role"] == "replay"
+    assert acct["thread"] == threading.current_thread().name
     assert acct["n"]["loop"] == 1      # the execute stage, one claim
     for phase in ("classify", "window/dispatch", "window/fetch_wait",
                   "validate", "commit/flush"):
         assert acct["n"].get(phase, 0) > 0, phase
     assert pipe._live_report()["account"]["n"] == acct["n"]
     assert A.current() is None         # the claim was given back
+
+
+def _streamed(n_blocks=12, rate=None, **kw):
+    from coreth_tpu.serve import ChainFeed, StreamingPipeline
+    from tests.test_serve import build_transfer_chain, _fresh_engine
+    from coreth_tpu.types import Block
+    genesis, blocks = build_transfer_chain(n_blocks, 4)
+    blocks = [Block.decode(b.encode()) for b in blocks]  # no senders
+    eng, _ = _fresh_engine(genesis)
+    pipe = StreamingPipeline(eng, ChainFeed(list(blocks), rate=rate),
+                             window_wait=0.005, **kw)
+    return eng, blocks, pipe
+
+
+def test_stream_wait_takes_the_wait_for_blocks_out_of_loop():
+    """``stream/wait`` is the execute thread's top-up loop, entered once
+    a window and never once an item: it holds every second spent in
+    ``_next_item`` — which read as ``loop`` before — and ``loop`` keeps
+    what is left."""
+    eng, blocks, pipe = _streamed(rate=200.0)
+    waited = [0.0, 0]
+    real = pipe._next_item
+
+    def timed(idle):
+        t0 = time.monotonic()
+        try:
+            return real(idle)
+        finally:
+            waited[0] += time.monotonic() - t0
+            waited[1] += 1
+
+    pipe._next_item = timed
+    t0 = time.monotonic()
+    rep = pipe.run()
+    wall = time.monotonic() - t0
+    assert eng.root == blocks[-1].header.root
+    row = rep.account
+    wait, loop = row["self_s"]["stream/wait"], row["self_s"]["loop"]
+    # the same interval read twice: round each call, and round the loop
+    assert waited[0] <= wait <= waited[0] + 0.05
+    assert wait > loop                 # a paced feed: mostly waiting
+    assert wait + loop < wall
+    # once a window at most, not once an item
+    assert 0 < row["n"]["stream/wait"] < waited[1]
+    assert row["n"]["stream/wait"] <= len(blocks) + 2
+    # a wait burns no CPU
+    assert row["cpu_s"]["stream/wait"] < 0.5 * wait
+
+
+def test_serve_threads_keep_accounts_and_the_report_reads_them():
+    """The feed and prefetch threads open accounts of their own inside
+    the run; ``feed_blocked_s`` / ``prefetch_blocked_s`` / ``overlap_s``
+    are those accounts' phases; the engine's account holds no second
+    thread's time."""
+    tr = obs.install()                 # armed: the ring shows the rows
+    t0 = time.monotonic()
+    eng, blocks, pipe = _streamed(n_blocks=24, depth=4, commit_delay=0.02)
+    t_run = time.monotonic()
+    rep = pipe.run()
+    t1 = time.monotonic()
+    assert eng.root == blocks[-1].header.root
+    by_role = {a.role: a.row()
+               for a in obs.accounts_between(t0, t1, role=None)}
+    assert set(by_role) == {"replay", "feed", "prefetch"}
+    assert obs.accounts_between(t0, t1) == [eng.account]
+    feed, pre = by_role["feed"], by_role["prefetch"]
+    assert feed["thread"] == "serve-feed"
+    assert pre["thread"] == "serve-prefetch"
+    for row in (feed, pre):
+        assert sum(row["self_s"].values()) == pytest.approx(
+            row["t_last"] - row["t_open"], rel=1e-9)
+        assert t_run < row["t_open"] < row["t_last"] <= t1
+    # each thread's CPU seconds, marked once a window's worth of blocks
+    for row in (feed, pre, by_role["replay"]):
+        assert 0 < sum(row["cpu_s"].values()) \
+            <= sum(row["self_s"].values()) + 1e-3
+    assert {k for k, v in feed["cpu_s"].items() if v} == {"loop"}
+    assert {k for k, v in pre["cpu_s"].items() if v} \
+        <= {"prefetch/touch_code"}
+    # the feed: two intervals a block by clock pairs, moved over
+    assert set(feed["n"]) == {"idle", "loop", "feed/source", "feed/put"}
+    assert feed["n"]["feed/put"] == len(blocks)
+    assert feed["n"]["feed/source"] == len(blocks) + 1  # + exhausted
+    assert feed["n"]["loop"] == 1
+    # the prefetch thread: phases a CHUNK, sender/* inside touch_code
+    chunks = pre["n"]["prefetch/touch_code"]
+    assert 0 < chunks <= len(blocks)
+    assert pre["n"]["prefetch/put"] == chunks
+    assert pre["n"]["sender/native"] == pre["n"]["sender/pack"] \
+        == pre["n"]["sender/apply"] <= chunks
+    assert "sender/native" not in rep.account["n"]
+    # the report's numbers are the accounts'
+    bp, pf = rep.backpressure, rep.prefetch
+    assert bp["feed_blocked_s"] == round(feed["self_s"]["feed/put"], 3)
+    assert bp["feed_blocked_s"] > 0    # a slow commit parks the feed
+    assert bp["prefetch_blocked_s"] \
+        == round(pre["self_s"]["prefetch/put"], 3)
+    warm = sum(v for k, v in pre["self_s"].items()
+               if k.startswith("sender/") or k == "prefetch/touch_code")
+    assert pf["overlap_s"] == rep.stages_s["prefetch"] == round(warm, 3)
+    # armed, the chunk phases are in the ring under the threads' own
+    # rows; the per-block intervals of the feed are not events at all
+    doc = tr.export()["traceEvents"]
+    rows = {e["tid"]: e["args"]["name"] for e in doc
+            if e["ph"] == "M" and e["name"] == "thread_name"}
+    where = {}
+    for e in doc:
+        if e["ph"] == "X":
+            where.setdefault(e["name"], set()).add(rows[e["tid"]])
+    assert where["prefetch/touch_code"] == {"serve-prefetch"}
+    assert where["sender/native"] == {"serve-prefetch"}
+    assert where["stream/wait"] == {threading.current_thread().name}
+    assert "feed/source" not in where and "feed/put" not in where
 
 
 # ------------------------------------------------- scopes on the kernels
