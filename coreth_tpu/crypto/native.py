@@ -96,9 +96,13 @@ def load():
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_char_p]
     lib.coreth_keccak256_batch.restype = None
-    lib.coreth_test_fe_mul.argtypes = [
-        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p]
-    lib.coreth_test_fe_mul.restype = None
+    lib.coreth_test_fe_op.argtypes = [
+        ctypes.c_int, ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_uint64)]
+    lib.coreth_test_fe_op.restype = None
+    lib.coreth_test_sc_inv.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+    lib.coreth_test_sc_inv.restype = None
     # test-only symbol compiled ONLY into the sanitized build (`make
     # sanitize`) — proves the ASan trap actually fires
     if hasattr(lib, "coreth_sanitize_smoke"):
@@ -139,7 +143,10 @@ def recover_address_native(msg_hash: bytes, r: int, s: int, recid: int) -> bytes
 
 def recover_addresses_batch(hashes: bytes, rs: bytes, ss: bytes,
                             recids: bytes):
-    """Batched recovery over packed buffers.  Returns (addresses, ok) bytes."""
+    """Batched recovery over packed buffers.  Returns (addresses, ok)
+    bytes: ``ok[i]`` 1, or 2 where the batch's sequential fallback
+    recovered what its fast path could not (ReplayStats.sigs_slow_path),
+    0 for an invalid signature."""
     n = len(recids)
     out = ctypes.create_string_buffer(20 * n)
     ok = ctypes.create_string_buffer(n)
@@ -153,10 +160,10 @@ def recover_senders_wire(wire: bytes, offsets, chain_id: int):
     (legacy RLP list, or type byte 1 / 2 and its list).  The native walk
     derives signing hash, r, s and recovery id by the rules of
     ``LatestSigner(chain_id)`` and feeds the batch above.  Returns
-    (addresses, ok) bytes; ``ok[i] == 0`` leaves transaction i to
-    ``signer.sender``: malformed or truncated bytes, an offset outside
-    ``wire``, a foreign chain id, high s, recovery id past 1, r or s
-    out of range."""
+    (addresses, ok) bytes, ``ok`` as above; ``ok[i] == 0`` leaves
+    transaction i to ``signer.sender``: malformed or truncated bytes, an
+    offset outside ``wire``, a foreign chain id, high s, recovery id
+    past 1, r or s out of range."""
     n = len(offsets) - 1
     if n < 0:
         raise ValueError("offsets must hold at least [0]")

@@ -209,6 +209,11 @@ class ReplayStats:
     # past 1, r or s out of range): signer.sender's per-transaction
     # path decided them.  0 on every chain the benchmark replays
     sigs_left_to_signer: int = 0
+    # lanes the batch's fast path could not finish and its sequential
+    # fallback (coreth_ecrecover) recovered instead (ok = 2): correct,
+    # at 3-30x the cost, and a fault of the arithmetic under the ladder
+    # that would otherwise pass silently.  0 on every valid chain
+    sigs_slow_path: int = 0
     # batched recoveries that raised (packing, the worker's batch, its
     # result): their txs fell to per-tx recovery in signer.sender —
     # correct, but not the path the counters above describe
@@ -1198,8 +1203,11 @@ class ReplayEngine:
         """Prime the sender caches the native batch vouches for.  A lane
         it answered ``ok = 0`` (the refusals crypto/native.
         recover_senders_wire lists) stays uncached: signer.sender's
-        per-transaction path decides it, as it did before."""
+        per-transaction path decides it, as it did before.  A lane it
+        answered ``ok = 2`` came from the batch's sequential fallback:
+        primed like any other, and counted."""
         self.stats.sigs_left_to_signer += ok.count(0)
+        self.stats.sigs_slow_path += ok.count(2)
         for i, tx in enumerate(todo):
             if ok[i]:
                 tx.set_sender(out[20 * i:20 * i + 20])

@@ -143,7 +143,8 @@ class StreamReport:
     # blocks_order_dependent: device blocks only the in-order solvency
     # rule could commit (a sender funded earlier in the same block);
     # sigs_left_to_signer: lanes of the native sender batch it did not
-    # vouch for, so signer.sender's per-tx path decided them
+    # vouch for, so signer.sender's per-tx path decided them;
+    # sigs_slow_path: lanes its sequential fallback recovered (ok = 2)
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -792,7 +793,8 @@ class StreamingPipeline:
                 "window_uploads": st.window_uploads,
                 "window_upload_bytes": st.window_upload_bytes,
                 "blocks_order_dependent": st.blocks_order_dependent,
-                "sigs_left_to_signer": st.sigs_left_to_signer}
+                "sigs_left_to_signer": st.sigs_left_to_signer,
+                "sigs_slow_path": st.sigs_slow_path}
 
     @staticmethod
     def _thread_seconds(acct) -> dict:

@@ -2,14 +2,18 @@
 //
 // Role parity with the reference's cgo libsecp256k1 binding (geth
 // crypto/secp256k1), which coreth drives in parallel for every block via
-// core/sender_cacher.go.  This implementation: 4x64-bit limbs with __int128
-// products, fast reduction mod p = 2^256 - 0x1000003D1, Jacobian points,
-// Shamir double-scalar multiplication for u1*G + u2*R, Fermat inversion.
-// Keccak for the address derivation comes from keccak.cc.
+// core/sender_cacher.go.  This implementation: field elements in 5x52-bit
+// limbs with lazy reduction (the design of libsecp256k1's
+// field_5x52_int128), scalars mod n in 4x64-bit limbs, Jacobian points,
+// inversions mod p and mod n by variable-time safegcd (Bernstein-Yang
+// divsteps, as libsecp256k1's modinv64_var).  Keccak for the address
+// derivation comes from keccak.cc.
 //
 // Correctness is anchored by the test suite: cross-checked against the
 // pure-Python implementation, which is itself anchored by the well-known
-// privkey=1 -> 0x7E5F4552091A69125d5DfCb7b8C2659029395Bdf vector.
+// privkey=1 -> 0x7E5F4552091A69125d5DfCb7b8C2659029395Bdf vector; the
+// field and scalar arithmetic is held to Python integers limb by limb
+// (tests/test_secp_field.py).
 
 #include <cstdint>
 #include <cstdlib>
@@ -25,18 +29,17 @@ extern "C" int coreth_ecrecover(const uint8_t*, const uint8_t*,
 namespace {
 
 typedef unsigned __int128 u128;
+typedef __int128 i128;
 
 struct U256 {
   uint64_t v[4];  // little-endian limbs
 };
 
-const U256 ZERO = {{0, 0, 0, 0}};
 const U256 ONE = {{1, 0, 0, 0}};
 
 // p = 2^256 - 2^32 - 977
 const U256 PRIME = {{0xFFFFFFFEFFFFFC2FULL, 0xFFFFFFFFFFFFFFFFULL,
                      0xFFFFFFFFFFFFFFFFULL, 0xFFFFFFFFFFFFFFFFULL}};
-const uint64_t P_C = 0x1000003D1ULL;  // 2^256 - p
 
 // group order n
 const U256 ORDER = {{0xBFD25E8CD0364141ULL, 0xBAAEDCE6AF48A03BULL,
@@ -101,86 +104,405 @@ inline void mod_sub(U256& r, const U256& a, const U256& b, const U256& m) {
   }
 }
 
-// ---- field arithmetic mod p (fast reduction using p = 2^256 - P_C) ----
+// ---- field elements mod p: 5x52-bit limbs, lazy reduction ----
+//
+// x = n[0] + n[1] 2^52 + n[2] 2^104 + n[3] 2^156 + n[4] 2^208.  A value
+// of MAGNITUDE m has n[0..3] <= 2m(2^52 - 1) and n[4] <= 2m(2^48 - 1):
+// any representative of its residue, not necessarily below p.  The
+// magnitudes are tracked by the code's structure (the "(m)" beside each
+// step), never at run time:
+//   fe_mul, fe_sqr      inputs <= 8, result 1
+//   fe_add              magnitudes add
+//   fe_negate(a, m)     a <= m, result m + 1
+//   fe_mul_int(a, k)    magnitude times k
+//   fe_normalize_weak   input <= 32, result 1
+//   fe_normalize        result below p: the only form that may be
+//                       serialized, tested for zero or have its parity
+//                       read (fe_normalizes_to_zero / fe_equal test a
+//                       residue without it)
+// Adds, negations and small multiples carry nothing and compare nothing:
+// a point formula pays one weak normalization where its sums would pass
+// 8.  A Point's coordinates are <= 4 between point operations, an
+// APoint's <= 2.
 
-void fe_mul(U256& r, const U256& a, const U256& b) {
-  uint64_t w[8] = {0};
+struct Fe {
+  uint64_t n[5];
+};
+
+const uint64_t M52 = 0xFFFFFFFFFFFFFULL;
+const uint64_t M48 = 0xFFFFFFFFFFFFULL;
+const uint64_t P_C = 0x1000003D1ULL;  // 2^256 - p
+
+const Fe FE_ONE = {{1, 0, 0, 0, 0}};
+const Fe FE_SEVEN = {{7, 0, 0, 0, 0}};
+
+// any 256-bit value (magnitude 1)
+inline void fe_from_u256(Fe& r, const U256& a) {
+  r.n[0] = a.v[0] & M52;
+  r.n[1] = (a.v[0] >> 52 | a.v[1] << 12) & M52;
+  r.n[2] = (a.v[1] >> 40 | a.v[2] << 24) & M52;
+  r.n[3] = (a.v[2] >> 28 | a.v[3] << 36) & M52;
+  r.n[4] = a.v[3] >> 16;
+}
+
+// a normalized
+inline void fe_to_u256(U256& r, const Fe& a) {
+  r.v[0] = a.n[0] | a.n[1] << 52;
+  r.v[1] = a.n[1] >> 12 | a.n[2] << 40;
+  r.v[2] = a.n[2] >> 24 | a.n[3] << 28;
+  r.v[3] = a.n[3] >> 36 | a.n[4] << 16;
+}
+
+Fe fe_const(const U256& a) {
+  Fe r;
+  fe_from_u256(r, a);
+  return r;
+}
+
+inline void fe_add(Fe& r, const Fe& a) {
+  for (int i = 0; i < 5; ++i) r.n[i] += a.n[i];
+}
+
+// r = 2(m + 1) p - a
+inline void fe_negate(Fe& r, const Fe& a, int m) {
+  const uint64_t k = 2 * (uint64_t)(m + 1);
+  r.n[0] = 0xFFFFEFFFFFC2FULL * k - a.n[0];
+  r.n[1] = M52 * k - a.n[1];
+  r.n[2] = M52 * k - a.n[2];
+  r.n[3] = M52 * k - a.n[3];
+  r.n[4] = M48 * k - a.n[4];
+}
+
+inline void fe_mul_int(Fe& r, int k) {
+  for (int i = 0; i < 5; ++i) r.n[i] *= (uint64_t)k;
+}
+
+// magnitude 1: every limb carried, bit 256 folded back once
+inline void fe_normalize_weak(Fe& r) {
+  uint64_t t0 = r.n[0], t1 = r.n[1], t2 = r.n[2], t3 = r.n[3], t4 = r.n[4];
+  const uint64_t x = t4 >> 48;
+  t4 &= M48;
+  t0 += x * P_C;
+  t1 += t0 >> 52; t0 &= M52;
+  t2 += t1 >> 52; t1 &= M52;
+  t3 += t2 >> 52; t2 &= M52;
+  t4 += t3 >> 52; t3 &= M52;
+  r = {{t0, t1, t2, t3, t4}};
+}
+
+// fully reduced: below p
+void fe_normalize(Fe& r) {
+  uint64_t t0 = r.n[0], t1 = r.n[1], t2 = r.n[2], t3 = r.n[3], t4 = r.n[4];
+  uint64_t x = t4 >> 48;
+  t4 &= M48;
+  t0 += x * P_C;
+  t1 += t0 >> 52; t0 &= M52;
+  t2 += t1 >> 52; t1 &= M52; uint64_t m = t1;
+  t3 += t2 >> 52; t2 &= M52; m &= t2;
+  t4 += t3 >> 52; t3 &= M52; m &= t3;
+  // at most one subtraction of p is left: at bit 256, or at p itself
+  x = (t4 >> 48) |
+      ((t4 == M48) & (m == M52) & (t0 >= 0xFFFFEFFFFFC2FULL));
+  t0 += x * P_C;
+  t1 += t0 >> 52; t0 &= M52;
+  t2 += t1 >> 52; t1 &= M52;
+  t3 += t2 >> 52; t2 &= M52;
+  t4 += t3 >> 52; t3 &= M52;
+  t4 &= M48;
+  r = {{t0, t1, t2, t3, t4}};
+}
+
+// a == 0 (mod p), for a <= 32: decided by the low limb in almost every
+// call (variable time; see fe_inv for why that is sound here)
+bool fe_normalizes_to_zero(const Fe& a) {
+  uint64_t t0 = a.n[0], t1 = a.n[1], t2 = a.n[2], t3 = a.n[3], t4 = a.n[4];
+  const uint64_t x = t4 >> 48;
+  t0 += x * P_C;
+  // z0 tracks a raw value of 0, z1 a raw value of p
+  uint64_t z0 = t0 & M52, z1 = z0 ^ 0x1000003D0ULL;
+  if (z0 != 0 && z1 != M52) return false;
+  t4 &= M48;
+  t1 += t0 >> 52; t0 &= M52; z0 = t0; z1 = t0 ^ 0x1000003D0ULL;
+  t2 += t1 >> 52; t1 &= M52; z0 |= t1; z1 &= t1;
+  t3 += t2 >> 52; t2 &= M52; z0 |= t2; z1 &= t2;
+  t4 += t3 >> 52; t3 &= M52; z0 |= t3; z1 &= t3;
+  z0 |= t4; z1 &= t4 ^ 0xF000000000000ULL;
+  return z0 == 0 || z1 == M52;
+}
+
+// a == b (mod p), for b <= 30
+inline bool fe_equal(const Fe& a, const Fe& b, int mb) {
+  Fe d;
+  fe_negate(d, b, mb);
+  fe_add(d, a);
+  return fe_normalizes_to_zero(d);
+}
+
+// r = a * b: the 5x52 product with the fold 2^260 == R (mod p) done
+// while the columns are summed (libsecp256k1 field_5x52_int128).
+// "[... c b a]" reads a + b 2^52 + c 2^104 + ...; px is column x of the
+// product.  Inputs <= 8 (limbs < 2^56), result magnitude 1; r may alias
+// a or b.
+void fe_mul(Fe& r, const Fe& a, const Fe& b) {
+  const uint64_t a0 = a.n[0], a1 = a.n[1], a2 = a.n[2], a3 = a.n[3],
+                 a4 = a.n[4];
+  const uint64_t b0 = b.n[0], b1 = b.n[1], b2 = b.n[2], b3 = b.n[3],
+                 b4 = b.n[4];
+  const uint64_t R = 0x1000003D10ULL;  // 2^260 mod p
+  u128 c, d;
+  uint64_t t3, t4, tx, u0;
+  d = (u128)a0 * b3 + (u128)a1 * b2 + (u128)a2 * b1 + (u128)a3 * b0;
+  c = (u128)a4 * b4;                      // [c 0 0 0 0 d 0 0 0] = [p8 .. p3 ..]
+  d += (u128)R * (uint64_t)c; c >>= 64;   // p8's low word into column 3
+  t3 = (uint64_t)d & M52; d >>= 52;
+  d += (u128)a0 * b4 + (u128)a1 * b3 + (u128)a2 * b2 + (u128)a3 * b1 +
+       (u128)a4 * b0;
+  d += (u128)(R << 12) * (uint64_t)c;     // p8's high word into column 4
+  t4 = (uint64_t)d & M52; d >>= 52;
+  tx = t4 >> 48; t4 &= M48;               // bit 256 of column 4 apart
+  c = (u128)a0 * b0;
+  d += (u128)a1 * b4 + (u128)a2 * b3 + (u128)a3 * b2 + (u128)a4 * b1;
+  u0 = (uint64_t)d & M52; d >>= 52;       // column 5
+  u0 = (u0 << 4) | tx;                    // with bit 256: a multiple of 2^256
+  c += (u128)u0 * (R >> 4);
+  r.n[0] = (uint64_t)c & M52; c >>= 52;
+  c += (u128)a0 * b1 + (u128)a1 * b0;
+  d += (u128)a2 * b4 + (u128)a3 * b3 + (u128)a4 * b2;
+  c += (u128)((uint64_t)d & M52) * R; d >>= 52;  // column 6 into 1
+  r.n[1] = (uint64_t)c & M52; c >>= 52;
+  c += (u128)a0 * b2 + (u128)a1 * b1 + (u128)a2 * b0;
+  d += (u128)a3 * b4 + (u128)a4 * b3;
+  c += (u128)R * (uint64_t)d; d >>= 64;   // column 7's low word into 2
+  r.n[2] = (uint64_t)c & M52; c >>= 52;
+  c += (u128)(R << 12) * (uint64_t)d + t3;  // and its high word into 3
+  r.n[3] = (uint64_t)c & M52; c >>= 52;
+  c += t4;
+  r.n[4] = (uint64_t)c;
+}
+
+// r = a^2, the product above with its symmetric terms doubled once
+void fe_sqr(Fe& r, const Fe& a) {
+  uint64_t a0 = a.n[0], a1 = a.n[1], a2 = a.n[2], a3 = a.n[3], a4 = a.n[4];
+  const uint64_t R = 0x1000003D10ULL;
+  u128 c, d;
+  uint64_t t3, t4, tx, u0;
+  d = (u128)(a0 * 2) * a3 + (u128)(a1 * 2) * a2;
+  c = (u128)a4 * a4;
+  d += (u128)R * (uint64_t)c; c >>= 64;
+  t3 = (uint64_t)d & M52; d >>= 52;
+  a4 *= 2;
+  d += (u128)a0 * a4 + (u128)(a1 * 2) * a3 + (u128)a2 * a2;
+  d += (u128)(R << 12) * (uint64_t)c;
+  t4 = (uint64_t)d & M52; d >>= 52;
+  tx = t4 >> 48; t4 &= M48;
+  c = (u128)a0 * a0;
+  d += (u128)a1 * a4 + (u128)(a2 * 2) * a3;
+  u0 = (uint64_t)d & M52; d >>= 52;
+  u0 = (u0 << 4) | tx;
+  c += (u128)u0 * (R >> 4);
+  r.n[0] = (uint64_t)c & M52; c >>= 52;
+  a0 *= 2;
+  c += (u128)a0 * a1;
+  d += (u128)a2 * a4 + (u128)a3 * a3;
+  c += (u128)((uint64_t)d & M52) * R; d >>= 52;
+  r.n[1] = (uint64_t)c & M52; c >>= 52;
+  c += (u128)a0 * a2 + (u128)a1 * a1;
+  d += (u128)a3 * a4;
+  c += (u128)R * (uint64_t)d; d >>= 64;
+  r.n[2] = (uint64_t)c & M52; c >>= 52;
+  c += (u128)(R << 12) * (uint64_t)d + t3;
+  r.n[3] = (uint64_t)c & M52; c >>= 52;
+  c += t4;
+  r.n[4] = (uint64_t)c;
+}
+
+// ---- modular inversion by safegcd, variable time ----
+//
+// Bernstein & Yang, "Fast constant-time gcd computation and modular
+// inversion" (2019), in the variable-time form of libsecp256k1's
+// secp256k1_modinv64_var: divsteps in batches of 62 on the low words of
+// f and g, each batch's 2x2 transition matrix then applied to the full
+// f, g (shrinking them by 62 bits) and to d, e (kept mod the modulus
+// with a multiple chosen to clear their low 62 bits).  Numbers are five
+// signed 62-bit limbs.  ~12 batches for a 256-bit input against ~500
+// multiplies for Fermat's a^(m-2).
+//
+// Variable time is sound here: recovery runs on public data alone (the
+// signature and the signed hash), never on a key.
+
+struct S62 {
+  int64_t v[5];  // sum v[i] 2^(62 i)
+};
+
+struct ModInfo {
+  S62 m;           // the modulus, limbs in (-2^62, 2^62)
+  uint64_t inv62;  // m^-1 mod 2^62
+};
+
+const ModInfo P_INFO = {{{-0x1000003D1LL, 0, 0, 0, 256}},
+                        0x27C7F6E22DDACACFULL};
+const ModInfo N_INFO = {{{-0x2DA1732FC9BEBFLL, -0x15448C6542DD7F11LL,
+                          -0x14LL, 0, 256}},
+                        0x34F20099AA774EC1ULL};
+
+const uint64_t M62 = UINT64_MAX >> 2;
+
+struct Trans {
+  int64_t u, v, q, r;
+};
+
+// 62 divsteps on the low words f0 (odd) and g0; returns the new eta
+// (-delta).  t maps [f, g] to 2^62 [f', g'].
+int64_t divsteps_62_var(int64_t eta, uint64_t f0, uint64_t g0, Trans& t) {
+  uint64_t u = 1, v = 0, q = 0, r = 1, f = f0, g = g0, m;
+  uint32_t w;
+  int i = 62, limit, zeros;
+  for (;;) {
+    // the zero bits of g all at once, a sentinel stopping at i
+    zeros = __builtin_ctzll(g | (UINT64_MAX << i));
+    g >>= zeros;
+    u <<= zeros;
+    v <<= zeros;
+    eta -= zeros;
+    i -= zeros;
+    if (i == 0) break;
+    // f and g odd here
+    if (eta < 0) {  // swap to (g, -f)
+      uint64_t tmp;
+      eta = -eta;
+      tmp = f; f = g; g = -tmp;
+      tmp = u; u = q; q = -tmp;
+      tmp = v; v = r; r = -tmp;
+      // cancel up to 6 bits of g, no more than i nor eta + 1 of them
+      limit = ((int)eta + 1) > i ? i : ((int)eta + 1);
+      m = (UINT64_MAX >> (64 - limit)) & 63U;
+      w = (uint32_t)((f * g * (f * f - 2)) & m);  // -g/f mod 2^6
+    } else {
+      // eta tends to be small here: up to 4 bits
+      limit = ((int)eta + 1) > i ? i : ((int)eta + 1);
+      m = (UINT64_MAX >> (64 - limit)) & 15U;
+      w = (uint32_t)(f + (((f + 1) & 4) << 1));  // 1/f mod 2^4
+      w = (uint32_t)((-(uint64_t)w * g) & m);
+    }
+    g += f * w;
+    q += u * w;
+    r += v * w;
+  }
+  t.u = (int64_t)u;
+  t.v = (int64_t)v;
+  t.q = (int64_t)q;
+  t.r = (int64_t)r;
+  return eta;
+}
+
+// [d, e] = t [d, e] / 2^62 (mod m), d and e kept in (-2m, m)
+void update_de_62(S62& d, S62& e, const Trans& t, const ModInfo& mi) {
+  const int64_t u = t.u, v = t.v, q = t.q, r = t.r;
+  // start from [u, q] if d < 0 and [v, r] if e < 0 (keeps the range),
+  // then the multiple of m that clears the low 62 bits
+  const int64_t sd = d.v[4] >> 63, se = e.v[4] >> 63;
+  int64_t md = (u & sd) + (v & se);
+  int64_t me = (q & sd) + (r & se);
+  i128 cd = (i128)u * d.v[0] + (i128)v * e.v[0];
+  i128 ce = (i128)q * d.v[0] + (i128)r * e.v[0];
+  md -= (int64_t)((mi.inv62 * (uint64_t)cd + (uint64_t)md) & M62);
+  me -= (int64_t)((mi.inv62 * (uint64_t)ce + (uint64_t)me) & M62);
+  cd += (i128)mi.m.v[0] * md;
+  ce += (i128)mi.m.v[0] * me;
+  cd >>= 62;
+  ce >>= 62;
+  for (int i = 1; i < 5; ++i) {
+    cd += (i128)u * d.v[i] + (i128)v * e.v[i] + (i128)mi.m.v[i] * md;
+    ce += (i128)q * d.v[i] + (i128)r * e.v[i] + (i128)mi.m.v[i] * me;
+    d.v[i - 1] = (int64_t)((uint64_t)cd & M62); cd >>= 62;
+    e.v[i - 1] = (int64_t)((uint64_t)ce & M62); ce >>= 62;
+  }
+  d.v[4] = (int64_t)cd;
+  e.v[4] = (int64_t)ce;
+}
+
+// [f, g] = t [f, g] / 2^62 over their len low limbs
+void update_fg_62_var(int len, S62& f, S62& g, const Trans& t) {
+  const int64_t u = t.u, v = t.v, q = t.q, r = t.r;
+  i128 cf = (i128)u * f.v[0] + (i128)v * g.v[0];
+  i128 cg = (i128)q * f.v[0] + (i128)r * g.v[0];
+  cf >>= 62;  // the low 62 bits are zero by construction
+  cg >>= 62;
+  for (int i = 1; i < len; ++i) {
+    cf += (i128)u * f.v[i] + (i128)v * g.v[i];
+    cg += (i128)q * f.v[i] + (i128)r * g.v[i];
+    f.v[i - 1] = (int64_t)((uint64_t)cf & M62); cf >>= 62;
+    g.v[i - 1] = (int64_t)((uint64_t)cg & M62); cg >>= 62;
+  }
+  f.v[len - 1] = (int64_t)cf;
+  g.v[len - 1] = (int64_t)cg;
+}
+
+// r in (-2m, m), negated when sign < 0, into [0, m)
+void normalize_62(S62& r, int64_t sign, const ModInfo& mi) {
+  const int64_t M = (int64_t)M62;
+  int64_t cond = r.v[4] >> 63;
+  for (int i = 0; i < 5; ++i) r.v[i] += mi.m.v[i] & cond;
+  cond = sign >> 63;
+  for (int i = 0; i < 5; ++i) r.v[i] = (r.v[i] ^ cond) - cond;
   for (int i = 0; i < 4; ++i) {
-    u128 carry = 0;
-    for (int j = 0; j < 4; ++j) {
-      u128 cur = (u128)a.v[i] * b.v[j] + w[i + j] + carry;
-      w[i + j] = (uint64_t)cur;
-      carry = cur >> 64;
-    }
-    w[i + 4] += (uint64_t)carry;
+    r.v[i + 1] += r.v[i] >> 62;
+    r.v[i] &= M;
   }
-  // fold hi*2^256 -> hi*P_C twice
-  U256 lo = {{w[0], w[1], w[2], w[3]}};
-  U256 hi = {{w[4], w[5], w[6], w[7]}};
-  // acc = lo + hi * P_C  (result fits in 256 + ~33 bits)
-  uint64_t w2[5] = {0};
-  {
-    u128 carry = 0;
-    for (int j = 0; j < 4; ++j) {
-      u128 cur = (u128)hi.v[j] * P_C + lo.v[j] + carry;
-      w2[j] = (uint64_t)cur;
-      carry = cur >> 64;
-    }
-    w2[4] = (uint64_t)carry;
+  cond = r.v[4] >> 63;
+  for (int i = 0; i < 5; ++i) r.v[i] += mi.m.v[i] & cond;
+  for (int i = 0; i < 4; ++i) {
+    r.v[i + 1] += r.v[i] >> 62;
+    r.v[i] &= M;
   }
-  // fold again: w2[4] * P_C.  A carry can still ripple out of limb 3
-  // (acc + w2[4]*P_C may reach 2^256); the dropped 2^256 == P_C (mod p),
-  // so a third conditional fold is required.
-  U256 acc = {{w2[0], w2[1], w2[2], w2[3]}};
-  {
-    u128 cur = (u128)w2[4] * P_C + acc.v[0];
-    acc.v[0] = (uint64_t)cur;
-    uint64_t carry = (uint64_t)(cur >> 64);
-    for (int j = 1; j < 4; ++j) {
-      u128 c2 = (u128)acc.v[j] + carry;
-      acc.v[j] = (uint64_t)c2;
-      carry = (uint64_t)(c2 >> 64);
-    }
-    if (carry) {  // acc wrapped to a tiny value; adding P_C cannot overflow
-      u128 c3 = (u128)acc.v[0] + P_C;
-      acc.v[0] = (uint64_t)c3;
-      uint64_t c = (uint64_t)(c3 >> 64);
-      for (int j = 1; j < 4 && c; ++j) {
-        u128 c4 = (u128)acc.v[j] + c;
-        acc.v[j] = (uint64_t)c4;
-        c = (uint64_t)(c4 >> 64);
-      }
-    }
-  }
-  while (cmp(acc, PRIME) >= 0) {
-    U256 t;
-    sub_raw(t, acc, PRIME);
-    acc = t;
-  }
-  r = acc;
 }
 
-inline void fe_sqr(U256& r, const U256& a) { fe_mul(r, a, a); }
-
-void fe_pow(U256& r, const U256& a, const U256& e) {
-  U256 acc = ONE, base = a;
-  for (int i = 0; i < 256; ++i) {
-    if ((e.v[i / 64] >> (i % 64)) & 1) {
-      U256 t;
-      fe_mul(t, acc, base);
-      acc = t;
+// a^-1 mod m for 0 <= a < m (0 -> 0)
+void modinv_var(U256& r, const U256& a, const ModInfo& mi) {
+  S62 d = {{0, 0, 0, 0, 0}}, e = {{1, 0, 0, 0, 0}}, f = mi.m;
+  S62 g = {{(int64_t)(a.v[0] & M62),
+            (int64_t)((a.v[0] >> 62 | a.v[1] << 2) & M62),
+            (int64_t)((a.v[1] >> 60 | a.v[2] << 4) & M62),
+            (int64_t)((a.v[2] >> 58 | a.v[3] << 6) & M62),
+            (int64_t)(a.v[3] >> 56)}};
+  int len = 5;
+  int64_t eta = -1;  // -delta, delta starting at 1
+  for (;;) {
+    Trans t;
+    eta = divsteps_62_var(eta, (uint64_t)f.v[0], (uint64_t)g.v[0], t);
+    update_de_62(d, e, t, mi);
+    update_fg_62_var(len, f, g, t);
+    if (g.v[0] == 0) {
+      int64_t cond = 0;
+      for (int j = 1; j < len; ++j) cond |= g.v[j];
+      if (cond == 0) break;  // g = 0: f = +-1, d = +-a^-1
     }
-    U256 t;
-    fe_sqr(t, base);
-    base = t;
+    // drop the top limb of f and g once both are 0 or -1
+    const int64_t fn = f.v[len - 1], gn = g.v[len - 1];
+    int64_t cond = ((int64_t)len - 2) >> 63;
+    cond |= fn ^ (fn >> 63);
+    cond |= gn ^ (gn >> 63);
+    if (cond == 0) {
+      f.v[len - 2] |= (int64_t)((uint64_t)fn << 62);
+      g.v[len - 2] |= (int64_t)((uint64_t)gn << 62);
+      --len;
+    }
   }
-  r = acc;
+  normalize_62(d, f.v[len - 1], mi);
+  r.v[0] = (uint64_t)d.v[0] | (uint64_t)d.v[1] << 62;
+  r.v[1] = (uint64_t)d.v[1] >> 2 | (uint64_t)d.v[2] << 60;
+  r.v[2] = (uint64_t)d.v[2] >> 4 | (uint64_t)d.v[3] << 58;
+  r.v[3] = (uint64_t)d.v[3] >> 6 | (uint64_t)d.v[4] << 56;
 }
 
-void fe_inv(U256& r, const U256& a) {
-  U256 e;
-  sub_raw(e, PRIME, {{2, 0, 0, 0}});
-  fe_pow(r, a, e);
+// r = a^-1 mod p (a <= 8; a == 0 -> 0), magnitude 1
+void fe_inv(Fe& r, const Fe& a) {
+  Fe t = a;
+  fe_normalize(t);
+  U256 x;
+  fe_to_u256(x, t);
+  modinv_var(x, x, P_INFO);
+  fe_from_u256(r, x);
 }
 
 // ---- scalar arithmetic mod n ----
@@ -255,75 +577,94 @@ void sc_reduce_wide(U256& r, const uint64_t w[8]) {
   r = t;
 }
 
-void sc_mul(U256& r, const U256& a, const U256& b, const U256& /*m*/) {
+void sc_mul(U256& r, const U256& a, const U256& b) {
   uint64_t w[8];
   mul_wide(w, a, b);
   sc_reduce_wide(r, w);
 }
 
-void sc_pow(U256& r, const U256& a, const U256& e, const U256& m) {
-  U256 acc = ONE, base = a;
-  for (int i = 0; i < 256; ++i) {
-    if ((e.v[i / 64] >> (i % 64)) & 1) {
-      U256 t;
-      sc_mul(t, acc, base, m);
-      acc = t;
-    }
-    U256 t;
-    sc_mul(t, base, base, m);
-    base = t;
-  }
-  r = acc;
-}
+// r = a^-1 mod n for 0 <= a < n
+inline void sc_inv(U256& r, const U256& a) { modinv_var(r, a, N_INFO); }
 
-void sc_inv(U256& r, const U256& a) {
-  U256 e;
-  sub_raw(e, ORDER, {{2, 0, 0, 0}});
-  sc_pow(r, a, e, ORDER);
-}
-
-// ---- Jacobian point arithmetic over fe ----
+// ---- Jacobian point arithmetic over Fe ----
+//
+// The formulas of the Python twin (crypto/secp256k1._jac_double /
+// _jac_add), over lazily reduced coordinates.  Every function may write
+// its result over an input.
 
 struct Point {
-  U256 x, y, z;  // z == 0 => infinity
+  Fe x, y, z;  // z == 0 (mod p) => infinity; coordinates <= 4
 };
 
-inline bool pt_is_inf(const Point& p) { return is_zero(p.z); }
+struct APoint {
+  Fe x, y;  // <= 2
+  bool inf;
+};
 
+const Point INF = {{{0, 0, 0, 0, 0}}, FE_ONE, {{0, 0, 0, 0, 0}}};
+
+inline bool pt_is_inf(const Point& p) { return fe_normalizes_to_zero(p.z); }
+
+// 2P.  The group has odd prime order, so no point has y == 0: infinity
+// is the only special case.  Out: X 1, Y 1, Z 2.
 void pt_double(Point& r, const Point& p) {
-  if (pt_is_inf(p) || is_zero(p.y)) {
-    r = {ZERO, ONE, ZERO};
+  if (pt_is_inf(p)) {
+    r = INF;
     return;
   }
-  U256 ysq, s, m, t;
-  fe_sqr(ysq, p.y);
+  Fe ysq, s, m, t, nx, ny;
+  fe_sqr(ysq, p.y);       // y^2 (1)
   fe_mul(s, p.x, ysq);
-  mod_add(s, s, s, PRIME);
-  mod_add(s, s, s, PRIME);  // s = 4*x*y^2
+  fe_mul_int(s, 4);       // s = 4 x y^2 (4)
   fe_sqr(m, p.x);
-  U256 m3;
-  mod_add(m3, m, m, PRIME);
-  mod_add(m, m3, m, PRIME);  // m = 3*x^2
-  U256 nx;
-  fe_sqr(nx, m);
-  mod_sub(nx, nx, s, PRIME);
-  mod_sub(nx, nx, s, PRIME);
-  U256 ysq2, y4;
-  fe_sqr(ysq2, ysq);  // y^4
-  // 8*y^4
-  mod_add(y4, ysq2, ysq2, PRIME);
-  mod_add(y4, y4, y4, PRIME);
-  mod_add(y4, y4, y4, PRIME);
-  U256 ny;
-  mod_sub(t, s, nx, PRIME);
-  fe_mul(ny, m, t);
-  mod_sub(ny, ny, y4, PRIME);
-  U256 nz;
-  fe_mul(nz, p.y, p.z);
-  mod_add(nz, nz, nz, PRIME);
+  fe_mul_int(m, 3);       // m = 3 x^2 (3)
+  fe_sqr(nx, m);          // (1)
+  fe_negate(t, s, 4);     // (5)
+  fe_add(nx, t);
+  fe_add(nx, t);          // nx = m^2 - 2 s (11)
+  fe_normalize_weak(nx);  // (1)
+  fe_negate(t, nx, 1);
+  fe_add(t, s);           // s - nx (6)
+  fe_mul(ny, m, t);       // (1)
+  fe_sqr(t, ysq);
+  fe_mul_int(t, 8);       // 8 y^4 (8)
+  fe_negate(t, t, 8);     // (9)
+  fe_add(ny, t);          // ny = m (s - nx) - 8 y^4 (10)
+  fe_normalize_weak(ny);  // (1)
+  fe_mul(r.z, p.y, p.z);
+  fe_mul_int(r.z, 2);     // nz = 2 y z (2)
   r.x = nx;
   r.y = ny;
-  r.z = nz;
+}
+
+// The addition once u1 = x1 z2^2 (<= 4), s1 = y1 z2^3 (<= 4), zz = z1 z2,
+// h = u2 - u1 (<= 6, not 0) and rr = s2 - s1 (<= 6) are known.  Out:
+// X 1, Y 3, Z 1.
+void pt_add_finish(Point& r, const Fe& u1, const Fe& s1, const Fe& zz,
+                   const Fe& h, const Fe& rr) {
+  Fe hsq, hcu, v, rsq, nx, ny, t, w;
+  fe_sqr(hsq, h);
+  fe_mul(hcu, hsq, h);    // h^3 (1)
+  fe_mul(v, u1, hsq);     // v = u1 h^2 (1)
+  fe_sqr(rsq, rr);        // (1)
+  fe_negate(nx, hcu, 1);
+  fe_negate(t, v, 1);
+  fe_mul_int(t, 2);
+  fe_add(nx, t);
+  fe_add(nx, rsq);        // nx = rr^2 - h^3 - 2 v (7)
+  fe_negate(t, rsq, 1);
+  w = v;
+  fe_mul_int(w, 3);
+  fe_add(t, w);
+  fe_add(t, hcu);         // v - nx = 3 v + h^3 - rr^2 (6)
+  fe_mul(ny, rr, t);
+  fe_mul(t, s1, hcu);
+  fe_negate(t, t, 1);
+  fe_add(ny, t);          // ny = rr (v - nx) - s1 h^3 (3)
+  fe_mul(r.z, zz, h);     // nz = z1 z2 h (1)
+  fe_normalize_weak(nx);  // (1)
+  r.x = nx;
+  r.y = ny;
 }
 
 void pt_add(Point& r, const Point& p1, const Point& p2) {
@@ -335,7 +676,7 @@ void pt_add(Point& r, const Point& p1, const Point& p2) {
     r = p1;
     return;
   }
-  U256 z1sq, z2sq, u1, u2, s1, s2, t;
+  Fe z1sq, z2sq, u1, u2, s1, s2, t, h, rr;
   fe_sqr(z1sq, p1.z);
   fe_sqr(z2sq, p2.z);
   fe_mul(u1, p1.x, z2sq);
@@ -343,61 +684,71 @@ void pt_add(Point& r, const Point& p1, const Point& p2) {
   fe_mul(t, z2sq, p2.z);
   fe_mul(s1, p1.y, t);
   fe_mul(t, z1sq, p1.z);
-  fe_mul(s2, p2.y, t);
-  if (cmp(u1, u2) == 0) {
-    if (cmp(s1, s2) != 0) {
-      r = {ZERO, ONE, ZERO};
+  fe_mul(s2, p2.y, t);    // u1, u2, s1, s2 (1)
+  fe_negate(h, u1, 1);
+  fe_add(h, u2);          // (3)
+  fe_negate(rr, s1, 1);
+  fe_add(rr, s2);         // (3)
+  if (fe_normalizes_to_zero(h)) {
+    if (!fe_normalizes_to_zero(rr)) {
+      r = INF;
       return;
     }
     pt_double(r, p1);
     return;
   }
-  U256 h, rr, hsq, hcu, v;
-  mod_sub(h, u2, u1, PRIME);
-  mod_sub(rr, s2, s1, PRIME);
-  fe_sqr(hsq, h);
-  fe_mul(hcu, hsq, h);
-  fe_mul(v, u1, hsq);
-  U256 nx;
-  fe_sqr(nx, rr);
-  mod_sub(nx, nx, hcu, PRIME);
-  mod_sub(nx, nx, v, PRIME);
-  mod_sub(nx, nx, v, PRIME);
-  U256 ny;
-  mod_sub(t, v, nx, PRIME);
-  fe_mul(ny, rr, t);
-  U256 s1h;
-  fe_mul(s1h, s1, hcu);
-  mod_sub(ny, ny, s1h, PRIME);
-  U256 nz;
   fe_mul(t, p1.z, p2.z);
-  fe_mul(nz, t, h);
-  r.x = nx;
-  r.y = ny;
-  r.z = nz;
+  pt_add_finish(r, u1, s1, t, h, rr);
+}
+
+// p1 (Jacobian) + p2 (affine): the 8M+3S mixed addition every table
+// hit uses.  Equal-x inputs degrade to pt_double / infinity exactly
+// like pt_add.
+void pt_add_mixed(Point& r, const Point& p1, const APoint& p2) {
+  if (p2.inf) {
+    r = p1;
+    return;
+  }
+  if (pt_is_inf(p1)) {
+    r = {p2.x, p2.y, FE_ONE};
+    return;
+  }
+  Fe z1sq, u2, s2, t, h, rr;
+  fe_sqr(z1sq, p1.z);
+  fe_mul(u2, p2.x, z1sq);
+  fe_mul(t, z1sq, p1.z);
+  fe_mul(s2, p2.y, t);    // u2, s2 (1)
+  fe_negate(h, p1.x, 4);
+  fe_add(h, u2);          // (6)
+  fe_negate(rr, p1.y, 4);
+  fe_add(rr, s2);         // (6)
+  if (fe_normalizes_to_zero(h)) {
+    if (!fe_normalizes_to_zero(rr)) {
+      r = INF;
+      return;
+    }
+    pt_double(r, p1);
+    return;
+  }
+  pt_add_finish(r, p1.x, p1.y, p1.z, h, rr);
 }
 
 // Shamir: k1*G + k2*Q in one double-and-add ladder.
 void pt_shamir(Point& r, const U256& k1, const U256& k2, const Point& q) {
-  Point g = {GX, GY, ONE};
+  const Point g = {fe_const(GX), fe_const(GY), FE_ONE};
   Point gq;
   pt_add(gq, g, q);
-  Point acc = {ZERO, ONE, ZERO};
+  Point acc = INF;
   for (int i = 255; i >= 0; --i) {
-    Point t;
-    pt_double(t, acc);
-    acc = t;
+    pt_double(acc, acc);
     int b1 = (k1.v[i / 64] >> (i % 64)) & 1;
     int b2 = (k2.v[i / 64] >> (i % 64)) & 1;
     if (b1 && b2)
-      pt_add(t, acc, gq);
+      pt_add(acc, acc, gq);
     else if (b1)
-      pt_add(t, acc, g);
+      pt_add(acc, acc, g);
     else if (b2)
-      pt_add(t, acc, q);
-    else
-      continue;
-    acc = t;
+      pt_add(acc, acc, q);
   }
   r = acc;
 }
@@ -416,10 +767,95 @@ void store_be(uint8_t* p, const U256& a) {
       p[(3 - i) * 8 + j] = (uint8_t)(a.v[i] >> (56 - 8 * j));
 }
 
+// a^((p+1)/4) by addition chain: 253 squarings + 13 multiplies (the
+// exponent is almost all ones).  a <= 8, result 1.  Chain verified
+// against (p+1)/4 in tests.
+void fe_sqrt_chain(Fe& r, const Fe& a) {
+  auto sqr_n = [](Fe& x, int n) {
+    for (int i = 0; i < n; ++i) fe_sqr(x, x);
+  };
+  Fe x2, x3, x6, x9, x11, x22, x44, x88, x176, x220, x223, t1;
+  fe_sqr(x2, a);
+  fe_mul(x2, x2, a);            // a^3
+  fe_sqr(x3, x2);
+  fe_mul(x3, x3, a);            // a^7
+  x6 = x3;
+  sqr_n(x6, 3);
+  fe_mul(x6, x6, x3);
+  x9 = x6;
+  sqr_n(x9, 3);
+  fe_mul(x9, x9, x3);
+  x11 = x9;
+  sqr_n(x11, 2);
+  fe_mul(x11, x11, x2);
+  x22 = x11;
+  sqr_n(x22, 11);
+  fe_mul(x22, x22, x11);
+  x44 = x22;
+  sqr_n(x44, 22);
+  fe_mul(x44, x44, x22);
+  x88 = x44;
+  sqr_n(x88, 44);
+  fe_mul(x88, x88, x44);
+  x176 = x88;
+  sqr_n(x176, 88);
+  fe_mul(x176, x176, x88);
+  x220 = x176;
+  sqr_n(x220, 44);
+  fe_mul(x220, x220, x44);
+  x223 = x220;
+  sqr_n(x223, 3);
+  fe_mul(x223, x223, x3);
+  t1 = x223;
+  sqr_n(t1, 23);
+  fe_mul(t1, t1, x22);
+  sqr_n(t1, 6);
+  fe_mul(t1, t1, x2);
+  sqr_n(t1, 2);
+  r = t1;
+}
+
+// R from (r, recid): x = r (+ n when recid & 2), y the root of x^3 + 7
+// whose parity is recid & 1.  False: x past p, or x^3 + 7 no square.
+bool lift_x(const U256& r, int recid, Fe& x, Fe& y) {
+  U256 xr = r;
+  if (recid & 2) {
+    if (add_raw(xr, r, ORDER)) return false;
+    if (cmp(xr, PRIME) >= 0) return false;
+  }
+  fe_from_u256(x, xr);
+  Fe ysq, chk;
+  fe_sqr(ysq, x);
+  fe_mul(ysq, ysq, x);
+  fe_add(ysq, FE_SEVEN);          // x^3 + 7 (2)
+  fe_sqrt_chain(y, ysq);
+  fe_sqr(chk, y);
+  if (!fe_equal(chk, ysq, 2)) return false;
+  fe_normalize(y);
+  if ((y.n[0] & 1) != (uint64_t)(recid & 1)) fe_negate(y, y, 1);  // (2)
+  return true;
+}
+
+// out20 = the address of affine (x, y): keccak of the 64-byte key, low
+// 20 bytes
+void address_of(const Fe& x, const Fe& y, uint8_t* out20) {
+  Fe nx = x, ny = y;
+  fe_normalize(nx);
+  fe_normalize(ny);
+  U256 ax, ay;
+  fe_to_u256(ax, nx);
+  fe_to_u256(ay, ny);
+  uint8_t pub[64], digest[32];
+  store_be(pub, ax);
+  store_be(pub + 32, ay);
+  coreth_keccak256(pub, 64, digest);
+  std::memcpy(out20, digest + 12, 20);
+}
+
 // ---- batch-only fast recovery (coreth_ecrecover_batch) ----
 //
-// The sequential coreth_ecrecover above is the native baseline's
-// primitive (one Shamir ladder per call) and stays untouched.  The
+// The sequential coreth_ecrecover below is the native baseline's
+// primitive (one Shamir ladder per call) and the batch's fallback.  The
 // batch entry point amortizes what a per-call API cannot:
 //   - u1*G via a once-built 32x255 affine comb table (8-bit windows):
 //     32 mixed additions, zero doublings, per signature;
@@ -434,9 +870,10 @@ void store_be(uint8_t* p, const U256& a) {
 // Every GLV split is verified on the spot (k1 + k2*lambda == k mod n
 // and both halves < 2^129); any mismatch — and any signature the fast
 // path cannot finish — falls back to coreth_ecrecover for that index,
-// so a constant or carry bug degrades to the slow path, never to a
-// wrong address.  CORETH_FAST_RECOVER=0 forces the per-signature
-// fallback everywhere (the A/B and bisection knob).
+// which answers ok = 2 when it recovers the key: a constant or carry
+// bug degrades to the slow path, never to a wrong address, and is
+// counted (ReplayStats.sigs_slow_path).  CORETH_FAST_RECOVER=0 forces
+// the per-signature fallback everywhere (the A/B and bisection knob).
 
 // lambda/beta: the cube roots of 1 realizing the curve endomorphism
 // (x, y) -> (beta*x, y) == lambda * P; lattice basis and the rounded
@@ -444,8 +881,9 @@ void store_be(uint8_t* p, const U256& a) {
 // (verified exhaustively against the Python twin in tests).
 const U256 GLV_LAMBDA = {{0xDF02967C1B23BD72ULL, 0x122E22EA20816678ULL,
                           0xA5261C028812645AULL, 0x5363AD4CC05C30E0ULL}};
-const U256 GLV_BETA = {{0xC1396C28719501EEULL, 0x9CF0497512F58995ULL,
-                        0x6E64479EAC3434E9ULL, 0x7AE96A2B657C0710ULL}};
+const Fe GLV_BETA = fe_const({{0xC1396C28719501EEULL, 0x9CF0497512F58995ULL,
+                               0x6E64479EAC3434E9ULL,
+                               0x7AE96A2B657C0710ULL}});
 // a1 == b2 (128 bits), B1 == -b1 (128 bits), a2 (129 bits)
 const U256 GLV_A1 = {{0xE86C90E49284EB15ULL, 0x3086D221A7D46BCDULL, 0, 0}};
 const U256 GLV_B1 = {{0x6F547FA90ABFE4C3ULL, 0xE4437ED6010E8828ULL, 0, 0}};
@@ -457,156 +895,33 @@ const U256 GLV_G1 = {{0xE893209A45DBB031ULL, 0x3DAA8A1471E8CA7FULL,
 const U256 GLV_G2 = {{0x1571B4AE8AC47F71ULL, 0x221208AC9DF506C6ULL,
                       0x6F547FA90ABFE4C4ULL, 0xE4437ED6010E8828ULL}};
 
-// a^((p+1)/4) by addition chain (255 squarings + 13 multiplies vs
-// ~506 multiplies for the generic bit-scan fe_pow — the exponent is
-// almost all ones).  Chain verified against (p+1)/4 in tests.
-void fe_sqrt_chain(U256& r, const U256& a) {
-  auto sqr_n = [](U256& x, int n) {
-    for (int i = 0; i < n; ++i) {
-      U256 t;
-      fe_sqr(t, x);
-      x = t;
-    }
-  };
-  U256 x2, x3, x6, x9, x11, x22, x44, x88, x176, x220, x223, t1, t;
-  fe_sqr(x2, a);
-  fe_mul(t, x2, a);
-  x2 = t;                       // a^3
-  fe_sqr(x3, x2);
-  fe_mul(t, x3, a);
-  x3 = t;                       // a^7
-  x6 = x3;
-  sqr_n(x6, 3);
-  fe_mul(t, x6, x3);
-  x6 = t;
-  x9 = x6;
-  sqr_n(x9, 3);
-  fe_mul(t, x9, x3);
-  x9 = t;
-  x11 = x9;
-  sqr_n(x11, 2);
-  fe_mul(t, x11, x2);
-  x11 = t;
-  x22 = x11;
-  sqr_n(x22, 11);
-  fe_mul(t, x22, x11);
-  x22 = t;
-  x44 = x22;
-  sqr_n(x44, 22);
-  fe_mul(t, x44, x22);
-  x44 = t;
-  x88 = x44;
-  sqr_n(x88, 44);
-  fe_mul(t, x88, x44);
-  x88 = t;
-  x176 = x88;
-  sqr_n(x176, 88);
-  fe_mul(t, x176, x88);
-  x176 = t;
-  x220 = x176;
-  sqr_n(x220, 44);
-  fe_mul(t, x220, x44);
-  x220 = t;
-  x223 = x220;
-  sqr_n(x223, 3);
-  fe_mul(t, x223, x3);
-  x223 = t;
-  t1 = x223;
-  sqr_n(t1, 23);
-  fe_mul(t, t1, x22);
-  t1 = t;
-  sqr_n(t1, 6);
-  fe_mul(t, t1, x2);
-  t1 = t;
-  sqr_n(t1, 2);
-  r = t1;
-}
-
-struct APoint {
-  U256 x, y;
-  bool inf;
-};
-
-// p1 (Jacobian) + p2 (affine): the 8M+3S mixed addition every table
-// hit uses.  Equal-x inputs degrade to pt_double / infinity exactly
-// like pt_add.
-void pt_add_mixed(Point& r, const Point& p1, const APoint& p2) {
-  if (p2.inf) {
-    r = p1;
-    return;
-  }
-  if (pt_is_inf(p1)) {
-    r = {p2.x, p2.y, ONE};
-    return;
-  }
-  U256 z1sq, u2, s2, t;
-  fe_sqr(z1sq, p1.z);
-  fe_mul(u2, p2.x, z1sq);
-  fe_mul(t, z1sq, p1.z);
-  fe_mul(s2, p2.y, t);
-  if (cmp(p1.x, u2) == 0) {
-    if (cmp(p1.y, s2) != 0) {
-      r = {ZERO, ONE, ZERO};
-      return;
-    }
-    pt_double(r, p1);
-    return;
-  }
-  U256 h, rr, hsq, hcu, v;
-  mod_sub(h, u2, p1.x, PRIME);
-  mod_sub(rr, s2, p1.y, PRIME);
-  fe_sqr(hsq, h);
-  fe_mul(hcu, hsq, h);
-  fe_mul(v, p1.x, hsq);
-  U256 nx;
-  fe_sqr(nx, rr);
-  mod_sub(nx, nx, hcu, PRIME);
-  mod_sub(nx, nx, v, PRIME);
-  mod_sub(nx, nx, v, PRIME);
-  U256 ny;
-  mod_sub(t, v, nx, PRIME);
-  fe_mul(ny, rr, t);
-  U256 yh;
-  fe_mul(yh, p1.y, hcu);
-  mod_sub(ny, ny, yh, PRIME);
-  U256 nz;
-  fe_mul(nz, p1.z, h);
-  r.x = nx;
-  r.y = ny;
-  r.z = nz;
-}
-
 // Normalize Jacobian points to affine with ONE field inversion
-// (Montgomery prefix products).  Infinity rows come back inf.
+// (Montgomery prefix products).  Infinity rows come back inf; the
+// others magnitude 1.
 void batch_to_affine(const Point* pts, APoint* out, size_t n) {
-  std::vector<U256> prefix(n);
+  std::vector<Fe> prefix(n);
   std::vector<size_t> live;
   live.reserve(n);
-  U256 acc = ONE;
+  Fe acc = FE_ONE;
   for (size_t i = 0; i < n; ++i) {
     out[i].inf = pt_is_inf(pts[i]);
     if (out[i].inf) continue;
-    U256 t;
-    fe_mul(t, acc, pts[i].z);
-    acc = t;
+    fe_mul(acc, acc, pts[i].z);
     prefix[i] = acc;
     live.push_back(i);
   }
   if (live.empty()) return;
-  U256 inv;
+  Fe inv;
   fe_inv(inv, acc);
   for (size_t k = live.size(); k-- > 0;) {
     size_t i = live[k];
-    U256 zinv;
+    Fe zinv, zi2, t;
     if (k == 0) {
       zinv = inv;
     } else {
       fe_mul(zinv, inv, prefix[live[k - 1]]);
     }
-    U256 t;
-    fe_mul(t, inv, pts[i].z);
-    inv = t;
-    U256 zi2;
+    fe_mul(inv, inv, pts[i].z);
     fe_sqr(zi2, zinv);
     fe_mul(out[i].x, pts[i].x, zi2);
     fe_mul(t, zi2, zinv);
@@ -614,30 +929,37 @@ void batch_to_affine(const Point* pts, APoint* out, size_t n) {
   }
 }
 
-// u1*G comb: TBL[w][v-1] = v * 2^(8w) * G, affine.  522KB, built once
-// under std::call_once on first batch call (the warm replay rep pays
-// it, like an XLA compile).
+// u1*G comb: TBL[w][v-1] = v * 2^(8w) * G, affine, one 64-byte line an
+// entry (4x64 limbs, converted at the lookup).  522KB, built once under
+// std::call_once on first batch call (the warm replay rep pays it,
+// like an XLA compile).
 constexpr int COMB_WINDOWS = 32;
 constexpr int COMB_VALS = 255;
-std::vector<APoint> g_comb;
+struct alignas(64) CombPt {
+  U256 x, y;
+};
+std::vector<CombPt> g_comb;
 std::once_flag g_comb_once;
 
 void build_g_comb() {
   std::vector<Point> jac(COMB_WINDOWS * COMB_VALS);
-  Point base = {GX, GY, ONE};
+  Point base = {fe_const(GX), fe_const(GY), FE_ONE};
   for (int w = 0; w < COMB_WINDOWS; ++w) {
     jac[w * COMB_VALS] = base;
     for (int v = 2; v <= COMB_VALS; ++v)
       pt_add(jac[w * COMB_VALS + v - 1], jac[w * COMB_VALS + v - 2],
              base);
-    for (int d = 0; d < 8; ++d) {
-      Point t;
-      pt_double(t, base);
-      base = t;
-    }
+    for (int d = 0; d < 8; ++d) pt_double(base, base);
   }
+  std::vector<APoint> aff(jac.size());
+  batch_to_affine(jac.data(), aff.data(), jac.size());
   g_comb.resize(jac.size());
-  batch_to_affine(jac.data(), g_comb.data(), jac.size());
+  for (size_t i = 0; i < aff.size(); ++i) {
+    fe_normalize(aff[i].x);
+    fe_normalize(aff[i].y);
+    fe_to_u256(g_comb[i].x, aff[i].x);
+    fe_to_u256(g_comb[i].y, aff[i].y);
+  }
 }
 
 // c = round((k * g) / 2^384): the mulhi step of the GLV division.
@@ -709,7 +1031,7 @@ bool glv_split(const U256& k, U256& k1, int& s1, U256& k2, int& s2) {
   U256 k1m = k1, k2m = k2, chk;
   if (s1 < 0 && !is_zero(k1)) sub_raw(k1m, ORDER, k1);
   if (s2 < 0 && !is_zero(k2)) sub_raw(k2m, ORDER, k2);
-  sc_mul(chk, k2m, GLV_LAMBDA, ORDER);
+  sc_mul(chk, k2m, GLV_LAMBDA);
   mod_add(chk, chk, k1m, ORDER);
   return cmp(chk, k) == 0;
 }
@@ -751,8 +1073,12 @@ struct FastSig {
   U256 k1, k2;          // |GLV halves| of u2
   int s1, s2;           // their signs
   Point tbl[8];         // {1,3,...,15} * R, Jacobian (then affine)
-  bool ready;
 };
+
+// z mod n for a 256-bit hash (z < 2n)
+inline void reduce_hash(U256& z) {
+  if (cmp(z, ORDER) >= 0) sub_raw(z, z, ORDER);
+}
 
 // One signature's validation + R + scalars; rinv comes from the batch
 // inversion.  Returns false -> caller routes index to the fallback.
@@ -761,32 +1087,15 @@ bool fast_prep(const uint8_t* hash32, const uint8_t* s32, const U256& r,
   U256 s, z;
   load_be(s, s32);
   load_be(z, hash32);
-  U256 x = r;
-  if (recid & 2) {
-    if (add_raw(x, r, ORDER)) return false;
-    if (cmp(x, PRIME) >= 0) return false;
-  }
-  U256 xsq, ysq, seven = {{7, 0, 0, 0}};
-  fe_sqr(xsq, x);
-  fe_mul(ysq, xsq, x);
-  mod_add(ysq, ysq, seven, PRIME);
-  U256 y;
-  fe_sqrt_chain(y, ysq);
-  U256 chk;
-  fe_sqr(chk, y);
-  if (cmp(chk, ysq) != 0) return false;
-  if ((y.v[0] & 1) != (uint64_t)(recid & 1)) mod_sub(y, PRIME, y, PRIME);
-  while (cmp(z, ORDER) >= 0) {
-    U256 t;
-    sub_raw(t, z, ORDER);
-    z = t;
-  }
-  sc_mul(fs.u1, z, rinv, ORDER);
+  Point rpt;
+  rpt.z = FE_ONE;
+  if (!lift_x(r, recid, rpt.x, rpt.y)) return false;
+  reduce_hash(z);
+  sc_mul(fs.u1, z, rinv);
   if (!is_zero(fs.u1)) mod_sub(fs.u1, ORDER, fs.u1, ORDER);
-  sc_mul(fs.u2, s, rinv, ORDER);
+  sc_mul(fs.u2, s, rinv);
   if (!glv_split(fs.u2, fs.k1, fs.s1, fs.k2, fs.s2)) return false;
   // odd multiples of R
-  Point rpt = {x, y, ONE};
   Point d2;
   pt_double(d2, rpt);
   fs.tbl[0] = rpt;
@@ -802,46 +1111,44 @@ void fast_ladder(Point& acc, const FastSig& fs, const APoint* tbl_aff) {
   int l1 = wnaf5(d1, fs.k1);
   int l2 = wnaf5(d2, fs.k2);
   int len = l1 > l2 ? l1 : l2;
-  acc = {ZERO, ONE, ZERO};
+  acc = INF;
   for (int i = len - 1; i >= 0; --i) {
-    Point t;
-    pt_double(t, acc);
-    acc = t;
+    pt_double(acc, acc);
     if (i < l1 && d1[i]) {
       int8_t d = d1[i];
       bool neg = (d < 0) != (fs.s1 < 0);
       APoint p = tbl_aff[(d < 0 ? -d : d) >> 1];
-      if (neg && !p.inf) mod_sub(p.y, PRIME, p.y, PRIME);
-      pt_add_mixed(t, acc, p);
-      acc = t;
+      if (neg && !p.inf) fe_negate(p.y, p.y, 1);  // (2)
+      pt_add_mixed(acc, acc, p);
     }
     if (i < l2 && d2[i]) {
       int8_t d = d2[i];
       bool neg = (d < 0) != (fs.s2 < 0);
       APoint p = tbl_aff[(d < 0 ? -d : d) >> 1];
       if (!p.inf) {
-        U256 bx;
-        fe_mul(bx, p.x, GLV_BETA);  // phi: (x,y) -> (beta x, y)
-        p.x = bx;
-        if (neg) mod_sub(p.y, PRIME, p.y, PRIME);
+        fe_mul(p.x, p.x, GLV_BETA);  // phi: (x,y) -> (beta x, y)
+        if (neg) fe_negate(p.y, p.y, 1);
       }
-      pt_add_mixed(t, acc, p);
-      acc = t;
+      pt_add_mixed(acc, acc, p);
     }
   }
   for (int w = 0; w < COMB_WINDOWS; ++w) {
     int v = (int)((fs.u1.v[w / 8] >> (8 * (w % 8))) & 0xFF);
     if (!v) continue;
-    Point t;
-    pt_add_mixed(t, acc, g_comb[w * COMB_VALS + v - 1]);
-    acc = t;
+    const CombPt& c = g_comb[w * COMB_VALS + v - 1];
+    APoint p;
+    fe_from_u256(p.x, c.x);
+    fe_from_u256(p.y, c.y);
+    p.inf = false;
+    pt_add_mixed(acc, acc, p);
   }
 }
 
 // Fast batch over [lo, hi): shared r^-1 batch inversion, shared wNAF
 // table normalization, per-signature ladders, shared final affine
 // conversion.  Each index the fast path cannot carry falls back to
-// the sequential coreth_ecrecover.
+// the sequential coreth_ecrecover, and is marked ok = 2 if that
+// recovers it.
 void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
                         const uint8_t* ss, const uint8_t* recids,
                         uint64_t lo, uint64_t hi, uint8_t* out,
@@ -863,9 +1170,7 @@ void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
     if (cmp(r, ORDER) >= 0 || cmp(s, ORDER) >= 0) continue;
     r_l[j] = r;
     state[j] = 1;
-    U256 t;
-    sc_mul(t, acc, r, ORDER);
-    acc = t;
+    sc_mul(acc, acc, r);
     prefix[j] = acc;
     live.push_back(j);
   }
@@ -880,11 +1185,9 @@ void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
       if (k == 0) {
         rinv = inv;
       } else {
-        sc_mul(rinv, inv, prefix[live[k - 1]], ORDER);
+        sc_mul(rinv, inv, prefix[live[k - 1]]);
       }
-      U256 t;
-      sc_mul(t, inv, r_l[j], ORDER);
-      inv = t;
+      sc_mul(inv, inv, r_l[j]);
       if (!fast_prep(hashes + 32 * i, ss + 32 * i, r_l[j], rinv,
                      recids[i], sigs[j]))
         state[j] = 2;  // residue failures land here too; fallback
@@ -900,7 +1203,7 @@ void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
   std::vector<APoint> flat_aff(flat.size());
   batch_to_affine(flat.data(), flat_aff.data(), flat.size());
   // ladders; results collect for one final batch affine conversion
-  std::vector<Point> res(n);
+  std::vector<Point> res(n, INF);
   size_t cursor = 0;
   for (uint64_t j = 0; j < n; ++j) {
     if (state[j] != 1) continue;
@@ -913,17 +1216,14 @@ void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
   for (uint64_t j = 0; j < n; ++j) {
     uint64_t i = lo + j;
     if (state[j] == 2) {
-      ok[i] = (uint8_t)coreth_ecrecover(hashes + 32 * i, rs + 32 * i,
-                                        ss + 32 * i, recids[i],
-                                        out + 20 * i);
+      ok[i] = coreth_ecrecover(hashes + 32 * i, rs + 32 * i, ss + 32 * i,
+                               recids[i], out + 20 * i)
+                  ? 2
+                  : 0;
       continue;
     }
     if (state[j] != 1 || res_aff[j].inf) continue;
-    uint8_t pub[64], digest[32];
-    store_be(pub, res_aff[j].x);
-    store_be(pub + 32, res_aff[j].y);
-    coreth_keccak256(pub, 64, digest);
-    std::memcpy(out + 20 * i, digest + 12, 20);
+    address_of(res_aff[j].x, res_aff[j].y, out + 20 * i);
     ok[i] = 1;
   }
 }
@@ -1171,61 +1471,27 @@ int coreth_ecrecover(const uint8_t* hash32, const uint8_t* r32,
   load_be(z, hash32);
   if (is_zero(r) || is_zero(s)) return 0;
   if (cmp(r, ORDER) >= 0 || cmp(s, ORDER) >= 0) return 0;
-  // x = r (+ n when recid & 2)
-  U256 x = r;
-  if (recid & 2) {
-    if (add_raw(x, r, ORDER)) return 0;
-    if (cmp(x, PRIME) >= 0) return 0;
-  }
-  // y^2 = x^3 + 7
-  U256 xsq, ysq, seven = {{7, 0, 0, 0}};
-  fe_sqr(xsq, x);
-  fe_mul(ysq, xsq, x);
-  mod_add(ysq, ysq, seven, PRIME);
-  // y = ysq^((p+1)/4)
-  U256 e = PRIME;
-  {  // (p+1)/4: p+1 overflows 256 bits? p < 2^256-1 so p+1 fits.
-    U256 p1;
-    add_raw(p1, PRIME, ONE);
-    // shift right by 2
-    for (int i = 0; i < 4; ++i) {
-      uint64_t hi = (i < 3) ? p1.v[i + 1] : 0;
-      e.v[i] = (p1.v[i] >> 2) | (hi << 62);
-    }
-  }
-  U256 y;
-  fe_pow(y, ysq, e);
-  U256 chk;
-  fe_sqr(chk, y);
-  if (cmp(chk, ysq) != 0) return 0;  // non-residue: invalid r
-  if ((y.v[0] & 1) != (uint64_t)(recid & 1)) mod_sub(y, PRIME, y, PRIME);
+  Point q;
+  q.z = FE_ONE;
+  if (!lift_x(r, recid, q.x, q.y)) return 0;  // non-residue: invalid r
   // u1 = -z/r mod n ; u2 = s/r mod n
-  U256 rinv, u1, u2, zmod = z;
-  while (cmp(zmod, ORDER) >= 0) {
-    U256 t;
-    sub_raw(t, zmod, ORDER);
-    zmod = t;
-  }
+  U256 rinv, u1, u2;
+  reduce_hash(z);
   sc_inv(rinv, r);
-  sc_mul(u1, zmod, rinv, ORDER);
+  sc_mul(u1, z, rinv);
   if (!is_zero(u1)) mod_sub(u1, ORDER, u1, ORDER);
-  sc_mul(u2, s, rinv, ORDER);
-  Point q = {x, y, ONE}, res;
+  sc_mul(u2, s, rinv);
+  Point res;
   pt_shamir(res, u1, u2, q);
   if (pt_is_inf(res)) return 0;
   // to affine
-  U256 zinv, zinv2, ax, ay, t;
+  Fe zinv, zinv2, ax, ay, t;
   fe_inv(zinv, res.z);
   fe_sqr(zinv2, zinv);
   fe_mul(ax, res.x, zinv2);
   fe_mul(t, zinv2, zinv);
   fe_mul(ay, res.y, t);
-  uint8_t pub[64];
-  store_be(pub, ax);
-  store_be(pub + 32, ay);
-  uint8_t digest[32];
-  coreth_keccak256(pub, 64, digest);
-  std::memcpy(out20, digest + 12, 20);
+  address_of(ax, ay, out20);
   return 1;
 }
 
@@ -1263,9 +1529,7 @@ void coreth_recover_prep(const uint8_t* hashes, const uint8_t* rs,
     store_be(be, x);
     for (int j = 0; j < 32; ++j) xs_le33[33 * i + j] = be[31 - j];
     ok[i] = 1;
-    U256 t;
-    sc_mul(t, acc, r, ORDER);
-    acc = t;
+    sc_mul(acc, acc, r);
     prefix[i] = acc;
     live.push_back(i);
   }
@@ -1278,27 +1542,17 @@ void coreth_recover_prep(const uint8_t* hashes, const uint8_t* rs,
     if (k == 0) {
       rinv = inv;
     } else {
-      sc_mul(rinv, inv, prefix[live[k - 1]], ORDER);
+      sc_mul(rinv, inv, prefix[live[k - 1]]);
     }
-    U256 t;
-    sc_mul(t, inv, r_l[i], ORDER);
-    inv = t;
+    sc_mul(inv, inv, r_l[i]);
     // u2 = s/r ; u1 = -(z/r)
     U256 s, z, u1, u2;
     load_be(s, ss + 32 * i);
     load_be(z, hashes + 32 * i);
-    while (cmp(z, ORDER) >= 0) {
-      U256 t2;
-      sub_raw(t2, z, ORDER);
-      z = t2;
-    }
-    sc_mul(u2, s, rinv, ORDER);
-    sc_mul(u1, z, rinv, ORDER);
-    if (!is_zero(u1)) {
-      U256 t2;
-      sub_raw(t2, ORDER, u1);
-      u1 = t2;
-    }
+    reduce_hash(z);
+    sc_mul(u2, s, rinv);
+    sc_mul(u1, z, rinv);
+    if (!is_zero(u1)) sub_raw(u1, ORDER, u1);
     uint8_t be[32];
     store_be(be, u1);
     for (int j = 0; j < 32; ++j) u1_le32[32 * i + j] = be[31 - j];
@@ -1315,15 +1569,17 @@ void coreth_recover_prep(const uint8_t* hashes, const uint8_t* rs,
 void coreth_recover_finish(const uint8_t* rows, uint64_t n,
                            const uint8_t* ok_in, uint8_t* out20,
                            uint8_t* ok) {
-  auto load_le33 = [](U256& v, const uint8_t* p) {
+  auto load_le33 = [](Fe& v, const uint8_t* p) {
     uint8_t be[32];
     for (int j = 0; j < 32; ++j) be[j] = p[31 - j];
-    load_be(v, be);
+    U256 u;
+    load_be(u, be);
+    fe_from_u256(v, u);
   };
-  std::vector<U256> z_l(n), prefix(n);
+  std::vector<Point> pts;
   std::vector<uint64_t> fin;
+  pts.reserve(n);
   fin.reserve(n);
-  U256 acc = ONE;
   for (uint64_t i = 0; i < n; ++i) {
     ok[i] = 0;
     const uint8_t* row = rows + 102 * i;
@@ -1334,61 +1590,60 @@ void coreth_recover_finish(const uint8_t* rows, uint64_t n,
       continue;
     }
     if (inf) continue;
-    U256 z;
-    load_le33(z, row + 66);
-    if (is_zero(z)) continue;
-    z_l[i] = z;
-    U256 t;
-    fe_mul(t, acc, z);
-    acc = t;
-    prefix[i] = acc;
+    Point p;
+    load_le33(p.x, row);
+    load_le33(p.y, row + 33);
+    load_le33(p.z, row + 66);
+    pts.push_back(p);
     fin.push_back(i);
   }
-  if (fin.empty()) return;
-  U256 inv;
-  fe_inv(inv, acc);
-  for (size_t k = fin.size(); k-- > 0;) {
-    uint64_t i = fin[k];
-    U256 zinv;
-    if (k == 0) {
-      zinv = inv;
-    } else {
-      fe_mul(zinv, inv, prefix[fin[k - 1]]);
-    }
-    U256 t;
-    fe_mul(t, inv, z_l[i]);
-    inv = t;
-    const uint8_t* row = rows + 102 * i;
-    U256 xj, yj, zi2, ax, ay;
-    load_le33(xj, row);
-    load_le33(yj, row + 33);
-    fe_sqr(zi2, zinv);
-    fe_mul(ax, xj, zi2);
-    fe_mul(t, zi2, zinv);
-    fe_mul(ay, yj, t);
-    uint8_t pub[64], digest[32];
-    store_be(pub, ax);
-    store_be(pub + 32, ay);
-    coreth_keccak256(pub, 64, digest);
-    std::memcpy(out20 + 20 * i, digest + 12, 20);
-    ok[i] = 1;
+  std::vector<APoint> aff(pts.size());
+  batch_to_affine(pts.data(), aff.data(), pts.size());
+  for (size_t k = 0; k < fin.size(); ++k) {
+    if (aff[k].inf) continue;
+    address_of(aff[k].x, aff[k].y, out20 + 20 * fin[k]);
+    ok[fin[k]] = 1;
   }
 }
 
-// Test hook: field multiplication mod p over big-endian 32-byte operands.
-// Exists so the carry-fold edge cases of fe_mul stay regression-tested from
-// Python (see tests/test_crypto.py).
-void coreth_test_fe_mul(const uint8_t* a32, const uint8_t* b32,
-                        uint8_t* out32) {
-  U256 a, b, r;
+// Test hook over raw 5x52 limbs, so tests/test_secp_field.py (and
+// test_crypto.py's carry-band regression) can hold every field operation
+// to Python integers at the magnitudes the point formulas reach (no cell
+// calls it).  op: 0 mul, 1 sqr, 2 add,
+// 3 negate(a, k), 4 mul_int(a, k), 5 normalize_weak, 6 normalize,
+// 7 normalizes_to_zero (out[0] = 0 / 1), 8 inv, 9 sqrt chain.
+void coreth_test_fe_op(int op, const uint64_t* a5, const uint64_t* b5,
+                       int k, uint64_t* out5) {
+  Fe a, b, r = {{0, 0, 0, 0, 0}};
+  std::memcpy(a.n, a5, sizeof a.n);
+  std::memcpy(b.n, b5, sizeof b.n);
+  switch (op) {
+    case 0: fe_mul(r, a, b); break;
+    case 1: fe_sqr(r, a); break;
+    case 2: r = a; fe_add(r, b); break;
+    case 3: fe_negate(r, a, k); break;
+    case 4: r = a; fe_mul_int(r, k); break;
+    case 5: r = a; fe_normalize_weak(r); break;
+    case 6: r = a; fe_normalize(r); break;
+    case 7: r.n[0] = fe_normalizes_to_zero(a); break;
+    case 8: fe_inv(r, a); break;
+    case 9: fe_sqrt_chain(r, a); break;
+  }
+  std::memcpy(out5, r.n, sizeof r.n);
+}
+
+// Test hook: a^-1 mod n over big-endian 32-byte operands (a < n).
+void coreth_test_sc_inv(const uint8_t* a32, uint8_t* out32) {
+  U256 a, r;
   load_be(a, a32);
-  load_be(b, b32);
-  fe_mul(r, a, b);
+  sc_inv(r, a);
   store_be(out32, r);
 }
 
 // Batched recovery: packed 32-byte hashes / r / s, recid bytes.
-// out: packed 20-byte addresses; ok[i] = 1 on success.
+// out: packed 20-byte addresses; ok[i] = 1 on success, 2 when the
+// sequential fallback recovered what the fast path could not (correct,
+// and a fault of the fast path to be counted; 0 on every valid chain).
 // Strided across hardware threads — the C++ twin of the reference's
 // GOMAXPROCS sender cacher (core/sender_cacher.go:49-80).  Degenerates
 // to the sequential loop on single-core hosts.
@@ -1427,7 +1682,8 @@ void coreth_ecrecover_batch(const uint8_t* hashes, const uint8_t* rs,
 // Batched recovery from the transactions' wire encodings, laid end to
 // end in `wire` and cut by offsets[n + 1].  Each chunk's thread derives
 // its lanes' signing hash, r, s and recovery id (wire_sig, by the rules
-// of LatestSigner(chain_id)) and recovers them as the batch above does.
+// of LatestSigner(chain_id)) and recovers them as the batch above does
+// (ok = 2 as there).
 // ok[i] = 0 says this transaction is not vouched for — malformed,
 // truncated, cut outside [0, wire_len], foreign chain id, high s,
 // recovery id past 1, r or s out of range, no such point — and is left

@@ -139,6 +139,46 @@ def test_malformed_lane_does_not_poison_its_segment(chain3):
         eng.signer.sender(bad)
 
 
+@pytest.mark.parametrize("form", ["replay", "warm_senders"])
+def test_a_built_chain_recovers_on_the_fast_path_alone(chain3, form):
+    """Every lane of a built chain is answered by the batch's fast path:
+    no lane falls to its sequential fallback (ok = 2), whose count rides
+    the stats row beside sigs_left_to_signer."""
+    blocks = _fresh(chain3)
+    eng = _engine()
+    if form == "replay":
+        assert eng.replay(blocks) == blocks[-1].root
+    else:
+        eng.warm_senders(blocks)
+    assert eng.stats.sigs_host == _n_txs(blocks)
+    assert eng.stats.sigs_slow_path == 0
+    assert eng.stats.sigs_left_to_signer == 0
+    assert eng.stats.row()["sigs_slow_path"] == 0
+
+
+def test_fallback_lanes_are_primed_and_counted(monkeypatch, chain3):
+    """A lane the batch answered ok = 2 (its sequential fallback
+    recovered it) primes the sender cache like ok = 1, and counts in
+    sigs_slow_path; ok = 0 still goes to sigs_left_to_signer."""
+    blocks = _fresh(chain3[:1])
+    n = _n_txs(blocks)
+    real = native.recover_senders_wire
+
+    def batch(wire, offsets, chain_id):
+        out, ok = real(wire, offsets, chain_id)
+        return out, bytes([2, 0]) + ok[2:]
+
+    monkeypatch.setattr(native, "recover_senders_wire", batch)
+    eng = _engine()
+    eng.warm_senders(blocks)
+    txs = blocks[0].transactions
+    assert eng.stats.sigs_slow_path == 1
+    assert eng.stats.sigs_left_to_signer == 1
+    assert txs[0].cached_sender() == ADDRS[0]
+    assert txs[1].cached_sender() is None
+    assert all(tx.cached_sender() in ADDRS for tx in txs[2:n])
+
+
 def _boom(*_a, **_k):
     raise RuntimeError("batch lost")
 

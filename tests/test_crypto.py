@@ -112,13 +112,21 @@ def test_native_keccak_batch_matches_singles():
 
 
 def test_native_fe_mul_carry_band():
-    """Regression: fe_mul's second reduction fold can carry out of limb 3;
-    the dropped 2^256 must be folded back in as P_C (mod p)."""
+    """Regression: fe_mul's folds of the high columns can carry past bit
+    256; the dropped 2^256 must come back as 2^256 - p (mod p).  Driven
+    through the raw-limb test entry (tests/test_secp_field.py has the
+    rest of the field arithmetic)."""
     import ctypes
     from coreth_tpu.crypto import native
     if native.load() is None:
         pytest.skip("native lib unavailable")
-    lib = native.load()  # loader declares coreth_test_fe_mul argtypes
+    lib = native.load()  # loader declares coreth_test_fe_op argtypes
+    limbs = ctypes.c_uint64 * 5
+
+    def fe(x):
+        return limbs(*[(x >> 52 * i) & (2**52 - 1) for i in range(4)],
+                     x >> 208)
+
     cases = [
         (0x200000000000000000000000000000000000000000000000000000003,
          0xDEBC32AB94B43FABCB3D33BEF15F01B6BB5DC8A5F93BB2A187AAE89CD3297E01),
@@ -128,9 +136,10 @@ def test_native_fe_mul_carry_band():
         (0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFF, 0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFF),
     ]
     for a, b in cases:
-        out = ctypes.create_string_buffer(32)
-        lib.coreth_test_fe_mul(a.to_bytes(32, "big"), b.to_bytes(32, "big"), out)
-        assert int.from_bytes(out.raw, "big") == (a * b) % S.P, (hex(a), hex(b))
+        out = limbs()
+        lib.coreth_test_fe_op(0, fe(a), fe(b), 0, out)
+        got = sum(v << 52 * i for i, v in enumerate(out))
+        assert got % S.P == (a * b) % S.P, (hex(a), hex(b))
 
 
 def test_native_recover_matches_python():

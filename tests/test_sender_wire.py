@@ -427,3 +427,8 @@ def test_sigs_left_to_signer_counts_exactly_the_refused_lanes():
     stream = StreamingPipeline(eng, ChainFeed([]))
     assert stream.run().lanes["sigs_left_to_signer"] == 3
     assert stream._live_report()["lanes"]["sigs_left_to_signer"] == 3
+    # the fast path carried every lane it vouched for: the fallback's
+    # count rides the same row, registry and report
+    assert st.sigs_slow_path == 0
+    assert reg.snapshot()["replay/sigs_slow_path"]["value"] == 0
+    assert stream._live_report()["lanes"]["sigs_slow_path"] == 0
