@@ -10,7 +10,9 @@ SharedMemory; Reject discards them.
 make_callbacks() wires the ConsensusCallbacks the dummy engine invokes
 during block processing (vm.go:986 onExtraStateChange): decode ExtData,
 semantic-verify, EVMStateTransfer each atomic tx, and return the block
-fee contribution + atomic gas used.
+fee contribution + atomic gas used.  The device engine takes the same
+semantics split in two (``on_extra_state_rows``): the bookkeeping here,
+the state transfer as rows it applies itself.
 """
 
 from __future__ import annotations
@@ -21,10 +23,34 @@ from typing import Dict, List, Optional, Tuple
 from coreth_tpu.atomic.shared_memory import Element, Requests, SharedMemory
 from coreth_tpu.atomic.trie import AtomicTrie
 from coreth_tpu.atomic.tx import (
-    AtomicTxError, Tx, UnsignedImportTx, UTXO, decode_ext_data,
+    AtomicTxError, Tx, UnsignedImportTx, UTXO, X2C_RATE, decode_ext_data,
     encode_ext_data,
 )
 from coreth_tpu.consensus.engine import ConsensusCallbacks
+
+
+def evm_side(unsigned) -> list:
+    """The EVM half of an atomic tx: an import's EVMOutputs (credits),
+    an export's EVMInputs (debits)."""
+    if isinstance(unsigned, UnsignedImportTx):
+        return unsigned.outs
+    return unsigned.ins
+
+
+def verify_banff_assets(unsigned, avax_asset_id: bytes) -> None:
+    """From Banff on an atomic tx moves AVAX alone (import_tx.go /
+    export_tx.go Verify: errImportNonAVAXInputBanff,
+    errImportNonAVAXOutputBanff, errExportNonAVAXInputBanff,
+    errExportNonAVAXOutputBanff)."""
+    if isinstance(unsigned, UnsignedImportTx):
+        sides = (("import input", unsigned.imported_inputs),
+                 ("import output", unsigned.outs))
+    else:
+        sides = (("export input", unsigned.ins),
+                 ("export output", unsigned.exported_outputs))
+    for what, items in sides:
+        if any(x.asset_id != avax_asset_id for x in items):
+            raise AtomicTxError(f"{what} cannot contain non-AVAX in Banff")
 
 
 @dataclass
@@ -154,6 +180,8 @@ class AtomicBackend:
         ownership — UTXO owners for imports, ETH-address signers for
         export EVM inputs."""
         tx.unsigned.verify(self.ctx)
+        if rules.is_banff:
+            verify_banff_assets(tx.unsigned, self.ctx.avax_asset_id)
         inputs = tx.unsigned.input_utxos()
         if len(set(inputs)) != len(inputs):
             raise AtomicTxError("duplicate input")
@@ -239,6 +267,11 @@ class AtomicBackend:
         self._pending[block_hash] = _PendingBlock(
             height, requests, parent_hash, frozenset(inputs))
 
+    def has_pending(self, block_hash: bytes) -> bool:
+        """The block's atomic effect is tracked: it was verified and is
+        not decided yet."""
+        return block_hash in self._pending
+
     def accept(self, block_hash: bytes, height: int = None) -> bytes:
         """Accept: index in the atomic trie + apply to shared memory
         (block.go:177 Accept -> atomicState.Accept).  Runs the trie
@@ -268,6 +301,30 @@ class AtomicBackend:
         self._pending.pop(block_hash, None)
 
 
+@dataclass
+class AtomicEffect:
+    """What a block's atomic txs do to the EVM state, for a caller that
+    applies it itself: the block fee contribution and atomic gas (None,
+    None before Apricot Phase 4), and ``rows()``."""
+    txs: List[Tx]
+    contribution: Optional[int]
+    gas_used: Optional[int]
+
+    def rows(self) -> List[Tuple[bool, bytes, int, int]]:
+        """EVMStateTransfer (import_tx.go:431 / export_tx.go:372) as
+        rows in ExtData order, ``(debit, address, wei, nonce)``: an
+        import output credits ``amount x 10^9`` wei (nonce unused, 0); an
+        export input debits as much from an account whose nonce has to
+        be ``nonce`` at that point, and sets it to ``nonce + 1``."""
+        out = []
+        for tx in self.txs:
+            debit = not isinstance(tx.unsigned, UnsignedImportTx)
+            for x in evm_side(tx.unsigned):
+                out.append((debit, x.address, x.amount * X2C_RATE,
+                            x.nonce if debit else 0))
+        return out
+
+
 def make_callbacks(backend: AtomicBackend, config,
                    pending_atomic_txs=None) -> ConsensusCallbacks:
     """ConsensusCallbacks wired to the atomic backend:
@@ -275,16 +332,18 @@ def make_callbacks(backend: AtomicBackend, config,
     - onExtraStateChange (vm.go:986): during block processing, decode
       ExtData, semantic-verify and apply EVMStateTransfer for each
       atomic tx, returning (block fee contribution wei, atomic gas)
+    - the same split for the device engine (``on_extra_state_rows``):
+      decode, semantic-verify, check conflicts and track the block in
+      the backend, and hand the state transfer back as an
+      ``AtomicEffect``; None where an EVM input or output is another
+      asset than AVAX (multicoin state, pre-Banff: the host path's)
     - onFinalizeAndAssemble (vm.go:979): at build time, pull atomic txs
       from `pending_atomic_txs()` (the mempool seam), apply them to the
       assembly state, and pack them as the block's ExtData
     """
     ctx = backend.ctx
 
-    def _apply_txs(txs, base_fee, number, time, statedb, parent_hash):
-        rules = config.rules(number, time)
-        contribution = 0
-        gas_used = 0
+    def _verify_txs(txs, base_fee, rules, parent_hash):
         seen_inputs = set()  # vm.verifyTxs: no UTXO spent twice per block
         for tx in txs:
             for inp in tx.unsigned.input_utxos():
@@ -294,16 +353,25 @@ def make_callbacks(backend: AtomicBackend, config,
             backend.semantic_verify(tx, base_fee, rules)
         # and none spent by a verified-but-unaccepted ancestor either
         backend.check_ancestor_conflicts(parent_hash, seen_inputs)
+
+    def _fees(txs, base_fee, rules):
+        if not rules.is_apricot_phase4:
+            return None, None
+        contribution = gas_used = 0
         for tx in txs:
-            if rules.is_apricot_phase4:
-                c, g = tx.block_fee_contribution(
-                    rules.is_apricot_phase5, ctx.avax_asset_id, base_fee)
-                contribution += c
-                gas_used += g
+            c, g = tx.block_fee_contribution(
+                rules.is_apricot_phase5, ctx.avax_asset_id, base_fee)
+            contribution += c
+            gas_used += g
+        return contribution, gas_used
+
+    def _apply_txs(txs, base_fee, number, time, statedb, parent_hash):
+        rules = config.rules(number, time)
+        _verify_txs(txs, base_fee, rules, parent_hash)
+        contribution, gas_used = _fees(txs, base_fee, rules)
+        for tx in txs:
             tx.unsigned.evm_state_transfer(ctx, statedb)
-        if rules.is_apricot_phase4:
-            return contribution, gas_used
-        return None, None
+        return contribution, gas_used
 
     def on_extra_state_change(block, statedb):
         txs = decode_ext_data(block.ext_data())
@@ -316,6 +384,23 @@ def make_callbacks(backend: AtomicBackend, config,
                            parent_hash=block.parent_hash)
         return contribution, gas_used
 
+    def on_extra_state_rows(block) -> Optional[AtomicEffect]:
+        txs = decode_ext_data(block.ext_data())
+        if any(x.asset_id != ctx.avax_asset_id
+               for tx in txs for x in evm_side(tx.unsigned)):
+            return None
+        rules = config.rules(block.number, block.time)
+        contribution, gas_used = _fees(txs, block.base_fee, rules)
+        block_hash = block.hash()
+        if txs and not backend.has_pending(block_hash):
+            # a block already tracked was verified before (on the host
+            # path beside the engine's branch, run again on Accept): its
+            # bookkeeping stands and is not made twice
+            _verify_txs(txs, block.base_fee, rules, block.parent_hash)
+            backend.insert_txs(block_hash, block.number, txs,
+                               parent_hash=block.parent_hash)
+        return AtomicEffect(txs, contribution, gas_used)
+
     def on_finalize_and_assemble(header, statedb, txs):
         atxs = pending_atomic_txs() if pending_atomic_txs else []
         if not atxs:
@@ -327,4 +412,5 @@ def make_callbacks(backend: AtomicBackend, config,
 
     return ConsensusCallbacks(
         on_extra_state_change=on_extra_state_change,
-        on_finalize_and_assemble=on_finalize_and_assemble)
+        on_finalize_and_assemble=on_finalize_and_assemble,
+        on_extra_state_rows=on_extra_state_rows)

@@ -51,6 +51,36 @@ def short_id(pubkey: Tuple[int, int]) -> bytes:
     return h.digest()
 
 
+def recover_pubkeys(digest: bytes, sigs: List[bytes]
+                    ) -> List[Tuple[int, int]]:
+    """The public key of each 65-byte [R||S||V] signature over
+    ``digest``: ONE native batch over them all
+    (crypto/native.recover_pubkeys_batch).  A signature the batch does
+    not vouch for, or every one without the native library, goes to the
+    pure-Python ``recover_pubkey`` — the reference — which recovers it or
+    raises as it always did."""
+    from coreth_tpu.crypto import native
+    keys: List[Optional[Tuple[int, int]]] = [None] * len(sigs)
+    lanes = [i for i, s in enumerate(sigs) if len(s) == 65]
+    if lanes and native.load() is not None:
+        out, ok = native.recover_pubkeys_batch(
+            digest * len(lanes), b"".join(sigs[i][0:32] for i in lanes),
+            b"".join(sigs[i][32:64] for i in lanes),
+            bytes(sigs[i][64] for i in lanes))
+        for j, i in enumerate(lanes):
+            if ok[j]:
+                keys[i] = (int.from_bytes(out[64 * j:64 * j + 32], "big"),
+                           int.from_bytes(out[64 * j + 32:64 * j + 64],
+                                          "big"))
+    for i, key in enumerate(keys):
+        if key is None:
+            sig = sigs[i]
+            keys[i] = secp.recover_pubkey(
+                digest, int.from_bytes(sig[0:32], "big"),
+                int.from_bytes(sig[32:64], "big"), sig[64])
+    return keys
+
+
 def calculate_dynamic_fee(cost: int, base_fee: Optional[int]) -> int:
     """nAVAX fee for `cost` gas at `base_fee` wei (tx.go:252)."""
     if base_fee is None:
@@ -438,16 +468,9 @@ class Tx:
 
     def _recover(self, to_addr) -> List[List[bytes]]:
         digest = sha256(self.unsigned_bytes())
-        out = []
-        for sigs in self.creds:
-            addrs = []
-            for sig in sigs:
-                r = int.from_bytes(sig[0:32], "big")
-                s = int.from_bytes(sig[32:64], "big")
-                pub = secp.recover_pubkey(digest, r, s, sig[64])
-                addrs.append(to_addr(pub))
-            out.append(addrs)
-        return out
+        pubs = iter(recover_pubkeys(
+            digest, [sig for sigs in self.creds for sig in sigs]))
+        return [[to_addr(next(pubs)) for _ in sigs] for sigs in self.creds]
 
     def recover_signers(self) -> List[List[bytes]]:
         """Short-id addresses recovered per credential (UTXO owners)."""

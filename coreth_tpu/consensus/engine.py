@@ -41,6 +41,12 @@ class ConsensusCallbacks:
     on_extra_state_change: Optional[Callable] = None
     # (header, statedb, txs) -> (extra_data, contribution, ext_gas_used)
     on_finalize_and_assemble: Optional[Callable] = None
+    # (block) -> effect | None: on_extra_state_change for a caller that
+    # applies the state transfer itself (the device engine) — the
+    # bookkeeping done, the transfer handed back (``effect.rows()``, with
+    # ``effect.contribution`` / ``effect.gas_used``); None where it
+    # cannot be rows
+    on_extra_state_rows: Optional[Callable] = None
 
 
 class DummyEngine:

@@ -62,6 +62,10 @@ def load():
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
         ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
     lib.coreth_ecrecover_batch.restype = None
+    lib.coreth_ecrecover_pubkeys.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
+    lib.coreth_ecrecover_pubkeys.restype = None
     lib.coreth_recover_wire.argtypes = [
         ctypes.c_char_p, ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64),
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
@@ -151,6 +155,20 @@ def recover_addresses_batch(hashes: bytes, rs: bytes, ss: bytes,
     out = ctypes.create_string_buffer(20 * n)
     ok = ctypes.create_string_buffer(n)
     _require().coreth_ecrecover_batch(hashes, rs, ss, recids, n, out, ok)
+    return out.raw, ok.raw
+
+
+def recover_pubkeys_batch(hashes: bytes, rs: bytes, ss: bytes,
+                          recids: bytes):
+    """recover_addresses_batch with the 64-byte public keys (x || y,
+    big-endian) in place of the addresses.  Returns (keys, ok) bytes,
+    ``ok`` as there."""
+    n = len(recids)
+    if not len(hashes) == len(rs) == len(ss) == 32 * n:
+        raise ValueError("hashes, r and s must be 32 bytes a signature")
+    out = ctypes.create_string_buffer(64 * n)
+    ok = ctypes.create_string_buffer(n)
+    _require().coreth_ecrecover_pubkeys(hashes, rs, ss, recids, n, out, ok)
     return out.raw, ok.raw
 
 

@@ -90,14 +90,6 @@ class VM:
             raise VMError("already initialized")
         genesis = parse_genesis_json(genesis_bytes)
         self.config = parse_config(config_bytes)
-        if self.config.state_processor == "device" \
-                and self.shared_memory is not None:
-            # atomic ExtData runs through consensus callbacks the
-            # engine's device paths do not make (ROADMAP R5): refuse,
-            # never compute on another path silently
-            raise VMError(
-                "state-processor \"device\" does not run the atomic "
-                "subsystem yet (shared_memory given): use \"host\"")
         engine = None
         if self.shared_memory is not None:
             from coreth_tpu.atomic import (
@@ -280,20 +272,25 @@ class VM:
         if self.warp_backend is not None:
             self._harvest_warp_messages(blk)
         if self.atomic_backend is not None:
-            from coreth_tpu.atomic import decode_ext_data
-            self.atomic_backend.accept(blk.id, height=blk.height)
-            txs = decode_ext_data(blk.block.ext_data())
-            if txs:
-                # index by tx id + height (atomic_tx_repository.go)
-                self.atomic_repository.write(blk.height, txs)
-                self.atomic_mempool.remove_accepted(
-                    [t.id() for t in txs])
-                # local txs spending the same UTXOs can never be valid
-                # again — drop them rather than letting the next build
-                # pull a guaranteed-to-fail spender
-                consumed = [i for t in txs
-                            for i in t.unsigned.input_utxos()]
-                self.atomic_mempool.remove_conflicts(consumed)
+            with self.account().enter("vm/atomic_accept"):
+                self._accept_atomic(blk)
+
+    def _accept_atomic(self, blk: PluginBlock) -> None:
+        """The block's atomic effect leaves the backend's processing set
+        for the atomic trie and shared memory (atomicState.Accept); its
+        txs are indexed and leave the mempool."""
+        from coreth_tpu.atomic import decode_ext_data
+        self.atomic_backend.accept(blk.id, height=blk.height)
+        txs = decode_ext_data(blk.block.ext_data())
+        if txs:
+            # index by tx id + height (atomic_tx_repository.go)
+            self.atomic_repository.write(blk.height, txs)
+            self.atomic_mempool.remove_accepted([t.id() for t in txs])
+            # local txs spending the same UTXOs can never be valid
+            # again — drop them rather than letting the next build
+            # pull a guaranteed-to-fail spender
+            consumed = [i for t in txs for i in t.unsigned.input_utxos()]
+            self.atomic_mempool.remove_conflicts(consumed)
 
     def _on_reject(self, blk: PluginBlock) -> None:
         if self.atomic_backend is not None:
@@ -366,8 +363,9 @@ class VM:
     def account(self):
         """The self-time account the consensus calls are phases of
         (``vm/parse``, ``vm/verify``, ``vm/insert``, ``vm/accept``,
-        ``vm/rollback``): the device state processor's engine's, so
-        they sum with its own; no account under the host processor."""
+        ``vm/atomic_accept``, ``vm/rollback``): the device state
+        processor's engine's, so they sum with its own; no account under
+        the host processor."""
         backend = self.chain.state_processor
         return obs.NULL_ACCOUNT if backend is None else backend.account
 

@@ -27,6 +27,17 @@ chain/ may not import replay/ (tools/lint/layers.toml), so ``eth``
 hands ``BlockChain`` this class as a factory and the chain calls what
 it is given: ``extends_tip``, ``execute``, ``accept``, ``reject``,
 ``reset``, ``note_host_verified``, ``account``.
+
+Atomic txs (a VM with shared memory).  The engine's window of one makes
+the calls the host path makes through ``on_extra_state_change``: its
+classify asks the chain's consensus callbacks for the block's atomic
+bookkeeping (``on_extra_state_rows``: semantic verify, conflicts, the
+backend's record of the block) and applies the state transfer as lanes
+of the same window.  Accept and Reject of the atomic effect stay the
+VM's (shared memory, the atomic trie); a rollback takes the atomic rows
+back with the block's other rows, since they are device rows and flat
+generations like any other.  An accepted block run again here after a
+rollback finds its record in the backend and is not recorded twice.
 """
 
 from __future__ import annotations

@@ -8,8 +8,10 @@ tx loop) for TPU:
    callee has no code and no multicoin flag) or an ERC-20 ``transfer()``
    call on a known-bytecode token (workloads/erc20) — exact per-tx gas
    derived from a host-side scalar simulation of the mapping-slot
-   sequence.  Anything else routes through the bit-exact host Processor
-   (execute-validate fallback, cf. SURVEY.md section 2.8).
+   sequence.  A block's atomic txs (ExtData) join it as credit / debit
+   lanes after its transactions.  Anything else routes through the
+   bit-exact host Processor (execute-validate fallback, cf. SURVEY.md
+   section 2.8).
 2. **Execute** (device): one jitted step per block — per-sender debits,
    per-recipient credits, and per-storage-slot token debits/credits as
    segment reductions over 16x16-bit limb arrays (ops/u256), with
@@ -42,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from coreth_tpu import faults, obs
+from coreth_tpu.atomic.tx import AtomicTxError
 from coreth_tpu.obs import recorder as forensics
 from coreth_tpu.consensus.engine import DummyEngine
 from coreth_tpu.ops import u256
@@ -170,6 +173,15 @@ class ReplayStats:
     # scanned (the window runner counts; machine_block._chunk_loop)
     machine_lanes_real: int = 0
     machine_lanes_padded: int = 0
+    # blocks with atomic txs in their ExtData (plugin/evm import / export
+    # txs): committed on the device path, their state transfer lanes
+    # after the block's transactions, and the atomic txs they held; and
+    # those the engine ran on the host path instead (an EVM input or
+    # output of another asset than AVAX, callbacks with no atomic
+    # semantics, or a device refusal)
+    atomic_blocks_device: int = 0
+    atomic_txs_device: int = 0
+    atomic_blocks_host: int = 0
     # blocks applied tolerantly after failing validation on every
     # backend (supervisor quarantine — streaming callers only)
     blocks_quarantined: int = 0
@@ -259,12 +271,24 @@ def _public_call(fn):
 
 # Packed tx-batch column layout — ONE host->device transfer per block
 # (each separate transfer pays a dispatch and a sync of its own):
-#   0 sender_idx | 1 recip_idx | 2 tx_nonce | 3 nonce_offset | 4 mask
+#   0 sender_idx | 1 recip_idx | 2 tx_nonce | 3 nonce_offset | 4 kind
 #   5 coinbase_idx (broadcast) | 6:22 value16 | 22:38 fee16
 #   38:54 required16 | 54 from_slot | 55 to_slot | 56:72 amount16
 # Native transfers carry amount16 = 0 / slots = 0 (the reserved dummy);
 # token transfers carry value16 = 0.  Both kinds batch into one step.
 TXD_COLS = 72
+# Column 4 says what a lane does.  Bit LANE_DEBIT: it takes value + fee
+# from its sender under the sender's next nonce; bit LANE_CREDIT: it
+# pays value to its recipient.  A transaction does both; an atomic tx's
+# state transfer rides the same step as lanes after the block's
+# transactions (_atomic_lanes): an export input debits alone, an import
+# output credits alone.  A padding lane does neither.
+LANE_DEBIT = 1
+LANE_CREDIT = 2
+LANE_TX = LANE_DEBIT | LANE_CREDIT
+# Device nonces are int32: an export whose nonce (or the nonce it sets)
+# does not fit takes the host path
+NONCE_CAP = (1 << 31) - 1
 # Lane (tx) axis of a transfer window: the smallest LANE_FLOOR *
 # LANE_STEP**n that holds the window's largest block — 16, 64, 256,
 # 1024, ... — so a window of one-tx blocks packs, uploads and scans 16
@@ -289,7 +313,7 @@ def pack_txd(txd: np.ndarray, batch: dict, B: int) -> None:
     txd[:B, 1] = batch["recips"]
     txd[:B, 2] = batch["nonces"]
     txd[:B, 3] = batch["offsets"]
-    txd[:B, 4] = 1
+    txd[:B, 4] = batch.get("kinds", LANE_TX)
     txd[:, 5] = batch["coinbase"]
     txd[:B, 6:22] = u256.pack_np(batch["values"])
     txd[:B, 22:38] = u256.pack_np(batch["fees"])
@@ -304,11 +328,14 @@ def txd_cols(txd):
     pack_txd layout (both execution backends consume it through this,
     so a layout change cannot silently diverge them).  Returns
     (senders, recips, values16, fees16, required16, tx_nonce,
-    nonce_offset, mask, coinbase, from_slots, to_slots, amount16)."""
+    nonce_offset, debits, coinbase, from_slots, to_slots, amount16,
+    credits): ``debits`` / ``credits`` the lanes with bit LANE_DEBIT /
+    LANE_CREDIT of column 4."""
+    kind = txd[:, 4]
     return (txd[:, 0], txd[:, 1], txd[:, 6:22], txd[:, 22:38],
             txd[:, 38:54], txd[:, 2], txd[:, 3],
-            txd[:, 4].astype(bool), txd[0, 5], txd[:, 54], txd[:, 55],
-            txd[:, 56:72])
+            (kind & LANE_DEBIT) != 0, txd[0, 5], txd[:, 54], txd[:, 55],
+            txd[:, 56:72], (kind & LANE_CREDIT) != 0)
 
 
 def _gather_fetch(balances, nonces, slot_vals, ok, t_idx, s_idx,
@@ -332,13 +359,14 @@ def _gather_fetch(balances, nonces, slot_vals, ok, t_idx, s_idx,
 def _step_core(balances, nonces, slot_vals, txd, num_accounts: int,
                num_slots: int):
     """One block of transfers (native + token) from a packed batch."""
-    (senders, recips, values, fees, required, tx_nonce, offsets, mask,
-     coinbase, from_slots, to_slots, amounts) = txd_cols(txd)
+    (senders, recips, values, fees, required, tx_nonce, offsets, debits,
+     coinbase, from_slots, to_slots, amounts, credits) = txd_cols(txd)
     nb, nn, ok, pre_ok = _transfer_step(
         balances, nonces, senders, recips, values, fees, required,
-        tx_nonce, offsets, mask, coinbase, num_accounts=num_accounts)
+        tx_nonce, offsets, debits, coinbase, num_accounts=num_accounts,
+        credits=credits)
     sv, ok_slots = _slot_step(
-        slot_vals, from_slots, to_slots, amounts, mask,
+        slot_vals, from_slots, to_slots, amounts, debits,
         num_slots=num_slots)
     return nb, nn, sv, ok & ok_slots, pre_ok & ok_slots
 
@@ -487,8 +515,14 @@ def _order_solvent(balances, sender_idx, recip_idx, credit, debit,
 @partial(jax.jit, static_argnames=("num_accounts",))
 def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
                    required16, tx_nonce, nonce_offset, mask, coinbase_idx,
-                   num_accounts: int):
+                   num_accounts: int, credits=None):
     """One block of pure transfers, batched.
+
+    ``mask`` holds the lanes that debit their sender (value + fee) under
+    its next nonce, ``credits`` those that pay their recipient the value
+    (None: the same lanes).  A transaction is both; an atomic tx's
+    export input debits alone and its import output credits alone, each
+    in its place after the block's transactions (pack_txd's column 4).
 
     required16 carries the buyGas balance requirement per tx
     (gas_limit * gas_fee_cap + value, state_transition.go:286).  The
@@ -524,7 +558,8 @@ def _transfer_step(balances, nonces, sender_idx, recip_idx, value16, fee16,
     debit = u256.add(value16, fee16)                      # [B, 16]
     debit = debit * mask_i[:, None]
     required = required16 * mask_i[:, None]
-    credit = value16 * mask_i[:, None]
+    credit_i = mask_i if credits is None else credits.astype(jnp.int32)
+    credit = value16 * credit_i[:, None]
     # nonce sequence: state nonce + #earlier same-sender txs in block
     expected = nonces[sender_idx] + nonce_offset
     nonce_ok = jnp.all(jnp.where(mask, tx_nonce == expected, True))
@@ -977,10 +1012,10 @@ class ReplayEngine:
         self.state = DeviceState(capacity, slot_capacity or capacity,
                                  n_shards=self._n_shards)
         self.signer = LatestSigner(config.chain_id)
-        # a DummyEngine with ConsensusCallbacks makes the host fallback
-        # path apply atomic ExtData txs (onExtraStateChange,
-        # plugin/evm/vm.go:986) — required to replay Avalanche-semantics
-        # segments (BASELINE config[4])
+        # a DummyEngine with ConsensusCallbacks gives the engine the
+        # atomic ExtData txs' semantics: the host fallback applies them
+        # through onExtraStateChange (plugin/evm/vm.go:986), the device
+        # path through on_extra_state_rows (_atomic_lanes)
         self.engine = engine or DummyEngine()
         self.engine.set_config(config)
         self.processor = Processor(config, engine=self.engine)
@@ -1069,6 +1104,9 @@ class ReplayEngine:
         # native call checks the receipt root)
         self.keep_receipts = False
         self.last_receipts: Optional[List[Receipt]] = None
+        # atomic txs of the last block the device path validated (a
+        # processing block whose window fold fails is counted back out)
+        self._atomic_txs_last = 0
         self.account.end(build)
 
     # ---------------------------------------------------------------- index
@@ -1286,11 +1324,9 @@ class ReplayEngine:
         simulating the mapping-slot value sequence on host (scalar dict
         updates — the O(txs) bookkeeping that replaces O(gas) host
         interpretation) and pre-builds the Transfer log; the wide u256
-        slot arithmetic itself runs batched on device (_slot_step)."""
-        if block.ext_data():
-            # atomic ExtData applies through the engine callbacks on
-            # the exact host path only
-            return None
+        slot arithmetic itself runs batched on device (_slot_step).  A
+        block's atomic txs (ExtData) ride the same batch as lanes after
+        its transactions (_atomic_lanes)."""
         if not self.supervisor.allows("device"):
             # supervisor demoted the device scope: every block routes
             # through the host ladder until the cooldown lapses (the
@@ -1389,15 +1425,75 @@ class ReplayEngine:
             prev = seen_count.get(sender, 0)
             offsets.append(prev)
             seen_count[sender] = prev + 1
-        coinbase_idx = self._account(block.header.coinbase)
+        batch = dict(senders=senders, recips=recips, values=values,
+                     fees=fees, required=required, nonces=nonces,
+                     offsets=offsets, from_slots=from_slots,
+                     to_slots=to_slots, amounts=amounts,
+                     gas_used=gas_used, logs=tx_logs)
+        if block.ext_data():
+            atomic = self._atomic_lanes(block, seen_count)
+            if atomic is None:
+                return None
+            batch["atomic"], lanes = atomic
+            batch["kinds"] = [LANE_TX] * len(senders)
+            for kind, idx, wei, nonce, offset in lanes:
+                batch["kinds"].append(kind)
+                senders.append(idx)
+                recips.append(idx)
+                values.append(wei)
+                fees.append(0)
+                required.append(wei if kind == LANE_DEBIT else 0)
+                nonces.append(nonce)
+                offsets.append(offset)
+                from_slots.append(0)
+                to_slots.append(0)
+                amounts.append(0)
+        batch["coinbase"] = self._account(block.header.coinbase)
         # the block classified clean: its slot writes become visible to
         # the next block's classification within this pending window
         self._slot_overlay.update(overlay)
-        return dict(senders=senders, recips=recips, values=values,
-                    fees=fees, required=required, nonces=nonces,
-                    offsets=offsets, coinbase=coinbase_idx,
-                    from_slots=from_slots, to_slots=to_slots,
-                    amounts=amounts, gas_used=gas_used, logs=tx_logs)
+        return batch
+
+    def _atomic_lanes(self, block: Block, seen_count: Dict[bytes, int]):
+        """``(effect, lanes)`` for the atomic txs in a block's ExtData,
+        or None: the host path takes the block.  The consensus
+        callbacks keep the bookkeeping that is not state
+        (``on_extra_state_rows``: decode, semantic verify with the
+        credentials, input conflicts, the backend's record of the block;
+        phase ``vm/atomic_verify``) and hand the state transfer back as
+        rows, which become lanes here (``classify/atomic``): ``(kind,
+        account, wei, nonce, offset)``, a credit of wei to the account,
+        or a debit of wei under the account's nonce — its offset counts
+        the block's earlier lanes from that account, so the nonce an
+        export must carry is the one the block's transactions leave.
+        None too without the callback (nothing to apply the atomic
+        semantics with) or where it declines (another asset than AVAX)."""
+        hook = self.engine.cb.on_extra_state_rows
+        if hook is None:
+            return None
+        acct = self.account
+        try:
+            with acct.enter("vm/atomic_verify"):
+                effect = hook(block)
+        except (AtomicTxError, ValueError):
+            return None  # the host path raises the canonical rejection
+        if effect is None:
+            return None
+        lanes = []
+        with acct.enter("classify/atomic"):
+            for debit, addr, wei, nonce in effect.rows():
+                idx = self.state.index.get(addr)
+                if idx is None:
+                    idx = self._account(addr)
+                if not debit:
+                    lanes.append((LANE_CREDIT, idx, wei, 0, 0))
+                    continue
+                if nonce >= NONCE_CAP:
+                    return None
+                offset = seen_count.get(addr, 0)
+                seen_count[addr] = offset + 1
+                lanes.append((LANE_DEBIT, idx, wei, nonce, offset))
+        return effect, lanes
 
     def _slot_view(self, s_idx: int, overlay: Dict[int, int]) -> int:
         """Sequential slot value as of the current classification point:
@@ -1503,8 +1599,7 @@ class ReplayEngine:
         K = 1
         while K < len(items):
             K *= 2
-        pad = lane_bucket(max(len(block.transactions)
-                              for block, _ in items))
+        pad = lane_bucket(max(len(batch["senders"]) for _, batch in items))
         t_pad = 256
         s_pad = 8
         touched_lists = []
@@ -1573,7 +1668,7 @@ class ReplayEngine:
         for g, l in slot_local.items():
             slot_gids[l] = self.state.slot_row_of[g]
         for k, (block, batch) in enumerate(items):
-            pack_txd(txds[k], local_batches[k], len(block.transactions))
+            pack_txd(txds[k], local_batches[k], len(batch["senders"]))
             t_idxs[k, :len(touched_lists[k])] = \
                 [acct_local[g] for g in touched_lists[k]]
             s_idxs[k, :len(slot_lists[k])] = \
@@ -1583,7 +1678,7 @@ class ReplayEngine:
 
     def _count_lanes(self, items, txds) -> None:
         self.stats.lanes_real += sum(
-            len(block.transactions) for block, _ in items)
+            len(batch["senders"]) for _, batch in items)
         self.stats.lanes_padded += txds.shape[0] * txds.shape[1]
 
     @_in_phase("window/prepare")
@@ -1955,15 +2050,28 @@ class ReplayEngine:
                 gas_used=gas_list[i],
                 logs=[logs[i]] if logs[i] is not None else [])
                 for i, tx in enumerate(block.transactions)]
+        atomic = batch.get("atomic")
         if self.config.is_apricot_phase4(block.time):
             if receipts is None:
                 # verify_block_fee reads only gas_used per receipt
                 receipts = [Receipt(gas_used=g) for g in gas_list]
+            # the atomic txs' gas and fee contribution, as Finalize
+            # holds them (consensus/engine.py)
+            ext_gas = 0
+            contribution = None
+            if atomic is not None:
+                ext_gas = atomic.gas_used or 0
+                contribution = atomic.contribution
+            if block.header.ext_data_gas_used != ext_gas:
+                raise _block_error(
+                    f"invalid extDataGasUsed: have "
+                    f"{block.header.ext_data_gas_used}, want {ext_gas}",
+                    block)
             from coreth_tpu.consensus.engine import ConsensusError
             try:
                 self.engine.verify_block_fee(
                     block.base_fee, block.header.block_gas_cost,
-                    block.transactions, receipts, None)
+                    block.transactions, receipts, contribution)
             except ConsensusError as exc:
                 # ReplayError so _complete_window's host retry (and
                 # the pipeline quarantine) own it, with the block
@@ -1994,6 +2102,10 @@ class ReplayEngine:
         self.parent_header = block.header
         self.stats.blocks_device += 1
         self.stats.txs += B
+        self._atomic_txs_last = 0 if atomic is None else len(atomic.txs)
+        if atomic is not None:
+            self.stats.atomic_blocks_device += 1
+            self.stats.atomic_txs_device += self._atomic_txs_last
 
     def _try_machine(self, block: Block) -> bool:
         """Execute an unclassifiable block on the general device step
@@ -2130,6 +2242,9 @@ class ReplayEngine:
             # one comes after the fold
             self.stats.blocks_device -= 1
             self.stats.txs -= len(block.transactions)
+            if block.ext_data():
+                self.stats.atomic_blocks_device -= 1
+                self.stats.atomic_txs_device -= self._atomic_txs_last
             self._restore(prev_root, prev_header, sorted(exc.accounts),
                           sorted(exc.writes))
             raise
@@ -2578,6 +2693,7 @@ class ReplayEngine:
         if self.keep_receipts:
             self.last_receipts = receipts
         self.stats.blocks_fallback += 1
+        self.stats.atomic_blocks_host += bool(block.ext_data())
         self.stats.txs += len(block.transactions)
         self.stats.t_fallback += time.monotonic() - t0
         return root

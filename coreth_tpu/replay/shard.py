@@ -113,11 +113,11 @@ def _build_window(mesh, mode: str = "psum"):
             txd, t_idx, s_idx = inp      # txd: (P/n, C) local tx shard
             (senders, recips, values, fees, required, tx_nonce,
              offsets, mask, coinbase, from_slots, to_slots,
-             amounts) = txd_cols(txd)
+             amounts, credits) = txd_cols(txd)
             mask_i = mask.astype(jnp.int32)
             debit = u256.add(values, fees) * mask_i[:, None]
             req = required * mask_i[:, None]
-            credit = values * mask_i[:, None]
+            credit = values * credits.astype(jnp.int32)[:, None]
             amt = amounts * mask_i[:, None]
             # full-working-set partials from the local tx shard
             debit_p = jax.ops.segment_sum(debit, senders,

@@ -836,20 +836,37 @@ bool lift_x(const U256& r, int recid, Fe& x, Fe& y) {
   return true;
 }
 
-// out20 = the address of affine (x, y): keccak of the 64-byte key, low
-// 20 bytes
-void address_of(const Fe& x, const Fe& y, uint8_t* out20) {
+// out64 = the uncompressed public key of affine (x, y): x || y, each
+// 32 bytes big-endian
+void pubkey_of(const Fe& x, const Fe& y, uint8_t* out64) {
   Fe nx = x, ny = y;
   fe_normalize(nx);
   fe_normalize(ny);
   U256 ax, ay;
   fe_to_u256(ax, nx);
   fe_to_u256(ay, ny);
+  store_be(out64, ax);
+  store_be(out64 + 32, ay);
+}
+
+// out20 = the address of affine (x, y): keccak of the 64-byte key, low
+// 20 bytes
+void address_of(const Fe& x, const Fe& y, uint8_t* out20) {
   uint8_t pub[64], digest[32];
-  store_be(pub, ax);
-  store_be(pub + 32, ay);
+  pubkey_of(x, y, pub);
   coreth_keccak256(pub, 64, digest);
   std::memcpy(out20, digest + 12, 20);
+}
+
+// What a recovered key is written as: its address (20 bytes) or the
+// key itself (64)
+enum KeyOut { KEY_ADDRESS = 20, KEY_PUBLIC = 64 };
+
+void key_out(const Fe& x, const Fe& y, KeyOut form, uint8_t* out) {
+  if (form == KEY_PUBLIC)
+    pubkey_of(x, y, out);
+  else
+    address_of(x, y, out);
 }
 
 // ---- batch-only fast recovery (coreth_ecrecover_batch) ----
@@ -1144,15 +1161,49 @@ void fast_ladder(Point& acc, const FastSig& fs, const APoint* tbl_aff) {
   }
 }
 
+// The sequential recovery (one Shamir ladder): the affine public key of
+// (hash, r, s, recid), false on an invalid signature.
+bool recover_affine(const uint8_t* hash32, const uint8_t* r32,
+                    const uint8_t* s32, int recid, Fe& ax, Fe& ay) {
+  if (recid < 0 || recid > 3) return false;
+  U256 r, s, z;
+  load_be(r, r32);
+  load_be(s, s32);
+  load_be(z, hash32);
+  if (is_zero(r) || is_zero(s)) return false;
+  if (cmp(r, ORDER) >= 0 || cmp(s, ORDER) >= 0) return false;
+  Point q;
+  q.z = FE_ONE;
+  if (!lift_x(r, recid, q.x, q.y)) return false;  // non-residue: invalid r
+  // u1 = -z/r mod n ; u2 = s/r mod n
+  U256 rinv, u1, u2;
+  reduce_hash(z);
+  sc_inv(rinv, r);
+  sc_mul(u1, z, rinv);
+  if (!is_zero(u1)) mod_sub(u1, ORDER, u1, ORDER);
+  sc_mul(u2, s, rinv);
+  Point res;
+  pt_shamir(res, u1, u2, q);
+  if (pt_is_inf(res)) return false;
+  // to affine
+  Fe zinv, zinv2, t;
+  fe_inv(zinv, res.z);
+  fe_sqr(zinv2, zinv);
+  fe_mul(ax, res.x, zinv2);
+  fe_mul(t, zinv2, zinv);
+  fe_mul(ay, res.y, t);
+  return true;
+}
+
 // Fast batch over [lo, hi): shared r^-1 batch inversion, shared wNAF
 // table normalization, per-signature ladders, shared final affine
 // conversion.  Each index the fast path cannot carry falls back to
-// the sequential coreth_ecrecover, and is marked ok = 2 if that
-// recovers it.
+// the sequential recover_affine, and is marked ok = 2 if that recovers
+// it.  Key i is written at out + form * i.
 void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
                         const uint8_t* ss, const uint8_t* recids,
-                        uint64_t lo, uint64_t hi, uint8_t* out,
-                        uint8_t* ok) {
+                        uint64_t lo, uint64_t hi, KeyOut form,
+                        uint8_t* out, uint8_t* ok) {
   std::call_once(g_comb_once, build_g_comb);
   const uint64_t n = hi - lo;
   std::vector<U256> r_l(n), prefix(n);
@@ -1216,14 +1267,17 @@ void fast_recover_range(const uint8_t* hashes, const uint8_t* rs,
   for (uint64_t j = 0; j < n; ++j) {
     uint64_t i = lo + j;
     if (state[j] == 2) {
-      ok[i] = coreth_ecrecover(hashes + 32 * i, rs + 32 * i, ss + 32 * i,
-                               recids[i], out + 20 * i)
-                  ? 2
-                  : 0;
+      Fe ax, ay;
+      ok[i] = 0;
+      if (recover_affine(hashes + 32 * i, rs + 32 * i, ss + 32 * i,
+                         recids[i], ax, ay)) {
+        key_out(ax, ay, form, out + (uint64_t)form * i);
+        ok[i] = 2;
+      }
       continue;
     }
     if (state[j] != 1 || res_aff[j].inf) continue;
-    address_of(res_aff[j].x, res_aff[j].y, out + 20 * i);
+    key_out(res_aff[j].x, res_aff[j].y, form, out + (uint64_t)form * i);
     ok[i] = 1;
   }
 }
@@ -1464,33 +1518,8 @@ extern "C" {
 // Returns 1 on success, 0 on invalid signature.
 int coreth_ecrecover(const uint8_t* hash32, const uint8_t* r32,
                      const uint8_t* s32, int recid, uint8_t* out20) {
-  if (recid < 0 || recid > 3) return 0;
-  U256 r, s, z;
-  load_be(r, r32);
-  load_be(s, s32);
-  load_be(z, hash32);
-  if (is_zero(r) || is_zero(s)) return 0;
-  if (cmp(r, ORDER) >= 0 || cmp(s, ORDER) >= 0) return 0;
-  Point q;
-  q.z = FE_ONE;
-  if (!lift_x(r, recid, q.x, q.y)) return 0;  // non-residue: invalid r
-  // u1 = -z/r mod n ; u2 = s/r mod n
-  U256 rinv, u1, u2;
-  reduce_hash(z);
-  sc_inv(rinv, r);
-  sc_mul(u1, z, rinv);
-  if (!is_zero(u1)) mod_sub(u1, ORDER, u1, ORDER);
-  sc_mul(u2, s, rinv);
-  Point res;
-  pt_shamir(res, u1, u2, q);
-  if (pt_is_inf(res)) return 0;
-  // to affine
-  Fe zinv, zinv2, ax, ay, t;
-  fe_inv(zinv, res.z);
-  fe_sqr(zinv2, zinv);
-  fe_mul(ax, res.x, zinv2);
-  fe_mul(t, zinv2, zinv);
-  fe_mul(ay, res.y, t);
+  Fe ax, ay;
+  if (!recover_affine(hash32, r32, s32, recid, ax, ay)) return 0;
   address_of(ax, ay, out20);
   return 1;
 }
@@ -1675,7 +1704,22 @@ void coreth_ecrecover_batch(const uint8_t* hashes, const uint8_t* rs,
     return;
   }
   in_chunks(n, [=](uint64_t lo, uint64_t hi) {
-    fast_recover_range(hashes, rs, ss, recids, lo, hi, out, ok);
+    fast_recover_range(hashes, rs, ss, recids, lo, hi, KEY_ADDRESS, out,
+                       ok);
+  });
+}
+
+// The batch above with the 64-byte public keys (x || y, big-endian) in
+// place of the addresses: what an Avalanche short id is made from
+// (ripemd160(sha256(compressed key))), which an address cannot give.
+// ok as above.  The atomic txs' credentials recover through it, one
+// call a transaction over all its signatures.
+void coreth_ecrecover_pubkeys(const uint8_t* hashes, const uint8_t* rs,
+                              const uint8_t* ss, const uint8_t* recids,
+                              uint64_t n, uint8_t* out64, uint8_t* ok) {
+  in_chunks(n, [=](uint64_t lo, uint64_t hi) {
+    fast_recover_range(hashes, rs, ss, recids, lo, hi, KEY_PUBLIC, out64,
+                       ok);
   });
 }
 
@@ -1708,7 +1752,7 @@ void coreth_recover_wire(const uint8_t* wire, uint64_t wire_len,
         rec[i] = recid;
     }
     if (!per_sig) {
-      fast_recover_range(h, r, s, rec, lo, hi, out, ok);
+      fast_recover_range(h, r, s, rec, lo, hi, KEY_ADDRESS, out, ok);
       return;
     }
     for (uint64_t i = lo; i < hi; ++i)  // the A/B knob, as above

@@ -676,16 +676,32 @@ def test_vm_phases_and_counters_are_published():
 
 # ============================================================ the selector
 def test_host_is_the_default_and_device_refuses_shared_memory():
-    gj = genesis_to_json(Forks().genesis)
+    """Host is the default; "device" takes shared memory (the atomic
+    subsystem runs on the engine: tests/test_atomic_device.py) and runs
+    blocks; an unknown value is refused."""
+    f = Forks()
+    gj = genesis_to_json(f.genesis)
     vm = VM()
     vm.initialize(gj)
     assert vm.config.state_processor == "host"
     assert vm.chain.state_processor is None and vm.chain.snaps is not None
     vm.shutdown()
     from coreth_tpu.atomic import Memory
-    vm = VM(shared_memory=Memory().new_shared_memory(b"\x01" * 32))
-    with pytest.raises(VMError, match="atomic subsystem"):
-        vm.initialize(gj, json.dumps({"state-processor": "device"}))
+    vm = VM(clock=lambda: f.now[0], engine_kw=dict(ENGINE_KW),
+            shared_memory=Memory().new_shared_memory(b"\x11" * 32))
+    vm.initialize(gj, json.dumps({"state-processor": "device"}))
+    assert vm.atomic_backend is not None
+    sp = vm.chain.state_processor
+    assert sp.engine.engine.cb.on_extra_state_rows is not None
+    a = f.make(f.g)
+    blk = vm.parse_block(a.encode())
+    blk.verify()
+    blk.accept()
+    assert vm.chain.last_accepted.root == a.root
+    assert sp.stats.blocks_verified_device == 1
+    vm.shutdown()
+    for other in f.vms.values():
+        other.shutdown()
     vm = VM()
     with pytest.raises(VMError, match="state_processor 'gpu'"):
         vm.initialize(gj, json.dumps({"state-processor": "gpu"}))
