@@ -148,7 +148,45 @@ class DummyEngine:
                          required_block_gas_cost: Optional[int],
                          txs, receipts,
                          extra_contribution: Optional[int]) -> None:
-        """verifyBlockFee (consensus.go:289)."""
+        """verifyBlockFee (consensus.go:289): the tips from each
+        transaction and its receipt."""
+        def tips(base_fee: int) -> int:
+            total = 0
+            for tx, receipt in zip(txs, receipts):
+                premium = tx.effective_gas_tip(base_fee)
+                if premium < 0:
+                    raise ConsensusError("negative effective tip")
+                total += premium * receipt.gas_used
+            return total
+        self._verify_tips(base_fee, required_block_gas_cost, tips,
+                          extra_contribution)
+
+    def verify_block_fee_columns(self, base_fee: Optional[int],
+                                 required_block_gas_cost: Optional[int],
+                                 paid: int, gas_used: int,
+                                 extra_contribution: Optional[int]) -> None:
+        """verify_block_fee from a block's totals: ``paid`` is the sum
+        of used_i x min(base_fee + tip_i, fee_cap_i) and ``gas_used``
+        the sum of used_i over its transactions, none of whose fee caps
+        is below the base fee (the replay classifier's fee column holds
+        these terms and refuses a lower cap).  Each term less base_fee x
+        used_i is that transaction's premium x used_i, so the tips are
+        paid - base_fee x gas_used."""
+        def tips(base_fee: int) -> int:
+            total = paid - base_fee * gas_used
+            if total < 0:
+                raise ConsensusError("negative effective tip")
+            return total
+        self._verify_tips(base_fee, required_block_gas_cost, tips,
+                          extra_contribution)
+
+    def _verify_tips(self, base_fee: Optional[int],
+                     required_block_gas_cost: Optional[int],
+                     tips: Callable[[int], int],
+                     extra_contribution: Optional[int]) -> None:
+        """The checks of verifyBlockFee round the tip total, in its
+        order: ``tips(base_fee)`` gives the sum of premium x gas used,
+        asked for once the base fee is known to be valid."""
         if self.mode.skip_block_fee:
             return
         if base_fee is None or base_fee <= 0:
@@ -161,11 +199,7 @@ class DummyEngine:
             if extra_contribution < 0:
                 raise ConsensusError("negative extra contribution")
             total_block_fee += extra_contribution
-        for tx, receipt in zip(txs, receipts):
-            premium = tx.effective_gas_tip(base_fee)
-            if premium < 0:
-                raise ConsensusError("negative effective tip")
-            total_block_fee += premium * receipt.gas_used
+        total_block_fee += tips(base_fee)
         block_gas = total_block_fee // base_fee
         if block_gas < required_block_gas_cost:
             raise ConsensusError(

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+from array import array
 
 from coreth_tpu import nativebuild
 
@@ -85,10 +86,11 @@ def load():
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
         ctypes.POINTER(ctypes.c_double)]
     lib.coreth_baseline_replay.restype = ctypes.c_int
-    lib.coreth_receipt_root.argtypes = [
+    lib.coreth_receipt_check.argtypes = [
         ctypes.POINTER(ctypes.c_uint64), ctypes.c_char_p, ctypes.c_char_p,
-        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p]
-    lib.coreth_receipt_root.restype = None
+        ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
+        ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
+    lib.coreth_receipt_check.restype = ctypes.c_int
     lib.coreth_evm_replay.argtypes = [
         ctypes.c_char_p, ctypes.c_uint64,
         ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64,
@@ -271,21 +273,34 @@ def evm_replay(tx_records: bytes, block_offsets, block_env: bytes,
     return rc, list(phases)
 
 
-def receipt_root(cum_gas, tx_types: bytes, has_log: bytes,
-                 log_blob: bytes):
-    """Receipt-trie root + header bloom for a device-path block in one
-    C++ call (DeriveSha/StackTrie + CreateBloom role — reference
-    core/types/hashing.go:97, bloom9.go).  Receipts are status-1 with 0
-    or 1 Transfer-shaped log (addr20 ++ 3*topic32 ++ data32 = 148B).
-    Returns (root32, bloom256)."""
+def receipt_check(gas_used, tx_types: bytes, has_log: bytes,
+                  log_blob: bytes):
+    """Receipt-trie root, header bloom and gas of a device-path block in
+    one C++ call (DeriveSha/StackTrie + CreateBloom role — reference
+    core/types/hashing.go:97, bloom9.go).  Receipt i is status 1, of
+    type ``tx_types[i]``, used ``gas_used[i]`` (an ``array('Q')``, or
+    any sequence of ints, packed here) and holds ``has_log[i]`` (0 / 1)
+    Transfer-shaped log; ``log_blob`` packs those logs in order, addr20
+    ++ 3*topic32 ++ data32 = 148 bytes each.  Returns (root32,
+    bloom256, gas); ValueError for columns of unequal lengths, a
+    has_log byte other than 0 / 1, a log blob of the wrong length, or
+    gas past 64 bits."""
     lib = _require()
     n = len(tx_types)
-    cg = (ctypes.c_uint64 * n)(*cum_gas)
+    if not (isinstance(gas_used, array) and gas_used.typecode == "Q"):
+        gas_used = array("Q", gas_used)
+    if len(gas_used) != n or len(has_log) != n:
+        raise ValueError(f"columns of {len(gas_used)} gas, {n} types and "
+                         f"{len(has_log)} log flags")
     root = ctypes.create_string_buffer(32)
     bloom = ctypes.create_string_buffer(256)
-    lib.coreth_receipt_root(cg, tx_types, has_log, log_blob, n, root,
-                            bloom)
-    return root.raw, bloom.raw
+    gas = ctypes.c_uint64()
+    rc = lib.coreth_receipt_check(
+        (ctypes.c_uint64 * n).from_buffer(gas_used), tx_types, has_log, n,
+        log_blob, len(log_blob), root, bloom, ctypes.byref(gas))
+    if rc:
+        raise ValueError("malformed receipt columns")
+    return root.raw, bloom.raw, gas.value
 
 
 def recover_prep(hashes: bytes, rs: bytes, ss: bytes, recids: bytes):

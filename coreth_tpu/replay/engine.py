@@ -35,6 +35,7 @@ import itertools
 import math
 import os
 import time
+from array import array
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -226,6 +227,10 @@ class ReplayStats:
     # at 3-30x the cost, and a fault of the arithmetic under the ladder
     # that would otherwise pass silently.  0 on every valid chain
     sigs_slow_path: int = 0
+    # device blocks whose receipts took the Python StackTrie path (a log
+    # outside the uniform Transfer shape, or no native library) and not
+    # native.receipt_check.  0 on every chain the benchmark replays
+    receipt_blocks_python: int = 0
     # batched recoveries that raised (packing, the worker's batch, its
     # result): their txs fell to per-tx recovery in signer.sender —
     # correct, but not the path the counters above describe
@@ -297,6 +302,26 @@ NONCE_CAP = (1 << 31) - 1
 # compiled _transfer_window variants at four for mainnet traffic.
 LANE_FLOOR = 16
 LANE_STEP = 4
+
+
+def transfer_log_record(log) -> Optional[bytes]:
+    """The 148 bytes ``native.receipt_check`` takes for a log of the
+    uniform Transfer shape — address20 ++ three topic32 ++ data32 —
+    else None: a receipt holding it takes the Python path."""
+    t = log.topics
+    if (len(log.address) == 20 and len(t) == 3 and len(log.data) == 32
+            and len(t[0]) == len(t[1]) == len(t[2]) == 32):
+        return log.address + t[0] + t[1] + t[2] + log.data
+    return None
+
+
+def device_receipts(txs, gas_used, logs) -> List[Receipt]:
+    """A device block's receipts: status 1, each transaction's gas and
+    cumulative gas, and its log if it has one."""
+    return [Receipt(tx_type=tx.tx_type, status=1, cumulative_gas_used=cum,
+                    gas_used=g, logs=[lg] if lg is not None else [])
+            for tx, g, cum, lg in zip(txs, gas_used,
+                                      itertools.accumulate(gas_used), logs)]
 
 
 def lane_bucket(n_txs: int) -> int:
@@ -1326,7 +1351,11 @@ class ReplayEngine:
         interpretation) and pre-builds the Transfer log; the wide u256
         slot arithmetic itself runs batched on device (_slot_step).  A
         block's atomic txs (ExtData) ride the same batch as lanes after
-        its transactions (_atomic_lanes)."""
+        its transactions (_atomic_lanes).  The same loop fills the
+        columns _validate_and_advance checks the receipts from:
+        ``gas_used`` (array 'Q'), ``tx_types``, ``has_log`` and
+        ``log_blob`` (the Transfer logs packed, or None where one is
+        outside that shape)."""
         if not self.supervisor.allows("device"):
             # supervisor demoted the device scope: every block routes
             # through the host ladder until the cooldown lapses (the
@@ -1347,8 +1376,13 @@ class ReplayEngine:
             if rules.is_apricot_phase1 and not no_token else None
         senders, recips, values, fees, required, nonces, offsets = \
             [], [], [], [], [], [], []
-        from_slots, to_slots, amounts, gas_used, tx_logs = \
-            [], [], [], [], []
+        from_slots, to_slots, amounts, tx_logs = [], [], [], []
+        # the receipt columns (_validate_and_advance): gas packed for
+        # native.receipt_check, the type bytes, and where the logs are
+        gas_used = array("Q")
+        tx_types = bytearray()
+        log_at: List[int] = []
+        log_recs: Optional[List[bytes]] = []
         seen_count: Dict[bytes, int] = {}
         overlay: Dict[int, int] = {}  # this block's slot sim, uncommitted
         # local bindings: this loop runs for every tx in the replay
@@ -1404,6 +1438,13 @@ class ReplayEngine:
                 to_slots.append(t_s)
                 amounts.append(amt)
                 tx_logs.append(log)
+                log_at.append(len(gas_used))
+                if log_recs is not None:
+                    rec = transfer_log_record(log)
+                    if rec is None:
+                        log_recs = None  # the Python receipt path
+                    else:
+                        log_recs.append(rec)
             else:
                 if tx.gas != TX_GAS:
                     return None
@@ -1418,6 +1459,7 @@ class ReplayEngine:
             senders.append(s_idx)
             recips.append(r_idx)
             gas_used.append(used)
+            tx_types.append(tx.tx_type)
             fees.append(used * price)
             # buyGas requirement (cap-based for typed txs)
             required.append(tx.gas * gas_fee_cap + tx.value)
@@ -1429,7 +1471,14 @@ class ReplayEngine:
                      fees=fees, required=required, nonces=nonces,
                      offsets=offsets, from_slots=from_slots,
                      to_slots=to_slots, amounts=amounts,
-                     gas_used=gas_used, logs=tx_logs)
+                     gas_used=gas_used, logs=tx_logs,
+                     tx_types=bytes(tx_types))
+        has_log = bytearray(len(gas_used))
+        for i in log_at:
+            has_log[i] = 1
+        batch["has_log"] = bytes(has_log)
+        batch["log_blob"] = None if log_recs is None \
+            else b"".join(log_recs)
         if block.ext_data():
             atomic = self._atomic_lanes(block, seen_count)
             if atomic is None:
@@ -2002,59 +2051,35 @@ class ReplayEngine:
         boundary of its own: the staging part is what ``t_trie``'s
         clock pair below reads, and the window moves it over."""
         B = len(block.transactions)
-        gas_list = batch["gas_used"]
-        logs = batch["logs"]
-        cums = []
-        cum = 0
-        for g in gas_list:
-            cum += g
-            cums.append(cum)
-        if cum != block.header.gas_used:
-            raise ReplayError("gas used mismatch")
-        # Receipt root + bloom: one C++ call when every log is the
-        # uniform Transfer shape (native.receipt_root docstring); the
-        # Python StackTrie path — pinned equivalent by
-        # tests/test_replay.py — remains for exotic shapes / no native.
-        uniform = self._native and all(
-            lg is None or (len(lg.topics) == 3 and len(lg.data) == 32
-                           and all(len(t) == 32 for t in lg.topics))
-            for lg in logs)
-        if uniform:
+        gas_col = batch["gas_used"]
+        log_blob = batch["log_blob"]
+        receipts = None
+        if self._native and log_blob is not None:
+            # root, bloom and gas from _classify's columns in one C++
+            # call, no Receipt object: every log has the uniform
+            # Transfer shape (native.receipt_check)
             from coreth_tpu.crypto import native as _n
-            tx_types = bytes(tx.tx_type for tx in block.transactions)
-            has_log = bytes(1 if lg is not None else 0 for lg in logs)
-            log_blob = b"".join(
-                lg.address + b"".join(lg.topics) + lg.data
-                for lg in logs if lg is not None)
-            rec_root, bloom = _n.receipt_root(
-                cums, tx_types, has_log, log_blob)
-            if rec_root != block.header.receipt_hash:
-                raise ReplayError("receipt root mismatch")
-            if bloom != block.header.bloom:
-                raise ReplayError("bloom mismatch")
-            receipts = None
+            rec_root, bloom, gas = _n.receipt_check(
+                gas_col, batch["tx_types"], batch["has_log"], log_blob)
         else:
-            receipts = [Receipt(
-                tx_type=tx.tx_type, status=1, cumulative_gas_used=cums[i],
-                gas_used=gas_list[i],
-                logs=[logs[i]] if logs[i] is not None else [])
-                for i, tx in enumerate(block.transactions)]
-            if derive_sha(receipts, derive_hasher()) \
-                    != block.header.receipt_hash:
-                raise ReplayError("receipt root mismatch")
-            if create_bloom(receipts) != block.header.bloom:
-                raise ReplayError("bloom mismatch")
+            # a log outside that shape, or no native library: the
+            # Python StackTrie path (ReplayStats.receipt_blocks_python)
+            receipts = device_receipts(block.transactions, gas_col,
+                                       batch["logs"])
+            gas = sum(gas_col)
+            rec_root = derive_sha(receipts, derive_hasher())
+            bloom = create_bloom(receipts)
+        if gas != block.header.gas_used:
+            raise ReplayError("gas used mismatch")
+        if rec_root != block.header.receipt_hash:
+            raise ReplayError("receipt root mismatch")
+        if bloom != block.header.bloom:
+            raise ReplayError("bloom mismatch")
         if self.keep_receipts:
-            self.last_receipts = receipts or [Receipt(
-                tx_type=tx.tx_type, status=1, cumulative_gas_used=cums[i],
-                gas_used=gas_list[i],
-                logs=[logs[i]] if logs[i] is not None else [])
-                for i, tx in enumerate(block.transactions)]
+            self.last_receipts = receipts or device_receipts(
+                block.transactions, gas_col, batch["logs"])
         atomic = batch.get("atomic")
         if self.config.is_apricot_phase4(block.time):
-            if receipts is None:
-                # verify_block_fee reads only gas_used per receipt
-                receipts = [Receipt(gas_used=g) for g in gas_list]
             # the atomic txs' gas and fee contribution, as Finalize
             # holds them (consensus/engine.py)
             ext_gas = 0
@@ -2069,9 +2094,11 @@ class ReplayEngine:
                     block)
             from coreth_tpu.consensus.engine import ConsensusError
             try:
-                self.engine.verify_block_fee(
+                # the tips from the fee column: the transactions' lanes
+                # are its first B (the atomic lanes after them pay none)
+                self.engine.verify_block_fee_columns(
                     block.base_fee, block.header.block_gas_cost,
-                    block.transactions, receipts, contribution)
+                    sum(batch["fees"][:B]), gas, contribution)
             except ConsensusError as exc:
                 # ReplayError so _complete_window's host retry (and
                 # the pipeline quarantine) own it, with the block
@@ -2101,6 +2128,7 @@ class ReplayEngine:
         self.stats.t_trie += time.monotonic() - t0
         self.parent_header = block.header
         self.stats.blocks_device += 1
+        self.stats.receipt_blocks_python += receipts is not None
         self.stats.txs += B
         self._atomic_txs_last = 0 if atomic is None else len(atomic.txs)
         if atomic is not None:

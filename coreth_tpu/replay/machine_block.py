@@ -465,16 +465,18 @@ class MachineBlockExecutor:
                 accounts[addr] = st
             return st
 
-        from coreth_tpu.replay.engine import _block_error
+        from coreth_tpu.replay.engine import _block_error, \
+            transfer_log_record
         # rows: (tx_type, status, used, cum, logs) — Receipt objects
         # materialize only on the non-uniform fallback; the uniform
         # Transfer log shape (status-1, <=1 log of 3*topic32+data32)
-        # derives root AND bloom in ONE C++ call (native.receipt_root,
-        # the engine _validate_and_advance twin) — Python Receipt
+        # derives root AND bloom in ONE C++ call (native.receipt_check,
+        # as the engine's _validate_and_advance) — Python Receipt
         # construction + consensus-RLP was ~8% of the specialized
-        # erc20-machine replay wall
+        # erc20-machine replay wall.  log_recs: the packed logs, None
+        # once a receipt is outside that shape
         rows: List[tuple] = []
-        uniform = bool(e._native)
+        log_recs: Optional[List[bytes]] = [] if e._native else None
         cum = 0
         writes_final: Dict[Tuple[bytes, bytes], int] = {}
         for i, pl in enumerate(plans):
@@ -510,26 +512,27 @@ class MachineBlockExecutor:
                 acct(pl.to)[0] += pl.value
             acct(block.header.coinbase)[0] += used * pl.price
             cum += used
-            if uniform and not (
-                    status == 1 and len(logs) <= 1
-                    and (not logs or (len(logs[0].topics) == 3
-                                      and all(len(t) == 32
-                                              for t in logs[0].topics)
-                                      and len(logs[0].data) == 32))):
-                uniform = False
+            if log_recs is not None:
+                if status != 1 or len(logs) > 1:
+                    log_recs = None
+                elif logs:
+                    rec = transfer_log_record(logs[0])
+                    if rec is None:
+                        log_recs = None
+                    else:
+                        log_recs.append(rec)
             rows.append((block.transactions[i].tx_type, status, used,
                          cum, logs))
         if cum != block.header.gas_used:
             raise _block_error("machine block: gas used mismatch", block)
         receipts: Optional[List[Receipt]] = None
-        if uniform:
+        if log_recs is not None:
             from coreth_tpu.crypto import native as _n
-            root, bloom = _n.receipt_root(
-                [r[3] for r in rows],
+            root, bloom, _ = _n.receipt_check(
+                [r[2] for r in rows],
                 bytes(r[0] for r in rows),
                 bytes(1 if r[4] else 0 for r in rows),
-                b"".join(lg.address + b"".join(lg.topics) + lg.data
-                         for r in rows for lg in r[4]))
+                b"".join(log_recs))
             if root != block.header.receipt_hash:
                 raise _block_error(
                     "machine block: receipt root mismatch", block)
@@ -593,6 +596,7 @@ class MachineBlockExecutor:
         e.parent_header = block.header
         self.blocks += 1
         e.stats.blocks_device += 1
+        e.stats.receipt_blocks_python += log_recs is None
         e.stats.txs += len(block.transactions)
         e.stats.t_trie += time.monotonic() - t1
         if defer:

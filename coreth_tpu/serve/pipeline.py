@@ -144,7 +144,9 @@ class StreamReport:
     # rule could commit (a sender funded earlier in the same block);
     # sigs_left_to_signer: lanes of the native sender batch it did not
     # vouch for, so signer.sender's per-tx path decided them;
-    # sigs_slow_path: lanes its sequential fallback recovered (ok = 2)
+    # sigs_slow_path: lanes its sequential fallback recovered (ok = 2);
+    # receipt_blocks_python: device blocks whose receipts took the
+    # Python StackTrie path instead of native.receipt_check
     lanes: dict = field(default_factory=dict)
 
     def row(self) -> dict:
@@ -794,7 +796,8 @@ class StreamingPipeline:
                 "window_upload_bytes": st.window_upload_bytes,
                 "blocks_order_dependent": st.blocks_order_dependent,
                 "sigs_left_to_signer": st.sigs_left_to_signer,
-                "sigs_slow_path": st.sigs_slow_path}
+                "sigs_slow_path": st.sigs_slow_path,
+                "receipt_blocks_python": st.receipt_blocks_python}
 
     @staticmethod
     def _thread_seconds(acct) -> dict:

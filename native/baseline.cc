@@ -589,23 +589,34 @@ uint64_t coreth_trie_export(void* h, uint8_t* out, uint64_t cap) {
   return need;
 }
 
-// ------------------------------------------------- receipt root builder
-//
-// The replay engine's per-block receipt root + header bloom in ONE
-// ctypes call (the DeriveSha/StackTrie + CreateBloom role, reference
-// core/types/hashing.go:97 + bloom9.go): the Python loop paid ~7us of
-// ctypes keccak overhead per hash across receipt blooms, receipt
-// encodings and trie nodes.  Device-path receipts are uniform: status
-// 1, cumulative gas, and 0 or 1 log of the ERC-20 Transfer shape
-// (address20 ++ 3 topics32 ++ data32 — 148 bytes packed per log).
-//
-// cum_gas:  n cumulative-gas values
-// tx_types: n bytes (0 = legacy untyped, else typed prefix byte)
-// has_log:  n bytes (0/1); log_blob: 148 bytes per has_log entry
-// Writes root32 and the block bloom (OR of receipt blooms, 256B BE).
+}  // extern "C"
 
-static void bloom_or(uint8_t bloom[256], const uint8_t* value,
-                     size_t len) {
+// --------------------------------------------------------- receipt check
+//
+// A device-path block's receipt root, header bloom and gas in ONE
+// ctypes call (the DeriveSha/StackTrie + CreateBloom role, reference
+// core/types/hashing.go:97 + bloom9.go), from the columns the replay
+// engine's classifier builds.  Device-path receipts are uniform: status
+// 1, cumulative gas, and 0 or 1 log of the ERC-20 Transfer shape
+// (address20 ++ 3 topics32 ++ data32 — LOG_REC bytes packed a log).
+//
+// The trie's keys are rlp(0..n-1), so its shape follows from n alone:
+// ReceiptTrie walks the keys in sorted order (DeriveSha's 1..0x7f, 0,
+// 0x80.. — hashing.go:87-110) over index ranges, one stack frame a
+// nibble level, and hashes each node as soon as its children are
+// known.  No heap node and no buffer a receipt: every encoding goes
+// through a fixed buffer of its frame.
+
+namespace {
+
+constexpr size_t LOG_REC = 148;
+// the largest encoding a frame holds: a branch of 16 hashed children
+// (16 x 33 + 1 bytes and its list header), or a leaf of one receipt
+// (type byte, status, gas, bloom, one log: ~450 bytes)
+constexpr size_t NODE_BUF = 640;
+constexpr size_t HEAD = 16;  // room for the headers written in front
+
+void bloom_or(uint8_t bloom[256], const uint8_t* value, size_t len) {
   uint8_t h[32];
   coreth_keccak256(value, len, h);
   for (int i = 0; i < 6; i += 2) {
@@ -614,58 +625,274 @@ static void bloom_or(uint8_t bloom[256], const uint8_t* value,
   }
 }
 
-void coreth_receipt_root(const uint64_t* cum_gas, const uint8_t* tx_types,
-                         const uint8_t* has_log, const uint8_t* log_blob,
-                         uint64_t n, uint8_t root_out[32],
-                         uint8_t bloom_out[256]) {
-  Trie trie;
-  std::memset(bloom_out, 0, 256);
-  size_t log_off = 0;
-  uint8_t nib[24];  // rlp(u64) is at most 9 bytes = 18 nibbles
-  for (uint64_t i = 0; i < n; ++i) {
-    uint8_t rbloom[256];
-    std::memset(rbloom, 0, 256);
-    Bytes logs_payload;
-    if (has_log[i]) {
-      const uint8_t* lg = log_blob + log_off;
-      log_off += 148;
-      bloom_or(rbloom, lg, 20);  // address
-      Bytes one;                 // [addr, [t0,t1,t2], data]
-      rlp_string(one, lg, 20);
-      Bytes topics;
-      for (int t = 0; t < 3; ++t) {
-        bloom_or(rbloom, lg + 20 + 32 * t, 32);
-        rlp_string(topics, lg + 20 + 32 * t, 32);
-      }
-      Bytes tl = rlp_list(topics);
-      one.insert(one.end(), tl.begin(), tl.end());
-      rlp_string(one, lg + 116, 32);
-      Bytes ol = rlp_list(one);
-      logs_payload.insert(logs_payload.end(), ol.begin(), ol.end());
-      for (int b = 0; b < 256; ++b) bloom_out[b] |= rbloom[b];
-    }
-    // receipt payload: [status=1, cum_gas, bloom, logs]
-    Bytes payload;
-    rlp_uint(payload, 1);
-    rlp_uint(payload, cum_gas[i]);
-    rlp_string(payload, rbloom, 256);
-    Bytes ll = rlp_list(logs_payload);
-    payload.insert(payload.end(), ll.begin(), ll.end());
-    Bytes enc = rlp_list(payload);
-    if (tx_types[i]) enc.insert(enc.begin(), tx_types[i]);
-    // trie key: rlp(uint i) — 1 byte below 0x80, 0x81/0x82-prefixed
-    // above (prefix-free across lengths, so the uniform-depth insert
-    // in Trie applies)
-    Bytes key;
-    rlp_uint(key, i);
-    size_t kn = 0;
-    for (uint8_t byte : key) {
-      nib[kn++] = byte >> 4;
-      nib[kn++] = byte & 0x0F;
-    }
-    trie.insert(nib, kn, enc);
+// The RLP header of a payload of len bytes, written just in front of
+// it (at most 9 bytes); returns the header's first byte.
+uint8_t* put_head(uint8_t* payload, size_t len, uint8_t short_base,
+                  uint8_t long_base) {
+  if (len < 56) {
+    *--payload = (uint8_t)(short_base + len);
+    return payload;
   }
-  trie.hash_root(root_out);
+  int nb = 0;
+  for (size_t v = len; v; v >>= 8) {
+    *--payload = (uint8_t)(v & 0xff);
+    ++nb;
+  }
+  *--payload = (uint8_t)(long_base + nb);
+  return payload;
+}
+
+uint8_t* put_string(uint8_t* out, const uint8_t* data, size_t len) {
+  if (len == 1 && data[0] < 0x80) {
+    *out = data[0];
+    return out + 1;
+  }
+  uint8_t* payload = out + (len < 56 ? 1 : 1 + (len < 256 ? 1 : 2));
+  put_head(payload, len, 0x80, 0xb7);  // len < 65536 here
+  std::memcpy(payload, data, len);
+  return payload + len;
+}
+
+uint8_t* put_uint(uint8_t* out, uint64_t v) {
+  uint8_t be[8];
+  int n = 0;
+  for (uint64_t x = v; x; x >>= 8) ++n;
+  for (int i = 0; i < n; ++i) be[i] = (uint8_t)(v >> (8 * (n - 1 - i)));
+  return put_string(out, be, n);
+}
+
+// A node's reference in its parent: its hash, or its encoding itself
+// when that is shorter than 32 bytes.  Returns the reference's length.
+size_t node_ref(const uint8_t* enc, size_t len, uint8_t ref[32]) {
+  if (len >= 32) {
+    coreth_keccak256(enc, len, ref);
+    return 32;
+  }
+  std::memcpy(ref, enc, len);
+  return len;
+}
+
+uint8_t* put_ref(uint8_t* out, const uint8_t* ref, size_t len) {
+  if (len == 32) return put_string(out, ref, 32);
+  std::memcpy(out, ref, len);  // an inline node
+  return out + len;
+}
+
+// hex-prefix encoding of nib[0..len) as an RLP string
+uint8_t* put_path(uint8_t* out, const uint8_t* nib, size_t len,
+                  bool leaf) {
+  uint8_t hp[10];  // rlp(u64) keys are at most 18 nibbles
+  size_t k = 0;
+  uint8_t flag = leaf ? 2 : 0;
+  size_t i = 0;
+  if (len % 2) {
+    hp[k++] = (uint8_t)(((flag | 1) << 4) | nib[0]);
+    i = 1;
+  } else {
+    hp[k++] = (uint8_t)(flag << 4);
+  }
+  for (; i < len; i += 2) hp[k++] = (uint8_t)((nib[i] << 4) | nib[i + 1]);
+  return put_string(out, hp, k);
+}
+
+struct ReceiptTrie {
+  const uint64_t* gas;
+  const uint8_t* types;
+  const uint8_t* has_log;
+  const uint8_t* logs;
+  uint64_t n;
+  uint8_t* bloom;
+  // the leaves come in key order: 1, 2, .. 0x7f, then 0, then 0x80 ..
+  // so every index but 0 is met after the one before it, and these
+  // run on from receipt 0's gas and log
+  uint64_t run_cum;
+  uint64_t run_log;
+
+  // the receipt index at sorted position p
+  uint64_t index_at(uint64_t p) const {
+    uint64_t m = n < 0x80 ? n : 0x80;
+    if (p + 1 < m) return p + 1;
+    if (p + 1 == m) return 0;
+    return p;
+  }
+
+  // rlp(i) as nibbles; returns their count
+  static size_t key(uint64_t i, uint8_t nib[18]) {
+    uint8_t k[9];
+    size_t kl = 1;
+    if (i == 0) {
+      k[0] = 0x80;
+    } else if (i < 0x80) {
+      k[0] = (uint8_t)i;
+    } else {
+      int nb = 0;
+      for (uint64_t v = i; v; v >>= 8) ++nb;
+      k[0] = (uint8_t)(0x80 + nb);
+      for (int j = 0; j < nb; ++j)
+        k[1 + j] = (uint8_t)(i >> (8 * (nb - 1 - j)));
+      kl = 1 + nb;
+    }
+    for (size_t j = 0; j < kl; ++j) {
+      nib[2 * j] = k[j] >> 4;
+      nib[2 * j + 1] = k[j] & 0x0F;
+    }
+    return 2 * kl;
+  }
+
+  uint8_t nibble_at(uint64_t p, size_t depth) const {
+    uint8_t nib[18];
+    key(index_at(p), nib);
+    return nib[depth];
+  }
+
+  size_t leaf(uint64_t p, size_t depth, uint8_t ref[32]) {
+    uint64_t i = index_at(p);
+    uint64_t cum;
+    const uint8_t* lg = nullptr;
+    if (i == 0) {
+      cum = gas[0];
+      if (has_log[0]) lg = logs;
+    } else {
+      run_cum += gas[i];
+      cum = run_cum;
+      if (has_log[i]) {
+        lg = logs + run_log;
+        run_log += LOG_REC;
+      }
+    }
+    // the receipt: [status 1, cumulative gas, bloom, logs]
+    uint8_t rbloom[256] = {0};
+    uint8_t rec[NODE_BUF];
+    uint8_t* body = rec + HEAD;
+    uint8_t* o = body;
+    *o++ = 0x01;
+    o = put_uint(o, cum);
+    uint8_t* bloom_at = o + 3;  // 0xb9 0x01 0x00, filled below
+    o = put_string(o, rbloom, 256);
+    if (lg) {
+      // [[address, [topic0, topic1, topic2], data]]: fixed lengths
+      bloom_or(bloom_at, lg, 20);
+      for (int t = 0; t < 3; ++t) bloom_or(bloom_at, lg + 20 + 32 * t, 32);
+      for (int b = 0; b < 256; ++b) bloom[b] |= bloom_at[b];
+      // list headers, 2 bytes each: the log list (157 bytes), the log
+      // (155) and its topics (99)
+      uint8_t* logs_at = o;
+      o += 4;
+      o = put_string(o, lg, 20);
+      uint8_t* topics_at = o;
+      o += 2;
+      for (int t = 0; t < 3; ++t) o = put_string(o, lg + 20 + 32 * t, 32);
+      put_head(topics_at + 2, 99, 0xc0, 0xf7);
+      o = put_string(o, lg + 116, 32);
+      put_head(logs_at + 4, (size_t)(o - logs_at - 4), 0xc0, 0xf7);
+      put_head(logs_at + 2, (size_t)(o - logs_at - 2), 0xc0, 0xf7);
+    } else {
+      *o++ = 0xc0;
+    }
+    uint8_t* enc = put_head(body, (size_t)(o - body), 0xc0, 0xf7);
+    if (types[i]) *--enc = types[i];
+    size_t enc_len = (size_t)(o - enc);
+    // the leaf node: [hex-prefix path, receipt]
+    uint8_t node[NODE_BUF];
+    uint8_t nib[18];
+    size_t kl = key(i, nib);
+    uint8_t* nb = node + HEAD;
+    uint8_t* q = put_path(nb, nib + depth, kl - depth, true);
+    q = put_string(q, enc, enc_len);
+    uint8_t* start = put_head(nb, (size_t)(q - nb), 0xc0, 0xf7);
+    return node_ref(start, (size_t)(q - start), ref);
+  }
+
+  // the branch over positions [lo, hi) (at least two), which share
+  // their first depth nibbles and differ at the next
+  size_t branch(uint64_t lo, uint64_t hi, size_t depth, uint8_t ref[32]) {
+    uint8_t node[NODE_BUF];
+    uint8_t* nb = node + HEAD;
+    uint8_t* q = nb;
+    uint64_t p = lo;
+    for (uint8_t c = 0; c < 16; ++c) {
+      if (p == hi || nibble_at(p, depth) != c) {
+        *q++ = 0x80;
+        continue;
+      }
+      // the first position past p whose nibble is not c
+      uint64_t a = p + 1, b = hi;
+      while (a < b) {
+        uint64_t mid = a + (b - a) / 2;
+        if (nibble_at(mid, depth) == c) a = mid + 1; else b = mid;
+      }
+      uint8_t child[32];
+      size_t cl = subtrie(p, a, depth + 1, child);
+      q = put_ref(q, child, cl);
+      p = a;
+    }
+    *q++ = 0x80;  // no value: rlp keys are prefix-free
+    uint8_t* start = put_head(nb, (size_t)(q - nb), 0xc0, 0xf7);
+    return node_ref(start, (size_t)(q - start), ref);
+  }
+
+  size_t subtrie(uint64_t lo, uint64_t hi, size_t depth, uint8_t ref[32]) {
+    if (hi - lo == 1) return leaf(lo, depth, ref);
+    uint8_t first[18], last[18];
+    size_t fl = key(index_at(lo), first);
+    size_t ll = key(index_at(hi - 1), last);
+    size_t cp = depth;
+    while (cp < fl && cp < ll && first[cp] == last[cp]) ++cp;
+    if (cp == depth) return branch(lo, hi, depth, ref);
+    // an extension node over the shared nibbles, then the branch
+    uint8_t child[32];
+    size_t cl = branch(lo, hi, cp, child);
+    uint8_t node[NODE_BUF];
+    uint8_t* nb = node + HEAD;
+    uint8_t* q = put_path(nb, first + depth, cp - depth, false);
+    q = put_ref(q, child, cl);
+    uint8_t* start = put_head(nb, (size_t)(q - nb), 0xc0, 0xf7);
+    return node_ref(start, (size_t)(q - start), ref);
+  }
+};
+
+}  // namespace
+
+extern "C" {
+
+// gas:      n per-transaction gas used (the cumulative sums are made here)
+// tx_types: n bytes (0 = legacy untyped, else typed prefix byte)
+// has_log:  n bytes (0 / 1); log_blob: LOG_REC bytes a has_log entry,
+//           log_len bytes in all
+// Writes the receipt root, the block bloom (OR of the receipt blooms,
+// 256B BE) and the block's gas.  Returns 0, or 1 for malformed input:
+// a has_log byte other than 0 / 1, a log blob of another length than
+// LOG_REC a log, or gas whose sum passes 2^64 - 1.
+int coreth_receipt_check(const uint64_t* gas, const uint8_t* tx_types,
+                         const uint8_t* has_log, uint64_t n,
+                         const uint8_t* log_blob, uint64_t log_len,
+                         uint8_t root_out[32], uint8_t bloom_out[256],
+                         uint64_t* gas_out) {
+  uint64_t total = 0, n_logs = 0;
+  for (uint64_t i = 0; i < n; ++i) {
+    if (has_log[i] > 1) return 1;
+    n_logs += has_log[i];
+    if (gas[i] > UINT64_MAX - total) return 1;
+    total += gas[i];
+  }
+  // overflow-safe: compare counts, not byte products
+  if (log_len % LOG_REC != 0 || log_len / LOG_REC != n_logs) return 1;
+  std::memset(bloom_out, 0, 256);
+  *gas_out = total;
+  if (n == 0) {
+    std::memcpy(root_out, EMPTY_ROOT, 32);
+    return 0;
+  }
+  ReceiptTrie t{gas, tx_types, has_log, log_blob, n, bloom_out,
+                gas[0], has_log[0] ? LOG_REC : 0};
+  uint8_t ref[32];
+  size_t len = t.subtrie(0, n, 0, ref);
+  if (len == 32) {
+    std::memcpy(root_out, ref, 32);
+  } else {
+    coreth_keccak256(ref, len, root_out);  // a root shorter than a hash
+  }
+  return 0;
 }
 
 // Packed tx record layout (byte offsets):

@@ -450,7 +450,7 @@ def test_replay_token_insufficient_falls_back_then_resumes():
 
 
 def test_native_receipt_root_parity():
-    """The C++ receipt-root builder (native.receipt_root — the
+    """The C++ receipt root (native.receipt_check — the
     DeriveSha + CreateBloom fast path) must be bit-identical to the
     Python StackTrie/bloom path across the rlp-key length boundary
     (127/129) and mixed typed/legacy receipts."""
@@ -460,7 +460,7 @@ def test_native_receipt_root_parity():
     if native.load() is None:
         pytest.skip("native lib unavailable")
     for ntx in (1, 127, 129, 260):
-        receipts, cums, types, haslog = [], [], [], []
+        receipts, gas, types, haslog = [], [], [], []
         blob = b""
         cum = 0
         for i in range(ntx):
@@ -479,12 +479,13 @@ def test_native_receipt_root_parity():
                 haslog.append(0)
             receipts.append(Receipt(tx_type=tx_type, status=1,
                                     cumulative_gas_used=cum, logs=logs))
-            cums.append(cum)
+            gas.append(21000 + i)
             types.append(tx_type)
-        root, bloom = native.receipt_root(
-            cums, bytes(types), bytes(haslog), blob)
+        root, bloom, total = native.receipt_check(
+            gas, bytes(types), bytes(haslog), blob)
         assert root == derive_sha(receipts, StackTrie())
         assert bloom == create_bloom(receipts)
+        assert total == cum
 
 
 def _coinbase_spends_its_fees_chain(first_to: int = 0x52):

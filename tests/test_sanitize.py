@@ -17,6 +17,7 @@ Skips without a C++ toolchain, like the existing rebuild path.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -105,6 +106,20 @@ def test_sender_wire_walk_hostile_encodings_under_asan():
     out = r.stdout + r.stderr
     assert r.returncode == 0, out[-3000:]
     assert "OK refused=" in r.stdout, out[-3000:]
+
+
+def test_receipt_check_under_asan():
+    """The device path's receipt check (coreth_receipt_check) with ASan
+    armed: the StackTrie parity sizes from 0 to 4,096 receipts, every
+    receipt type and log share, a gas change, and the malformed
+    columns it must refuse before reading past them."""
+    r = _run(["-m", "pytest", "tests/test_receipt_check.py", "-q",
+              "-k", "receipt_check_", "-p", "no:cacheprovider",
+              "-p", "no:randomly"])
+    tail = r.stdout[-2000:] + r.stderr[-2000:]
+    assert r.returncode == 0, tail
+    m = re.search(r"(\d+) passed", r.stdout)
+    assert m and int(m.group(1)) >= 60, tail
 
 
 def test_hostexec_vectors_and_trie_differential_under_asan():
