@@ -16,7 +16,8 @@ from __future__ import annotations
 import os
 import threading as _threading
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from types import MappingProxyType
+from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 import jax
@@ -132,6 +133,27 @@ def word16c(v: int) -> np.ndarray:
     return w
 
 
+def slot_hash(key: bytes, aid: int) -> int:
+    """Host twin of machine.slot_hash: the hash-index bucket of a
+    (address id, normalized slot key) pair, before masking to the
+    index size."""
+    lo = key[31] | (key[30] << 8)
+    hi = key[29] | (key[28] << 8)
+    h = (((lo | (hi << 16)) * 0x9E3779B1)
+         ^ (aid * 0x85EBCA77)) & 0xFFFFFFFF
+    h = ((h ^ (h >> 15)) * 0xC2B2AE3D) & 0xFFFFFFFF
+    return (h ^ (h >> 13)) & 0x7FFFFFFF
+
+
+# R_FRAME sub-reasons by name (ReplayStats.machine_frame_host_txs): the
+# device's FR_CALLEE is split here into a precompile and an account
+# whose code the window's table cannot hold (none, or not device code)
+FRAME_WHY = {M.FR_VALUE: "value", M.FR_DEPTH: "depth", M.FR_POOL: "pool",
+             M.FR_FAIL: "callee_fail"}
+FRAME_REASONS = ("value", "precompile", "no_code", "depth", "pool",
+                 "callee_fail")
+
+
 def _norm_slot_key(key: bytes) -> bytes:
     """Normal-storage partition of a raw 32-byte slot key: bit 0 of
     byte 0 cleared — the twin of statedb.normalize_state_key and of the
@@ -189,6 +211,12 @@ def _static_premap(code: bytes) -> Tuple[bytes, ...]:
     return cached
 
 
+# a lane without call frames touches no other contract: one shared,
+# read-only empty map, not a new dict a lane (a tracked dict a
+# transaction moves the collector's schedule)
+NO_SLOTS: Mapping = MappingProxyType({})
+
+
 @dataclass
 class TxSpec:
     """One machine transaction: a plain call into device-eligible code."""
@@ -204,6 +232,9 @@ class TxSpec:
     storage: Dict[bytes, Tuple[int, int]] = field(default_factory=dict)
     # access-list pre-warmed slots (EIP-2930); also marked warm
     warm_slots: Tuple[bytes, ...] = ()
+    # ((contract, key32) -> (current, original)) for contracts the
+    # tx's call frames reach (the root's own are in `storage`)
+    ext_storage: Mapping[Tuple[bytes, bytes], Tuple[int, int]] = NO_SLOTS
 
 
 @dataclass
@@ -225,6 +256,16 @@ class TxResult:
     reads: Dict[bytes, int]       # key -> observed pre-tx value
     writes: Dict[bytes, int]      # key -> final value (uncommitted)
     host_reason: int = 0
+    # call frames: the other contracts' slots, ((contract, key) ->
+    # value); each log's emitting address (None: every log is the
+    # root's); frames entered and steps run on the device; an R_FRAME
+    # escape's sub-reason (FRAME_REASONS) once the host has named it
+    xreads: Mapping[Tuple[bytes, bytes], int] = NO_SLOTS
+    xwrites: Mapping[Tuple[bytes, bytes], int] = NO_SLOTS
+    log_addrs: Optional[List[bytes]] = None
+    frames: int = 0
+    steps: int = 0
+    frame_why: Optional[str] = None
 
     @property
     def ok(self) -> bool:
@@ -242,6 +283,175 @@ def _pow2(n: int, floor: int) -> int:
     return v
 
 
+def code_rows(cache: Dict[Tuple[bytes, int], Tuple], fork: str,
+              code: bytes, code_cap: int) -> Tuple:
+    """(code row, jdest row, code length) of one bytecode under one
+    code_cap bucket, memoized in ``cache`` (read-only arrays)."""
+    key = (code, code_cap)
+    rows = cache.get(key)
+    if rows is None:
+        cb = np.zeros((code_cap + 33,), dtype=np.int32)
+        arr = np.frombuffer(code, dtype=np.uint8)
+        cb[:len(arr)] = arr
+        jd = np.zeros((code_cap,), dtype=np.int32)
+        for d in T.scan_code(code, fork).jumpdests:
+            if d < code_cap:
+                jd[d] = 1
+        cb.setflags(write=False)
+        jd.setflags(write=False)
+        rows = (cb, jd, len(arr))
+        cache[key] = rows
+    return rows
+
+
+def push_row(cb: np.ndarray) -> np.ndarray:
+    """(len(cb), 16) limbs of the value a PUSH at each pc of the code
+    row `cb` pushes (zero elsewhere): a call kernel reads one row per
+    step instead of gathering the push's bytes."""
+    raw = cb.astype(np.uint8).tobytes() + b"\x00" * 32
+    out = np.zeros((len(cb), u256.LIMBS), dtype=np.int32)
+    for pc in np.nonzero((cb > 0x5F) & (cb <= 0x7F))[0].tolist():
+        out[pc] = word16c(int.from_bytes(
+            raw[pc + 1:pc + 1 + int(cb[pc]) - 0x5F], "big"))
+    return out
+
+
+# saved frames a lane may stack: depth 4 below the root covers a
+# router -> pair -> token tree (depth 3) with one to spare
+FRAME_CAP = 4
+
+
+def _address_words(data: bytes) -> List[bytes]:
+    """Calldata words (past the selector) that read as addresses: the
+    callee-table prefetch's candidates (a token path, a recipient)."""
+    out = []
+    for w in range(min(16, (len(data) - 4) // 32)):
+        v = int.from_bytes(data[4 + 32 * w:36 + 32 * w], "big")
+        if (1 << 96) <= v < (1 << 160):
+            out.append(v.to_bytes(20, "big"))
+    return out
+
+
+class Callees:
+    """Address ids and resolved callee codes for call frames.
+
+    ``code_resolver(addr)`` gives an account's runtime code, b"" for
+    an account without code, None for a precompile or another target
+    the device must not run.  A resolved address keeps its answer for
+    the resolver's owner's lifetime (a window's state cannot deploy
+    code: CREATE is not a device opcode).  Each address gets a small
+    integer id, the address id the device's storage entries, logs and
+    callee table carry."""
+
+    def __init__(self, fork: str,
+                 code_resolver: Optional[Callable[[bytes],
+                                                  Optional[bytes]]]):
+        self.fork = fork
+        self.resolve = code_resolver
+        self.aid_of: Dict[bytes, int] = {}
+        self.aid_addr: List[bytes] = []
+        # address -> device code, or None with the reason in why_of
+        self.code_of: Dict[bytes, Optional[bytes]] = {}
+        self.why_of: Dict[bytes, str] = {}
+
+    def aid(self, addr: bytes) -> int:
+        i = self.aid_of.get(addr)
+        if i is None:
+            i = self.aid_of[addr] = len(self.aid_addr)
+            self.aid_addr.append(addr)
+        return i
+
+    def learn(self, addr: bytes) -> bool:
+        """Resolve `addr` once; True when it was not known before."""
+        if addr in self.code_of:
+            return False
+        code = self.resolve(addr) if self.resolve is not None else b""
+        if code is None:
+            self.code_of[addr] = None
+            self.why_of[addr] = "precompile"
+        elif not code or not T.scan_code(code, self.fork).eligible:
+            self.code_of[addr] = None
+            self.why_of[addr] = "no_code"
+        else:
+            self.code_of[addr] = code
+        return True
+
+    def table(self, lanes):
+        """(entries, codes) of one window from its lanes' (address,
+        code): every root contract and every resolved callee with
+        device code, as (address, code row) rows, and the distinct codes
+        in row order — each lane's own code among them (a lane starts
+        on its code's row)."""
+        rows: Dict[bytes, int] = {}
+        codes: List[bytes] = []
+        entries: List[Tuple[bytes, int]] = []
+        merged: Dict[bytes, bytes] = {}
+        for a, c in lanes:
+            merged.setdefault(a, c)
+            if c not in rows:
+                rows[c] = len(codes)
+                codes.append(c)
+        for a, c in self.code_of.items():
+            if c is not None:
+                merged.setdefault(a, c)
+        for a, c in merged.items():
+            r = rows.get(c)
+            if r is None:
+                r = rows[c] = len(codes)
+                codes.append(c)
+            entries.append((a, r))
+            self.aid(a)
+        return entries, codes
+
+    def escape_why(self, res: "TxResult", addr: Optional[bytes]) -> None:
+        """Name an R_FRAME escape at an unresolvable callee."""
+        if addr is not None and res.frame_why is None:
+            res.frame_why = self.why_of.get(addr, "no_code")
+
+
+def _call_params(p: M.MachineParams, entries, codes,
+                 fork: str) -> M.MachineParams:
+    """`p` widened for a window whose code uses call frames: every
+    table code's features and length, the frame / table capacities."""
+    feats = set(p.features)
+    max_code = p.code_cap
+    for c in codes:
+        feats |= set(T.scan_code(c, fork).features)
+        max_code = max(max_code, _pow2(len(c), 256))
+    return M.MachineParams(
+        fork=p.fork, batch=p.batch, code_cap=max_code,
+        data_cap=p.data_cap, scache_cap=max(p.scache_cap, 32),
+        features=frozenset(feats), frame_cap=FRAME_CAP,
+        callee_cap=_pow2(len(entries), 8),
+        code_rows=_pow2(len(codes), 4))
+
+
+def _call_tables(p: M.MachineParams, entries, codes, callees: Callees,
+                 code_pack) -> dict:
+    """The window-wide callee / code table inputs (numpy)."""
+    NA, NC = p.callee_cap, p.code_rows
+    code_tab = np.zeros((NC, p.code_cap + 33), dtype=np.int32)
+    push_tab = np.zeros((NC, p.code_cap + 33, u256.LIMBS), dtype=np.int32)
+    jdest_tab = np.zeros((NC, p.code_cap), dtype=np.int32)
+    clen_tab = np.zeros((NC,), dtype=np.int32)
+    for r, c in enumerate(codes):
+        cb, jd, ln = code_pack(c, p.code_cap)
+        code_tab[r] = cb
+        push_tab[r] = push_row(cb)
+        jdest_tab[r] = jd
+        clen_tab[r] = ln
+    ctab_addr = np.zeros((NA, u256.LIMBS), dtype=np.int32)
+    ctab_aid = np.zeros((NA,), dtype=np.int32)
+    ctab_cid = np.full((NA,), -1, dtype=np.int32)
+    for k, (a, r) in enumerate(entries):
+        ctab_addr[k] = word16c(addr_word(a))
+        ctab_aid[k] = callees.aid(a)
+        ctab_cid[k] = r
+    return dict(code_tab=code_tab, push_tab=push_tab, jdest_tab=jdest_tab,
+                clen_tab=clen_tab, ctab_addr=ctab_addr, ctab_aid=ctab_aid,
+                ctab_cid=ctab_cid)
+
+
 class MachineRunner:
     """Executes batches of TxSpecs under one fork + block env.
 
@@ -251,11 +461,16 @@ class MachineRunner:
 
     def __init__(self, fork: str, env: BlockEnv,
                  storage_resolver: Callable[[bytes, bytes], int],
-                 max_rounds: int = 6):
+                 max_rounds: int = 6,
+                 code_resolver: Optional[Callable[[bytes],
+                                                  Optional[bytes]]] = None):
         self.fork = fork
         self.env = env
         self.resolver = storage_resolver
         self.max_rounds = max_rounds
+        self.callees = Callees(fork, code_resolver)
+        self._code_rows: Dict[Tuple[bytes, int], Tuple] = {}
+        self._ctab = None
 
     def _params(self, txs: List[TxSpec]) -> M.MachineParams:
         feats = set()
@@ -267,8 +482,9 @@ class MachineRunner:
             feats |= set(info.features)
             max_code = max(max_code, len(t.code))
             max_data = max(max_data, len(t.calldata))
-            max_slots = max(max_slots, len(t.storage) + 8)
-        return M.MachineParams(
+            max_slots = max(max_slots, len(t.storage)
+                            + len(t.ext_storage) + 8)
+        p = M.MachineParams(
             fork=self.fork,
             batch=_pow2(len(txs), 8),
             code_cap=_pow2(max_code, 256),
@@ -276,6 +492,19 @@ class MachineRunner:
             scache_cap=_pow2(max_slots, 8),
             features=frozenset(feats),
         )
+        self._ctab = None
+        if "call" in feats:
+            for t in txs:
+                for a in _address_words(t.calldata):
+                    self.callees.learn(a)
+            entries, codes = self.callees.table(
+                [(t.address, t.code) for t in txs])
+            p = _call_params(p, entries, codes, self.fork)
+            self._ctab = (entries, codes)
+        return p
+
+    def _code_pack(self, code: bytes, code_cap: int) -> Tuple:
+        return code_rows(self._code_rows, self.fork, code, code_cap)
 
     def _pack(self, txs: List[TxSpec], p: M.MachineParams) -> dict:
         B = p.batch
@@ -292,6 +521,7 @@ class MachineRunner:
         sorig = np.zeros((B, S, u256.LIMBS), dtype=np.int32)
         sflag = np.zeros((B, S), dtype=np.int32)
         scnt = np.zeros((B,), dtype=np.int32)
+        saddr = np.zeros((B, S), dtype=np.int32)
         words = {k: np.zeros((B, u256.LIMBS), dtype=np.int32)
                  for k in ("callvalue", "caller_w", "address_w",
                            "origin_w", "gasprice_w")}
@@ -326,6 +556,16 @@ class MachineRunner:
                 sflag[i, j] = M.F_VALID | (
                     M.F_WARM if key in t.warm_slots else 0)
             scnt[i] = len(t.storage)
+            if self._ctab is not None:
+                saddr[i, :scnt[i]] = self.callees.aid(t.address)
+                for j, ((a, key), (cur, orig)) in enumerate(
+                        t.ext_storage.items(), start=int(scnt[i])):
+                    skey[i, j] = wordify(int.from_bytes(key, "big"))
+                    sval[i, j] = wordify(cur)
+                    sorig[i, j] = wordify(orig)
+                    sflag[i, j] = M.F_VALID
+                    saddr[i, j] = self.callees.aid(a)
+                scnt[i] += len(t.ext_storage)
 
         env = self.env
         inputs = dict(
@@ -350,6 +590,19 @@ class MachineRunner:
             chainid_w=jnp.asarray(wordify(env.chain_id)),
             basefee_w=jnp.asarray(wordify(env.base_fee)),
         )
+        if self._ctab is not None:
+            entries, codes = self._ctab
+            tabs = _call_tables(p, entries, codes, self.callees,
+                                self._code_pack)
+            row_of = {c: r for r, c in enumerate(codes)}
+            cid0 = np.zeros((B,), dtype=np.int32)
+            aid0 = np.zeros((B,), dtype=np.int32)
+            for i, t in enumerate(txs):
+                cid0[i] = row_of[t.code]
+                aid0[i] = self.callees.aid(t.address)
+            inputs.update({k: jnp.asarray(v) for k, v in tabs.items()})
+            inputs.update(cid0=jnp.asarray(cid0), aid0=jnp.asarray(aid0),
+                          saddr=jnp.asarray(saddr))
         return inputs
 
     def run(self, txs: List[TxSpec]) -> List[TxResult]:
@@ -373,13 +626,27 @@ class MachineRunner:
             ticket = _count_dispatch()
             out = PackedOut(np.asarray(fn(self._pack(txs, p))["packed"]),
                             p)
+            out.aid_addr = self.callees.aid_addr
             obs.device_done(ticket)
             missing = self._collect_misses(out, txs)
-            if not missing:
+            fresh_callee = False
+            if out.call:
+                for i in range(len(txs)):
+                    cm = callee_miss(out, i)
+                    if cm is not None and self.callees.learn(cm):
+                        fresh_callee = True
+            if not missing and not fresh_callee:
                 return self._unpack(out, txs)
             for i, keys in missing.items():
                 t = txs[i]
                 for key in keys:
+                    if out.call:
+                        a, key = key
+                        if a != t.address:
+                            v = self.resolver(a, key)
+                            t.ext_storage = {**t.ext_storage,
+                                             (a, key): (v, v)}
+                            continue
                     v = self.resolver(t.address, key)
                     t.storage[key] = (v, v)
         # rounds exhausted: anything still missing goes to host
@@ -397,15 +664,27 @@ class MachineRunner:
             # may have mispriced on a speculative miss value, so they
             # must resolve + rerun too
             keys = []
-            for key in miss_keys(out, i):
-                if key not in t.storage:
-                    keys.append(key)
+            if out.call:
+                for a, key in miss_entries(out, i):
+                    known = key in t.storage if a == t.address \
+                        else (a, key) in t.ext_storage
+                    if not known:
+                        keys.append((a, key))
+            else:
+                for key in miss_keys(out, i):
+                    if key not in t.storage:
+                        keys.append(key)
             if keys:
                 missing[i] = keys
         return missing
 
     def _unpack(self, out: "PackedOut", txs) -> List[TxResult]:
-        return results_for_rows(out, np.arange(len(txs)))
+        res = results_for_rows(out, np.arange(len(txs)),
+                               [t.address for t in txs])
+        if out.call:
+            for i, r in enumerate(res):
+                self.callees.escape_why(r, callee_miss(out, i))
+        return res
 
 
 # ------------------------------------------------------------ unpack
@@ -451,6 +730,17 @@ class PackedOut:
         self.log_cnt = take(1)[:, 0]
         self.log_top = take(LC * 4 * 16, (LC, 4, 16))
         self.log_data = take(LC * LD, (LC, LD))
+        self.call = "call" in p.features
+        if self.call:
+            self.saddr = take(S)
+            self.log_aid = take(LC)
+            self.nframes = take(1)[:, 0]
+            self.lsteps = take(1)[:, 0]
+            self.fwhy = take(1)[:, 0]
+            self.cmiss = take(16)
+        # address of each address id (set by the runner that packed the
+        # lanes; call frames only)
+        self.aid_addr: List[bytes] = []
         self._kb = self._vb = self._ob = None
         self._tb = self._db = None
 
@@ -491,6 +781,29 @@ def _word_int(limbs: np.ndarray) -> int:
     for l in range(16):
         v |= int(limbs[l]) << (16 * l)
     return v
+
+
+def callee_miss(out: PackedOut, i: int) -> Optional[bytes]:
+    """The callee address lane i stopped at (R_FRAME / FR_CALLEE: not
+    in the window's callee table), else None."""
+    if not out.call or int(out.host_reason[i]) != M.R_FRAME \
+            or int(out.fwhy[i]) != M.FR_CALLEE:
+        return None
+    return _key_bytes(out.cmiss[i])[12:]
+
+
+def miss_entries(out: PackedOut, i: int) -> List[Tuple[bytes, bytes]]:
+    """(contract, key) of every storage entry lane i executed against a
+    speculative zero (F_MISS), under the call feature."""
+    ents = []
+    n = int(out.scnt[i])
+    kb = out.key_blob()
+    for j in range(n):
+        if out.sflag[i, j] & M.F_MISS:
+            off = (i * out.S + j) * 32
+            ents.append((out.aid_addr[int(out.saddr[i, j])],
+                         kb[off:off + 32]))
+    return ents
 
 
 def miss_keys(out: PackedOut, i: int) -> List[bytes]:
@@ -628,7 +941,9 @@ def result_from_row(out: PackedOut, i: int) -> TxResult:
         writes=writes, host_reason=int(out.host_reason[i]))
 
 
-def results_for_rows(out: PackedOut, rows) -> List[TxResult]:
+def results_for_rows(out: PackedOut, rows,
+                     roots: Optional[List[bytes]] = None
+                     ) -> List[TxResult]:
     """TxResults for many PackedOut rows in one pass.
 
     The per-lane ``result_from_row`` pays a numpy scalar index + bounds
@@ -636,7 +951,13 @@ def results_for_rows(out: PackedOut, rows) -> List[TxResult]:
     ~15% of replay wall).  Here the validity masks, flag tests, and
     int conversions happen once per call as array ops; the remaining
     Python loop touches only entries that exist (``nonzero`` of the
-    mask), not the padded S/LC capacity."""
+    mask), not the padded S/LC capacity.
+
+    Under the call feature ``roots`` gives each row's root contract:
+    its entries go to ``reads`` / ``writes`` as before, every other
+    contract's to ``xreads`` / ``xwrites``, and logs carry addresses."""
+    if out.call:
+        return _results_call(out, rows, roots)
     rows = np.asarray(rows, dtype=np.int64)
     n = rows.shape[0]
     if not n:
@@ -692,6 +1013,60 @@ def results_for_rows(out: PackedOut, rows) -> List[TxResult]:
             for k in range(n)]
 
 
+def _results_call(out: PackedOut, rows, roots) -> List[TxResult]:
+    """results_for_rows under the call feature (entries and logs keyed
+    by the address ids the lanes' frames ran under)."""
+    res = []
+    kb, vb, ob = out.key_blob(), out.val_blob(), out.orig_blob()
+    tb, db = out.topic_blob(), out.data_blob()
+    S, LC, LD = out.S, out.LC, out.LD
+    addrs = out.aid_addr
+    for k, i in enumerate(np.asarray(rows, dtype=np.int64).tolist()):
+        root = roots[k]
+        reads: Dict[bytes, int] = {}
+        writes: Dict[bytes, int] = {}
+        xreads: Dict[Tuple[bytes, bytes], int] = {}
+        xwrites: Dict[Tuple[bytes, bytes], int] = {}
+        for j in range(int(out.scnt[i])):
+            fl = int(out.sflag[i, j])
+            if not fl & M.F_VALID:
+                continue
+            o = (i * S + j) * 32
+            key = kb[o:o + 32]
+            addr = addrs[int(out.saddr[i, j])]
+            if fl & M.F_READ:
+                v = int.from_bytes(ob[o:o + 32], "big")
+                if addr == root:
+                    reads[key] = v
+                else:
+                    xreads[(addr, key)] = v
+            if fl & M.F_WRITTEN:
+                v = int.from_bytes(vb[o:o + 32], "big")
+                if addr == root:
+                    writes[key] = v
+                else:
+                    xwrites[(addr, key)] = v
+        logs, log_addrs = [], []
+        for j in range(int(out.log_cnt[i])):
+            b = ((i * LC + j) * 4) * 32
+            topics = [tb[b + 32 * t:b + 32 * (t + 1)]
+                      for t in range(int(out.log_nt[i, j]))]
+            d = (i * LC + j) * LD
+            logs.append((topics, db[d:d + int(out.log_dlen[i, j])]))
+            log_addrs.append(addrs[int(out.log_aid[i, j])])
+        why = None
+        if int(out.host_reason[i]) == M.R_FRAME:
+            why = FRAME_WHY.get(int(out.fwhy[i]))
+        res.append(TxResult(
+            status=int(out.status[i]), gas_left=int(out.gas[i]),
+            refund=int(out.refund[i]), logs=logs, reads=reads,
+            writes=writes, host_reason=int(out.host_reason[i]),
+            xreads=xreads, xwrites=xwrites, log_addrs=log_addrs,
+            frames=int(out.nframes[i]), steps=int(out.lsteps[i]),
+            frame_why=why))
+    return res
+
+
 # ----------------------------------------------------------- OCC window
 @dataclass
 class WindowResult:
@@ -740,7 +1115,15 @@ class MachineWindowRunner:
       residual heuristic for keys neither static nor keccak-derivable;
       anything still outside the premap surfaces as an F_MISS escape
       and resolves through the bounded re-dispatch loop, counted in
-      ``discovery_dispatches``).
+      ``discovery_dispatches``);
+    - under call frames, the footprint of a window's lanes
+      (``_footprints``): before the first dispatch each lane runs on
+      the native executor against the committed state, and every
+      callee it enters and every (contract, slot) it reads is resolved
+      and mapped.  A frame's reads depend on each other (a transfer
+      that read a zero balance reverts before its next read), so
+      resolving one missed read per re-dispatch would take one device
+      attempt per link of that chain.
     """
 
     COMMON_CAP = 8   # premapped common keys per contract
@@ -751,10 +1134,20 @@ class MachineWindowRunner:
 
     def __init__(self, fork: str,
                  storage_resolver: Callable[[bytes, bytes], int],
-                 max_attempts: int = 6):
+                 max_attempts: int = 6,
+                 code_resolver: Optional[Callable[[bytes],
+                                                  Optional[bytes]]] = None):
         self.fork = fork
         self.resolver = storage_resolver
         self.max_attempts = max_attempts
+        # call frames: address ids, resolved callee codes, and the
+        # global table's hash index (host mirror, grown in place)
+        self.callees = Callees(fork, code_resolver)
+        self._native = None       # footprint session, built on first use
+        self._hx: Optional[np.ndarray] = None
+        self._hx_aid: Optional[np.ndarray] = None
+        self._hx_n = 0
+        self._ctab = None
         self.slot_gid: Dict[Tuple[bytes, bytes], int] = {}
         self.gid_keys: List[Tuple[bytes, bytes]] = []
         self.vals: List[int] = []
@@ -880,6 +1273,8 @@ class MachineWindowRunner:
         self.table_cap = 0
         self._synced = 0
         self._stale = True
+        self._hx = self._hx_aid = None
+        self._hx_n = 0
 
     def invalidate(self) -> None:
         """Device table no longer matches the committed state (a dirty
@@ -915,6 +1310,60 @@ class MachineWindowRunner:
 
     def _key_mapped(self, contract: bytes, key: bytes) -> bool:
         return (contract, key) in self.slot_gid
+
+    def _native_session(self, chain_id: int):
+        """The native session the footprint pre-run uses (None without
+        the native library): its slot reads map the slot, its code
+        reads resolve the callee."""
+        if self._native is None:
+            from coreth_tpu.evm.hostexec.backend import (
+                HostExecBackend, load_hostexec)
+            if load_hostexec() is None:
+                return None
+
+            def slot(contract: bytes, key: bytes) -> bytes:
+                return self.vals[self._gid(contract, key)].to_bytes(
+                    32, "big")
+
+            def code(addr: bytes) -> Optional[bytes]:
+                self.callees.learn(addr)
+                if self.callees.resolve is None:
+                    return b""
+                return self.callees.resolve(addr)
+
+            self._native = HostExecBackend(self.fork, chain_id, slot, code)
+        return self._native
+
+    def _footprints(self, items, framed) -> None:
+        """Map the footprint of a window's call lanes (those whose code
+        is in `framed`) before its first dispatch (see the class
+        docstring): each lane runs alone on the native executor against
+        the committed state, its writes dropped.  Only a prediction — a
+        lane the native run took down another path still escapes on a
+        miss and reruns."""
+        from coreth_tpu.evm.forks import COINBASE_WARM_FORKS
+        be = None
+        for env, specs in items:
+            lanes = [t for t in specs if t.code in framed]
+            if not lanes:
+                continue
+            if be is None:
+                be = self._native_session(env.chain_id)
+                if be is None:
+                    return
+                be.clear_storage()  # committed values moved since
+            be.set_env(env.coinbase, env.timestamp, env.number,
+                       env.gas_limit, env.base_fee)
+            for t in lanes:
+                warm = [t.origin, t.address]
+                if self.fork in COINBASE_WARM_FORKS:  # EIP-3651
+                    warm.append(env.coinbase)
+                be.set_code(t.address, t.code)
+                try:
+                    be.call(t.caller, t.address, t.value, t.gas_price,
+                            t.calldata, t.gas, warm_addrs=warm)
+                except Exception:  # noqa: BLE001 — a native fault only loses the prediction: the device's miss-and-rerun resolves the window
+                    return
 
     def _mapped_rows(self) -> int:
         """Rows the (largest) table arena must hold right now."""
@@ -962,21 +1411,7 @@ class MachineWindowRunner:
         one code_cap bucket (memoized; rows are assigned whole into the
         batch tensors — a contiguous copy instead of per-lane scan +
         jumpdest walk)."""
-        key = (code, code_cap)
-        rows = self._code_rows.get(key)
-        if rows is None:
-            cb = np.zeros((code_cap + 33,), dtype=np.int32)
-            arr = np.frombuffer(code, dtype=np.uint8)
-            cb[:len(arr)] = arr
-            jd = np.zeros((code_cap,), dtype=np.int32)
-            for d in T.scan_code(code, self.fork).jumpdests:
-                if d < code_cap:
-                    jd[d] = 1
-            cb.setflags(write=False)
-            jd.setflags(write=False)
-            rows = (cb, jd, len(arr))
-            self._code_rows[key] = rows
-        return rows
+        return code_rows(self._code_rows, self.fork, code, code_cap)
 
     # -------------------------------------------------------- prediction
     def _rc_src(self, t: TxSpec, tag: tuple) -> bytes:
@@ -1086,6 +1521,37 @@ class MachineWindowRunner:
             if not left2:
                 return
 
+    def _sk(self, p: M.MachineParams) -> Tuple:
+        """The specialized-program set a kernel of `p` carries: none
+        under call frames (every lane on the generic kernel)."""
+        return () if "call" in p.features else self._spec_key()
+
+    def _call_index(self, G: int):
+        """The global table's address ids (G,) and its open-addressed
+        hash index (M.HSPAN x G,) of slot ids, grown in place as rows
+        are mapped (rebuilt when G re-buckets); machine.slot_hash
+        probes it.  A key that finds no free bucket within M.HPROBE
+        stays out of the index: a lane that reads it misses, and the
+        miss premaps it into that lane's cache for the rerun."""
+        H = M.HSPAN * G
+        if self._hx is None or self._hx.shape[0] != H:
+            self._hx = np.full((H,), -1, dtype=np.int32)
+            self._hx_aid = np.zeros((G,), dtype=np.int32)
+            self._hx_n = 0
+        hx, aids = self._hx, self._hx_aid
+        for g in range(self._hx_n, len(self.gid_keys)):
+            contract, key = self.gid_keys[g]
+            a = self.callees.aid(contract)
+            aids[g] = a
+            h = slot_hash(key, a) & (H - 1)
+            for k in range(M.HPROBE):
+                b = (h + k) & (H - 1)
+                if hx[b] < 0:
+                    hx[b] = g
+                    break
+        self._hx_n = len(self.gid_keys)
+        return aids, hx
+
     # ------------------------------------------------------------- shape
     def _occ_params(self, items, premaps):
         feats = set()
@@ -1107,7 +1573,8 @@ class MachineWindowRunner:
                 max_data = max(max_data, len(t.calldata))
                 max_slots = max(max_slots, len(pre) + 8)
                 for k in pre:
-                    if (t.address, k) not in self.slot_gid:
+                    ak = k if isinstance(k, tuple) else (t.address, k)
+                    if ak not in self.slot_gid:
                         unmapped += 1
         p = M.MachineParams(
             fork=self.fork,
@@ -1115,7 +1582,25 @@ class MachineWindowRunner:
             code_cap=_pow2(max_code, 256),
             data_cap=_pow2(max_data, 128),
             scache_cap=_pow2(max_slots, 8),
-            features=frozenset(feats))
+            features=frozenset(feats | set(self._hw_feats)))
+        self._ctab = None
+        if "call" in p.features:
+            lanes = []
+            for _env, specs in items:
+                for t in specs:
+                    lanes.append((t.address, t.code))
+                    for a in _address_words(t.calldata):
+                        self.callees.learn(a)
+            entries, codes = self.callees.table(lanes)
+            # every table contract's PUSH-constant slots are rows of the
+            # global table before the dispatch: a frame finds them by
+            # the hash index, with no premap of its own
+            for a, r in entries:
+                for k in _static_premap(codes[r]):
+                    if (a, k) not in self.slot_gid:
+                        self._gid(a, k)
+            p = _call_params(p, entries, codes, self.fork)
+            self._ctab = (entries, codes)
         occ = M.OccParams(
             blocks=_pow2(len(items), 1),
             table_cap=_pow2(len(self.vals) + unmapped + 1, 64),
@@ -1144,7 +1629,10 @@ class MachineWindowRunner:
             code_cap=max(p.code_cap, hw.get("code_cap", 0)),
             data_cap=max(p.data_cap, hw.get("data_cap", 0)),
             scache_cap=max(p.scache_cap, hw.get("scache_cap", 0)),
-            features=feats)
+            features=feats,
+            frame_cap=p.frame_cap,
+            callee_cap=max(p.callee_cap, hw.get("callee_cap", 0)),
+            code_rows=max(p.code_rows, hw.get("code_rows", 0)))
         occ = M.OccParams(
             blocks=max(occ.blocks, hw.get("blocks", 0)),
             table_cap=max(occ.table_cap, self.table_cap,
@@ -1152,7 +1640,8 @@ class MachineWindowRunner:
             rounds=p.batch + 1)
         hw.update(batch=p.batch, code_cap=p.code_cap,
                   data_cap=p.data_cap, scache_cap=p.scache_cap,
-                  blocks=occ.blocks)
+                  blocks=occ.blocks, callee_cap=p.callee_cap,
+                  code_rows=p.code_rows)
         return p, occ
 
     def _device_tables(self, G: int):
@@ -1330,9 +1819,34 @@ class MachineWindowRunner:
         faults.fire(PT_DISPATCH)
         if discovered is None:
             discovered = [[{} for _t in specs] for _env, specs in items]
+        if attempt == 1:
+            codes = dict.fromkeys(t.code for _env, specs in items
+                                  for t in specs)
+            framed = {c for c in codes
+                      if "call" in T.scan_code(c, self.fork).features}
+            if framed:
+                with self.account.enter("machine/callees"):
+                    self._footprints(items, framed)
         premaps, predicted = self._premaps(items, discovered)
         p, occ = self._occ_params(items, premaps)
         W, L, S, G = occ.blocks, p.batch, p.scache_cap, occ.table_cap
+        call = "call" in p.features
+        if call:
+            acct_c = self.account
+            acct_c.enter("machine/callees")
+            try:
+                entries, codes = self._ctab
+                ctabs = _call_tables(p, entries, codes, self.callees,
+                                     self._code_pack)
+                row_of = {c: r for r, c in enumerate(codes)}
+                cid0 = np.zeros((W, L), dtype=np.int32)
+                aid0 = np.zeros((W, L), dtype=np.int32)
+                for bi, (_env, specs) in enumerate(items):
+                    for li, t in enumerate(specs):
+                        cid0[bi, li] = row_of[t.code]
+                        aid0[bi, li] = self.callees.aid(t.address)
+            finally:
+                acct_c.exit()
 
         # the lane -> bytecode assignment recurs window after window
         # (workloads run a stable hot-contract set), and the code /
@@ -1392,7 +1906,7 @@ class MachineWindowRunner:
                 words["origin_w"][bi, li] = word16c(addr_word(t.origin))
                 words["gasprice_w"][bi, li] = word16c(t.gas_price)
                 pid = self._spec_progs.get(t.code, -1) \
-                    if self._specialize else -1
+                    if self._specialize and not call else -1
                 prog_id[bi, li] = pid
                 if pid >= 0 and self._spec_reqs.get(t.code):
                     kjobs.append((bi, li, t, env,
@@ -1403,7 +1917,10 @@ class MachineWindowRunner:
                     elif self._specialize:
                         self.specialize_escapes += 1
                 for j, key in enumerate(block_pre[li]):
-                    sgid[bi, li, j] = self._gid(t.address, key)
+                    if isinstance(key, tuple):
+                        sgid[bi, li, j] = self._gid(*key)
+                    else:
+                        sgid[bi, li, j] = self._gid(t.address, key)
         fill_kdig(kdig, kjobs)
         self.lanes_real += int(active.sum())
         self.lanes_padded += active.size
@@ -1444,6 +1961,13 @@ class MachineWindowRunner:
                 basefee_w=jnp.asarray(basefee_w),
                 chainid_w=jnp.asarray(word16(chain_id)),
             )
+            if call:
+                g_aid, hidx = self._call_index(G)
+                inputs.update({k: jnp.asarray(v) for k, v in ctabs.items()})
+                inputs.update(cid0=jnp.asarray(cid0),
+                              aid0=jnp.asarray(aid0),
+                              g_aid=jnp.asarray(g_aid),
+                              hidx=jnp.asarray(hidx))
             acct.switch("machine/dispatch")
             fn = self._get_kernel(p, occ)
             ticket = _count_dispatch()
@@ -1486,12 +2010,12 @@ class MachineWindowRunner:
 
     def _kernel(self, p: M.MachineParams, occ: M.OccParams,
                 sk: Optional[Tuple] = None):
-        sk = self._spec_key() if sk is None else sk
+        sk = self._sk(p) if sk is None else sk
         return M.get_occ_machine(p, occ, sk)
 
     def _kernel_compiled(self, p: M.MachineParams,
                          occ: M.OccParams) -> bool:
-        return M.occ_compiled(p, occ, self._spec_key())
+        return M.occ_compiled(p, occ, self._sk(p))
 
     def _bucket_key(self, p: M.MachineParams, occ: M.OccParams,
                     sk: Tuple) -> Tuple:
@@ -1512,7 +2036,7 @@ class MachineWindowRunner:
         specialized-program set is part of the bucket identity: a new
         hot contract mid-run retraces exactly like a new op family
         would."""
-        key = self._bucket_key(p, occ, self._spec_key())
+        key = self._bucket_key(p, occ, self._sk(p))
         if key not in self._buckets_used:
             self._buckets_used.add(key)
             if not self._cold:
@@ -1565,6 +2089,21 @@ class MachineWindowRunner:
             basefee_w=jnp.zeros((W, u256.LIMBS), dtype=i32),
             chainid_w=jnp.zeros((u256.LIMBS,), dtype=i32),
         )
+        if "call" in p.features:
+            NA, NC = p.callee_cap, p.code_rows
+            inputs.update(
+                code_tab=jnp.zeros((NC, p.code_cap + 33), dtype=i32),
+                push_tab=jnp.zeros((NC, p.code_cap + 33, u256.LIMBS),
+                                   dtype=i32),
+                jdest_tab=jnp.zeros((NC, p.code_cap), dtype=i32),
+                clen_tab=jnp.zeros((NC,), dtype=i32),
+                ctab_addr=jnp.zeros((NA, u256.LIMBS), dtype=i32),
+                ctab_aid=jnp.zeros((NA,), dtype=i32),
+                ctab_cid=jnp.full((NA,), -1, dtype=i32),
+                cid0=jnp.zeros((W, L), dtype=i32),
+                aid0=jnp.zeros((W, L), dtype=i32),
+                g_aid=jnp.zeros((rows,), dtype=i32),
+                hidx=jnp.full((M.HSPAN * rows,), -1, dtype=i32))
         table = jnp.zeros((rows, u256.LIMBS), dtype=i32)
         key_tab = jnp.zeros((rows, u256.LIMBS), dtype=i32)
         return table, key_tab, inputs
@@ -1601,7 +2140,7 @@ class MachineWindowRunner:
                           table_cap=max(occ.table_cap * 2,
                                         self._table_floor),
                           rounds=occ.rounds)
-        sk = self._spec_key()
+        sk = self._sk(p)
         bk = self._bucket_key(p, nxt, sk)
         if bk in self._buckets_used:
             return
@@ -1682,6 +2221,7 @@ class MachineWindowRunner:
             pw = packed.shape[2] - 4
             pout = PackedOut(
                 packed[:, :, :pw].reshape(-1, pw), p)
+            pout.aid_addr = self.callees.aid_addr
             extra = packed[:, :, pw:]
             missing = False
             for bi, (_env, specs) in enumerate(handle["items"]):
@@ -1691,7 +2231,25 @@ class MachineWindowRunner:
                         continue  # escaped lanes only carry misses
                     disc = handle["discovered"][bi][li]
                     fresh: List[bytes] = []
-                    for key in miss_keys(pout, bi * Lp + fl):
+                    row = bi * Lp + fl
+                    if pout.call:
+                        # a callee the window's table lacked: resolve it
+                        # and rerun; storage misses by (contract, key)
+                        cm = callee_miss(pout, row)
+                        if cm is not None and self.callees.learn(cm):
+                            missing = True
+                        root_keys = []
+                        for a, key in miss_entries(pout, row):
+                            if a == t.address:
+                                root_keys.append(key)
+                                continue
+                            if not self._key_mapped(a, key):
+                                self._discover_key(handle, bi, li, a, key)
+                            if (a, key) not in disc:
+                                disc[(a, key)] = None
+                                missing = True
+                    for key in (root_keys if pout.call
+                                else miss_keys(pout, row)):
                         if not self._key_mapped(t.address, key):
                             self._discover_key(handle, bi, li,
                                                t.address, key)
@@ -1717,7 +2275,12 @@ class MachineWindowRunner:
             slots = [self._lane_idx(handle, bi, li)
                      for li in range(len(specs))]
             res = results_for_rows(
-                pout, np.asarray(slots, dtype=np.int64) + bi * Lp)
+                pout, np.asarray(slots, dtype=np.int64) + bi * Lp,
+                [t.address for t in specs])
+            if pout.call:
+                for r, fl in zip(res, slots):
+                    self.callees.escape_why(
+                        r, callee_miss(pout, bi * Lp + fl))
             if slots:
                 com = extra[bi, slots, 0].astype(bool)
                 esc = (extra[bi, slots, 1]
@@ -1756,7 +2319,14 @@ class MachineWindowRunner:
                 touched: Dict[bytes, None] = {}
                 kb = pout.key_blob()
                 flags = pout.sflag[row]
+                root_aid = self.callees.aid_of.get(t.address) \
+                    if pout.call else None
                 for j in range(int(pout.scnt[row])):
+                    # the common set and the premap's hits are the root
+                    # contract's own keys; a callee's are mapped by
+                    # discovery
+                    if pout.call and pout.saddr[row, j] != root_aid:
+                        continue
                     if flags[j] & (M.F_READ | M.F_WRITTEN):
                         off = (row * pout.S + j) * 32
                         touched[kb[off:off + 32]] = None

@@ -34,7 +34,7 @@ machine replaces for device-resident transactions.
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict, FrozenSet, Tuple
 
 import numpy as np
@@ -54,7 +54,24 @@ F_VALID, F_WARM, F_WRITTEN, F_MISS, F_READ = 1, 2, 4, 8, 16
 
 # host_reason codes (diagnostics)
 (R_NONE, R_STACK, R_MEM, R_SCACHE, R_TCACHE, R_LOG, R_COPY, R_KECCAK,
- R_STEPS, R_OPCODE) = range(10)
+ R_STEPS, R_OPCODE, R_FRAME) = range(11)
+
+# R_FRAME sub-reasons (the lane's `fwhy`): why a call frame handed the
+# transaction to the host interpreter.  FR_CALLEE is refined on the
+# host (adapter.frame_reason) into a callee the window has not resolved
+# yet (resolved and rerun, never a host tx), a precompile, or an
+# account without code.
+(FR_NONE, FR_VALUE, FR_CALLEE, FR_DEPTH, FR_POOL, FR_FAIL) = range(6)
+
+# probes of the global slot-table hash index a frame's storage lookup
+# makes before it records a miss (adapter.slot_hash is the host twin),
+# and the index's buckets per row of the table (a load of at most 1/4)
+HPROBE = 8
+HSPAN = 4
+
+# lanes a call-frame OCC kernel steps at once: a round runs the lanes it
+# re-executes in chunks of this many (see build_occ_machine)
+CALL_LANES = 32
 
 _LIMIT_25 = 1 << 25  # mem/copy addresses beyond this are always-OOG
 LIMBS = u256.LIMBS
@@ -76,6 +93,12 @@ class MachineParams:
     copy_cap: int = 512
     max_steps: int = 1 << 16
     features: FrozenSet[str] = frozenset()
+    # call frames (feature "call" only; 0 elsewhere, so graphs without
+    # the feature keep their key): saved frames a lane may stack, rows
+    # of the window's callee table, rows of its code table
+    frame_cap: int = 0
+    callee_cap: int = 0
+    code_rows: int = 0
 
     @property
     def refunds(self) -> bool:
@@ -114,6 +137,25 @@ def _put(stack, pos, val, mask):
                     stack.shape[1])  # OOB -> drop
     return stack.at[jnp.arange(stack.shape[0]), pos].set(
         val, mode="drop")
+
+
+def _peek_dense(stack, sp, k):
+    """_peek as a one-hot select along the stack axis.  On the TPU a
+    per-lane dynamic index is an element-serial gather whose cost grows
+    with the lanes; a select over the whole (small) stack is vector
+    work.  Used by graphs with the "call" feature."""
+    idx = jnp.clip(sp - 1 - k, 0, stack.shape[1] - 1)
+    oh = jnp.arange(stack.shape[1], dtype=jnp.int32)[None, :] \
+        == idx[:, None]
+    return jnp.sum(jnp.where(oh[..., None], stack, 0), axis=1)
+
+
+def _put_dense(stack, pos, val, mask):
+    """_put as a one-hot select (see _peek_dense)."""
+    idx = jnp.clip(pos, 0, stack.shape[1] - 1)
+    oh = (jnp.arange(stack.shape[1], dtype=jnp.int32)[None, :]
+          == idx[:, None]) & mask[:, None]
+    return jnp.where(oh[..., None], val[:, None, :], stack)
 
 
 def _fits25(w):
@@ -170,16 +212,59 @@ _FIELDS = ("pc", "gas", "status", "sp", "refund", "steps", "stack",
            "tkey", "tval", "tcnt", "log_top", "log_nt", "log_data",
            "log_dlen", "log_cnt", "host_reason")
 
+# Lane state the "call" feature adds (graphs without it carry none of
+# it): storage-entry address ids and global slot ids, transient-entry
+# address ids, each log's emitting address id; the running frame's
+# registers (depth, code row, address id, ADDRESS / CALLER words, stack
+# and memory bases, calldata region, static flag); the return-data
+# buffer; the saved frames (`fs` ints, `fsw` words); the lane's warm
+# callee-table rows (EIP-2929); and what the host reads: frames
+# entered, steps run, the R_FRAME sub-reason, the unresolved callee.
+_CALL_FIELDS = ("saddr", "sgid", "taddr", "log_aid", "dep", "cid", "aid",
+                "addr_w", "caller_w", "sb", "mb", "cdb", "cdl", "stat",
+                "rd", "rdl", "fs", "fsw", "warm", "nframes", "lsteps",
+                "fwhy", "cmiss")
+
+# one saved frame's int row (fs[lane, depth, k])
+(FS_PC, FS_GAS, FS_SP, FS_SB, FS_MB, FS_MSIZE, FS_CID, FS_AID, FS_CDB,
+ FS_CDL, FS_STAT, FS_RO, FS_RL) = range(13)
+_FS_W = 13
+
+
+def slot_hash(key_lo, key_hi, aid):
+    """Bucket of a (address id, slot key) pair in the global slot
+    table's hash index, from the key's two low limbs and the address
+    id, both multiplied through and the bits folded (murmur3's
+    finalizer): a contract's consecutive constant slots must not land
+    in consecutive buckets, where linear probing of HPROBE steps runs
+    out.  uint32 arithmetic on both sides: adapter.slot_hash is the
+    host twin."""
+    h = ((key_lo.astype(jnp.uint32) | (key_hi.astype(jnp.uint32) << 16))
+         * jnp.uint32(0x9E3779B1)) \
+        ^ (aid.astype(jnp.uint32) * jnp.uint32(0x85EBCA77))
+    h = (h ^ (h >> 15)) * jnp.uint32(0xC2B2AE3D)
+    return (h ^ (h >> 13)).astype(jnp.int32) & jnp.int32(0x7FFFFFFF)
+
 
 # corethlint: jit-factory — exec_lanes runs inside the jitted kernels
-def _build_exec(params: MachineParams):
+def _build_exec(params: MachineParams, lookup: bool = False):
     """Core lane executor shared by the single-shot machine
     (build_machine) and the device-resident OCC kernel
     (build_occ_machine): exec_lanes(inputs, storage, active) runs every
     active lane to completion (one inner while_loop over steps) and
     returns the final state dict.  `storage` is the initial
     (skey, sval, sorig, sflag, scnt) cache tuple so the OCC kernel can
-    re-seed lanes between rounds without host round-trips."""
+    re-seed lanes between rounds without host round-trips; under the
+    "call" feature it adds the entries' address ids and global slot ids
+    (saddr, sgid).
+
+    Feature "call": value-0 CALL / STATICCALL frames, EXTCODESIZE,
+    RETURNDATASIZE, RETURNDATACOPY.  A frame's stack and memory are
+    regions of the lane's pools above its parent's; code comes from the
+    window's code table by row; a frame's storage entries carry its
+    address id.  `lookup` (the OCC kernel) lets a storage access the
+    lane's cache lacks find its row in the global slot table through
+    the hash index before it is recorded as a miss."""
     p = params
     ot = T.op_tables(p.fork)
     CONST = jnp.asarray(ot.const_gas)
@@ -190,10 +275,32 @@ def _build_exec(params: MachineParams):
     feats = p.features
     refunds = p.refunds
     rows = jnp.arange(B)
+    call = "call" in feats
+    fields = _FIELDS + (_CALL_FIELDS if call else ())
+    if call:
+        from coreth_tpu.evm.forks import COINBASE_WARM_FORKS
+        FC, NA, RC = p.frame_cap, p.callee_cap, p.copy_cap
+        CW = p.code_cap + 33
+        warm_cb = p.fork in COINBASE_WARM_FORKS          # EIP-3651
+        COLD_X = (P.COLD_ACCOUNT_ACCESS_COST_EIP2929
+                  - P.WARM_STORAGE_READ_COST_EIP2929)
 
     def exec_lanes(inputs, storage, active):
         code = inputs["code"]
         jdest = inputs["jdest"]
+        if call:
+            code_tab = inputs["code_tab"]        # (NC, CW)
+            push_tab = inputs["push_tab"]        # (NC, CW, 16)
+            jdest_tab = inputs["jdest_tab"]      # (NC, code_cap)
+            clen_tab = inputs["clen_tab"]        # (NC,)
+            ctab_addr = inputs["ctab_addr"]      # (NA, 16)
+            ctab_aid = inputs["ctab_aid"]        # (NA,)
+            ctab_cid = inputs["ctab_cid"]        # (NA,) -1 = unused row
+            ctab_ok = ctab_cid >= 0
+            if lookup:
+                g_tbl, g_key = inputs["g_tbl"], inputs["g_key"]
+                g_aid, hix = inputs["g_aid"], inputs["hidx"]
+                GT, HT = g_key.shape[0], hix.shape[0]
         calldata = inputs["calldata"]
         data_len = inputs["data_len"]
         ctx_words = {
@@ -211,15 +318,31 @@ def _build_exec(params: MachineParams):
         gaslimit = inputs["gaslimit"]
 
         def step(carry):
-            st = dict(zip(_FIELDS, carry))
+            st = dict(zip(fields, carry))
             pc, gas, status, sp = (st["pc"], st["gas"], st["status"],
                                    st["sp"])
             stack, mem, msize = st["stack"], st["mem"], st["msize"]
             running = status == RUN
+            if call:
+                dep, cid, aid = st["dep"], st["cid"], st["aid"]
+                sb, mb = st["sb"], st["mb"]
+                deep = dep > 0
 
-            op = jnp.take_along_axis(
-                code, jnp.clip(pc, 0, code.shape[1] - 1)[:, None],
-                axis=1)[:, 0]
+            def moff(x):
+                """A frame-relative memory index made absolute."""
+                if not call:
+                    return x
+                return x + (mb if x.ndim == 1 else mb[:, None])
+
+            peek = _peek_dense if call else _peek
+            put = _put_dense if call else _put
+
+            if call:
+                op = code_tab[cid, jnp.clip(pc, 0, CW - 1)]
+            else:
+                op = jnp.take_along_axis(
+                    code, jnp.clip(pc, 0, code.shape[1] - 1)[:, None],
+                    axis=1)[:, 0]
             op = jnp.where(running, op, 0)
 
             nin = NIN[op]
@@ -228,16 +351,22 @@ def _build_exec(params: MachineParams):
             const_gas = CONST[op]
 
             # ---------------- stack discipline
-            under = sp < nin
-            newsp = sp - nin + nout
-            over_1024 = newsp > P.STACK_LIMIT
+            if call:
+                # a frame's stack starts at its base in the lane's pool
+                under = (sp - sb) < nin
+                newsp = sp - nin + nout
+                over_1024 = (newsp - sb) > P.STACK_LIMIT
+            else:
+                under = sp < nin
+                newsp = sp - nin + nout
+                over_1024 = newsp > P.STACK_LIMIT
             over_cap = (newsp > p.stack_cap) & ~over_1024
             undefined = sup == 0
             hostop = sup == 2
 
-            a = _peek(stack, sp, 0)
-            b = _peek(stack, sp, 1)
-            c = _peek(stack, sp, 2)
+            a = peek(stack, sp, 0)
+            b = peek(stack, sp, 1)
+            c = peek(stack, sp, 2)
             a_v, a_fit = _fits25(a)
             b_v, b_fit = _fits25(b)
             c_v, c_fit = _fits25(c)
@@ -257,6 +386,12 @@ def _build_exec(params: MachineParams):
             is_keccak = m(0x20)
             is_ret_rev = m(0xF3) | m(0xFD)
             is_ddcopy = m(0x37) | m(0x39)          # calldata/code copy
+            if call:
+                is_call, is_scall = m(0xF1), m(0xFA)
+                is_xcs, is_rds, is_rdc = m(0x3B), m(0x3D), m(0x3E)
+                calls = is_call | is_scall
+                # RETURNDATACOPY has CALLDATACOPY's (dst, src, len) shape
+                is_ddcopy = is_ddcopy | is_rdc
             is_mcopy = m(0x5E)
             is_sload, is_sstore = m(0x54), m(0x55)
             is_jump, is_jumpi = m(0x56), m(0x57)
@@ -284,7 +419,43 @@ def _build_exec(params: MachineParams):
                 need = jnp.where(is_mcopy & nonzc, mx + c_v, need)
                 m_oog = m_oog | (
                     is_mcopy & nonzc & ~(a_fit & b_fit & c_fit))
-            m_host_mem = (need > p.mem_cap) & ~m_oog
+            if call:
+                # CALL: gas addr value in_off in_sz out_off out_sz;
+                # STATICCALL: gas addr in_off in_sz out_off out_sz —
+                # memory is the larger of the two regions (memoryCall).
+                # Gated: most steps make no call.
+                def call_regions():
+                    d = peek(stack, sp, 3)
+                    e_ = peek(stack, sp, 4)
+                    f_ = peek(stack, sp, 5)
+                    g_ = peek(stack, sp, 6)
+                    icw = is_call[:, None]
+                    io_v, io_fit = _fits25(jnp.where(icw, d, c))
+                    in_sz = jnp.where(icw, e_, d)
+                    is_v, is_fit = _fits25(in_sz)
+                    oo_v, oo_fit = _fits25(jnp.where(icw, f_, e_))
+                    out_sz = jnp.where(icw, g_, f_)
+                    os_v, os_fit = _fits25(out_sz)
+                    in_nz = ~u256.is_zero(in_sz)
+                    out_nz = ~u256.is_zero(out_sz)
+                    need_c = jnp.maximum(
+                        jnp.where(in_nz, io_v + is_v, 0),
+                        jnp.where(out_nz, oo_v + os_v, 0))
+                    oog_c = (in_nz & ~(io_fit & is_fit)) \
+                        | (out_nz & ~(oo_fit & os_fit))
+                    return need_c, oog_c, io_v, is_v, oo_v, os_v, in_nz
+
+                zi = jnp.zeros((B,), dtype=jnp.int32)
+                zb = jnp.zeros((B,), dtype=bool)
+                need_c, oog_c, io_v, is_v, oo_v, os_v, in_nz = \
+                    jax.lax.cond(jnp.any(calls), call_regions,
+                                 lambda: (zi, zb, zi, zi, zi, zi, zb))
+                need = jnp.where(calls, need_c, need)
+                m_oog = m_oog | (calls & oog_c)
+                # a frame's memory sits above its base in the lane's pool
+                m_host_mem = (mb + need > p.mem_cap) & ~m_oog
+            else:
+                m_host_mem = (need > p.mem_cap) & ~m_oog
             need_c = jnp.clip(need, 0, p.mem_cap)
             new_msize = jnp.maximum(msize, _ceil32(need_c))
             exp_gas = jnp.where(
@@ -328,12 +499,62 @@ def _build_exec(params: MachineParams):
                                     | (st["log_cnt"] >= LC))
                 m_host = m_host | too_log
                 reason = jnp.where(too_log, R_LOG, reason)
+            if call:
+                acc = calls | is_xcs
+
+                def callee_lookup():
+                    # the window's callee table (the address is the low
+                    # 160 bits: limbs 10..15 cleared)
+                    tgt = jnp.where(is_xcs[:, None], a, b).at[:, 10:].set(0)
+                    hit_t = jnp.all(
+                        ctab_addr[None, :, :] == tgt[:, None, :],
+                        axis=-1) & ctab_ok[None, :]
+                    found_t = jnp.any(hit_t, axis=-1)
+                    tidx = jnp.argmax(hit_t, axis=-1).astype(jnp.int32)
+                    warm_t = st["warm"][rows, tidx] & found_t
+                    # EIP-2929: the cold surcharge is charged before the
+                    # 63/64 rule sees the gas
+                    # (makeCallVariantGasCallEIP2929)
+                    cold_x = jnp.where(acc & found_t & ~warm_t, COLD_X, 0)
+                    avail = gas - const_gas - cold_x - exp_gas
+                    req = jnp.where(a_fit, a_v, _LIMIT_25)
+                    child = jnp.where(
+                        calls & (avail >= 0),
+                        jnp.minimum(req, avail - avail // 64), 0)
+                    return tgt, found_t, tidx, cold_x, child
+
+                tgt, found_t, tidx, cold_x, child = jax.lax.cond(
+                    jnp.any(acc), callee_lookup,
+                    lambda: (jnp.zeros((B, LIMBS), dtype=jnp.int32),
+                             jnp.zeros((B,), dtype=bool),
+                             jnp.zeros((B,), dtype=jnp.int32),
+                             jnp.zeros((B,), dtype=jnp.int32),
+                             jnp.zeros((B,), dtype=jnp.int32)))
+                dyn = dyn + cold_x + child
+                stat_on = st["stat"] != 0
+                f_value = is_call & ~c_zero
+                f_callee = acc & ~found_t
+                f_depth = calls & (dep >= FC)
+                f_ret = m(0xF3) & deep & (b_v > RC)
+                f_static = stat_on & (is_sstore | is_log | m(0x5D))
+                fwhy_now = jnp.where(
+                    f_value, FR_VALUE, jnp.where(
+                        f_callee, FR_CALLEE, jnp.where(
+                            f_depth, FR_DEPTH, jnp.where(
+                                f_ret, FR_POOL, jnp.where(
+                                    f_static, FR_FAIL, FR_NONE)))))
+                f_any = fwhy_now != FR_NONE
+                m_host = m_host | f_any
+                reason = jnp.where(f_any, R_FRAME, reason)
 
             # ---------------- jumps
             dest_ok = a_fit & (a_v < p.code_cap)
-            dest_bit = jnp.take_along_axis(
-                jdest, jnp.clip(a_v, 0, p.code_cap - 1)[:, None],
-                axis=1)[:, 0]
+            if call:
+                dest_bit = jdest_tab[cid, jnp.clip(a_v, 0, p.code_cap - 1)]
+            else:
+                dest_bit = jnp.take_along_axis(
+                    jdest, jnp.clip(a_v, 0, p.code_cap - 1)[:, None],
+                    axis=1)[:, 0]
             jump_valid = dest_ok & (dest_bit == 1)
             jumpi_taken = is_jumpi & ~b_zero
             take_jump = is_jump | jumpi_taken
@@ -344,6 +565,9 @@ def _build_exec(params: MachineParams):
             # through the arm masks as a free no-op
             pre_err = under | over_1024 | undefined | bad_jump \
                 | m_oog | m(0xFE)
+            if call:
+                # EIP-211: a copy past the return data is an error
+                pre_err = pre_err | (is_rdc & (b_v + c_v > st["rdl"]))
             ok_pre = running & ~pre_err & ~m_host
 
             # ---------------- cheap value families (always compiled)
@@ -368,26 +592,51 @@ def _build_exec(params: MachineParams):
             # PUSH0..PUSH32: big-endian bytes following pc
             pushlen = jnp.where(is_push, op - 0x5F, 0)
             le_pos = jnp.arange(32, dtype=jnp.int32)[None, :]
-            idxp = pc[:, None] + pushlen[:, None] - le_pos
-            pbytes = jnp.take_along_axis(
-                code, jnp.clip(idxp, 0, code.shape[1] - 1), axis=1)
-            pbytes = jnp.where(le_pos < pushlen[:, None], pbytes, 0)
-            pword = jnp.stack(
-                [pbytes[:, 2 * l] | (pbytes[:, 2 * l + 1] << 8)
-                 for l in range(LIMBS)], axis=-1)
+            if call:
+                # the push's value, decoded per pc on the host
+                pword = push_tab[cid, jnp.clip(pc, 0, CW - 1)]
+            else:
+                idxp = pc[:, None] + pushlen[:, None] - le_pos
+                pbytes = jnp.take_along_axis(
+                    code, jnp.clip(idxp, 0, code.shape[1] - 1), axis=1)
+                pbytes = jnp.where(le_pos < pushlen[:, None], pbytes, 0)
+                pword = jnp.stack(
+                    [pbytes[:, 2 * l] | (pbytes[:, 2 * l + 1] << 8)
+                     for l in range(LIMBS)], axis=-1)
             val = sel(is_push, pword)
 
             # DUP_n
-            dup_val = _peek(stack, sp, jnp.clip(op - 0x80, 0, 15))
+            dup_val = peek(stack, sp, jnp.clip(op - 0x80, 0, 15))
             val = sel(is_dup, dup_val)
 
             # CALLDATALOAD: 32 bytes from calldata[a..], zero-padded
             cd_idx = a_v[:, None] + 31 - le_pos
-            cd_ok = (a_fit[:, None] & (cd_idx >= a_v[:, None])
-                     & (cd_idx < data_len[:, None])
-                     & (cd_idx < p.data_cap))
-            cd_bytes = jnp.take_along_axis(
-                calldata, jnp.clip(cd_idx, 0, p.data_cap - 1), axis=1)
+            if call:
+                # a called frame's calldata is a region of its parent's
+                # memory (cdb, cdl)
+                cd_len = jnp.where(deep, st["cdl"], data_len)
+                cd_ok = (a_fit[:, None] & (cd_idx >= a_v[:, None])
+                         & (cd_idx < cd_len[:, None])
+                         & (deep[:, None] | (cd_idx < p.data_cap)))
+
+                def cd_load():
+                    cd_top = jnp.take_along_axis(
+                        calldata, jnp.clip(cd_idx, 0, p.data_cap - 1),
+                        axis=1)
+                    cd_mem = jnp.take_along_axis(
+                        mem, jnp.clip(st["cdb"][:, None] + cd_idx, 0,
+                                      p.mem_cap - 1), axis=1)
+                    return jnp.where(deep[:, None], cd_mem, cd_top)
+
+                cd_bytes = jax.lax.cond(
+                    jnp.any(m(0x35)), cd_load,
+                    lambda: jnp.zeros((B, 32), dtype=jnp.int32))
+            else:
+                cd_ok = (a_fit[:, None] & (cd_idx >= a_v[:, None])
+                         & (cd_idx < data_len[:, None])
+                         & (cd_idx < p.data_cap))
+                cd_bytes = jnp.take_along_axis(
+                    calldata, jnp.clip(cd_idx, 0, p.data_cap - 1), axis=1)
             cd_bytes = jnp.where(cd_ok, cd_bytes, 0)
             cd_word = jnp.stack(
                 [cd_bytes[:, 2 * l] | (cd_bytes[:, 2 * l + 1] << 8)
@@ -395,13 +644,25 @@ def _build_exec(params: MachineParams):
             val = sel(m(0x35), cd_word)
 
             # context / block words
-            val = sel(m(0x30), ctx_words["address"])
-            val = sel(m(0x32), ctx_words["origin"])
-            val = sel(m(0x33), ctx_words["caller"])
-            val = sel(m(0x34), ctx_words["callvalue"])
-            val = sel(m(0x36), word_of_scalar(data_len, (B,)))
-            val = sel(m(0x38), word_of_scalar(
-                jnp.broadcast_to(inputs["code_len"], (B,)), (B,)))
+            if call:
+                val = sel(m(0x30), st["addr_w"])
+                val = sel(m(0x32), ctx_words["origin"])
+                val = sel(m(0x33), st["caller_w"])
+                val = sel(m(0x34), jnp.where(
+                    deep[:, None], 0, ctx_words["callvalue"]))
+                val = sel(m(0x36), word_of_scalar(cd_len, (B,)))
+                val = sel(m(0x38), word_of_scalar(clen_tab[cid], (B,)))
+                val = sel(is_xcs, word_of_scalar(
+                    clen_tab[jnp.clip(ctab_cid[tidx], 0)], (B,)))
+                val = sel(is_rds, word_of_scalar(st["rdl"], (B,)))
+            else:
+                val = sel(m(0x30), ctx_words["address"])
+                val = sel(m(0x32), ctx_words["origin"])
+                val = sel(m(0x33), ctx_words["caller"])
+                val = sel(m(0x34), ctx_words["callvalue"])
+                val = sel(m(0x36), word_of_scalar(data_len, (B,)))
+                val = sel(m(0x38), word_of_scalar(
+                    jnp.broadcast_to(inputs["code_len"], (B,)), (B,)))
             val = sel(m(0x3A), ctx_words["gasprice"])
             val = sel(m(0x41), coinbase_w)
             val = sel(m(0x42), word_of_scalar(
@@ -421,10 +682,20 @@ def _build_exec(params: MachineParams):
                 jnp.maximum(gas - const_gas, 0), (B,)))
 
             # MLOAD: big-endian byte j of the word is mem[off + j]
-            ml_be = jnp.take_along_axis(
-                mem, jnp.clip(jnp.clip(a_v, 0, p.mem_cap)[:, None]
-                              + le_pos, 0, p.mem_cap - 1), axis=1)
-            val = sel(is_mload, _bytes_to_limbs(ml_be))
+            if call:
+                # gated: a gather per lane per step costs the TPU more
+                # than the cond
+                val = jax.lax.cond(
+                    jnp.any(is_mload), lambda: sel(is_mload, _bytes_to_limbs(
+                        jnp.take_along_axis(mem, jnp.clip(
+                            moff(jnp.clip(a_v, 0, p.mem_cap)[:, None]
+                                 + le_pos), 0, p.mem_cap - 1), axis=1))),
+                    lambda: val)
+            else:
+                ml_be = jnp.take_along_axis(
+                    mem, jnp.clip(moff(jnp.clip(a_v, 0, p.mem_cap)[:, None]
+                                       + le_pos), 0, p.mem_cap - 1), axis=1)
+                val = sel(is_mload, _bytes_to_limbs(ml_be))
 
             # ---------------- heavy families (statically + cond gated)
             if "mul" in feats:
@@ -497,7 +768,7 @@ def _build_exec(params: MachineParams):
 
                 def keccak_family():
                     KC = p.keccak_cap
-                    off = jnp.clip(a_v, 0, p.mem_cap)
+                    off = moff(jnp.clip(a_v, 0, p.mem_cap))
                     jj = jnp.arange(KC, dtype=jnp.int32)[None, :]
                     src = jnp.take_along_axis(
                         mem, jnp.clip(off[:, None] + jj, 0,
@@ -533,6 +804,8 @@ def _build_exec(params: MachineParams):
             refund_d = jnp.zeros((B,), dtype=jnp.int32)
             st_err = jnp.zeros((B,), dtype=bool)
             st_host = jnp.zeros((B,), dtype=bool)
+            if call:
+                saddr, sgid = st["saddr"], st["sgid"]
             if "storage" in feats:
                 mask_any = (is_sload | is_sstore) & ok_pre
 
@@ -546,6 +819,10 @@ def _build_exec(params: MachineParams):
                     new = b
                     hit = jnp.all(skey == key[:, None, :], axis=-1) \
                         & ((sflag & F_VALID) != 0)
+                    if call:
+                        # an entry is (address id, slot): the running
+                        # frame's contract, not the lane's root
+                        hit = hit & (saddr == aid[:, None])
                     found = jnp.any(hit, axis=-1)
                     hidx = jnp.argmax(hit, axis=-1)
                     need_app = mask_any & ~found
@@ -557,6 +834,29 @@ def _build_exec(params: MachineParams):
                     cur = jnp.where(found[:, None], sval[rows, eidx], 0)
                     orig = jnp.where(found[:, None],
                                      sorig[rows, eidx], 0)
+                    miss = need_app
+                    if call and lookup:
+                        # not in the lane's cache: probe the global slot
+                        # table's hash index; a row found there is read
+                        # against the block-start table (OCC validation
+                        # checks it like a premapped entry), and only a
+                        # key the table lacks is a miss
+                        h0 = slot_hash(key[:, 0], key[:, 1], aid) & (HT - 1)
+                        probes = (h0[:, None] + jnp.arange(HPROBE)[None, :]) \
+                            & (HT - 1)
+                        pg = hix[probes]                       # (B, HP)
+                        pgc = jnp.clip(pg, 0, GT - 1)
+                        pm = (pg >= 0) \
+                            & jnp.all(g_key[pgc] == key[:, None, :], axis=-1) \
+                            & (g_aid[pgc] == aid[:, None])
+                        lfound = jnp.any(pm, axis=-1)
+                        lgid = jnp.take_along_axis(
+                            pgc, jnp.argmax(pm, axis=-1)[:, None],
+                            axis=1)[:, 0]
+                        lval = jnp.where(lfound[:, None], g_tbl[lgid], 0)
+                        cur = jnp.where(found[:, None], cur, lval)
+                        orig = jnp.where(found[:, None], orig, lval)
+                        miss = need_app & ~lfound
                     # SLOAD gas (gas_sload_eip2929)
                     c_sload = jnp.where(
                         warm, P.WARM_STORAGE_READ_COST_EIP2929,
@@ -614,17 +914,21 @@ def _build_exec(params: MachineParams):
                     do_write = do_entry & ~sentry & afford
                     wflag = eflag
                     wflag = wflag | F_VALID | F_READ | F_WARM
-                    wflag = jnp.where(need_app, wflag | F_MISS, wflag)
+                    wflag = jnp.where(miss, wflag | F_MISS, wflag)
                     wflag = jnp.where(is_sstore & do_write,
                                       wflag | F_WRITTEN, wflag)
                     nkey = jnp.where((do_entry & need_app)[:, None],
                                      key, skey[rows, eidx])
+                    if call and lookup:
+                        fresh_v = lval
+                    else:
+                        fresh_v = 0
                     nval = jnp.where(
                         (do_write & is_sstore)[:, None], new,
-                        jnp.where((do_entry & need_app)[:, None], 0,
+                        jnp.where((do_entry & need_app)[:, None], fresh_v,
                                   sval[rows, eidx]))
-                    nori = jnp.where((do_entry & need_app)[:, None], 0,
-                                     sorig[rows, eidx])
+                    nori = jnp.where((do_entry & need_app)[:, None],
+                                     fresh_v, sorig[rows, eidx])
                     eidx_w = jnp.where(do_entry, eidx, S)
                     skey2 = skey.at[rows, eidx_w].set(nkey, mode="drop")
                     sval2 = sval.at[rows, eidx_w].set(nval, mode="drop")
@@ -634,22 +938,48 @@ def _build_exec(params: MachineParams):
                         jnp.where(do_entry, wflag, 0), mode="drop")
                     scnt2 = scnt + (do_entry & need_app).astype(
                         jnp.int32)
+                    if call:
+                        v = jnp.where((is_sload & do_write)[:, None],
+                                      cur, val)
+                        app = do_entry & need_app
+                        saddr2 = saddr.at[rows, eidx_w].set(
+                            jnp.where(app, aid, saddr[rows, eidx]),
+                            mode="drop")
+                        if lookup:
+                            sgid2 = sgid.at[rows, eidx_w].set(
+                                jnp.where(app, jnp.where(miss, GT, lgid),
+                                          sgid[rows, eidx]), mode="drop")
+                        else:
+                            sgid2 = sgid
+                        return (v, cost, rd, sentry & mask_any,
+                                full, skey2, sval2, sorig2, sflag2, scnt2,
+                                saddr2, sgid2)
                     v = jnp.where((is_sload & do_write)[:, None],
                                   jnp.where(found[:, None], cur, 0),
                                   val)
                     return (v, cost, rd, sentry & mask_any,
                             full, skey2, sval2, sorig2, sflag2, scnt2)
 
-                (val, cost_st, refund_d, st_err, st_host, skey, sval,
-                 sorig, sflag, scnt) = jax.lax.cond(
-                    jnp.any(mask_any), storage_family,
-                    lambda: (val, cost_st, refund_d, st_err, st_host,
-                             skey, sval, sorig, sflag, scnt))
+                if call:
+                    (val, cost_st, refund_d, st_err, st_host, skey, sval,
+                     sorig, sflag, scnt, saddr, sgid) = jax.lax.cond(
+                        jnp.any(mask_any), storage_family,
+                        lambda: (val, cost_st, refund_d, st_err, st_host,
+                                 skey, sval, sorig, sflag, scnt, saddr,
+                                 sgid))
+                else:
+                    (val, cost_st, refund_d, st_err, st_host, skey, sval,
+                     sorig, sflag, scnt) = jax.lax.cond(
+                        jnp.any(mask_any), storage_family,
+                        lambda: (val, cost_st, refund_d, st_err, st_host,
+                                 skey, sval, sorig, sflag, scnt))
                 m_host = m_host | st_host
                 reason = jnp.where(st_host, R_SCACHE, reason)
 
             # ---------------- transient storage (cancun)
             tkey, tval, tcnt = st["tkey"], st["tval"], st["tcnt"]
+            if call:
+                taddr = st["taddr"]
             if "tstorage" in feats:
                 is_tload, is_tstore = m(0x5C), m(0x5D)
                 mask_any = (is_tload | is_tstore) & ok_pre
@@ -658,6 +988,8 @@ def _build_exec(params: MachineParams):
                     key = a
                     hit = jnp.all(tkey == key[:, None, :], axis=-1) \
                         & (jnp.arange(TC)[None, :] < tcnt[:, None])
+                    if call:
+                        hit = hit & (taddr == aid[:, None])
                     found = jnp.any(hit, axis=-1)
                     hidx = jnp.argmax(hit, axis=-1)
                     need_app = mask_any & is_tstore & ~found
@@ -672,12 +1004,22 @@ def _build_exec(params: MachineParams):
                     tval2 = tval.at[rows, eidx_w].set(b, mode="drop")
                     tcnt2 = tcnt + (do & need_app).astype(jnp.int32)
                     v = jnp.where((is_tload & do)[:, None], cur, val)
+                    if call:
+                        taddr2 = taddr.at[rows, eidx_w].set(
+                            aid, mode="drop")
+                        return v, full, tkey2, tval2, tcnt2, taddr2
                     return v, full, tkey2, tval2, tcnt2
 
-                val, t_host, tkey, tval, tcnt = jax.lax.cond(
-                    jnp.any(mask_any), t_family,
-                    lambda: (val, jnp.zeros((B,), dtype=bool),
-                             tkey, tval, tcnt))
+                if call:
+                    val, t_host, tkey, tval, tcnt, taddr = jax.lax.cond(
+                        jnp.any(mask_any), t_family,
+                        lambda: (val, jnp.zeros((B,), dtype=bool),
+                                 tkey, tval, tcnt, taddr))
+                else:
+                    val, t_host, tkey, tval, tcnt = jax.lax.cond(
+                        jnp.any(mask_any), t_family,
+                        lambda: (val, jnp.zeros((B,), dtype=bool),
+                                 tkey, tval, tcnt))
                 m_host = m_host | t_host
                 reason = jnp.where(t_host, R_TCACHE, reason)
 
@@ -694,14 +1036,21 @@ def _build_exec(params: MachineParams):
             ms_mask = ok & (is_mstore | is_mstore8)
             n_write = jnp.where(is_mstore8, 1, 32)
             wj = jnp.arange(32, dtype=jnp.int32)[None, :]
-            w_idx = a_v[:, None] + wj
+            w_idx = moff(a_v[:, None] + wj)
             w_idx = jnp.where(
                 ms_mask[:, None] & (wj < n_write[:, None]),
                 jnp.clip(w_idx, 0, p.mem_cap - 1), p.mem_cap)
             w_src = jnp.where(is_mstore8[:, None],
                               jnp.broadcast_to((b[:, 0] & 0xFF)[:, None],
                                                (B, 32)), w_bytes)
-            mem = mem.at[rows[:, None], w_idx].set(w_src, mode="drop")
+            if call:
+                mem = jax.lax.cond(
+                    jnp.any(ms_mask),
+                    lambda: mem.at[rows[:, None], w_idx].set(
+                        w_src, mode="drop"),
+                    lambda: mem)
+            else:
+                mem = mem.at[rows[:, None], w_idx].set(w_src, mode="drop")
 
             # copies (calldata/code/mcopy)
             copy_mask = ok & copy3
@@ -719,18 +1068,35 @@ def _build_exec(params: MachineParams):
                         b_fit[:, None] & (src_idx < data_len[:, None])
                         & (src_idx < p.data_cap), cd, 0)
                     # beyond data_cap with real data_len<=cap: zeros ok
-                    co = jnp.take_along_axis(
-                        code, jnp.clip(src_idx, 0, code.shape[1] - 1),
-                        axis=1)
-                    co = jnp.where(
-                        b_fit[:, None] & (src_idx < code.shape[1]),
-                        co, 0)
+                    if call:
+                        cdm = jnp.take_along_axis(
+                            mem, jnp.clip(st["cdb"][:, None] + src_idx, 0,
+                                          p.mem_cap - 1), axis=1)
+                        cdm = jnp.where(
+                            b_fit[:, None]
+                            & (src_idx < st["cdl"][:, None]), cdm, 0)
+                        cd = jnp.where(deep[:, None], cdm, cd)
+                        co = code_tab[cid[:, None],
+                                      jnp.clip(src_idx, 0, CW - 1)]
+                        co = jnp.where(b_fit[:, None] & (src_idx < CW),
+                                       co, 0)
+                    else:
+                        co = jnp.take_along_axis(
+                            code, jnp.clip(src_idx, 0, code.shape[1] - 1),
+                            axis=1)
+                        co = jnp.where(
+                            b_fit[:, None] & (src_idx < code.shape[1]),
+                            co, 0)
                     mm = jnp.take_along_axis(
-                        mem, jnp.clip(src_idx, 0, p.mem_cap - 1),
+                        mem, jnp.clip(moff(src_idx), 0, p.mem_cap - 1),
                         axis=1)
+                    if call:
+                        rdv = jnp.take_along_axis(
+                            st["rd"], jnp.clip(src_idx, 0, RC - 1), axis=1)
+                        mm = jnp.where(is_rdc[:, None], rdv, mm)
                     src = jnp.where(m(0x37)[:, None], cd,
                                     jnp.where(m(0x39)[:, None], co, mm))
-                    d_idx = a_v[:, None] + jj
+                    d_idx = moff(a_v[:, None] + jj)
                     d_idx = jnp.where(
                         copy_mask[:, None] & (jj < c_v[:, None]),
                         jnp.clip(d_idx, 0, p.mem_cap - 1), p.mem_cap)
@@ -744,20 +1110,22 @@ def _build_exec(params: MachineParams):
             log_top, log_nt = st["log_top"], st["log_nt"]
             log_data, log_dlen = st["log_data"], st["log_dlen"]
             log_cnt = st["log_cnt"]
+            if call:
+                log_aid = st["log_aid"]
             if "log" in feats:
                 lmask = ok & is_log
 
                 def log_family():
                     n = jnp.clip(op - 0xA0, 0, 4)
                     topics = jnp.stack(
-                        [_peek(stack, sp, 2 + k) for k in range(4)],
+                        [peek(stack, sp, 2 + k) for k in range(4)],
                         axis=1)  # (B, 4, 16)
                     tmask = (jnp.arange(4)[None, :] < n[:, None])
                     topics = jnp.where(tmask[..., None], topics, 0)
                     LD = p.log_data_cap
                     jj = jnp.arange(LD, dtype=jnp.int32)[None, :]
                     dsrc = jnp.take_along_axis(
-                        mem, jnp.clip(a_v[:, None] + jj, 0,
+                        mem, jnp.clip(moff(a_v[:, None] + jj), 0,
                                       p.mem_cap - 1), axis=1)
                     dsrc = jnp.where(jj < b_v[:, None], dsrc, 0)
                     slot = jnp.where(lmask, jnp.clip(log_cnt, 0, LC - 1),
@@ -768,25 +1136,35 @@ def _build_exec(params: MachineParams):
                     ld2 = log_data.at[rows, slot].set(dsrc, mode="drop")
                     ll2 = log_dlen.at[rows, slot].set(b_v, mode="drop")
                     lc2 = log_cnt + lmask.astype(jnp.int32)
+                    if call:
+                        la2 = log_aid.at[rows, slot].set(aid, mode="drop")
+                        return lt2, ln2, ld2, ll2, lc2, la2
                     return lt2, ln2, ld2, ll2, lc2
 
-                log_top, log_nt, log_data, log_dlen, log_cnt = \
-                    jax.lax.cond(
-                        jnp.any(lmask), log_family,
-                        lambda: (log_top, log_nt, log_data, log_dlen,
-                                 log_cnt))
+                if call:
+                    log_top, log_nt, log_data, log_dlen, log_cnt, \
+                        log_aid = jax.lax.cond(
+                            jnp.any(lmask), log_family,
+                            lambda: (log_top, log_nt, log_data, log_dlen,
+                                     log_cnt, log_aid))
+                else:
+                    log_top, log_nt, log_data, log_dlen, log_cnt = \
+                        jax.lax.cond(
+                            jnp.any(lmask), log_family,
+                            lambda: (log_top, log_nt, log_data, log_dlen,
+                                     log_cnt))
 
             # ---------------- stack writes
             has_push = (nout > 0) & ~is_swap
-            stack = _put(stack, newsp - 1, val, ok & has_push)
+            stack = put(stack, newsp - 1, val, ok & has_push)
 
             # SWAP: exchange top with top-1-n
             swap_n = jnp.clip(op - 0x8F, 1, 16)
             sw_mask = ok & is_swap
             top_v = a
-            oth_v = _peek(stack, sp, swap_n)
-            stack = _put(stack, sp - 1, oth_v, sw_mask)
-            stack = _put(stack, sp - 1 - swap_n, top_v, sw_mask)
+            oth_v = peek(stack, sp, swap_n)
+            stack = put(stack, sp - 1, oth_v, sw_mask)
+            stack = put(stack, sp - 1 - swap_n, top_v, sw_mask)
 
             # ---------------- advance
             is_stop = m(0x00) | m(0xF3)
@@ -800,6 +1178,14 @@ def _build_exec(params: MachineParams):
                                               jnp.where(ok & is_revert,
                                                         REVERT, RUN)))),
                 status)
+            if call:
+                # a called frame's STOP / RETURN resumes its parent; its
+                # REVERT or failure hands the tx to the host (R_FRAME)
+                pushing = ok & calls
+                popping = ok & is_stop & deep
+                bad_end = running & deep & (err | (ok & is_revert))
+                status = jnp.where(popping, RUN, status)
+                status = jnp.where(bad_end, HOST, status)
             gas = jnp.where(ok, gas - cost, gas)
             sp = jnp.where(ok, newsp, sp)
             pc = jnp.where(ok & (status == RUN), next_pc, pc)
@@ -809,6 +1195,115 @@ def _build_exec(params: MachineParams):
                                     st["host_reason"])
 
             out = dict(st)
+            if call:
+                warm = jax.lax.cond(
+                    jnp.any(ok & acc),
+                    lambda: st["warm"].at[
+                        rows, jnp.where(ok & acc & found_t, tidx, NA)].set(
+                            True, mode="drop"),
+                    lambda: st["warm"])
+                regs0 = (pc, gas, sp, msize, stack, mem, dep, cid, aid,
+                         st["addr_w"], st["caller_w"], sb, mb, st["cdb"],
+                         st["cdl"], st["stat"], st["rd"], st["rdl"],
+                         st["fs"], st["fsw"])
+
+                def frame_family():
+                    # push: the parent's registers into the saved row at
+                    # its depth, the child's from the callee table
+                    slot = jnp.where(pushing, jnp.clip(dep, 0, FC - 1), FC)
+                    saved = jnp.stack(
+                        [pc, gas, newsp - 1, sb, mb, msize, cid, aid,
+                         st["cdb"], st["cdl"], st["stat"], oo_v, os_v],
+                        axis=1)
+                    fs2 = st["fs"].at[rows, slot].set(saved, mode="drop")
+                    fsw2 = st["fsw"].at[rows, slot].set(
+                        jnp.stack([st["addr_w"], st["caller_w"]], axis=1),
+                        mode="drop")
+                    # pop: the child's output into the return buffer and
+                    # the parent's out region, the child's memory zeroed
+                    pslot = jnp.clip(dep - 1, 0, FC - 1)
+                    par = st["fs"][rows, pslot]
+                    parw = st["fsw"][rows, pslot]
+                    ret_len = jnp.where(popping & m(0xF3), b_v, 0)
+                    jr = jnp.arange(RC, dtype=jnp.int32)[None, :]
+                    rsrc = jnp.take_along_axis(
+                        mem, jnp.clip(mb[:, None] + a_v[:, None] + jr, 0,
+                                      p.mem_cap - 1), axis=1)
+                    rsrc = jnp.where(jr < ret_len[:, None], rsrc, 0)
+                    ncp = jnp.minimum(ret_len, par[:, FS_RL])
+                    dst = jnp.where(
+                        popping[:, None] & (jr < ncp[:, None]),
+                        jnp.clip(par[:, FS_MB][:, None]
+                                 + par[:, FS_RO][:, None] + jr,
+                                 0, p.mem_cap - 1), p.mem_cap)
+                    mem2 = mem.at[rows[:, None], dst].set(rsrc, mode="drop")
+                    pos = jnp.arange(p.mem_cap, dtype=jnp.int32)[None, :]
+                    mem2 = jnp.where(popping[:, None] & (pos >= mb[:, None]),
+                                     0, mem2)
+                    stack2 = put(stack, par[:, FS_SP],
+                                  word_of_scalar(jnp.ones((B,), jnp.int32),
+                                                 (B,)), popping)
+
+                    def pick(push_v, pop_v, cur):
+                        return jnp.where(pushing, push_v,
+                                         jnp.where(popping, pop_v, cur))
+
+                    def pickw(push_v, pop_v, cur):
+                        return jnp.where(pushing[:, None], push_v,
+                                         jnp.where(popping[:, None], pop_v,
+                                                   cur))
+
+                    return (
+                        pick(0, par[:, FS_PC], pc),
+                        pick(child, par[:, FS_GAS] + gas, gas),
+                        pick(newsp - 1, par[:, FS_SP] + 1, sp),
+                        pick(0, par[:, FS_MSIZE], msize),
+                        stack2, mem2,
+                        dep + pushing.astype(jnp.int32)
+                        - popping.astype(jnp.int32),
+                        pick(ctab_cid[tidx], par[:, FS_CID], cid),
+                        pick(ctab_aid[tidx], par[:, FS_AID], aid),
+                        pickw(tgt, parw[:, 0], st["addr_w"]),
+                        pickw(st["addr_w"], parw[:, 1], st["caller_w"]),
+                        pick(newsp - 1, par[:, FS_SB], sb),
+                        pick(mb + msize, par[:, FS_MB], mb),
+                        pick(mb + io_v, par[:, FS_CDB], st["cdb"]),
+                        pick(jnp.where(in_nz, is_v, 0), par[:, FS_CDL],
+                             st["cdl"]),
+                        pick(st["stat"] | is_scall.astype(jnp.int32),
+                             par[:, FS_STAT], st["stat"]),
+                        jnp.where(popping[:, None], rsrc, st["rd"]),
+                        pick(0, ret_len, st["rdl"]),
+                        fs2, fsw2)
+
+                # the frame push / pop's stable name in a device trace
+                with jax.named_scope("coreth/call_frame"):
+                    (pc, gas, sp, msize, stack, mem, dep2, cid2, aid2,
+                     addr_w2, caller_w2, sb2, mb2, cdb2, cdl2, stat2, rd2,
+                     rdl2, fs2, fsw2) = jax.lax.cond(
+                        jnp.any(pushing | popping), frame_family,
+                        lambda: regs0)
+                fw = jnp.where(host_now & (reason == R_FRAME), fwhy_now,
+                               st["fwhy"])
+                # a pool overflows inside a called frame: a frame escape
+                pool = host_now & deep & ((reason == R_STACK)
+                                          | (reason == R_MEM))
+                fw = jnp.where(pool, FR_POOL, fw)
+                fw = jnp.where(bad_end, FR_FAIL, fw)
+                host_reason = jnp.where(pool | bad_end, R_FRAME,
+                                        host_reason)
+                unresolved = host_now & (reason == R_FRAME) \
+                    & (fwhy_now == FR_CALLEE)
+                out.update(
+                    saddr=saddr, sgid=sgid, taddr=taddr, log_aid=log_aid,
+                    dep=dep2, cid=cid2, aid=aid2, addr_w=addr_w2,
+                    caller_w=caller_w2, sb=sb2, mb=mb2, cdb=cdb2,
+                    cdl=cdl2, stat=stat2, rd=rd2, rdl=rdl2, fs=fs2,
+                    fsw=fsw2, warm=warm,
+                    nframes=st["nframes"] + pushing.astype(jnp.int32),
+                    lsteps=st["lsteps"] + running.astype(jnp.int32),
+                    fwhy=fw,
+                    cmiss=jnp.where(unresolved[:, None], tgt, st["cmiss"]))
             out.update(pc=pc, gas=gas, status=status, sp=sp,
                        refund=refund, steps=st["steps"] + 1,
                        stack=stack, mem=mem, msize=msize, skey=skey,
@@ -817,14 +1312,17 @@ def _build_exec(params: MachineParams):
                        log_top=log_top, log_nt=log_nt,
                        log_data=log_data, log_dlen=log_dlen,
                        log_cnt=log_cnt, host_reason=host_reason)
-            return tuple(out[f] for f in _FIELDS)
+            return tuple(out[f] for f in fields)
 
         def cond(carry):
-            st = dict(zip(_FIELDS, carry))
+            st = dict(zip(fields, carry))
             return jnp.any(st["status"] == RUN) \
                 & (st["steps"] < p.max_steps)
 
-        skey0, sval0, sorig0, sflag0, scnt0 = storage
+        if call:
+            skey0, sval0, sorig0, sflag0, scnt0, saddr0, sgid0 = storage
+        else:
+            skey0, sval0, sorig0, sflag0, scnt0 = storage
         init = dict(
             pc=jnp.zeros((B,), dtype=jnp.int32),
             gas=inputs["start_gas"].astype(jnp.int32),
@@ -849,9 +1347,34 @@ def _build_exec(params: MachineParams):
             log_cnt=jnp.zeros((B,), dtype=jnp.int32),
             host_reason=jnp.zeros((B,), dtype=jnp.int32),
         )
+        if call:
+            z = jnp.zeros((B,), dtype=jnp.int32)
+            aid0 = inputs["aid0"]
+            # EIP-2929 warm at the start: the root contract, the origin,
+            # and from Durango the coinbase (EIP-3651)
+            warm0 = (ctab_aid[None, :] == aid0[:, None]) | jnp.all(
+                ctab_addr[None, :, :] == ctx_words["origin"][:, None, :],
+                axis=-1)
+            if warm_cb:
+                warm0 = warm0 | jnp.all(
+                    ctab_addr[None, :, :] == coinbase_w[:, None, :],
+                    axis=-1)
+            init.update(
+                saddr=saddr0, sgid=sgid0,
+                taddr=jnp.zeros((B, TC), dtype=jnp.int32),
+                log_aid=jnp.zeros((B, LC), dtype=jnp.int32),
+                dep=z, cid=inputs["cid0"], aid=aid0,
+                addr_w=ctx_words["address"], caller_w=ctx_words["caller"],
+                sb=z, mb=z, cdb=z, cdl=z, stat=z,
+                rd=jnp.zeros((B, RC), dtype=jnp.int32), rdl=z,
+                fs=jnp.zeros((B, FC, _FS_W), dtype=jnp.int32),
+                fsw=jnp.zeros((B, FC, 2, LIMBS), dtype=jnp.int32),
+                warm=warm0 & ctab_ok[None, :],
+                nframes=z, lsteps=z, fwhy=z,
+                cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32))
         final = jax.lax.while_loop(
-            cond, step, tuple(init[f] for f in _FIELDS))
-        st = dict(zip(_FIELDS, final))
+            cond, step, tuple(init[f] for f in fields))
+        st = dict(zip(fields, final))
         # lanes still running at the step bound escape to host
         timed_out = st["status"] == RUN
         st["status"] = jnp.where(timed_out, HOST, st["status"])
@@ -868,8 +1391,11 @@ def _build_exec(params: MachineParams):
 def pack_result(B: int, st: dict):
     """ONE packed int32 output row per lane: every separate
     device->host array transfer pays a sync of its own, so the
-    adapter downloads this single tensor instead of ~12 arrays."""
-    return jnp.concatenate([
+    adapter downloads this single tensor instead of ~12 arrays.  The
+    "call" feature appends its columns after the common layout:
+    entry address ids, log address ids, frames entered, steps run,
+    the R_FRAME sub-reason and the unresolved callee's word."""
+    cols = [
         st["status"][:, None], st["gas"][:, None],
         st["refund"][:, None], st["host_reason"][:, None],
         st["scnt"][:, None], st["sflag"],
@@ -877,7 +1403,11 @@ def pack_result(B: int, st: dict):
         st["sorig"].reshape(B, -1), st["log_nt"],
         st["log_dlen"], st["log_cnt"][:, None],
         st["log_top"].reshape(B, -1),
-        st["log_data"].reshape(B, -1)], axis=1)
+        st["log_data"].reshape(B, -1)]
+    if "saddr" in st:
+        cols += [st["saddr"], st["log_aid"], st["nframes"][:, None],
+                 st["lsteps"][:, None], st["fwhy"][:, None], st["cmiss"]]
+    return jnp.concatenate(cols, axis=1)
 
 
 def build_machine(params: MachineParams):
@@ -894,11 +1424,12 @@ def build_machine(params: MachineParams):
     exec_lanes = _build_exec(params)
 
     def run(inputs):
-        st = exec_lanes(
-            inputs,
-            (inputs["skey"], inputs["sval"], inputs["sorig"],
-             inputs["sflag"], inputs["scnt"]),
-            inputs["active"])
+        storage = (inputs["skey"], inputs["sval"], inputs["sorig"],
+                   inputs["sflag"], inputs["scnt"])
+        if "call" in params.features:
+            storage += (inputs["saddr"],
+                        jnp.zeros_like(inputs["sflag"]))
+        st = exec_lanes(inputs, storage, inputs["active"])
         st["packed"] = pack_result(params.batch, st)
         return st
 
@@ -942,6 +1473,20 @@ class OccParams:
 _OCC_RES = ("status", "gas", "refund", "host_reason", "scnt", "sflag",
             "skey", "sval", "sorig", "log_top", "log_nt", "log_data",
             "log_dlen", "log_cnt")
+# ... and under the "call" feature
+_OCC_CALL_RES = ("saddr", "sgid", "log_aid", "nframes", "lsteps", "fwhy",
+                 "cmiss")
+
+# per-lane inputs of a block's exec (leading axis: the lanes)
+_LANE_KEYS = ("code", "jdest", "code_len", "calldata", "data_len",
+              "start_gas", "callvalue", "caller_w", "address_w",
+              "origin_w", "gasprice_w", "cid0", "aid0", "kdig")
+
+# window-wide inputs of a "call" kernel (not scanned per block): the
+# code and callee tables, the global table's address ids and its hash
+# index
+_CALL_SHARED = ("code_tab", "push_tab", "jdest_tab", "clen_tab",
+                "ctab_addr", "ctab_aid", "ctab_cid", "g_aid", "hidx")
 
 
 def build_occ_machine(params: MachineParams, occ: OccParams,
@@ -985,7 +1530,17 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
     speculative table — the adapter discards them.
     """
     p = params
-    exec_lanes = _build_exec(p)
+    call = "call" in p.features
+    # call-frame windows run every lane on the generic kernel
+    assert not (call and spec), "specialized programs under 'call'"
+    if call:
+        # a call window steps CALL_LANES lanes at a time (see occ_body)
+        K = min(p.batch, CALL_LANES)
+        exec_lanes = _build_exec(replace(p, batch=K), lookup=True)
+    else:
+        exec_lanes = _build_exec(p)
+    res_fields = _OCC_RES + (_OCC_CALL_RES if call else ())
+    shared = ("chainid_w",) + (_CALL_SHARED if call else ())
     if spec:
         from coreth_tpu.evm.device import specialize as SP
         spec_fns = tuple(SP.build_spec_exec(prog, p) for prog in spec)
@@ -999,6 +1554,27 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                   "start_gas", "callvalue", "caller_w", "address_w",
                   "origin_w", "gasprice_w", "timestamp", "number",
                   "gaslimit", "coinbase_w", "basefee_w")
+
+    def exec_chunked(exec_in, storage, run, res):
+        """Call windows: the lanes of `run`, in tx order, K at a time;
+        each chunk's results scattered into `res` (lanes not run keep
+        theirs).  The step's cost grows with the lanes it carries, and
+        after a block's first round a round re-executes few lanes."""
+        idx_all = jnp.nonzero(run, size=B + K, fill_value=B)[0]
+        nch = (jnp.sum(run.astype(jnp.int32)) + K - 1) // K
+
+        def chunk(ci, acc):
+            idx = jax.lax.dynamic_slice(idx_all, (ci * K,), (K,))
+            live = idx < B
+            idc = jnp.where(live, idx, 0)
+            ex = {k: (v[idc] if k in _LANE_KEYS else v)
+                  for k, v in exec_in.items()}
+            st = exec_lanes(ex, tuple(s[idc] for s in storage), live)
+            tgt = jnp.where(live, idx, B)
+            return {f: acc[f].at[tgt].set(st[f], mode="drop")
+                    for f in res_fields}
+
+        return jax.lax.fori_loop(0, nch, chunk, res)
 
     def exec_mixed(exec_in, storage, active, prog_id):
         """Per-lane program selection: generic interpreter for
@@ -1026,6 +1602,11 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
         def block_body(tbl, binp):
             exec_in = {k: binp[k] for k in _EXEC_KEYS}
             exec_in["chainid_w"] = chainid_w
+            if call:
+                exec_in.update({k: blocks_in[k] for k in _CALL_SHARED})
+                exec_in.update(cid0=binp["cid0"], aid0=binp["aid0"],
+                               g_tbl=tbl, g_key=key_tab)
+                aid_tab = blocks_in["g_aid"]
             # host-evaluated keccak digests for specialized lanes
             # (specialize.KDIG_CAP slots; direct callers without
             # specialized programs may omit the input)
@@ -1067,6 +1648,14 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                 log_dlen=jnp.zeros((B, p.log_cap), dtype=jnp.int32),
                 log_cnt=jnp.zeros((B,), dtype=jnp.int32),
             )
+            if call:
+                zb = jnp.zeros((B,), dtype=jnp.int32)
+                res0.update(
+                    saddr=jnp.zeros((B, S), dtype=jnp.int32),
+                    sgid=jnp.full((B, S), G, dtype=jnp.int32),
+                    log_aid=jnp.zeros((B, p.log_cap), dtype=jnp.int32),
+                    nframes=zb, lsteps=zb, fwhy=zb,
+                    cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32))
             carry0 = (
                 jnp.int32(0),                        # round
                 active0,                             # pending
@@ -1076,23 +1665,69 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                 jnp.zeros((B,), dtype=bool),         # escape
                 tbl,                                 # table after valid
             )
+            if call:
+                # a lane's slot ids grow with what its frames found in
+                # the table; a rerun is seeded with all of them
+                carry0 = carry0 + (sgid,)
 
             def occ_cond(c):
-                rnd, pending, _seeds, _res, _ok, escape, _t = c
+                rnd, pending, escape = c[0], c[1], c[5]
                 # any escape dirties the block: the host takes over, so
                 # burning more device rounds on it is pure waste
                 return (rnd < R) & jnp.any(pending) & ~jnp.any(escape)
 
             def occ_body(c):
-                rnd, pending, seeds, res, _ok, _esc, _t = c
-                st = exec_mixed(
-                    exec_in, (skey0, seeds, seeds, sflag0, nkeys),
-                    pending, prog_id)
-                res = {
-                    f: jnp.where(
-                        pending.reshape((B,) + (1,) * (res[f].ndim - 1)),
-                        st[f], res[f])
-                    for f in _OCC_RES}
+                rnd, pending, seeds, res = c[0], c[1], c[2], c[3]
+                if call:
+                    sg_in = c[7]
+                    pm_in = sg_in < G
+                    sk0 = jnp.where(pm_in[..., None], key_tab.at[sg_in].get(
+                        mode="fill", fill_value=0), 0)
+                    sa0 = jnp.where(pm_in, aid_tab.at[sg_in].get(
+                        mode="fill", fill_value=0), 0)
+                    storage = (sk0, seeds, seeds,
+                               jnp.where(pm_in, F_VALID, 0).astype(
+                                   jnp.int32),
+                               jnp.sum(pm_in.astype(jnp.int32), axis=1),
+                               sa0, sg_in)
+                else:
+                    storage = (skey0, seeds, seeds, sflag0, nkeys)
+                if call:
+                    # a pending lane that read a slot an EARLIER pending
+                    # lane wrote (both as last run) would read it stale
+                    # again: it waits a round (Block-STM's estimate
+                    # marks).  The first pending lane never waits, and
+                    # a waiting lane keeps its last results, which the
+                    # sweep below judges like any others.
+                    ent = jnp.arange(S)[None, :] < res["scnt"][:, None]
+                    g_prev = res["sgid"]
+                    lanes = jnp.broadcast_to(
+                        jnp.arange(B, dtype=jnp.int32)[:, None], (B, S))
+                    w_prev = ent & ((res["sflag"] & F_WRITTEN) != 0) \
+                        & (g_prev < G) & pending[:, None]
+                    first_w = jnp.full((G + 1,), B, jnp.int32).at[
+                        jnp.where(w_prev, g_prev, G).reshape(-1)].min(
+                        lanes.reshape(-1), mode="drop")
+                    r_prev = ent & ((res["sflag"] & F_READ) != 0) \
+                        & (g_prev < G)
+                    waits = pending & (res["status"] != HOST) & jnp.any(
+                        r_prev & (first_w.at[g_prev].get(
+                            mode="fill", fill_value=B) < lanes), axis=1)
+                    res = exec_chunked(exec_in, storage, pending & ~waits,
+                                       res)
+                else:
+                    st = exec_mixed(exec_in, storage, pending, prog_id)
+                    res = {
+                        f: jnp.where(
+                            pending.reshape(
+                                (B,) + (1,) * (res[f].ndim - 1)),
+                            st[f], res[f])
+                        for f in res_fields}
+                if call:
+                    sgid_r = res["sgid"]
+                    prem_r = sgid_r < G
+                else:
+                    sgid_r, prem_r = sgid, premapped
 
                 # sequential validation sweep ON DEVICE: walk lanes in
                 # tx order against the block-start table, committing
@@ -1104,20 +1739,33 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                                  axis=1)
                 hosty = (res["status"] == HOST) | missed
                 skip = res["status"] == SKIP
+                if call:
+                    # a frame's escape is only as good as the state it
+                    # read: a HOST lane whose reads the prefix has moved
+                    # (a callee that failed on stale reserves, say)
+                    # re-executes like any conflict; one whose reads
+                    # hold escapes.  A miss always escapes.
+                    stale_ok = (res["status"] == HOST) & ~missed
+                else:
+                    stale_ok = None
 
                 def val_body(j, vc):
                     t2, ok, pend2, seeds2, esc = vc
-                    cur = gather(t2, sgid[j])        # (S, 16)
+                    cur = gather(t2, sgid_r[j])      # (S, 16)
                     readf = entry[j] & ((res["sflag"][j] & F_READ) != 0) \
-                        & premapped[j]
+                        & prem_r[j]
                     match = jnp.all(res["sorig"][j] == cur, axis=-1)
                     reads_ok = jnp.all(~readf | match)
                     valid = ~skip[j] & ~hosty[j] & reads_ok
                     wr = entry[j] & ((res["sflag"][j] & F_WRITTEN) != 0) \
-                        & premapped[j] & valid & (res["status"][j] == STOP)
-                    gids_w = jnp.where(wr, sgid[j], G)
+                        & prem_r[j] & valid & (res["status"][j] == STOP)
+                    gids_w = jnp.where(wr, sgid_r[j], G)
                     t2 = t2.at[gids_w].set(res["sval"][j], mode="drop")
-                    repend = ~skip[j] & ~hosty[j] & ~reads_ok
+                    if call:
+                        repend = ~skip[j] & (~hosty[j] | stale_ok[j]) \
+                            & ~reads_ok
+                    else:
+                        repend = ~skip[j] & ~hosty[j] & ~reads_ok
                     # pending lanes re-execute against the prefix state
                     # at their position — `cur` before lane j's writes,
                     # exactly the host sweep's dict(state) snapshot
@@ -1125,7 +1773,12 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                         jnp.where(repend, cur, seeds2[j]))
                     ok = ok.at[j].set(valid)
                     pend2 = pend2.at[j].set(repend)
-                    esc = esc.at[j].set(hosty[j] & active0[j])
+                    if call:
+                        esc = esc.at[j].set(
+                            hosty[j] & ~(stale_ok[j] & ~reads_ok)
+                            & active0[j])
+                    else:
+                        esc = esc.at[j].set(hosty[j] & active0[j])
                     return (t2, ok, pend2, seeds2, esc)
 
                 # ---- vectorized validation fast path.  The B-step
@@ -1143,12 +1796,12 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                 # sweep, so conflicting blocks keep exact OCC
                 # semantics.
                 rflags = entry & ((res["sflag"] & F_READ) != 0) \
-                    & premapped
+                    & prem_r
                 pot_w = entry & ((res["sflag"] & F_WRITTEN) != 0) \
-                    & premapped \
+                    & prem_r \
                     & (~skip & ~hosty
                        & (res["status"] == STOP))[:, None]
-                gids_w_all = jnp.where(pot_w, sgid, G).reshape(-1)
+                gids_w_all = jnp.where(pot_w, sgid_r, G).reshape(-1)
                 nw = jnp.zeros((G + 1,), jnp.int32).at[gids_w_all].add(
                     1, mode="drop")
                 lane_ids = jnp.broadcast_to(
@@ -1156,9 +1809,9 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                 wlane = jnp.full((G + 1,), -1, jnp.int32).at[
                     gids_w_all].set(lane_ids.reshape(-1), mode="drop")
                 conflict = jnp.any(nw[:G] > 1) | jnp.any(
-                    rflags & (nw.at[sgid].get(mode="fill",
-                                              fill_value=0) > 0)
-                    & (wlane.at[sgid].get(mode="fill", fill_value=-1)
+                    rflags & (nw.at[sgid_r].get(mode="fill",
+                                                fill_value=0) > 0)
+                    & (wlane.at[sgid_r].get(mode="fill", fill_value=-1)
                        != lane_ids))
 
                 def fast_sweep(_):
@@ -1166,19 +1819,24 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                     # the block-start table: validate reads against
                     # it, apply all valid writes in one scatter, and
                     # mirror the sweep's pending/seed updates exactly
-                    cur0 = gather(tbl, sgid)
+                    cur0 = gather(tbl, sgid_r)
                     match0 = jnp.all(res["sorig"] == cur0, axis=-1)
                     reads_ok0 = jnp.all(~rflags | match0, axis=1)
                     valid0 = ~skip & ~hosty & reads_ok0
                     wr0 = pot_w & valid0[:, None]
                     t2f = tbl.at[
-                        jnp.where(wr0, sgid, G).reshape(-1)].set(
+                        jnp.where(wr0, sgid_r, G).reshape(-1)].set(
                         res["sval"].reshape(-1, LIMBS), mode="drop")
-                    pend0 = ~skip & ~hosty & ~reads_ok0
+                    if call:
+                        pend0 = ~skip & (~hosty | stale_ok) & ~reads_ok0
+                        esc0 = hosty & ~(stale_ok & ~reads_ok0)
+                    else:
+                        pend0 = ~skip & ~hosty & ~reads_ok0
+                        esc0 = hosty
                     seeds2f = jnp.where(pend0[:, None, None], cur0,
                                         seeds)
                     return (t2f, valid0, pend0, seeds2f,
-                            hosty & active0)
+                            esc0 & active0)
 
                 def slow_sweep(_):
                     return jax.lax.fori_loop(
@@ -1189,12 +1847,22 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
 
                 t2, ok, pend2, seeds2, esc = jax.lax.cond(
                     conflict, slow_sweep, fast_sweep, operand=None)
+                if call:
+                    # slot ids found this round first (premapped order),
+                    # misses last: the next round's seeded prefix
+                    order = jnp.argsort((sgid_r >= G).astype(jnp.int32),
+                                        axis=1, stable=True)
+                    return (rnd + 1, pend2,
+                            jnp.take_along_axis(seeds2, order[..., None],
+                                                axis=1),
+                            res, ok, esc, t2,
+                            jnp.take_along_axis(sgid_r, order, axis=1))
                 return (rnd + 1, pend2, seeds2, res, ok, esc, t2)
 
             # one OCC round's stable name in a device trace
             with jax.named_scope("coreth/occ_round"):
                 rnd, pending, _seeds, res, committed, escape, tbl_f = \
-                    jax.lax.while_loop(occ_cond, occ_body, carry0)
+                    jax.lax.while_loop(occ_cond, occ_body, carry0)[:7]
             # committed/escape/pending/rounds ride as 4 extra packed
             # columns so the host fetches ONE tensor per window
             extra = jnp.stack(
@@ -1209,7 +1877,7 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
             return tbl_f, out
 
         tbl_final, packed = jax.lax.scan(block_body, table, {
-            k: v for k, v in blocks_in.items() if k != "chainid_w"})
+            k: v for k, v in blocks_in.items() if k not in shared})
         return dict(table=tbl_final, packed=packed)
 
     return occ_run

@@ -58,6 +58,10 @@ FEATURE_OPS: Dict[int, str] = {
     0x54: "storage", 0x55: "storage",
     0x5C: "tstorage", 0x5D: "tstorage",
     0xA0: "log", 0xA1: "log", 0xA2: "log", 0xA3: "log", 0xA4: "log",
+    # value-0 call frames (a value-bearing CALL, a callee outside the
+    # window's code table and a callee's REVERT / failure go to the
+    # host: machine.R_FRAME)
+    0xF1: "call", 0xFA: "call", 0x3B: "call", 0x3D: "call", 0x3E: "call",
 }
 
 # Fork-introduced opcodes the device machine implements beyond the

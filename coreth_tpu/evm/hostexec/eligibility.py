@@ -37,7 +37,7 @@ NATIVE_BASE = frozenset(
     + list(range(0x10, 0x1E))      # LT..SAR
     + [0x20]                       # KECCAK256
     + [0x30, 0x32, 0x33, 0x34, 0x35, 0x36, 0x37, 0x38, 0x39, 0x3A]
-    + [0x3D, 0x3E]                 # RETURNDATASIZE RETURNDATACOPY
+    + [0x3B, 0x3D, 0x3E]           # EXTCODESIZE RETURNDATASIZE/COPY
     + [0x41, 0x42, 0x43, 0x44, 0x45, 0x46]  # COINBASE..CHAINID
     + list(range(0x50, 0x5C))      # POP..JUMPDEST
     + list(range(0x60, 0xA5))      # PUSHn DUPn SWAPn LOGn

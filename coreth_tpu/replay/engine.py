@@ -174,6 +174,13 @@ class ReplayStats:
     # scanned (the window runner counts; machine_block._chunk_loop)
     machine_lanes_real: int = 0
     machine_lanes_padded: int = 0
+    # call frames (evm/device/machine.py, feature "call"): frames the
+    # committed lanes entered on the device, and the device steps they
+    # ran; transactions a call frame handed to the host, by R_FRAME
+    # sub-reason (adapter.FRAME_REASONS, and "mesh")
+    machine_frames: int = 0
+    machine_lane_steps: int = 0
+    machine_frame_host_txs: Dict[str, int] = field(default_factory=dict)
     # blocks with atomic txs in their ExtData (plugin/evm import / export
     # txs): committed on the device path, their state transfer lanes
     # after the block's transactions, and the atomic txs they held; and
@@ -2732,6 +2739,12 @@ class ReplayEngine:
         engine-side analog of the blockchain.go timer metrics)."""
         from coreth_tpu.metrics import Gauge, get_or_register
         for name, value in self.stats.row().items():
+            if isinstance(value, dict):
+                # a count by reason: one gauge a reason
+                for k, v in value.items():
+                    get_or_register(f"{prefix}/{name}/{k}", Gauge,
+                                    registry).update(v)
+                continue
             get_or_register(f"{prefix}/{name}", Gauge,
                             registry).update(value)
         if self.flat is not None:

@@ -65,6 +65,27 @@ class TxPlan:
     code: bytes = b""
 
 
+def _put_writes(into: dict, pl: TxPlan, res) -> None:
+    """Every slot a lane wrote into ``into`` by (contract, key): its
+    root contract's and, under call frames, the other contracts'."""
+    for key, v in res.writes.items():
+        into[(pl.to, key)] = v
+    if res.xwrites:
+        into.update(res.xwrites)
+
+
+def _lane_reads(pl: TxPlan, res):
+    for key, v in res.reads.items():
+        yield (pl.to, key), v
+    yield from res.xreads.items()
+
+
+def _uses_frames(plans, fork: str) -> bool:
+    return any(pl.kind == "call"
+               and "call" in DT.scan_code(pl.code, fork).features
+               for pl in plans)
+
+
 class MachineBlockExecutor:
     """Owns classification + execution of machine blocks for one
     ReplayEngine (shares its tries and DeviceState mirrors).
@@ -103,6 +124,14 @@ class MachineBlockExecutor:
         self.windows = 0           # fused OCC windows completed
         self.window_attempts = 0   # dispatches those windows took
         self.dirty_blocks = 0      # blocks the fused path escalated
+        # call frames: frames entered and device steps run by the lanes
+        # the fused path committed; txs a frame handed to the host, by
+        # R_FRAME sub-reason (adapter.FRAME_REASONS; "mesh": a call-
+        # frame block on a mesh engine, which runs them on the host)
+        self.frames = 0
+        self.lane_steps = 0
+        self.frame_host: Dict[str, int] = {}
+        self._avoid: frozenset = frozenset()
         self.last_writes: Dict[Tuple[bytes, bytes], int] = {}
         # blocks fully finished+staged by the current _chunk_loop call
         # (read by execute_run's fault containment)
@@ -132,7 +161,21 @@ class MachineBlockExecutor:
         if r is not None:
             for k in out:
                 out[k] += getattr(r, k)
+        out.update(frames=self.frames, lane_steps=self.lane_steps,
+                   frame_host_txs=dict(self.frame_host))
         return out
+
+    def _count_frames(self) -> None:
+        """The call-frame counters into the engine's stats."""
+        st = self.e.stats
+        st.machine_frames = self.frames
+        st.machine_lane_steps = self.lane_steps
+        st.machine_frame_host_txs = dict(self.frame_host)
+
+    def _frame_host(self, why: Optional[str], n: int = 1) -> None:
+        if why is not None:
+            self.frame_host[why] = self.frame_host.get(why, 0) + n
+            self._count_frames()
 
     # ------------------------------------------------------------ classify
     def classify(self, block: Block) -> Optional[List[TxPlan]]:
@@ -148,6 +191,7 @@ class MachineBlockExecutor:
         base_fee = block.base_fee
         from coreth_tpu.evm.precompiles import special_call_targets
         avoid = special_call_targets(rules)
+        self._avoid = frozenset(avoid)
         plans: List[TxPlan] = []
         for tx in block.transactions:
             if tx.to is None or tx.access_list:
@@ -238,6 +282,8 @@ class MachineBlockExecutor:
                     for key, v in res.writes.items():
                         scratch.set_state(pl.to, key,
                                           v.to_bytes(32, "big"))
+                    for (c, key), v in res.xwrites.items():
+                        scratch.set_state(c, key, v.to_bytes(32, "big"))
                     scratch.finalise(True)
                 continue
             # solvency is validated later by the account sweep over
@@ -262,22 +308,30 @@ class MachineBlockExecutor:
             else:
                 status = M.ERR
             logs = []
+            log_addrs = []
             writes = {}
+            xwrites = {}
             if status == M.STOP:
                 logs = [([bytes(t) for t in lg.topics], bytes(lg.data))
                         for lg in scratch.logs[n_logs:]]
-                obj = scratch._objects.get(pl.to)
-                if obj is not None:
+                log_addrs = [bytes(lg.address)
+                             for lg in scratch.logs[n_logs:]]
+                # a call frame writes other contracts' storage too
+                for c, obj in scratch._objects.items():
                     for key in list(obj.dirty_storage):
-                        cur = scratch.get_state(pl.to, key,
-                                                _normalize=False)
-                        writes[key] = int.from_bytes(cur, "big")
+                        v = int.from_bytes(scratch.get_state(
+                            c, key, _normalize=False), "big")
+                        if c == pl.to:
+                            writes[key] = v
+                        else:
+                            xwrites[(c, key)] = v
             else:
                 del scratch.logs[n_logs:]
             scratch.finalise(True)
             results[i] = TxResult(
                 status=status, gas_left=gas_left, refund=0, logs=logs,
-                reads={}, writes=writes)
+                reads={}, writes=writes, xwrites=xwrites,
+                log_addrs=log_addrs)
             self.host_txs += 1
         # which executor actually served the suffix: EVM.call routes
         # eligible txs through the native backend (evm/hostexec bridge)
@@ -374,22 +428,27 @@ class MachineBlockExecutor:
                 for i, overlay in pending:
                     pl = plans[i]
                     storage = {}
+                    ext = {}
                     for (c, k), v in overlay.items():
                         if c == pl.to:
                             storage[k] = (v, v)
+                        else:
+                            ext[(c, k)] = (v, v)
                     specs.append(TxSpec(
                         code=pl.code, calldata=pl.data,
                         gas=pl.gas_limit - pl.intrinsic,
                         value=pl.value, caller=pl.sender,
                         address=pl.to, origin=pl.sender,
-                        gas_price=pl.price, storage=storage))
+                        gas_price=pl.price, storage=storage,
+                        ext_storage=ext))
 
                 def resolver(addr, key):
                     # per-batch resolver: misses fall to block-start
                     # state (overlay entries were preloaded in specs)
                     return base(addr, key)
 
-                runner = MachineRunner(self._fork, env, resolver)
+                runner = MachineRunner(self._fork, env, resolver,
+                                       code_resolver=self._callee_code)
                 batch = runner.run(specs)
                 for (i, _), res in zip(pending, batch):
                     results[i] = res
@@ -406,10 +465,10 @@ class MachineBlockExecutor:
                     e.stats.t_device += time.monotonic() - t0
                     return None
                 ok = True
-                for key, observed in res.reads.items():
-                    cur = state.get((pl.to, key))
+                for ck, observed in _lane_reads(pl, res):
+                    cur = state.get(ck)
                     if cur is None:
-                        cur = base(pl.to, key)
+                        cur = base(*ck)
                     if cur != observed:
                         ok = False
                         break
@@ -417,8 +476,7 @@ class MachineBlockExecutor:
                     pending.append((i, dict(state)))
                     continue
                 if res.status == M.STOP:
-                    for key, v in res.writes.items():
-                        state[(pl.to, key)] = v
+                    _put_writes(state, pl, res)
             if not pending:
                 break
             self.rounds += 1
@@ -500,11 +558,16 @@ class MachineBlockExecutor:
                 value_moves = res.status == M.STOP
                 logs = []
                 if status == 1:
-                    for topics, data in res.logs:
-                        logs.append(Log(address=pl.to, topics=topics,
-                                        data=data))
-                    for key, v in res.writes.items():
-                        writes_final[(pl.to, key)] = v
+                    addrs = res.log_addrs
+                    for j, (topics, data) in enumerate(res.logs):
+                        logs.append(Log(
+                            address=addrs[j] if addrs else pl.to,
+                            topics=topics, data=data))
+                    _put_writes(writes_final, pl, res)
+                    for c, _k in res.xwrites:
+                        # a callee's new storage root re-folds with its
+                        # account
+                        acct(c)
             s[1] += 1
             s[0] -= used * pl.price
             if value_moves:
@@ -752,7 +815,8 @@ class MachineBlockExecutor:
                     self._fork, self._base_value, e.mesh)
             else:
                 self._runner = MachineWindowRunner(
-                    self._fork, self._base_value)
+                    self._fork, self._base_value,
+                    code_resolver=self._callee_code)
             # the runner charges its own phases (machine/upload,
             # dispatch, fetch_wait) to this engine's account
             self._runner.account = e.account
@@ -760,6 +824,21 @@ class MachineBlockExecutor:
             self._runner_fork = self._fork
         self._runner_epoch = e.storage_epoch
         return self._runner
+
+    def _callee_code(self, addr: bytes) -> Optional[bytes]:
+        """A call frame's callee, for the window's code table: its
+        runtime code, b"" for an account without code, None for a
+        precompile, a prohibited address or a multicoin account (the
+        host takes a tx that calls one)."""
+        e = self.e
+        if addr in self._avoid or is_prohibited(addr):
+            return None
+        idx = e._account(addr)
+        if e.state.multicoin[idx]:
+            return None
+        if not e.state.has_code[idx]:
+            return b""
+        return e.db.contract_code(e.state.code_hashes[idx])
 
     def _window_items(self, chunk):
         """(BlockEnv, [TxSpec]) pairs for the call lanes of a chunk."""
@@ -841,6 +920,18 @@ class MachineBlockExecutor:
         if not bool(int(os.environ.get("CORETH_DEVICE_OCC", "1"))):
             block, plans = items[0]
             return 1 if self.execute(block, plans) is not None else 0
+        if getattr(self.e, "mesh", None) is not None:
+            # the mesh runner has no call frames: a block whose code
+            # makes them goes to the host path (counted), and a run
+            # stops before the first such block
+            for n, (_block, plans) in enumerate(items):
+                if _uses_frames(plans, self._fork):
+                    if n == 0:
+                        self._frame_host("mesh", sum(
+                            1 for pl in plans if pl.kind == "call"))
+                        return 0
+                    items = items[:n]
+                    break
         runner = self._window_runner()
         chunks = [items[k:k + self.WINDOW]
                   for k in range(0, len(items), self.WINDOW)]
@@ -968,8 +1059,7 @@ class MachineBlockExecutor:
                         writes: Dict[Tuple[bytes, bytes], int] = {}
                         for pl, res in zip(calls, wres.results[k]):
                             if res.status == M.STOP:
-                                for key, v in res.writes.items():
-                                    writes[(pl.to, key)] = v
+                                _put_writes(writes, pl, res)
                         runner.commit_block(writes)
                     pre_committed = True
                     if early is not None:
@@ -985,6 +1075,10 @@ class MachineBlockExecutor:
                         results = {i: wres.results[k][n]
                                    for n, i in enumerate(call_idx)}
                         self.rounds += max(0, wres.rounds[k] - 1)
+                        for r in wres.results[k]:
+                            self.frames += r.frames
+                            self.lane_steps += r.steps
+                        self._count_frames()
                         # deferred: the whole window's writes dedupe to
                         # last-value-per-(contract, slot) and fold in ONE
                         # batch per contract below, after the next
@@ -1005,6 +1099,8 @@ class MachineBlockExecutor:
                     # (execute() flushes the staged clean prefix first)
                     self.dirty_blocks += 1
                     obs.instant("machine/dirty_block", number=block.number)
+                    for r in wres.results[k]:
+                        self._frame_host(r.frame_why)
                     runner.invalidate()
                     root = self.execute(block, plans)
                     if root is None:

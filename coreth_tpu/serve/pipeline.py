@@ -792,6 +792,10 @@ class StreamingPipeline:
         return {"real": st.lanes_real, "padded": st.lanes_padded,
                 "machine_real": st.machine_lanes_real,
                 "machine_padded": st.machine_lanes_padded,
+                "machine_frames": st.machine_frames,
+                "machine_lane_steps": st.machine_lane_steps,
+                "machine_frame_host_txs": dict(
+                    st.machine_frame_host_txs),
                 "window_uploads": st.window_uploads,
                 "window_upload_bytes": st.window_upload_bytes,
                 "blocks_order_dependent": st.blocks_order_dependent,

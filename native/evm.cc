@@ -16,8 +16,8 @@
 // implements for AP2+ chains (EIP-2929 warm/cold with journaled access
 // sets, EIP-2200/3529 SSTORE ladder with the refund counter tracked,
 // quadratic memory, copy/log/keccak/exp word costs) plus nested
-// value-0 CALL/STATICCALL with EIP-150 63/64 forwarding and
-// RETURNDATASIZE/RETURNDATACOPY.  Anything outside that set (defined
+// value-0 CALL/STATICCALL with EIP-150 63/64 forwarding,
+// RETURNDATASIZE/RETURNDATACOPY and EXTCODESIZE.  Anything outside that set (defined
 // per fork but not compiled here: BALANCE, CREATE, DELEGATECALL,
 // value-carrying subcalls, precompile targets, ...) aborts the tx with
 // a HOST status so the caller re-runs it on the exact Python
@@ -754,6 +754,22 @@ FrameRes run_frame(Exec& X, const uint8_t* caller,
         stack.push_back(X.gasprice);
         if (stack.size() > 1024) { res.gas = 0; return res; }
         ++pc; continue;
+      case 0x3B: { NEED(1); USE(WARM_READ);                // EXTCODESIZE
+        // EIP-2929: the cold-account surcharge, as CALL pays it
+        std::string target = low20(stack.back());
+        int64_t cold = warm_addr_check(X, target)
+                           ? 0 : COLD_ACCOUNT - WARM_READ;
+        if (gas < cold) { res.gas = 0; return res; }
+        gas -= cold;
+        Contract* cc = nullptr;
+        int kind = lookup_code(X, target, &cc);
+        if (kind < 0) {
+          X.host_reason = op;
+          res.status = ST_HOST;
+          return res;
+        }
+        stack.back() = u256_from64(kind == 1 ? cc->code.size() : 0);
+        ++pc; continue; }
       case 0x3D: USE(G_QUICK);                             // RETURNDATASIZE
         stack.push_back(u256_from64(retdata.size()));
         if (stack.size() > 1024) { res.gas = 0; return res; }
@@ -1125,10 +1141,10 @@ void build_replay_optable(uint8_t* t) {
       0x00, 0x01, 0x02, 0x03, 0x04, 0x05, 0x06, 0x07, 0x08, 0x09,
       0x0A, 0x0B, 0x10, 0x11, 0x12, 0x13, 0x14, 0x15, 0x16, 0x17,
       0x18, 0x19, 0x1A, 0x1B, 0x1C, 0x1D, 0x20, 0x30, 0x32, 0x33,
-      0x34, 0x35, 0x36, 0x37, 0x38, 0x39, 0x3A, 0x3D, 0x3E, 0x41,
-      0x42, 0x43, 0x44, 0x45, 0x46, 0x48, 0x50, 0x51, 0x52, 0x53,
-      0x54, 0x55, 0x56, 0x57, 0x58, 0x59, 0x5A, 0x5B, 0x5F, 0xF1,
-      0xF3, 0xFA, 0xFD, 0xFE};
+      0x34, 0x35, 0x36, 0x37, 0x38, 0x39, 0x3A, 0x3B, 0x3D, 0x3E,
+      0x41, 0x42, 0x43, 0x44, 0x45, 0x46, 0x48, 0x50, 0x51, 0x52,
+      0x53, 0x54, 0x55, 0x56, 0x57, 0x58, 0x59, 0x5A, 0x5B, 0x5F,
+      0xF1, 0xF3, 0xFA, 0xFD, 0xFE};
   for (uint8_t op : ops) t[op] = OP_NATIVE;
   for (int op = 0x60; op <= 0xA4; ++op) t[op] = OP_NATIVE;
 }

@@ -53,6 +53,12 @@ from tools.lint.semconf import tree_claims
 SENDER = b"\x11" * 20
 CONTRACT = b"\xcc" * 20
 EOA = b"\xee" * 20
+# a contract in the pre-state of every leg, for the call family
+# (CALL / STATICCALL / EXTCODESIZE): stores a word in memory and
+# returns it — 32 bytes of return data
+CALLEE = b"\xca" * 20
+CALLEE_CODE = bytes([0x60, 0x2A, 0x60, 0x00, 0x52,
+                     0x60, 0x20, 0x60, 0x00, 0xF3])
 COINBASE = bytes.fromhex("0100000000000000000000000000000000000000")
 NUMBER, TIME = 5, 3_000
 GAS = 200_000
@@ -82,6 +88,7 @@ SPECIAL = {
     0x20: [(0, 32), (0, 0), (1, 64), (0, 1 << 64)],
     0x37: [(0, 0, 32), (1, 31, 7), (0, 0, 0)],
     0x39: [(0, 0, 16), (2, 1, 5), (0, 0, 0)],
+    0x3B: [(int.from_bytes(CALLEE, "big"),), (int.from_bytes(EOA, "big"),)],
     0x3E: [(0, 0, 0), (0, 0, 1)],            # 2nd: out-of-bounds err
     0x51: [(0,), (32,), (1 << 64,)],          # huge offset: OOG
     0x52: [(0, 7), (64, 1 << 255), (1 << 64, 1)],
@@ -96,9 +103,13 @@ SPECIAL = {
     0xA2: [(0, 32, 1, 2)],
     0xA3: [(0, 0, 1, 2, 3)],
     0xA4: [(0, 32, 1, 2, 3, 4)],
-    0xF1: [(60_000, int.from_bytes(EOA, "big"), 0, 0, 0, 0, 0)],
+    0xF1: [(60_000, int.from_bytes(CALLEE, "big"), 0, 0, 0, 0, 32),
+           (60_000, int.from_bytes(EOA, "big"), 0, 0, 0, 0, 0),
+           (60_000, int.from_bytes(CALLEE, "big"), 0, 0, 4, 8, 16)],
     0xF3: [(0, 0), (0, 32)],
-    0xFA: [(60_000, int.from_bytes(EOA, "big"), 0, 0, 0, 0)],
+    0xFA: [(60_000, int.from_bytes(CALLEE, "big"), 0, 0, 0, 32),
+           (60_000, int.from_bytes(EOA, "big"), 0, 0, 0, 0),
+           ((1 << 256) - 1, int.from_bytes(CALLEE, "big"), 0, 4, 8, 64)],
     0xFD: [(0, 0), (0, 32)],
 }
 
@@ -214,6 +225,7 @@ def interp_run(fork: str, code: bytes):
     db = Database()
     statedb = StateDB(EMPTY_ROOT, db)
     statedb.set_code(CONTRACT, code)
+    statedb.set_code(CALLEE, CALLEE_CODE)
     for k, v in STORAGE.items():
         statedb.set_state(CONTRACT, k, v.to_bytes(32, "big"))
     statedb.add_balance(SENDER, 10**18)
@@ -247,7 +259,8 @@ def native_run_all(fork: str, cases):
     def slots(_addr, key):
         return committed.get(key, b"\x00" * 32)
 
-    be = HostExecBackend(fork, ENV.chain_id, slots, lambda _a: b"")
+    be = HostExecBackend(fork, ENV.chain_id, slots,
+                         lambda a: CALLEE_CODE if a == CALLEE else b"")
     be.set_env(COINBASE, TIME, NUMBER, ENV.gas_limit, BASE_FEE)
     out = []
     try:
@@ -267,7 +280,9 @@ def device_run_all(fork: str, cases):
     committed = {normalize_state_key(k): v
                  for k, v in STORAGE.items()}
     runner = MachineRunner(fork, ENV,
-                           lambda _addr, key: committed.get(key, 0))
+                           lambda _addr, key: committed.get(key, 0),
+                           code_resolver=lambda a: CALLEE_CODE
+                           if a == CALLEE else b"")
     specs = [TxSpec(code=c.code, calldata=CALLDATA, gas=GAS, value=0,
                     caller=SENDER, address=CONTRACT, origin=SENDER,
                     gas_price=GAS_PRICE) for c in cases]
