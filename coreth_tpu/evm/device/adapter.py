@@ -258,13 +258,15 @@ class TxResult:
     host_reason: int = 0
     # call frames: the other contracts' slots, ((contract, key) ->
     # value); each log's emitting address (None: every log is the
-    # root's); frames entered and steps run on the device; an R_FRAME
+    # root's); frames entered and steps run on the device, and of the
+    # steps those the fused step ran (machine / fused.py); an R_FRAME
     # escape's sub-reason (FRAME_REASONS) once the host has named it
     xreads: Mapping[Tuple[bytes, bytes], int] = NO_SLOTS
     xwrites: Mapping[Tuple[bytes, bytes], int] = NO_SLOTS
     log_addrs: Optional[List[bytes]] = None
     frames: int = 0
     steps: int = 0
+    fused_steps: int = 0
     frame_why: Optional[str] = None
 
     @property
@@ -738,6 +740,7 @@ class PackedOut:
             self.lsteps = take(1)[:, 0]
             self.fwhy = take(1)[:, 0]
             self.cmiss = take(16)
+            self.fsteps = take(1)[:, 0]
         # address of each address id (set by the runner that packed the
         # lanes; call frames only)
         self.aid_addr: List[bytes] = []
@@ -1063,7 +1066,7 @@ def _results_call(out: PackedOut, rows, roots) -> List[TxResult]:
             writes=writes, host_reason=int(out.host_reason[i]),
             xreads=xreads, xwrites=xwrites, log_addrs=log_addrs,
             frames=int(out.nframes[i]), steps=int(out.lsteps[i]),
-            frame_why=why))
+            fused_steps=int(out.fsteps[i]), frame_why=why))
     return res
 
 
@@ -1080,6 +1083,9 @@ class WindowResult:
     clean: List[bool]
     rounds: List[int]                   # device OCC rounds per block
     attempts: int                       # dispatches this window took
+    # call windows: times the fused step returned to the XLA step, per
+    # block over its rounds (machine.build_occ_machine)
+    exits: List[int] = field(default_factory=list)
 
 
 class MachineWindowRunner:
@@ -2218,7 +2224,7 @@ class MachineWindowRunner:
             with self.account.enter("machine/fetch_wait"):
                 packed = np.asarray(handle["out"]["packed"])
                 self._on_result_fetch(handle)
-            pw = packed.shape[2] - 4
+            pw = packed.shape[2] - (5 if "call" in p.features else 4)
             pout = PackedOut(
                 packed[:, :, :pw].reshape(-1, pw), p)
             pout.aid_addr = self.callees.aid_addr
@@ -2271,6 +2277,7 @@ class MachineWindowRunner:
             break
         self._cold = False
         results, committed, escape, clean, rounds = [], [], [], [], []
+        exits = []
         for bi, (_env, specs) in enumerate(handle["items"]):
             slots = [self._lane_idx(handle, bi, li)
                      for li in range(len(specs))]
@@ -2296,10 +2303,12 @@ class MachineWindowRunner:
             escape.append(esc)
             clean.append(bool(com.all()) if slots else True)
             rounds.append(rnd)
+            exits.append(int(extra[bi, slots[0], 4])
+                         if pout.call and slots else 0)
         self._update_common(handle, pout, clean)
         return WindowResult(results=results, committed=committed,
                             escape=escape, clean=clean, rounds=rounds,
-                            attempts=handle["attempt"])
+                            attempts=handle["attempt"], exits=exits)
 
     def _update_common(self, handle, pout: PackedOut,
                        clean: List[bool]) -> None:

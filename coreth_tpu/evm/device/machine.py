@@ -219,11 +219,12 @@ _FIELDS = ("pc", "gas", "status", "sp", "refund", "steps", "stack",
 # and memory bases, calldata region, static flag); the return-data
 # buffer; the saved frames (`fs` ints, `fsw` words); the lane's warm
 # callee-table rows (EIP-2929); and what the host reads: frames
-# entered, steps run, the R_FRAME sub-reason, the unresolved callee.
+# entered, steps run, the R_FRAME sub-reason, the unresolved callee,
+# and of the steps those the fused step ran (fused.py).
 _CALL_FIELDS = ("saddr", "sgid", "taddr", "log_aid", "dep", "cid", "aid",
                 "addr_w", "caller_w", "sb", "mb", "cdb", "cdl", "stat",
                 "rd", "rdl", "fs", "fsw", "warm", "nframes", "lsteps",
-                "fwhy", "cmiss")
+                "fwhy", "cmiss", "fsteps")
 
 # one saved frame's int row (fs[lane, depth, k])
 (FS_PC, FS_GAS, FS_SP, FS_SB, FS_MB, FS_MSIZE, FS_CID, FS_AID, FS_CDB,
@@ -247,7 +248,8 @@ def slot_hash(key_lo, key_hi, aid):
 
 
 # corethlint: jit-factory — exec_lanes runs inside the jitted kernels
-def _build_exec(params: MachineParams, lookup: bool = False):
+def _build_exec(params: MachineParams, lookup: bool = False,
+                fused: bool = False):
     """Core lane executor shared by the single-shot machine
     (build_machine) and the device-resident OCC kernel
     (build_occ_machine): exec_lanes(inputs, storage, active) runs every
@@ -264,7 +266,13 @@ def _build_exec(params: MachineParams, lookup: bool = False):
     window's code table by row; a frame's storage entries carry its
     address id.  `lookup` (the OCC kernel) lets a storage access the
     lane's cache lacks find its row in the global slot table through
-    the hash index before it is recorded as a miss."""
+    the hash index before it is recorded as a miss.
+
+    `fused` (the call OCC kernel): the steps run as a loop of two
+    parts, the Pallas kernel of fused.py, which steps every lane's light
+    opcodes until each parks on one it leaves, then ONE step below for
+    the parked lanes; the same opcodes in the same order per lane, so
+    the same results.  ``st["fexits"]`` counts the kernel's returns."""
     p = params
     ot = T.op_tables(p.fork)
     CONST = jnp.asarray(ot.const_gas)
@@ -284,6 +292,9 @@ def _build_exec(params: MachineParams, lookup: bool = False):
         warm_cb = p.fork in COINBASE_WARM_FORKS          # EIP-3651
         COLD_X = (P.COLD_ACCOUNT_ACCESS_COST_EIP2929
                   - P.WARM_STORAGE_READ_COST_EIP2929)
+    if fused:
+        from coreth_tpu.evm.device import fused as F
+        fprepare, fkernel = F.build(p)
 
     def exec_lanes(inputs, storage, active):
         code = inputs["code"]
@@ -1371,10 +1382,42 @@ def _build_exec(params: MachineParams, lookup: bool = False):
                 fsw=jnp.zeros((B, FC, 2, LIMBS), dtype=jnp.int32),
                 warm=warm0 & ctab_ok[None, :],
                 nframes=z, lsteps=z, fwhy=z,
-                cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32))
-        final = jax.lax.while_loop(
-            cond, step, tuple(init[f] for f in fields))
+                cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32), fsteps=z)
+        carry0 = tuple(init[f] for f in fields)
+        if fused:
+            pre = fprepare(inputs)
+
+            def fstep(carry):
+                st = fkernel(dict(zip(fields, carry)), pre)
+                # the step bound is per lane: a lane's own step count is
+                # the lockstep count it would have stopped at
+                timed = (st["status"] == RUN) & (st["lsteps"] >= p.max_steps)
+                st["status"] = jnp.where(timed, HOST, st["status"])
+                st["host_reason"] = jnp.where(timed, R_STEPS,
+                                              st["host_reason"])
+                return tuple(st[f] for f in fields)
+
+            def more(carry):
+                return jnp.any(carry[fields.index("status")] == RUN)
+
+            def fbody(c):
+                # the kernel, then one XLA step for the lanes it parked:
+                # with none left it changes no lane (every update is
+                # masked by RUN), once an exec, and a cond around it
+                # would copy the whole carried state each iteration
+                carry = fstep(c[0])
+                return step(carry), c[1] + more(carry).astype(jnp.int32)
+
+            # the fused step's stable name in a device trace
+            with jax.named_scope("coreth/fused_step"):
+                final, exits = jax.lax.while_loop(
+                    lambda c: more(c[0]), fbody, (carry0, jnp.int32(0)))
+        else:
+            final = jax.lax.while_loop(cond, step, carry0)
+            exits = jnp.int32(0)
         st = dict(zip(fields, final))
+        if call:
+            st["fexits"] = exits
         # lanes still running at the step bound escape to host
         timed_out = st["status"] == RUN
         st["status"] = jnp.where(timed_out, HOST, st["status"])
@@ -1394,7 +1437,8 @@ def pack_result(B: int, st: dict):
     adapter downloads this single tensor instead of ~12 arrays.  The
     "call" feature appends its columns after the common layout:
     entry address ids, log address ids, frames entered, steps run,
-    the R_FRAME sub-reason and the unresolved callee's word."""
+    the R_FRAME sub-reason, the unresolved callee's word and the steps
+    the fused step ran."""
     cols = [
         st["status"][:, None], st["gas"][:, None],
         st["refund"][:, None], st["host_reason"][:, None],
@@ -1406,7 +1450,8 @@ def pack_result(B: int, st: dict):
         st["log_data"].reshape(B, -1)]
     if "saddr" in st:
         cols += [st["saddr"], st["log_aid"], st["nframes"][:, None],
-                 st["lsteps"][:, None], st["fwhy"][:, None], st["cmiss"]]
+                 st["lsteps"][:, None], st["fwhy"][:, None], st["cmiss"],
+                 st["fsteps"][:, None]]
     return jnp.concatenate(cols, axis=1)
 
 
@@ -1475,7 +1520,7 @@ _OCC_RES = ("status", "gas", "refund", "host_reason", "scnt", "sflag",
             "log_dlen", "log_cnt")
 # ... and under the "call" feature
 _OCC_CALL_RES = ("saddr", "sgid", "log_aid", "nframes", "lsteps", "fwhy",
-                 "cmiss")
+                 "cmiss", "fsteps")
 
 # per-lane inputs of a block's exec (leading axis: the lanes)
 _LANE_KEYS = ("code", "jdest", "code_len", "calldata", "data_len",
@@ -1490,7 +1535,7 @@ _CALL_SHARED = ("code_tab", "push_tab", "jdest_tab", "clen_tab",
 
 
 def build_occ_machine(params: MachineParams, occ: OccParams,
-                      spec: Tuple = ()):
+                      spec: Tuple = (), fused: bool = True):
     """Fused multi-block OCC kernel; returns
     occ_run(table, key_tab, blocks_in) -> dict.
 
@@ -1520,11 +1565,17 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
     and results merge by lane mask — the generic kernel IS the escape
     hatch for trace-ineligible code.
 
+    Under "call" a round steps its lanes through the fused step
+    (fused.py) and the XLA step in turn; `fused=False` keeps the XLA
+    step alone (the differential tests' reference).
+
     Returns {"table": (G,16), "packed": (W,B,PW+4)}: per-lane machine
     results in the pack_result layout plus 4 trailing columns —
-    committed / escape / pending / rounds.  Committed lanes validated
-    against the exact sequential prefix; escape lanes need host
-    attention (HOST status or a storage key outside the premap);
+    committed / escape / pending / rounds (and under "call" a fifth:
+    the block's fused-step exits, over its rounds and chunks).
+    Committed lanes validated against the exact sequential prefix;
+    escape lanes need host attention (HOST status or a storage key
+    outside the premap);
     pending lanes mean the round cap was hit (only reachable alongside
     escapes).  Blocks after the first dirty block computed against a
     speculative table — the adapter discards them.
@@ -1535,8 +1586,11 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
     assert not (call and spec), "specialized programs under 'call'"
     if call:
         # a call window steps CALL_LANES lanes at a time (see occ_body)
+        from coreth_tpu.evm.device import fused as F
         K = min(p.batch, CALL_LANES)
-        exec_lanes = _build_exec(replace(p, batch=K), lookup=True)
+        pk = replace(p, batch=K)
+        # the fused step while its lane state and tables fit SMEM
+        exec_lanes = _build_exec(pk, lookup=True, fused=fused and F.fits(pk))
     else:
         exec_lanes = _build_exec(p)
     res_fields = _OCC_RES + (_OCC_CALL_RES if call else ())
@@ -1555,15 +1609,17 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                   "origin_w", "gasprice_w", "timestamp", "number",
                   "gaslimit", "coinbase_w", "basefee_w")
 
-    def exec_chunked(exec_in, storage, run, res):
+    def exec_chunked(exec_in, storage, run, res, exits):
         """Call windows: the lanes of `run`, in tx order, K at a time;
         each chunk's results scattered into `res` (lanes not run keep
-        theirs).  The step's cost grows with the lanes it carries, and
-        after a block's first round a round re-executes few lanes."""
+        theirs), its fused-step exits added to `exits`.  The step's cost
+        grows with the lanes it carries, and after a block's first
+        round a round re-executes few lanes."""
         idx_all = jnp.nonzero(run, size=B + K, fill_value=B)[0]
         nch = (jnp.sum(run.astype(jnp.int32)) + K - 1) // K
 
-        def chunk(ci, acc):
+        def chunk(ci, c):
+            acc, n = c
             idx = jax.lax.dynamic_slice(idx_all, (ci * K,), (K,))
             live = idx < B
             idc = jnp.where(live, idx, 0)
@@ -1571,10 +1627,10 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                   for k, v in exec_in.items()}
             st = exec_lanes(ex, tuple(s[idc] for s in storage), live)
             tgt = jnp.where(live, idx, B)
-            return {f: acc[f].at[tgt].set(st[f], mode="drop")
-                    for f in res_fields}
+            return ({f: acc[f].at[tgt].set(st[f], mode="drop")
+                     for f in res_fields}, n + st["fexits"])
 
-        return jax.lax.fori_loop(0, nch, chunk, res)
+        return jax.lax.fori_loop(0, nch, chunk, (res, exits))
 
     def exec_mixed(exec_in, storage, active, prog_id):
         """Per-lane program selection: generic interpreter for
@@ -1655,7 +1711,8 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                     sgid=jnp.full((B, S), G, dtype=jnp.int32),
                     log_aid=jnp.zeros((B, p.log_cap), dtype=jnp.int32),
                     nframes=zb, lsteps=zb, fwhy=zb,
-                    cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32))
+                    cmiss=jnp.zeros((B, LIMBS), dtype=jnp.int32),
+                    fsteps=zb)
             carry0 = (
                 jnp.int32(0),                        # round
                 active0,                             # pending
@@ -1667,8 +1724,9 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
             )
             if call:
                 # a lane's slot ids grow with what its frames found in
-                # the table; a rerun is seeded with all of them
-                carry0 = carry0 + (sgid,)
+                # the table; a rerun is seeded with all of them; the
+                # fused-step exits of the block's rounds
+                carry0 = carry0 + (sgid, jnp.int32(0))
 
             def occ_cond(c):
                 rnd, pending, escape = c[0], c[1], c[5]
@@ -1713,8 +1771,8 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                     waits = pending & (res["status"] != HOST) & jnp.any(
                         r_prev & (first_w.at[g_prev].get(
                             mode="fill", fill_value=B) < lanes), axis=1)
-                    res = exec_chunked(exec_in, storage, pending & ~waits,
-                                       res)
+                    res, exits = exec_chunked(
+                        exec_in, storage, pending & ~waits, res, c[8])
                 else:
                     st = exec_mixed(exec_in, storage, pending, prog_id)
                     res = {
@@ -1856,20 +1914,24 @@ def build_occ_machine(params: MachineParams, occ: OccParams,
                             jnp.take_along_axis(seeds2, order[..., None],
                                                 axis=1),
                             res, ok, esc, t2,
-                            jnp.take_along_axis(sgid_r, order, axis=1))
+                            jnp.take_along_axis(sgid_r, order, axis=1),
+                            exits)
                 return (rnd + 1, pend2, seeds2, res, ok, esc, t2)
 
             # one OCC round's stable name in a device trace
             with jax.named_scope("coreth/occ_round"):
-                rnd, pending, _seeds, res, committed, escape, tbl_f = \
-                    jax.lax.while_loop(occ_cond, occ_body, carry0)[:7]
-            # committed/escape/pending/rounds ride as 4 extra packed
-            # columns so the host fetches ONE tensor per window
-            extra = jnp.stack(
-                [committed.astype(jnp.int32),
-                 escape.astype(jnp.int32),
-                 pending.astype(jnp.int32),
-                 jnp.broadcast_to(rnd, (B,))], axis=1)
+                cf = jax.lax.while_loop(occ_cond, occ_body, carry0)
+            rnd, pending, _seeds, res, committed, escape, tbl_f = cf[:7]
+            # committed/escape/pending/rounds (and the fused-step exits)
+            # ride as extra packed columns so the host fetches ONE
+            # tensor per window
+            extra = [committed.astype(jnp.int32),
+                     escape.astype(jnp.int32),
+                     pending.astype(jnp.int32),
+                     jnp.broadcast_to(rnd, (B,))]
+            if call:
+                extra.append(jnp.broadcast_to(cf[8], (B,)))
+            extra = jnp.stack(extra, axis=1)
             out = jnp.concatenate([pack_result(B, res), extra], axis=1)
             # tbl_f = block-start table + committed lanes' writes in tx
             # order; a dirty block taints every later block's base, but

@@ -176,10 +176,16 @@ class ReplayStats:
     machine_lanes_padded: int = 0
     # call frames (evm/device/machine.py, feature "call"): frames the
     # committed lanes entered on the device, and the device steps they
-    # ran; transactions a call frame handed to the host, by R_FRAME
+    # ran (of them, those the fused step ran and those the XLA step
+    # ran, and the times the fused step returned to the XLA step over
+    # the committed blocks' rounds: machine.py / fused.py);
+    # transactions a call frame handed to the host, by R_FRAME
     # sub-reason (adapter.FRAME_REASONS, and "mesh")
     machine_frames: int = 0
     machine_lane_steps: int = 0
+    machine_fused_lane_steps: int = 0
+    machine_xla_lane_steps: int = 0
+    machine_fused_exits: int = 0
     machine_frame_host_txs: Dict[str, int] = field(default_factory=dict)
     # blocks with atomic txs in their ExtData (plugin/evm import / export
     # txs): committed on the device path, their state transfer lanes

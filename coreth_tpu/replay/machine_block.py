@@ -125,11 +125,15 @@ class MachineBlockExecutor:
         self.window_attempts = 0   # dispatches those windows took
         self.dirty_blocks = 0      # blocks the fused path escalated
         # call frames: frames entered and device steps run by the lanes
-        # the fused path committed; txs a frame handed to the host, by
+        # the fused path committed (of those steps, the ones the fused
+        # step ran, and the times it returned to the XLA step over the
+        # clean blocks' rounds); txs a frame handed to the host, by
         # R_FRAME sub-reason (adapter.FRAME_REASONS; "mesh": a call-
         # frame block on a mesh engine, which runs them on the host)
         self.frames = 0
         self.lane_steps = 0
+        self.fused_lane_steps = 0
+        self.fused_exits = 0
         self.frame_host: Dict[str, int] = {}
         self._avoid: frozenset = frozenset()
         self.last_writes: Dict[Tuple[bytes, bytes], int] = {}
@@ -162,6 +166,9 @@ class MachineBlockExecutor:
             for k in out:
                 out[k] += getattr(r, k)
         out.update(frames=self.frames, lane_steps=self.lane_steps,
+                   fused_lane_steps=self.fused_lane_steps,
+                   xla_lane_steps=self.lane_steps - self.fused_lane_steps,
+                   fused_exits=self.fused_exits,
                    frame_host_txs=dict(self.frame_host))
         return out
 
@@ -170,6 +177,9 @@ class MachineBlockExecutor:
         st = self.e.stats
         st.machine_frames = self.frames
         st.machine_lane_steps = self.lane_steps
+        st.machine_fused_lane_steps = self.fused_lane_steps
+        st.machine_xla_lane_steps = self.lane_steps - self.fused_lane_steps
+        st.machine_fused_exits = self.fused_exits
         st.machine_frame_host_txs = dict(self.frame_host)
 
     def _frame_host(self, why: Optional[str], n: int = 1) -> None:
@@ -1075,9 +1085,12 @@ class MachineBlockExecutor:
                         results = {i: wres.results[k][n]
                                    for n, i in enumerate(call_idx)}
                         self.rounds += max(0, wres.rounds[k] - 1)
+                        if wres.exits:
+                            self.fused_exits += wres.exits[k]
                         for r in wres.results[k]:
                             self.frames += r.frames
                             self.lane_steps += r.steps
+                            self.fused_lane_steps += r.fused_steps
                         self._count_frames()
                         # deferred: the whole window's writes dedupe to
                         # last-value-per-(contract, slot) and fold in ONE

@@ -794,6 +794,9 @@ class StreamingPipeline:
                 "machine_padded": st.machine_lanes_padded,
                 "machine_frames": st.machine_frames,
                 "machine_lane_steps": st.machine_lane_steps,
+                "machine_fused_lane_steps": st.machine_fused_lane_steps,
+                "machine_xla_lane_steps": st.machine_xla_lane_steps,
+                "machine_fused_exits": st.machine_fused_exits,
                 "machine_frame_host_txs": dict(
                     st.machine_frame_host_txs),
                 "window_uploads": st.window_uploads,

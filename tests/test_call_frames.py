@@ -596,3 +596,302 @@ def test_frame_escapes_bit_exact_through_host():
     assert st.machine_frame_host_txs == want
     assert st.blocks_fallback == len(cases)
     assert st.machine_frames >= 2 * 2           # the plain blocks
+    # every committed lane step ran on one of the two paths
+    assert st.machine_fused_lane_steps + st.machine_xla_lane_steps \
+        == st.machine_lane_steps
+    assert st.machine_fused_lane_steps > st.machine_xla_lane_steps > 0
+    assert st.machine_fused_exits > 0
+
+
+# ------------------------------------ the fused step against the XLA step
+
+def _op(name):
+    return bytes([{"ADD": 0x01, "MUL": 0x02, "SUB": 0x03, "LT": 0x10,
+                   "GT": 0x11, "SLT": 0x12, "SGT": 0x13, "EQ": 0x14,
+                   "ISZERO": 0x15, "AND": 0x16, "OR": 0x17, "XOR": 0x18,
+                   "NOT": 0x19, "SHL": 0x1B, "SHR": 0x1C}[name]])
+
+
+def _pushn(v: int, n: int) -> bytes:
+    """PUSHn of v (n bytes, big endian; PUSH0 for n = 0)."""
+    return bytes([0x5F + n]) + v.to_bytes(n, "big") if n else b"\x5f"
+
+
+_EDGE = (0, 1, 2, 0xFFFF, 0x10000, 1 << 128, (1 << 255) - 1, 1 << 255,
+         (1 << 256) - 1, 255, 256, 257, 15, 16, 17, 300, 1 << 200)
+
+
+def _alu(rng) -> bytes:
+    """Binary and unary light ALU ops on edge and random operands of
+    every push length, each result stored."""
+    out = bytearray()
+    for r in range(10):
+        a, b = (rng.choice(_EDGE) if rng.random() < 0.6
+                else rng.getrandbits(rng.choice((8, 64, 200, 256)))
+                for _ in range(2))
+        for v in (b, a):
+            n = max((v.bit_length() + 7) // 8, rng.choice((0, 1, 32)))
+            out += _pushn(v, n) if v or n else b"\x5f"
+        op = rng.choice(("ADD", "MUL", "SUB", "LT", "GT", "SLT", "SGT", "EQ",
+                         "AND", "OR", "XOR", "SHL", "SHR", "ISZERO",
+                         "NOT"))
+        out += _op(op)
+        if op in ("ISZERO", "NOT"):
+            out += b"\x90\x50"                  # SWAP1 POP: drop b
+        out += _sstore(10 + r)
+    return bytes(out) + b"\x00"
+
+
+def _memory(rng) -> bytes:
+    """MSTORE / MSTORE8 / MLOAD at aligned and unaligned offsets,
+    CALLDATALOAD across and past the calldata's end, MSIZE."""
+    out = bytearray()
+    for _ in range(4):
+        off = rng.choice((0, 1, 2, 3, 5, 30, 33, 64 + 7, 96))
+        out += _push(rng.getrandbits(256)) + _push(off) + b"\x52"
+        out += _push(rng.getrandbits(8)) + _push(rng.randrange(140)) \
+            + b"\x53"
+    for r, off in enumerate(rng.sample((0, 1, 2, 3, 7, 30, 31, 60, 99), 5)):
+        out += _push(off) + b"\x51" + _sstore(30 + r)
+    for r, off in enumerate(rng.sample((0, 1, 3, 4, 5, 30, 33, 35, 36,
+                                        40, 1 << 40), 5)):
+        out += _push(off) + b"\x35" + _sstore(40 + r)
+    out += b"\x59" + _sstore(50) + b"\x36" + _sstore(51)
+    return bytes(out) + b"\x00"
+
+
+def _context() -> bytes:
+    """Every context read of the fused step, deep stack DUP16 / SWAP16,
+    and a counted JUMPI loop with a never-taken bad destination."""
+    out = bytearray()
+    for k, op in enumerate((0x30, 0x32, 0x33, 0x34, 0x36, 0x38, 0x3D, 0x42,
+                            0x43, 0x58, 0x59, 0x5A)):
+        out += bytes([op]) + _sstore(60 + k)
+    out += b"".join(_push(v + 1) for v in range(17))
+    out += b"\x8f\x9f" + _sstore(80) + _sstore(81)   # DUP16 SWAP16
+    out += b"\x50" * 15
+    out += _push(0) + _push(3) + b"\x57"             # JUMPI not taken
+    loop = len(out) + 2                               # after PUSH1 0
+    out += _push(0) + b"\x5b" + _push(1) + b"\x01" + b"\x80" + _push(10) \
+        + b"\x11" + _push(loop) + b"\x57" + _sstore(82)
+    return bytes(out) + b"\x00"
+
+
+def _fused_cases(base: int):
+    """Lanes for the fused step's differential: the light opcode
+    families on their edges, lanes that park on a failing light op
+    (bad jump, out of gas, a stack or memory limit), a lane that parks
+    at its first op, one of light ops alone, a frame that returns into
+    its parent mid-stream.  Labels name the test case that reads them."""
+    rng = random.Random(0xF05ED)
+    out = []
+
+    def one(label, code, gas=300_000, callee=None):
+        addrs = _addrs(base + len(out))
+        codes = {addrs[0]: code}
+        codes[addrs[1]] = callee or assemble([("return", 32)], addrs)
+        out.append(Case(label, codes, addrs[0], gas))
+
+    for j in range(6):
+        one(f"light_ops/alu{j}", _alu(rng))
+    for j in range(4):
+        one(f"light_ops/mem{j}", _memory(rng))
+    one("light_ops/context", _context())
+    # EIP-2200 / 3529's ladder on a cached slot (1, pre-state 5) and a
+    # fresh one (40): clear, restore, dirty, no-op, set then clear
+    one("light_ops/storage", b"".join(
+        _push(v) + _sstore(k) for k, v in ((1, 0), (1, 5), (1, 7), (1, 7),
+                                           (40, 3), (40, 0), (40, 0)))
+        + _push(40) + b"\x54" + _sstore(41)
+        + _push(1) + b"\x54" + _sstore(42) + b"\x00")
+    loop = b"\x5b" + _push(1) + b"\x50" + _push(0) + b"\x56"
+    one("mixed_chunk/bad_jump", _push(3) + b"\x56\x00")
+    one("mixed_chunk/oog", loop, gas=3_000)
+    one("mixed_chunk/stack_cap", _push(1) * 70 + b"\x00")
+    one("mixed_chunk/mem_cap", _push(1) + _push(4090) + b"\x52\x00")
+    one("mixed_chunk/mem_oog", _push(1) + _push(1 << 30) + b"\x52\x00")
+    one("mixed_chunk/sloads", b"".join(
+        _push(1) + b"\x54" + _push(k) + b"\x01" + _sstore(90 + k)
+        for k in range(6)) + b"\x00")
+    one("mixed_chunk/sentry", _push(1) + _sstore(1) + b"\x00", gas=2_300)
+    one("mixed_chunk/keccak", b"".join(
+        _push(32) + _push(0) + b"\x20" + _sstore(60 + k)
+        for k in range(3)) + b"\x00")
+    one("park_first", b"\x46" + _sstore(91) + _alu(rng))   # CHAINID
+    one("light_only", _push(7) + _push(9) + b"\x01" + _push(64) + b"\x52"
+        + _push(64) + b"\x51" + b"\x50" + b"\x00")
+    addrs = _addrs(base + len(out))
+    child = (_push(0) + b"\x35" + _push(2) + b"\x01" + _push(0) + b"\x52"
+             + _push(0x77) + _push(32) + b"\x52"
+             + _push(64) + _push(0) + b"\xf3")
+    root = (_push(0xABC) + _push(0) + b"\x52"
+            + _push(64) + _push(0x100) + _push(32) + _push(0) + _push(0)
+            + _push(int.from_bytes(addrs[1], "big")) + b"\x5a\xf1"
+            + b"\x3d" + _push(0x100) + b"\x51" + b"\x01"
+            + _push(0x120) + b"\x51" + b"\x01" + _sstore(95) + b"\x00")
+    out.append(Case("return_mid_stream", {addrs[0]: root, addrs[1]: child},
+                    addrs[0], 300_000))
+    return out
+
+
+def occ_run_all(cases, fused=True, max_steps=None):
+    """Every case a lane of ONE block through the call OCC kernel (the
+    window runner's path), with the fused step or the XLA step alone."""
+    from dataclasses import replace as _replace
+    import jax
+    from coreth_tpu.evm.device.adapter import MachineWindowRunner
+    committed, codes = {}, {}
+    for c in cases:
+        committed.update(_pre(c))
+        codes.update(c.codes)
+    runner = MachineWindowRunner(
+        FORK, lambda a, k: committed.get((a, k), 0),
+        code_resolver=lambda a: None if a == IDENTITY
+        else codes.get(a, b""))
+    for a in codes:
+        runner.callees.learn(a)
+    if max_steps is not None:
+        bucket = runner._occ_params
+        runner._occ_params = lambda items, pre: (lambda p, o: (
+            _replace(p, max_steps=max_steps), o))(*bucket(items, pre))
+    if not fused:
+        built = {}
+
+        def kernel(p, occ, sk=None):
+            if (p, occ) not in built:
+                built[(p, occ)] = jax.jit(
+                    M.build_occ_machine(p, occ, (), fused=False),
+                    donate_argnums=(0,))
+            return built[(p, occ)]
+        runner._kernel = kernel
+    specs = [TxSpec(code=c.codes[c.root], calldata=CALLDATA, gas=c.gas,
+                    value=0, caller=SENDER, address=c.root, origin=SENDER,
+                    gas_price=GAS_PRICE) for c in cases]
+    w = runner.complete(runner.issue([(ENV, specs)]))
+    return w.results[0], w
+
+
+def _lane_view(r):
+    return (r.status, r.gas_left, r.refund, r.host_reason, r.reads,
+            r.writes, dict(r.xreads), dict(r.xwrites), r.logs, r.log_addrs,
+            r.frames, r.steps, r.frame_why)
+
+
+@pytest.fixture(scope="module")
+def fused_legs():
+    cases = corpus() + _fused_cases(500)
+    fused, wf = occ_run_all(cases)
+    xla, wx = occ_run_all(cases, fused=False)
+    oracle = [interp_run(c) for c in cases]
+    return cases, fused, xla, oracle, (wf, wx)
+
+
+@pytest.fixture(scope="module")
+def step_bound_legs():
+    """Lanes that loop forever under a 60-step bound: one of light ops
+    alone, one that leaves the fused step every few ops (CHAINID); and
+    a call tree beside them, which makes the window a call window."""
+    addrs = _addrs(700)
+    light = b"\x5b" + _push(1) + b"\x50" + _push(0) + b"\x56"
+    mixed = b"\x5b\x46\x50" + _push(0) + b"\x56"
+    cases = [Case("light_loop", {addrs[0]: light}, addrs[0], 300_000),
+             Case("mixed_loop", {addrs[2]: mixed}, addrs[2], 300_000),
+             Case("call_tree", {
+                 addrs[3]: assemble([("call", "call", 4, "all", 0, 32)],
+                                    addrs),
+                 addrs[4]: assemble([("return", 32)], addrs)},
+                 addrs[3], 300_000)]
+    fused, wf = occ_run_all(cases, max_steps=60)
+    xla, _wx = occ_run_all(cases, fused=False, max_steps=60)
+    return cases, fused, xla, wf
+
+
+def _device_view(c, r):
+    writes = {(c.root, k): v for k, v in r.writes.items()}
+    writes.update(r.xwrites)
+    addrs = r.log_addrs or [c.root] * len(r.logs)
+    logs = [(a, list(t), d) for a, (t, d) in zip(addrs, r.logs)]
+    return (r.status, r.gas_left, r.refund, writes, logs)
+
+
+@pytest.mark.parametrize("which", [
+    "corpus", "light_ops", "mixed_chunk", "park_first", "step_bound",
+    "return_mid_stream"])
+def test_fused_step_matches_the_xla_step(request, which):
+    """The call OCC kernel with its light opcodes in the fused step
+    (Pallas, in interpret mode off the chip) against the same kernel
+    with the XLA step alone, lane by lane — status, gas, host_reason,
+    storage entries read and written, logs, frames, steps — and, on
+    every lane the device answers, against the host interpreter."""
+    if which == "step_bound":
+        cases, fused, xla, wf = request.getfixturevalue("step_bound_legs")
+        for c, rf, rx in zip(cases, fused, xla):
+            assert _lane_view(rf) == _lane_view(rx), c.label
+        for c, rf in zip(cases[:2], fused):
+            # the bound is per lane, across the kernel's returns
+            assert (rf.status, rf.host_reason, rf.steps) == (
+                M.HOST, M.R_STEPS, 60), c.label
+        assert fused[0].fused_steps == 60            # light ops alone
+        # CHAINID at steps 2, 7, ..., 57: twelve XLA steps, one for
+        # each of the kernel's returns
+        assert fused[1].fused_steps == 48 and wf.exits == [12]
+        return
+    cases, fused, xla, oracle, (wf, wx) = request.getfixturevalue(
+        "fused_legs")
+    picked = [k for k, c in enumerate(cases)
+              if (which == "corpus" and "/" not in c.label and c.label
+                  not in ("park_first", "light_only", "return_mid_stream"))
+              or c.label.split("/")[0] == which]
+    assert picked, which
+    bad = []
+    for k in picked:
+        c, rf, rx = cases[k], fused[k], xla[k]
+        if _lane_view(rf) != _lane_view(rx):
+            bad.append(f"{c.label}: fused {_lane_view(rf)} != "
+                       f"xla {_lane_view(rx)}")
+        elif rf.status != M.HOST and not _agree(
+                _device_view(c, rf), oracle[k], _pre(c)):
+            bad.append(f"{c.label}: fused {_device_view(c, rf)} != "
+                       f"interpreter {oracle[k]}")
+        # every lane step ran on one of the two paths
+        assert 0 <= rf.fused_steps <= rf.steps and rx.fused_steps == 0
+    assert not bad, "\n".join(bad)
+    assert wf.committed[0].tolist() == wx.committed[0].tolist()
+    assert wf.rounds == wx.rounds and wx.exits == [0]
+    views = [_lane_view(fused[k]) for k in picked]
+    # the XLA step's share of each lane: what the fused step leaves
+    xla_steps = [fused[k].steps - fused[k].fused_steps for k in picked]
+    if which == "light_ops":
+        # the cached SLOADs and SSTOREs included, nothing leaves
+        assert all(v[0] == M.STOP for v in views)
+        assert xla_steps == [0] * len(picked)
+    elif which == "mixed_chunk":
+        # errors the fused step parked on, decided by the XLA step (the
+        # sentry's SSTORE among them), the KECCAK256s it leaves
+        assert [v[0] for v in views] == [M.ERR, M.ERR, M.HOST, M.HOST,
+                                          M.ERR, M.STOP, M.ERR, M.STOP]
+        assert [v[3] for v in views][2:4] == [M.R_STACK, M.R_MEM]
+        assert xla_steps == [1, 1, 1, 1, 1, 0, 1, 3]
+    elif which == "park_first":
+        # CHAINID alone
+        assert views[0][0] == M.STOP and xla_steps == [1]
+    elif which == "return_mid_stream":
+        # the CALL and the callee's RETURN into its parent
+        assert views[0][0] == M.STOP and views[0][10] == 1
+        assert views[0][5] and xla_steps == [2]
+
+
+def test_light_ops_alone_take_no_xla_step(fused_legs):
+    """A lane of light opcodes alone, STOP included, never leaves the
+    fused step; the token window's kernel builds no fused step."""
+    cases, fused, _xla, _oracle, _w = fused_legs
+    (r,) = [fused[k] for k, c in enumerate(cases) if c.label == "light_only"]
+    assert r.status == M.STOP and r.steps == r.fused_steps == 9
+    import jax
+    from coreth_tpu.evm.device.adapter import MachineWindowRunner
+    from coreth_tpu.workloads.erc20 import TOKEN_RUNTIME, transfer_calldata
+    p, occ = _window_params(TOKEN_RUNTIME,
+                            transfer_calldata(b"\x22" * 20, 5))
+    args = MachineWindowRunner(FORK, lambda a, k: 0)._warm_args(p, occ)
+    text = str(jax.make_jaxpr(M.build_occ_machine(p, occ))(*args))
+    assert "pallas_call" not in text and "fused_step" not in text

@@ -235,6 +235,33 @@ def test_occ_machine_donates_its_table(one_chip, lanes, table_cap, spec):
     assert mem.alias_size_in_bytes >= table_cap * 16 * 4, mem
 
 
+def test_call_kernel_steps_in_one_mosaic_kernel(one_chip, monkeypatch):
+    """The call OCC kernel at the Uniswap cell's shape (a gas-full
+    block of swaps buckets to 256 lanes, stepped 32 at a time; the
+    router, pair and token codes' features, the longest 1,237 bytes):
+    its light opcodes step in ONE Pallas kernel, and Mosaic lowers it —
+    the only check of that lowering that needs no chip.  The kernel's
+    lane state has to fit the chip's scalar memory."""
+    from coreth_tpu.evm.device import fused as F
+    # the program picks interpret mode off the TPU; here the compile is
+    # for a described chip, so the test asks for the chip's kernel
+    monkeypatch.setattr(F, "interpret", lambda: False)
+    p = M.MachineParams(
+        fork=FORK, batch=256, code_cap=2048, data_cap=512, scache_cap=32,
+        features=frozenset(
+            "call div keccak log mul shift storage".split()),
+        frame_cap=4, callee_cap=128, code_rows=4)
+    assert F.fits(M.replace(p, batch=M.CALL_LANES))
+    occ = M.OccParams(blocks=1, table_cap=4096, rounds=p.batch + 1)
+    runner = MachineWindowRunner(FORK, lambda _c, _k: 0)
+    args = _like(runner._warm_args(p, occ), one_chip)
+    compiled = jax.jit(M.build_occ_machine(p, occ),
+                       donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "coreth_fused_step" in text
+
+
 # ------------------------------------------------------------ four chips
 @pytest.mark.parametrize("mode,expect", [
     ("psum", "all-reduce"), ("ppermute", "collective-permute")])
